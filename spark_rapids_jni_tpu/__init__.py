@@ -32,9 +32,12 @@ one-exchange broadcast q3).
 
 Pallas posture: the shipped hot paths are XLA-emitted (the measured hot
 spots are layout transforms, scans, sorts, and gathers the compiler
-already fuses; scatter-heavy forms were redesigned scatter-free —
-BASELINE.md); one experimental Pallas kernel (ops/pallas_q1.py) probes
-the residual headroom.
+already fuses; scatter-heavy forms were redesigned scatter-free after a
+v5e reading in 2026-07 put scatters 1.6-4x behind the scan forms). The
+maintained kernel tier (ops/pallas/, ``kernels.tier``, default ``xla``)
+holds the Pallas kernels registered in ``ops.pallas.registered()``, each
+with its XLA bit-identity oracle; ``chip_smoke.py`` compiles every one
+natively and compares it with its oracle on the chip.
 
 Layer map (TPU equivalent of reference SURVEY.md section 1):
   L4' Java API parity sources  -> java/ (build-gated; no JVM in this image)
@@ -55,10 +58,18 @@ import os as _os
 # initialize any jax backend — only config updates. Callers pin the platform
 # (utils.platform.force_cpu_platform) AFTER importing us; a module-level
 # array/device query anywhere in the import graph would break that.
-if not _os.environ.get("SPARK_RAPIDS_TPU_NO_X64"):
-    import jax as _jax
+import jax as _jax
 
+if not _os.environ.get("SPARK_RAPIDS_TPU_NO_X64"):
     _jax.config.update("jax_enable_x64", True)
+
+# The persistent compile cache: where JAX_COMPILATION_CACHE_DIR is set JAX
+# has already read it and that is the whole mechanism; otherwise the cache
+# goes to the one fixed path (utils/config.cache_dir()).
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    from spark_rapids_jni_tpu.utils.config import FIXED_CACHE_DIR as _FIXED
+
+    _jax.config.update("jax_compilation_cache_dir", _FIXED)
 
 from spark_rapids_jni_tpu.types import DType, TypeId  # noqa: E402
 from spark_rapids_jni_tpu.columnar import Column, Table  # noqa: E402

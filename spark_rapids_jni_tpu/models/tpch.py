@@ -215,6 +215,19 @@ _Q1_RF_DOMAIN = (ord("A"), ord("N"), ord("R"))
 _Q1_LS_DOMAIN = (ord("F"), ord("O"))
 
 
+def _q1_planned_plan() -> fusion.Plan:
+    """q1 with planner-declared flag domains as ONE fusible region:
+    filter+derive -> bounded-domain groupby (no sort, static order)."""
+    from spark_rapids_jni_tpu.ops.planner import scalar_domain
+
+    return fusion.Plan("tpch_q1_planned", fusion.GroupBy(
+        fusion.Project(fusion.Scan("lineitem"), _q1_work_table),
+        (0, 1), tuple(_Q1_AGGS),
+        domains=(scalar_domain(_Q1_RF_DOMAIN),
+                 scalar_domain(_Q1_LS_DOMAIN)),
+        label="plan"))
+
+
 @func_range("tpch_q1_planned_result")
 def tpch_q1_planned_result(lineitem: Table):
     """q1 with PLANNER-DECLARED key domains: the flag domains come from
@@ -227,15 +240,9 @@ def tpch_q1_planned_result(lineitem: Table):
     checked and unchecked wrappers below. Lowered through the general
     planner facility (ops/planner.plan_groupby) — q1 is just the first
     client of the declared-domain plan, not a special case."""
-    from spark_rapids_jni_tpu.ops.planner import PlannedGroupBy, scalar_domain
+    from spark_rapids_jni_tpu.ops.planner import PlannedGroupBy
 
-    plan = fusion.Plan("tpch_q1_planned", fusion.GroupBy(
-        fusion.Project(fusion.Scan("lineitem"), _q1_work_table),
-        (0, 1), tuple(_Q1_AGGS),
-        domains=(scalar_domain(_Q1_RF_DOMAIN),
-                 scalar_domain(_Q1_LS_DOMAIN)),
-        label="plan"))
-    out = fusion.execute(plan, {"lineitem": lineitem})
+    out = fusion.execute(_q1_planned_plan(), {"lineitem": lineitem})
     res = PlannedGroupBy(out.table, out.meta["plan.present"],
                          out.meta["plan.domain_miss"],
                          out.meta["plan.lowered"],
@@ -306,6 +313,12 @@ def _q6_reduce(lineitem: Table, row_valid) -> Table:
     return Table([Column(t.decimal64(-4), total, any_row)])
 
 
+def _q6_plan() -> fusion.Plan:
+    """q6 as a one-node fused region: the masked multiply-accumulate."""
+    return fusion.Plan("tpch_q6", fusion.Project(
+        fusion.Scan("lineitem"), _q6_reduce, rowwise=False))
+
+
 @func_range("tpch_q6")
 def tpch_q6(lineitem: Table) -> Column:
     """TPC-H q6: SELECT sum(l_extendedprice * l_discount) WHERE shipdate
@@ -324,9 +337,8 @@ def tpch_q6(lineitem: Table) -> Column:
 
     Returns a 1-row DECIMAL64(scale -4) column (null iff no row matched).
     """
-    plan = fusion.Plan("tpch_q6", fusion.Project(
-        fusion.Scan("lineitem"), _q6_reduce, rowwise=False))
-    return fusion.execute(plan, {"lineitem": lineitem}).table.column(0)
+    return fusion.execute(
+        _q6_plan(), {"lineitem": lineitem}).table.column(0)
 
 
 def tpch_q6_numpy(lineitem: Table) -> int:
@@ -856,7 +868,8 @@ def tpch_q3_planned(customer: Table, orders: Table, lineitem: Table,
     TPC-H DDL + load-order facts). Both joins collapse to arithmetic +
     gather — the join phase compiles with ZERO sorts (HLO-pinned in
     tests), where the general q3 pays two build-side lexsorts + probe
-    searchsorteds on the 230 ns/row machinery (BASELINE.md). The
+    searchsorteds on the sort-based machinery (~230 ns/row for general
+    q1 on a v5e in 2026-07, before the runtime stack; not measured since). The
     orderkey groupby stays on the general (sort-based) path: its
     cardinality is data-dependent, which is exactly the boundary of
     what a planner can declare.

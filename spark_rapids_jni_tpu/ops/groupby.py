@@ -98,8 +98,9 @@ def _rows_equal_prev(table: Table, keys: Sequence[int]) -> jnp.ndarray:
 # smaller than the scan it replaces — see the gate in groupby_aggregate)
 # the boundary machinery switches from full-length scans to block-level
 # reductions (see _group_starts / _boundary_prefix): a cumsum over n rows
-# is latency-bound on the TPU (measured 68ms for 4M int64 lanes, ~0.9 GB/s
-# effective — BASELINE.md), while a block-sum pass is bandwidth-bound and
+# is latency-bound on the TPU (68ms for 4M int64 lanes, ~0.9 GB/s effective,
+# on a v5e in 2026-07, before the runtime stack; not measured since), while
+# a block-sum pass is bandwidth-bound and
 # the per-boundary partials are O(m * block).
 _SMALL_M = 1024
 _MIN_BLOCK = 32
@@ -212,7 +213,8 @@ def _segmented_extremum(vv: jnp.ndarray, seg_start: jnp.ndarray,
     is the segmented-reduce monoid (associative), so
     ``lax.associative_scan`` compiles it to a log-depth scan — replacing
     ``jax.ops.segment_min/max``, whose scatter formulation serializes on
-    the TPU (BASELINE.md measured 1.6-4x against scan forms). Read the
+    the TPU (1.6-4x behind the scan forms on a v5e in 2026-07; not
+    measured since). Read the
     per-group result at each group's last row."""
     pick = jnp.minimum if op == "min" else jnp.maximum
 
@@ -495,8 +497,9 @@ def _dense_group_bounds(group_id: jnp.ndarray | None, n: int,
                         m: int) -> tuple:
     """(num_groups, g_lo, g_hi) from sorted dense group ids: every
     per-group boundary is a binary search, not a scatter — scatters
-    serialize on the TPU (measured 4x slower than the scan/searchsorted
-    formulation at 4M rows on v5e; BASELINE.md). ``group_id`` is None
+    serialize on the TPU (4x slower than the scan/searchsorted
+    formulation at 4M rows on a v5e in 2026-07, before the runtime stack;
+    not measured since). ``group_id`` is None
     only when n == 0."""
     garange = jnp.arange(m, dtype=jnp.int32)
     if group_id is None or n == 0:
@@ -1609,8 +1612,9 @@ def groupby_aggregate_bounded(
     zero scan, zero scatter — one streaming pass.
 
     The general groupby's cost on TPU is the key sort + row gather +
-    boundary machinery (BASELINE.md: sort 55 ms + gather 32 ms of the
-    ~280 ms q1 iteration at 4M rows). When the planner knows each key
+    boundary machinery (sort 55 ms + gather 32 ms of the ~280 ms q1
+    iteration at 4M rows on a v5e in 2026-07, before the runtime stack;
+    not measured since). When the planner knows each key
     column's candidate values (dictionary stats; CHAR(1) flag domains in
     TPC-H q1), dense group ids come from a searchsorted against the tiny
     sorted domain and every aggregate is a masked whole-column reduction

@@ -39,6 +39,7 @@ __all__ = [
     "TierDecision",
     "register_kernel",
     "registered",
+    "block_index",
     "decide",
     "fall_back",
     "resolved_tier",
@@ -159,6 +160,17 @@ def fall_back(op: str, reason: str) -> TierDecision:
     decision = TierDecision("xla", "oracle", reason)
     record_kernel_tier(op, tier="xla", mode="oracle", reason=reason)
     return decision
+
+
+def block_index(i, *_scalar_prefetch) -> tuple:
+    """The index_map every kernel here uses: grid step ``i`` -> block
+    ``(i, 0, 0)``. The zeros are explicit int32 — under x64 a Python int
+    traces as int64, and Mosaic cannot legalize an int64 in an index map
+    ("failed to legalize operation 'func.return' ... (i32, i64, i64)")."""
+    import jax.numpy as jnp
+
+    zero = jnp.int32(0)
+    return (i, zero, zero)
 
 
 def kernels_digest() -> tuple:

@@ -33,7 +33,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from spark_rapids_jni_tpu.ops.pallas import register_kernel
+from spark_rapids_jni_tpu.ops.pallas import block_index, register_kernel
 
 _BLOCK = 2048      # rows per grid step (16 x 128 int32 tile)
 _SUB = 256         # rows per int32-safe partial (2^16 * 256 < 2^31)
@@ -174,13 +174,13 @@ def accumulate(
     # layout — in-kernel rank-changing reshapes are what Mosaic rejects
     gid3 = gid.reshape(nb, _SUBS, _SUB)
     lanes3 = [lane.reshape(nb, _SUBS, _SUB) for lane in lanes]
-    spec = pl.BlockSpec((1, _SUBS, _SUB), lambda i: (i, 0, 0))
+    spec = pl.BlockSpec((1, _SUBS, _SUB), block_index)
     out = pl.pallas_call(
         _make_kernel(m, tuple(lane_meta), total),
         out_shape=jax.ShapeDtypeStruct((nb, _SUBS, total), jnp.int32),
         grid=(nb,),
         in_specs=[spec] * (1 + lane_n),
-        out_specs=pl.BlockSpec((1, _SUBS, total), lambda i: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, _SUBS, total), block_index),
         interpret=interpret,
     )(gid3, *lanes3)
     # tiny combine outside the kernel: (nb*SUBS, m*L) partials -> (m, L)

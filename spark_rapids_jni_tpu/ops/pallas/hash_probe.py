@@ -27,7 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from spark_rapids_jni_tpu.ops.pallas import register_kernel
+from spark_rapids_jni_tpu.ops.pallas import block_index, register_kernel
 
 _BLOCK = 2048      # probe rows per grid step
 _SUB = 256
@@ -69,12 +69,15 @@ def _probe_kernel(build_ref, probe_ref, lt_ref, le_ref):
     def body(j, carry):
         lt, le = carry
         b = build_ref[j]                       # scalar from SMEM
-        lt = lt + jnp.where(b < p, 1, 0).astype(jnp.int32)
-        le = le + jnp.where(b <= p, 1, 0).astype(jnp.int32)
+        # bool -> int32 directly: under x64 a where() over Python ints is
+        # int64, which Mosaic cannot convert
+        lt = lt + (b < p).astype(jnp.int32)
+        le = le + (b <= p).astype(jnp.int32)
         return lt, le
 
+    # int32 bounds: Python ints would make the loop counter int64 under x64
     lt, le = jax.lax.fori_loop(
-        0, build_ref.shape[0], body, (zero, zero))
+        jnp.int32(0), jnp.int32(build_ref.shape[0]), body, (zero, zero))
     lt_ref[0] = lt
     le_ref[0] = le
 
@@ -106,9 +109,9 @@ def probe_lo_hi(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((1, _SUBS, _SUB), lambda i, b: (i, 0, 0))],
+        in_specs=[pl.BlockSpec((1, _SUBS, _SUB), block_index)],
         out_specs=[
-            pl.BlockSpec((1, _SUBS, _SUB), lambda i, b: (i, 0, 0)),
+            pl.BlockSpec((1, _SUBS, _SUB), block_index),
         ] * 2,
     )
     lt, le = pl.pallas_call(

@@ -52,7 +52,7 @@ from spark_rapids_jni_tpu.models.tpch import (
     L_SHIPDATE,
     L_TAX,
 )
-from spark_rapids_jni_tpu.ops.pallas import register_kernel
+from spark_rapids_jni_tpu.ops.pallas import block_index, register_kernel
 
 _BLOCK = 2048      # rows per grid step (16 x 128 int32 tile)
 _SUB = 256         # rows per int32-safe partial (7.1e6 * 256 < 2^31)
@@ -98,15 +98,19 @@ def _q1_kernel(qty_ref, price_ref, disc_ref, tax_ref, ship_ref, rf_ref,
     ls = ls_ref[0]
 
     keep = ship <= _Q1_CUTOFF_DAYS
+    # every constant is an explicit int32: under x64 a where() over Python
+    # ints is int64, which Mosaic cannot convert back
+    i32 = jnp.int32
     # flag codes via the declared domains (planner facts, not data sort)
-    rfc = jnp.where(rf == _Q1_RF_DOMAIN[0], 0,
-                    jnp.where(rf == _Q1_RF_DOMAIN[1], 1,
-                              jnp.where(rf == _Q1_RF_DOMAIN[2], 2, -1)))
-    lsc = jnp.where(ls == _Q1_LS_DOMAIN[0], 0,
-                    jnp.where(ls == _Q1_LS_DOMAIN[1], 1, -1))
+    rfc = jnp.where(rf == _Q1_RF_DOMAIN[0], i32(0),
+                    jnp.where(rf == _Q1_RF_DOMAIN[1], i32(1),
+                              jnp.where(rf == _Q1_RF_DOMAIN[2], i32(2),
+                                        i32(-1))))
+    lsc = jnp.where(ls == _Q1_LS_DOMAIN[0], i32(0),
+                    jnp.where(ls == _Q1_LS_DOMAIN[1], i32(1), i32(-1)))
     miss = (rfc < 0) | (lsc < 0)
     gid = jnp.where(keep & ~miss, rfc * 2 + lsc,
-                    jnp.where(keep, 7, 6)).astype(jnp.int32)
+                    jnp.where(keep, i32(7), i32(6)))
 
     w = 100 - disc
     dp = price * w                      # < 1.05e9, int32-exact
@@ -140,10 +144,10 @@ def _q1_kernel(qty_ref, price_ref, disc_ref, tax_ref, ship_ref, rf_ref,
             # dtype pinned: under x64 jnp.sum would promote the int32
             # partial to int64, which Mosaic rejects at the int32 out_ref
             # swap — every partial is int32-exact by the limb bounds above
-            p = jnp.sum(jnp.where(mask, lane, 0), axis=1,
+            p = jnp.sum(jnp.where(mask, lane, i32(0)), axis=1,
                         keepdims=True, dtype=jnp.int32)   # (SUBS, 1)
             acc = acc + jnp.where(
-                col_ids == g * _LANES + li, p, 0)
+                col_ids == g * _LANES + li, p, i32(0))
     out_ref[0] = acc
 
 
@@ -164,7 +168,7 @@ def _q1_partials_fn(row_args, aux_args, row_valids, *, interpret: bool):
     # layout — in-kernel rank-changing reshapes are what Mosaic rejects
     cols = [c.reshape(nb, subs, _SUB) for c in
             (qty, price, disc, tax, ship, rf, ls)]
-    spec = pl.BlockSpec((1, subs, _SUB), lambda i: (i, 0, 0))
+    spec = pl.BlockSpec((1, subs, _SUB), block_index)
     out = pl.pallas_call(
         _q1_kernel,
         out_shape=jax.ShapeDtypeStruct(
@@ -172,7 +176,7 @@ def _q1_partials_fn(row_args, aux_args, row_valids, *, interpret: bool):
         grid=(nb,),
         in_specs=[spec] * 7,
         out_specs=pl.BlockSpec((1, subs, _M * _LANES),
-                               lambda i: (i, 0, 0)),
+                               block_index),
         interpret=interpret,
     )(*cols)
     # tiny int64 combine outside the kernel: (nb, subs, m, lanes) -> (m, lanes)

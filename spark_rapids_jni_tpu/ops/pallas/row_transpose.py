@@ -3,13 +3,17 @@
 
 The XLA path builds the fixed-width row image by a wide lane
 concatenation of per-column byte pieces (+ alignment zero-pads + packed
-validity bytes). This kernel replaces the interleave: each grid step
-takes a 256-row slice of every byte piece (pre-cast to int32 lanes on
-the XLA side — byte values are exact in int32) and assembles the
-(256, row_width) output tile by broadcasted_iota where-selects, one
-static output byte column at a time. Alignment gaps and the trailing
-64-bit row pad fall out of the zero-initialized accumulator, so the
-result is byte-for-byte ``jnp.concatenate(pieces, axis=1)``.
+validity bytes). This kernel replaces the interleave. Rows ride the LANE
+dimension on the way in: each piece arrives transposed, (width, n) int32
+(byte values are exact in int32), so a grid step reads a dense
+(width, 256) slab — a (256, width) block would pad its 1/4/8-byte last
+dimension to 128 lanes, 16-128x the bytes, which at 6M rows exhausted
+HBM on the v5e. The step assembles the (row_width, 256) image by
+broadcasted_iota where-selects, one static output byte row at a time,
+and stores its transpose, the (256, row_width) output tile. Alignment
+gaps and the trailing 64-bit row pad fall out of the zero-initialized
+accumulator, so the result is byte-for-byte
+``jnp.concatenate(pieces, axis=1)``.
 
 Rows are "ragged" across schemas, not within a batch: the kernel closure
 is specialized per (starts, widths) layout — exactly the static schema
@@ -31,7 +35,7 @@ import jax.numpy as jnp
 
 from spark_rapids_jni_tpu.ops.pallas import register_kernel
 
-_ROWS = 256          # rows per grid step (32 int32 sublane tiles)
+_ROWS = 256          # rows per grid step (two 128-lane tiles)
 MAX_ROW_BYTES = 256  # row-image cap (select-assembly unrolls per byte)
 
 register_kernel(
@@ -58,21 +62,32 @@ def _round_up(x: int, mult: int) -> int:
 
 def _make_kernel(starts_widths: tuple[tuple[int, int], ...], total: int):
     """Kernel closure over the static row layout: piece ``pi`` lands at
-    byte offset ``starts_widths[pi][0]``; untouched columns stay zero
+    byte offset ``starts_widths[pi][0]``; untouched bytes stay zero
     (alignment gaps, trailing row pad)."""
 
     def kernel(*refs):
         out_ref = refs[-1]
-        col_ids = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, total), 1)
-        acc = jnp.zeros((_ROWS, total), jnp.int32)
+        byte_ids = jax.lax.broadcasted_iota(jnp.int32, (total, _ROWS), 0)
+        acc = jnp.zeros((total, _ROWS), jnp.int32)
         for pi, (start, width) in enumerate(starts_widths):
-            piece = refs[pi][0]                # (_ROWS, width)
+            piece = refs[pi][...]              # (width, _ROWS): rows on lanes
             for k in range(width):
-                col = piece[:, k:k + 1]        # (_ROWS, 1) keepdims slice
-                acc = jnp.where(col_ids == start + k, col, acc)
-        out_ref[0] = acc
+                row = piece[k:k + 1, :]        # (1, _ROWS) keepdims slice
+                acc = jnp.where(byte_ids == start + k, row, acc)
+        out_ref[...] = acc.T                   # (_ROWS, total)
 
     return kernel
+
+
+def _lane_block(i):
+    """index_map of an input piece: all of its byte rows, lane block i
+    (explicit int32 zero: see ``block_index``)."""
+    return (jnp.int32(0), i)
+
+
+def _row_block(i):
+    """index_map of the output: row block i, every byte column."""
+    return (i, jnp.int32(0))
 
 
 def assemble_rows(
@@ -94,22 +109,20 @@ def assemble_rows(
     ins = []
     starts_widths = []
     for start, piece in zip(starts, pieces):
-        a = piece.astype(jnp.int32)            # bytes are exact in int32
+        a = piece.astype(jnp.int32).T          # bytes are exact in int32
         if pad:
             a = jnp.concatenate(
-                [a, jnp.zeros((pad, a.shape[1]), jnp.int32)])
-        ins.append(a.reshape(nb, _ROWS, a.shape[1]))
+                [a, jnp.zeros((a.shape[0], pad), jnp.int32)], axis=1)
+        ins.append(a)
         starts_widths.append((int(start), int(piece.shape[1])))
     out = pl.pallas_call(
         _make_kernel(tuple(starts_widths), total),
-        out_shape=jax.ShapeDtypeStruct((nb, _ROWS, total), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((nb * _ROWS, total), jnp.int32),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, _ROWS, w), lambda i: (i, 0, 0))
-            for _, w in starts_widths
+            pl.BlockSpec((w, _ROWS), _lane_block) for _, w in starts_widths
         ],
-        out_specs=pl.BlockSpec((1, _ROWS, total), lambda i: (i, 0, 0)),
+        out_specs=pl.BlockSpec((_ROWS, total), _row_block),
         interpret=interpret,
     )(*ins)
-    rows = out.astype(jnp.uint8).reshape(nb * _ROWS, total)
-    return rows[:n, :size_per_row]
+    return out[:n, :size_per_row].astype(jnp.uint8)

@@ -1,6 +1,7 @@
 """Bounded-domain groupby planning — the facility behind the 125x q1 win.
 
-Round-4 hardware measurements (BASELINE.md) showed planner-declared key
+Hardware measurements (bench_tpu_ledger.jsonl: a v5e in 2026-07, before
+the runtime stack; not measured since) showed planner-declared key
 domains beat the general sort-based groupby by 125x at 16M rows: when every
 key column's candidate values are known at plan time, grouping lowers to
 ``groupby_aggregate_bounded`` — zero sort, zero gather, zero scan, zero
@@ -236,8 +237,9 @@ def dense_pk_join(
       layout of a loaded dimension or generated key column). The join
       is then pure arithmetic + one row gather — ZERO sorts anywhere,
       and the general join's build-side lexsort + probe searchsorted
-      (the dominant terms of the 230 ns/row unbounded pipeline,
-      BASELINE.md) vanish. The declaration is VERIFIED, not trusted:
+      (the dominant terms of the unbounded pipeline, ~230 ns/row for
+      general q1 on a v5e in 2026-07; not measured since) vanish. The
+      declaration is VERIFIED, not trusted:
       each gathered build key is compared to the probe key, and a slot
       holding a different valid key raises ``pk_violation``.
     * ``clustered=False``: one lexsort of the (small) build side; the

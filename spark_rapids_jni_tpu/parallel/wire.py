@@ -66,8 +66,8 @@ def pack_bits(values: jnp.ndarray, spec: BitPack) -> tuple[jnp.ndarray, jnp.ndar
     TPU-first formulation: gather-based, not scatter-based. Each output
     word OR-combines the <= ceil(32/bits)+1 values whose bit fields overlap
     it — a static unrolled loop of dense gathers the VPU tiles cleanly
-    (the scatter-add formulation measured ~3x slower than CPU on v5e; see
-    BASELINE.md).
+    (the scatter-add formulation measured ~3x slower than CPU on a v5e in
+    2026-07; not measured since).
     """
     bits = spec.bits
     n = int(values.shape[-1])

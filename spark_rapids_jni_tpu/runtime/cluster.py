@@ -540,8 +540,9 @@ class QueryCluster(QueryFleet):
             evt, slot = ent
             slot.update(msg)
             evt.set()
-        elif t in ("xpack_done", "xmerge_done"):
-            key = (str(msg.get("xid", "")), t, int(msg.get("part", -1)))
+        elif t in ("xpack_done", "xmerge_done", "xbusy"):
+            done = (t if t != "xbusy" else f"{msg.get('phase')}_done")
+            key = (str(msg.get("xid", "")), done, int(msg.get("part", -1)))
             with self._lock:
                 ent = self._x_waits.get(key)
             if ent is None:
@@ -549,6 +550,11 @@ class QueryCluster(QueryFleet):
             evt, slot, rid, wgen = ent
             if rid != r.rid or wgen != gen:
                 return  # stale generation's straggler
+            if t == "xbusy":
+                # the worker is computing (True) or back on the wire
+                # (False): _x_collect's stall clock follows it
+                slot["busy"] = bool(msg.get("on"))
+                return
             slot.update(msg)
             evt.set()
 
@@ -965,24 +971,39 @@ class QueryCluster(QueryFleet):
 
     def _x_collect(self, wait: tuple, deadline: Optional[float],
                    cap: float, what: str) -> Dict[str, Any]:
-        """Block for one xpack/xmerge reply slot. A caller-deadline
-        expiry raises ``TimeoutError`` (retryable — the ticket
-        unclaims); a per-phase stall or an error reply raises the
-        classified ``TransportError`` that trips the routed fallback."""
+        """Block for one xpack/xmerge reply slot. ``cap``
+        (``exchange.direct_timeout_s``) times the WIRE, never the plan:
+        the clock runs only while the worker is not ``busy`` computing
+        (its ``xbusy`` frames), so a pack or merge plan that compiles
+        cold for minutes on a chip stays on the direct lane, bounded
+        like any routed query by the caller's deadline and the worker's
+        liveness (``_on_replica_death`` fails this wait fast). A
+        caller-deadline expiry raises ``TimeoutError`` (retryable — the
+        ticket unclaims); ``cap`` seconds of wire stall or an error
+        reply raises the classified ``TransportError`` that trips the
+        routed fallback."""
         key, evt, slot, rid = wait
-        left = (cap if deadline is None
-                else min(cap, deadline - time.monotonic()))
-        ok = evt.wait(max(0.0, left))
+        on_wire = 0.0
+        tick = time.monotonic()
+        while not evt.wait(0.05):
+            now = time.monotonic()
+            if not slot.get("busy"):
+                on_wire += now - tick
+            tick = now
+            if deadline is not None and now >= deadline:
+                break
+            if on_wire >= cap:
+                break
         with self._lock:
             self._x_waits.pop(key, None)
-        if not ok:
+        if not evt.is_set():
             if deadline is not None and time.monotonic() >= deadline:
                 raise TimeoutError(
                     f"cluster: direct exchange {what} on {rid} not done "
                     "before the caller deadline")
             raise resilience.TransportError(
-                f"cluster: direct exchange {what} on {rid} did not "
-                f"complete within {cap}s", host=rid,
+                f"cluster: direct exchange {what} on {rid} stalled on "
+                f"the wire for {cap}s", host=rid,
                 seam="exchange.wire")
         if slot.get("status") != "ok":
             raise resilience.TransportError(
@@ -1193,6 +1214,16 @@ class QueryCluster(QueryFleet):
 # ---------------------------------------------------------------------------
 
 
+def _x_busy(chan: _FrameChannel, xid: str, phase: str, part: int,
+            on: bool) -> None:
+    """Tell the supervisor whether this direct-exchange handler is
+    computing (plan, split, seal, merge — a cold plan compiles for
+    minutes on a chip) or on the wire: ``exchange.direct_timeout_s``
+    times only the wire (``QueryCluster._x_collect``)."""
+    chan.send({"t": "xbusy", "xid": xid, "phase": phase, "part": part,
+               "on": on})
+
+
 def _handle_xpack(chan: _FrameChannel, srv, msg: Dict[str, Any],
                   hid: str, peer) -> None:
     """Worker-side phase 1 of a direct exchange: run the pack plan over
@@ -1211,6 +1242,7 @@ def _handle_xpack(chan: _FrameChannel, srv, msg: Dict[str, Any],
     xid, sp = str(msg.get("xid", "")), int(msg.get("part", -1))
     src_id = f"p{sp}"
     try:
+        _x_busy(chan, xid, "xpack", sp, True)
         delay_ms = float(
             os.environ.get(fleetmod._ENV_SERVE_DELAY, "0") or 0.0)
         if delay_ms > 0:
@@ -1234,19 +1266,24 @@ def _handle_xpack(chan: _FrameChannel, srv, msg: Dict[str, Any],
         per_dest = xch.split_wire(fused.table, rc, parts)
         dests = {int(d["part"]): d for d in msg.get("dests", [])}
         fps: Dict[int, str] = {}
-        routed: Dict[int, bytes] = {}
-        sent: List[int] = []
-        bytes_direct = bytes_routed = 0
+        blobs: Dict[int, bytes] = {}
         for dp, flights in enumerate(per_dest):
             if not flights:
                 continue
             dest_in = (flights[0] if len(flights) == 1
                        else concatenate(flights))
-            blob = xch.serialize_flight(
+            blobs[dp] = xch.serialize_flight(
                 dest_in, op="exchange.direct_pack", xid=xid,
                 src=src_id, dest=dp)
-            fp = dcn.flight_fingerprint(blob)
-            fps[dp] = fp
+            fps[dp] = dcn.flight_fingerprint(blobs[dp])
+        # everything is packed and sealed: from here on this handler
+        # only flies, and the supervisor's direct_timeout_s clock runs
+        _x_busy(chan, xid, "xpack", sp, False)
+        routed: Dict[int, bytes] = {}
+        sent: List[int] = []
+        bytes_direct = bytes_routed = 0
+        for dp, blob in blobs.items():
+            fp = fps[dp]
             d = dests[dp]
             header = {"xid": xid, "src": src_id, "part": dp,
                       "grant": d.get("grant", ""), "fp": fp}
@@ -1327,6 +1364,8 @@ def _handle_xmerge(chan: _FrameChannel, srv, msg: Dict[str, Any],
                         host=hid, seam="exchange.wire")
                 flights = peer.wait_flights(xid, dp, direct_srcs,
                                             timeout=timeout)
+            # the flights are in: verify, decode and merge are compute
+            _x_busy(chan, xid, "xmerge", dp, True)
             tables = []
             for src_id, want_fp in manifest:
                 blob = routed.get(src_id)

@@ -15,9 +15,11 @@ Compilation is explicit — ``jax.jit(fn).lower(args).compile()`` — rather
 than delegated to jit's internal cache, so compiles and hits are exact,
 countable events (telemetry counters ``dispatch.compile`` /
 ``dispatch.hit``; ``dispatch.padded_waste_bytes`` accounts the padding
-tax). JAX's persistent compilation cache is wired from
-``SPARK_RAPIDS_TPU_DISPATCH_CACHE`` (or ``dispatch.persistent_cache_dir``)
-so steady-state runs start warm across processes.
+tax; ``dispatch.compile_ms`` is the wall time spent lowering and
+compiling). JAX's persistent compilation cache makes a second process
+start warm: it lives where ``JAX_COMPILATION_CACHE_DIR`` says, else at
+the fixed path ``utils/config.cache_dir()`` names (set once at package
+import), with JAX's own persistence thresholds.
 
 Fail-safe posture: anything this layer cannot bucket or compile — tracer
 inputs (the op is already inside a caller's trace), Arrow-layout strings,
@@ -26,15 +28,15 @@ the op's implementation directly, with the reason counted. Dispatch must
 never change what an op computes, only how often XLA compiles it.
 
 Config knobs (utils/config.py): ``dispatch.enabled``,
-``dispatch.bucket_base``, ``dispatch.max_waste_frac``,
-``dispatch.persistent_cache_dir``.
+``dispatch.bucket_base``, ``dispatch.max_waste_frac``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
-import os
 import threading
+import time
 import warnings
 from functools import partial
 from typing import Any, Callable, Optional, Sequence
@@ -63,15 +65,12 @@ __all__ = [
     "clear",
 ]
 
-_ENV_CACHE_DIR = "SPARK_RAPIDS_TPU_DISPATCH_CACHE"
-
 _lock = threading.RLock()
 _EXEC_CACHE: dict = {}
 # key -> threading.Event: a first-compile currently in flight. Concurrent
 # callers of the same key park on the event and reuse the leader's
 # executable instead of compiling it N times (single-flight).
 _INFLIGHT: dict = {}
-_persistent_initialized = False
 
 
 class Unbucketable(Exception):
@@ -278,78 +277,18 @@ def _signature(tree: Any) -> tuple:
 # ---------------------------------------------------------------------------
 
 
-def _refresh_cache_index(cache_dir: str) -> None:
-    """Maintain the repo-owned ``index.json`` beside JAX's persistent
-    cache entries: which jax version wrote them and how many processes
-    have wired the directory. Written crash-safely (tmp + ``os.replace``
-    + fsync, utils/atomic_io.py); a corrupt/truncated index from an
-    earlier crash is DISCARDED with a telemetry event — warm start then
-    costs one re-count, never a crash or a poisoned cache."""
-    from spark_rapids_jni_tpu.telemetry.events import record_degrade
-    from spark_rapids_jni_tpu.utils.atomic_io import (
-        atomic_write_json,
-        load_json,
-    )
-
-    index_path = os.path.join(cache_dir, "index.json")
-    index, corrupt = load_json(index_path)
-    if corrupt is not None:
-        REGISTRY.counter("dispatch.persistent_cache_index_discarded").inc()
-        record_degrade("dispatch.persistent_cache", "state_discarded",
-                       tier="persistent", trigger="corrupt",
-                       rung=0, path=index_path, reason=corrupt)
-        index = None
-    if not isinstance(index, dict):
-        index = {}
-    index["version"] = 1
-    index["jax"] = str(jax.__version__)
-    index["wired"] = int(index.get("wired", 0)) + 1
-    atomic_write_json(index_path, index)
-
-
-def _init_persistent_cache() -> None:
-    """Wire JAX's cross-process compilation cache (idempotent). The short
-    env var wins over the config option; thresholds are dropped to zero so
-    the small CPU-test executables persist too."""
-    global _persistent_initialized
-    with _lock:
-        if _persistent_initialized:
-            return
-        _persistent_initialized = True
-    cache_dir = os.environ.get(_ENV_CACHE_DIR) or str(
-        get_option("dispatch.persistent_cache_dir") or "")
-    if not cache_dir:
-        return
+@contextlib.contextmanager
+def _compile_timer(op: str):
+    """Wall time of one lower+compile into ``dispatch.compile_ms`` and
+    ``dispatch.compile_ms.<op>`` (a persistent-cache hit shows here as a
+    short compile)."""
+    t0 = time.perf_counter()
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        _refresh_cache_index(cache_dir)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        for opt, val in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(opt, val)
-            # knob names drift across jax versions; a miss only loses
-            # tuning, never correctness, and the outer handler already
-            # counts real failures
-            # tpulint: disable=error-must-classify
-            except Exception:
-                pass
-        # jax latches the cache as disabled at the FIRST compile in the
-        # process; imports above us always compile something, so force a
-        # re-read of the dir we just set
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        # private-module probe, absent on some jax versions; the cache
-        # still serves compiles after this point and the outer handler
-        # counts real failures
-        # tpulint: disable=error-must-classify
-        except Exception:
-            pass
-        REGISTRY.gauge("dispatch.persistent_cache").set(1)
-    except Exception:
-        REGISTRY.counter("dispatch.persistent_cache_error").inc()
+        yield
+    finally:
+        ms = (time.perf_counter() - t0) * 1e3
+        REGISTRY.histogram("dispatch.compile_ms").observe(ms)
+        REGISTRY.histogram(f"dispatch.compile_ms.{op}").observe(ms)
 
 
 def _cache_lookup(key) -> tuple:
@@ -481,14 +420,12 @@ def call(
            jax.default_backend())
     compiled, lead_ev = _cache_lookup(key)
     if compiled is None:
-        _init_persistent_cache()
-
         def _compile():
             faults.fire("dispatch.compile", 0, op=op)
             jitted = (jax.jit(fn, donate_argnums=(0,)) if donate_rows
                       else jax.jit(fn))
             with spans.child("dispatch.compile", op=op), \
-                    warnings.catch_warnings():
+                    _compile_timer(op), warnings.catch_warnings():
                 # backends without donation support (CPU) warn per
                 # donated buffer at lowering; the declaration is still
                 # honored where the platform implements it
@@ -588,11 +525,9 @@ def sharded_call(
            _signature(args), jax.default_backend())
     compiled, lead_ev = _cache_lookup(key)
     if compiled is None:
-        _init_persistent_cache()
-
         def _compile():
             faults.fire("dispatch.compile", 0, op=op)
-            with spans.child("dispatch.compile", op=op):
+            with spans.child("dispatch.compile", op=op), _compile_timer(op):
                 return jax.jit(build()).lower(*args).compile()
 
         exc = None

@@ -44,6 +44,16 @@ every session with it. This module turns "a server" into "a service":
   file), then restart it warm off the shared JAX persistent compile
   cache — a planned exit, not a classified death.
 
+Process model: one process per chip. The supervisor stays off the
+accelerator (it only frames, fingerprints and routes, on the CPU backend)
+and ASSIGNS each worker its platform and device: the platform is
+``JAX_PLATFORMS`` of the worker environment (``worker_env``, else the
+supervisor's own), and on ``tpu`` replica *i* is pinned to chip *i*
+through libtpu's own process-bounds variables. A worker's ``boot_ok``
+names the platform, device kind and device it actually came up on; a
+worker on a platform or device it was not given is a classified failed
+boot (``fleet.boot_refused``), never ``live``.
+
 Every supervision decision is observable: unconditional ``fleet.*``
 counters, ``record_fleet`` events, replica-tagged telemetry (workers
 stamp ``replica=`` on every record and span via ``telemetry.replica``),
@@ -256,11 +266,45 @@ class _Query:
         self.shard = shard  # (table name, part index) or None
 
 
+def _tpu_pin(index: int) -> Dict[str, str]:
+    """libtpu's variables that bound one process to ONE chip of the host
+    (chip ``index``), each process its own mesh controller port — the
+    documented recipe for several single-chip processes on one TPU host."""
+    port = str(8476 + int(index))
+    return {
+        "TPU_VISIBLE_CHIPS": str(int(index)),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_MESH_CONTROLLER_ADDRESS": "localhost:" + port,
+        "TPU_MESH_CONTROLLER_PORT": port,
+    }
+
+
+def _device_report() -> Dict[str, Any]:
+    """What this process actually runs on, as JAX reports it (initializes
+    the backend — a worker that cannot reach its device dies here, at
+    boot, not at its first query)."""
+    import jax
+
+    devs = jax.devices()
+    return {
+        "platform": str(devs[0].platform),
+        "device_kind": str(devs[0].device_kind),
+        "device_id": int(devs[0].id),
+        "device_count": len(devs),
+        # the chip libtpu was bounded to (None: unbounded / not a TPU)
+        "chip": os.environ.get("TPU_VISIBLE_CHIPS"),
+    }
+
+
 class _Replica:
     """One supervised worker subprocess and its control-channel state."""
 
-    def __init__(self, rid: str):
+    def __init__(self, rid: str, index: int = 0):
         self.rid = rid
+        self.index = index  # position in the fleet: the chip it is given
+        # what the worker's boot_ok said it runs on (_device_report)
+        self.device: Dict[str, Any] = {}
         self.state = "booting"  # booting|live|draining|dead|quarantined
         self.generation = 0
         self.proc: Optional[subprocess.Popen] = None
@@ -310,6 +354,7 @@ class QueryFleet:
         self.n_replicas = max(1, int(replicas if replicas is not None
                                      else get_option("fleet.replicas")))
         self._worker_env = dict(worker_env or {})
+        self.platform = self._assigned_platform()
         self._lock = threading.RLock()
         self._cond = threading.Condition(self._lock)
         self._closed = False
@@ -328,7 +373,7 @@ class QueryFleet:
         self._cost: Dict[str, float] = {}
         self._replicas: List[_Replica] = []
         for i in range(self.n_replicas):
-            r = _Replica(f"{self._ID_PREFIX}{i}")
+            r = _Replica(f"{self._ID_PREFIX}{i}", i)
             r.env_extra = dict((per_replica_env or {}).get(r.rid, {}))
             self._replicas.append(r)
         _LIVE_FLEETS.add(self)
@@ -341,19 +386,32 @@ class QueryFleet:
 
     # -- worker lifecycle ----------------------------------------------------
 
+    def _assigned_platform(self) -> str:
+        """The platform every worker of this fleet is given: the first
+        entry of ``JAX_PLATFORMS`` in ``worker_env``, else in the
+        supervisor's environment, else the supervisor's own backend. An
+        accelerator belongs to one process, so a fleet of accelerator
+        workers needs a supervisor pinned to the CPU
+        (``utils.platform.force_cpu_platform()`` or ``JAX_PLATFORMS=cpu``)
+        — refused here, at once, instead of as N boot timeouts."""
+        import jax
+
+        spec = (self._worker_env.get("JAX_PLATFORMS")
+                or os.environ.get("JAX_PLATFORMS") or jax.default_backend())
+        platform = str(spec).split(",")[0].strip().lower()
+        own = str(jax.config.jax_platforms or "").split(",")[0].strip()
+        if platform != "cpu" and own != "cpu":
+            raise ValueError(
+                f"fleet workers are assigned platform {platform!r} but the "
+                f"supervisor is not pinned to the CPU (jax_platforms="
+                f"{jax.config.jax_platforms!r}): a chip belongs to one "
+                f"process — call force_cpu_platform() in the supervisor")
+        return platform
+
     def _worker_environment(self, r: _Replica) -> Dict[str, str]:
         from spark_rapids_jni_tpu.runtime import integrity
 
         env = dict(os.environ)
-        # workers must land on the supervisor's backend even when it was
-        # forced programmatically rather than via the environment
-        if "JAX_PLATFORMS" not in env:
-            jax = sys.modules.get("jax")
-            if jax is not None:
-                try:
-                    env["JAX_PLATFORMS"] = str(jax.default_backend())
-                except RuntimeError:
-                    pass  # backend not initialized; worker picks its own
         # propagate option state that lives in this process's overrides
         # (env-set options are already inherited)
         env["SPARK_RAPIDS_TPU_TELEMETRY_REPLICA"] = r.rid
@@ -367,8 +425,30 @@ class QueryFleet:
             if val:
                 env[var] = "1" if val is True else str(val)
         env.update(self._worker_env)
+        # the assignment: this platform, and on tpu one chip per process
+        env["JAX_PLATFORMS"] = self.platform
+        if self.platform == "tpu":
+            env.update(_tpu_pin(r.index))
+        # per-replica chaos overrides last (they may break the assignment;
+        # the boot_ok check is what notices)
         env.update(r.env_extra)
         return env
+
+    def _boot_refusal(self, r: _Replica, report: Dict[str, Any]) -> str:
+        """Why this worker's boot_ok is NOT what it was given ("" = it
+        is): the assigned platform, and on tpu exactly its own chip."""
+        got = str(report.get("platform", "?"))
+        if got != self.platform:
+            return (f"booted on platform {got!r}, was assigned "
+                    f"{self.platform!r}")
+        if self.platform == "tpu":
+            want = _tpu_pin(r.index)["TPU_VISIBLE_CHIPS"]
+            if (report.get("chip") != want
+                    or int(report.get("device_count", 0)) != 1):
+                return (f"booted on chip {report.get('chip')!r} with "
+                        f"{report.get('device_count')} device(s), was "
+                        f"assigned chip {want} alone")
+        return ""
 
     def _extra(self, r: _Replica) -> Dict[str, Any]:
         """Identity context merged into supervision events and
@@ -437,14 +517,32 @@ class QueryFleet:
                 return
             t = msg.get("t")
             if t == "boot_ok":
+                report = {k: msg.get(k) for k in (
+                    "platform", "device_kind", "device_id", "device_count",
+                    "chip")}
+                refusal = self._boot_refusal(r, report)
+                if refusal:
+                    # a worker on a platform or device it was not given
+                    # must never serve: a classified failed boot, counted
+                    # toward the crash-loop breaker like any other
+                    REGISTRY.counter("fleet.boot_refused").inc()
+                    record_fleet("fleet.spawn", "boot_refused",
+                                 replica=r.rid, pid=msg.get("pid", 0),
+                                 reason=refusal, **report, **self._extra(r))
+                    self._declare_dead(r, gen, resilience.ReplicaDeadError(
+                        f"fleet: replica {r.rid} {refusal}",
+                        replica=r.rid, seam="fleet.boot", **self._extra(r)))
+                    continue
                 with self._cond:
                     if r.generation == gen and r.state == "booting":
+                        r.device = report
                         r.state = "live"
                         r.last_pong = time.monotonic()
                         r.live_evt.set()
                         self._cond.notify_all()
                 record_fleet("fleet.spawn", "live", replica=r.rid,
-                             pid=msg.get("pid", 0), **self._extra(r))
+                             pid=msg.get("pid", 0), **report,
+                             **self._extra(r))
             elif t == "pong":
                 with self._lock:
                     r.last_pong = time.monotonic()
@@ -1008,6 +1106,7 @@ class QueryFleet:
                     "replica": r.rid, "state": r.state,
                     "pid": r.proc.pid if r.proc is not None else None,
                     "generation": r.generation,
+                    "device": dict(r.device),
                     "inflight": len(r.inflight),
                     "served": r.served_total,
                     "crashes": r.crashes_total,
@@ -1225,7 +1324,7 @@ def _worker_loop(chan: _FrameChannel, replica: str,
     if int(get_option("server.warmup_top_n")) > 0:
         from spark_rapids_jni_tpu.models import tpch  # noqa: F401  (registers warmup builders)
         srv.warmup()
-    chan.send({"t": "boot_ok", "pid": os.getpid()})
+    chan.send({"t": "boot_ok", "pid": os.getpid(), **_device_report()})
     frozen = False
     try:
         while True:

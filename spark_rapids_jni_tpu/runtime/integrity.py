@@ -93,7 +93,7 @@ _ENV = "SPARK_RAPIDS_TPU_INTEGRITY"
 def enabled() -> bool:
     """Is integrity verification on? The short env var
     SPARK_RAPIDS_TPU_INTEGRITY is checked first (same precedence pattern
-    as SPARK_RAPIDS_TPU_DISPATCH_CACHE), then the ``integrity.enabled``
+    as SPARK_RAPIDS_TPU_KERNEL_TIER), then the ``integrity.enabled``
     option."""
     env = os.environ.get(_ENV)
     if env is not None:

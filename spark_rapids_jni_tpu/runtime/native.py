@@ -6,10 +6,12 @@ packaging scheme pom.xml:385-421). Here the equivalent search order is:
 
   1. ``SPARK_RAPIDS_TPU_NATIVE_LIB`` env var (explicit path);
   2. a packaged ``_lib/libtpudf.so`` next to this module;
-  3. ``build/native/libtpudf.so`` under the repo root;
-  4. if a toolchain is available, configure+build it with cmake/ninja into
-     ``build/native`` (the dev-workflow path; the reference drives the same
-     step from Maven at the validate phase, pom.xml:306-333).
+  3. in a checkout (``src/native`` present): configure and build it with
+     cmake/ninja into ``build/native`` and load that. The build runs on
+     every first touch — a no-op when current — so a library left over from
+     other sources is never loaded; a failed build raises with the
+     compiler's output (the reference drives the same step from Maven at
+     the validate phase, pom.xml:306-333).
 
 Loading is lazy and memoized; errors carry the full search trail.
 """
@@ -17,8 +19,10 @@ Loading is lazy and memoized; errors carry the full search trail.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import pathlib
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -253,28 +257,57 @@ def _candidate_paths() -> list[pathlib.Path]:
     if env:
         out.append(pathlib.Path(env))
     out.append(pathlib.Path(__file__).parent / "_lib" / _LIB_NAME)
-    out.append(_REPO_ROOT / "build" / "native" / _LIB_NAME)
     return out
 
 
+def _cache_matches(build: pathlib.Path, src: pathlib.Path) -> bool:
+    """Was ``build/CMakeCache.txt`` configured for THIS source and build
+    directory? A cache copied in from another path (a relocated checkout)
+    makes cmake refuse to run; it is discarded instead."""
+    want = {"CMAKE_HOME_DIRECTORY": str(src), "CMAKE_CACHEFILE_DIR": str(build)}
+    try:
+        text = (build / "CMakeCache.txt").read_text(errors="replace")
+    except OSError:
+        return False
+    for line in text.splitlines():
+        key, _, rest = line.partition(":")
+        if key in want and rest.partition("=")[2] != want.pop(key):
+            return False
+    return not want
+
+
+def _run_build_step(cmd: list[str]) -> None:
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True)
+    except OSError as exc:
+        raise OSError(f"native build: cannot run {cmd[0]}: {exc}") from exc
+    if proc.returncode != 0:
+        raise OSError(
+            f"native build failed (rc={proc.returncode}): {' '.join(cmd)}\n"
+            f"{proc.stdout}{proc.stderr}")
+
+
 def _build_native() -> Optional[pathlib.Path]:
-    src = _REPO_ROOT / "src" / "native"
-    build = _REPO_ROOT / "build" / "native"
+    """Configure (when needed) and build ``src/native`` into
+    ``build/native``; None outside a checkout. Serialized across processes
+    (fleet workers and test processes all land here on first touch)."""
+    src = (_REPO_ROOT / "src" / "native").resolve()
+    build = (_REPO_ROOT / "build" / "native").resolve()
     if not src.exists():
         return None
-    try:
-        subprocess.run(
-            ["cmake", "-S", str(src), "-B", str(build), "-G", "Ninja"],
-            check=True,
-            capture_output=True,
-        )
-        subprocess.run(
-            ["ninja", "-C", str(build)], check=True, capture_output=True
-        )
-    except (OSError, subprocess.CalledProcessError):
-        return None
-    lib = build / _LIB_NAME
-    return lib if lib.exists() else None
+    build.parent.mkdir(parents=True, exist_ok=True)
+    with open(build.parent / ".native.lock", "a") as lock:
+        fcntl.flock(lock.fileno(), fcntl.LOCK_EX)
+        try:
+            if build.exists() and not _cache_matches(build, src):
+                shutil.rmtree(build)
+            if not (build / "build.ninja").exists():
+                _run_build_step(
+                    ["cmake", "-S", str(src), "-B", str(build), "-G", "Ninja"])
+            _run_build_step(["ninja", "-C", str(build)])
+        finally:
+            fcntl.flock(lock.fileno(), fcntl.LOCK_UN)
+    return build / _LIB_NAME
 
 
 def load_native() -> NativeLib:
@@ -282,17 +315,22 @@ def load_native() -> NativeLib:
     with _lock:
         if _loaded is not None:
             return _loaded
-        tried = []
-        for path in _candidate_paths():
-            if path.exists():
-                _loaded = NativeLib(ctypes.CDLL(str(path)), path)
-                return _loaded
-            tried.append(str(path))
-        built = _build_native()
-        if built is not None:
-            _loaded = NativeLib(ctypes.CDLL(str(built)), built)
-            return _loaded
+    # find or build OUTSIDE _lock: the build can take seconds and waits on
+    # a cross-process file lock; racing threads serialize on that instead
+    tried = []
+    found = None
+    for path in _candidate_paths():
+        if path.exists():
+            found = path
+            break
+        tried.append(str(path))
+    if found is None:
+        found = _build_native()
+    if found is None:
         raise OSError(
-            f"could not locate or build {_LIB_NAME}; searched: {tried} "
-            "and cmake build of src/native failed"
-        )
+            f"could not locate {_LIB_NAME}; searched: {tried}, and there "
+            f"is no src/native under {_REPO_ROOT} to build it from")
+    with _lock:
+        if _loaded is None:
+            _loaded = NativeLib(ctypes.CDLL(str(found)), found)
+        return _loaded

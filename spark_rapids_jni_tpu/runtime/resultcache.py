@@ -40,7 +40,8 @@ they are the FIRST thing pressure evicts: the limiter's high-watermark
 reaction sheds cache entries (demote to host tier + release charge)
 before any live query's working set spills, and a parked query's drain
 threshold discounts evictable cache bytes (``memory.py``). Capacity is an
-LRU in RESIDENT (stored) bytes (``cache.max_bytes``): entries demoted to
+LRU in RESIDENT (stored) bytes (``cache.max_bytes``; by default an eighth
+of the limiter's budget, at least 256 MiB): entries demoted to
 the host/disk tier count at their codec-compressed footprint
 (``compress.py``), so the same budget holds more results; ``stats()``
 reports both ``bytes`` (logical) and ``stored_bytes`` (resident).
@@ -308,7 +309,9 @@ class ResultCache:
     def _max_bytes(self) -> int:
         if self._max_bytes_override is not None:
             return int(self._max_bytes_override)
-        return int(get_option("cache.max_bytes"))
+        # 0 (the default): a share of the budget entries are charged to
+        return (int(get_option("cache.max_bytes"))
+                or max(256 << 20, self._limiter.budget // 8))
 
     @staticmethod
     def _validate_key(key) -> CacheKey:

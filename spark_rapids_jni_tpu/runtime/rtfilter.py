@@ -62,7 +62,7 @@ from spark_rapids_jni_tpu.telemetry import spans
 from spark_rapids_jni_tpu.telemetry.events import record_rtfilter
 from spark_rapids_jni_tpu.telemetry.registry import REGISTRY
 from spark_rapids_jni_tpu.utils.atomic_io import atomic_write_json, load_json
-from spark_rapids_jni_tpu.utils.config import get_option
+from spark_rapids_jni_tpu.utils.config import cache_dir, get_option
 
 try:
     import fcntl
@@ -116,11 +116,8 @@ class _SelectivityStore:
         explicit = str(get_option("rtfilter.path") or "")
         if explicit:
             return explicit
-        cache_dir = os.environ.get("SPARK_RAPIDS_TPU_DISPATCH_CACHE") or str(
-            get_option("dispatch.persistent_cache_dir") or "")
-        if cache_dir:
-            return os.path.join(cache_dir, "learned_selectivity.json")
-        return ""
+        base = cache_dir()
+        return os.path.join(base, "learned_selectivity.json") if base else ""
 
     def _read_file(self, path: str) -> Optional[dict]:
         state, corrupt = load_json(path)

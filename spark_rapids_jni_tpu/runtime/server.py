@@ -49,8 +49,8 @@ Contracts, in order of importance:
   (input + result device bytes) is blended (EMA, ``server.estimate_alpha``)
   into a per-plan-signature estimate that replaces the static
   ``fusion.estimate_hbm_bytes`` base for future submits, persisted
-  crash-safely beside the dispatch persistent cache
-  (``server.estimate_path``), so a fresh process admits from measured
+  crash-safely beside the compile cache (``server.estimate_path``, else
+  ``utils/config.cache_dir()``), so a fresh process admits from measured
   truth. Persistence is debounced off the hot path (at most one write
   per ``server.estimate_save_interval_s``; ``close()`` flushes).
 
@@ -101,7 +101,7 @@ from spark_rapids_jni_tpu.telemetry.events import (
 from spark_rapids_jni_tpu.utils.atomic_io import atomic_write_json, load_json
 from spark_rapids_jni_tpu.telemetry import spans
 from spark_rapids_jni_tpu.telemetry.registry import REGISTRY
-from spark_rapids_jni_tpu.utils.config import get_option
+from spark_rapids_jni_tpu.utils.config import cache_dir, get_option
 from spark_rapids_jni_tpu.utils.log import get_logger
 
 __all__ = ["QueryRejected", "QueryTicket", "Session", "QueryServer",
@@ -216,6 +216,12 @@ class QueryTicket:
         self.status = "queued"
         self.queue_wait_s: Optional[float] = None
         self.latency_s: Optional[float] = None
+        # where on the degrade ladder the query FINISHED (what inspect()
+        # shows while it runs); None until executed — a result-cache hit
+        # never executes and keeps None
+        self.tier: Optional[str] = None
+        self.rung: Optional[int] = None
+        self.steps: Optional[int] = None
         self._submitted_at = time.monotonic()
         self._value: Any = None
         self._exc: Optional[BaseException] = None
@@ -320,7 +326,7 @@ class QueryServer:
         self.limiter.attach_result_cache(self.result_cache)
         # learned admission: plan signature -> EMA of measured working-set
         # bytes, loaded from (and written through to) the crash-safe state
-        # file beside the dispatch persistent cache
+        # file beside the compile cache
         self._learned_lock = threading.Lock()
         self._learned: dict[str, float] = {}
         self._learned_dirty = False
@@ -712,16 +718,13 @@ class QueryServer:
     @staticmethod
     def _resolve_estimate_path() -> str:
         """Where learned estimates persist: ``server.estimate_path`` if
-        set, else ``learned_estimates.json`` beside the dispatch
-        persistent cache; empty (in-memory only) when neither exists."""
+        set, else ``learned_estimates.json`` in ``cache_dir()`` beside the
+        compile cache; empty (in-memory only) when that is switched off."""
         explicit = str(get_option("server.estimate_path") or "")
         if explicit:
             return explicit
-        cache_dir = os.environ.get("SPARK_RAPIDS_TPU_DISPATCH_CACHE") or str(
-            get_option("dispatch.persistent_cache_dir") or "")
-        if cache_dir:
-            return os.path.join(cache_dir, "learned_estimates.json")
-        return ""
+        base = cache_dir()
+        return os.path.join(base, "learned_estimates.json") if base else ""
 
     def _read_learned_file(self) -> Optional[dict]:
         """Read + sanitize the shared estimate file. ``None`` = nothing
@@ -941,6 +944,9 @@ class QueryServer:
             if ticket is None:
                 return
             self._serve(ticket)
+            # an idle worker must not pin its last query's bound tables
+            # (at SF10 that is 2.3 GB of HBM per waiting worker)
+            ticket = None
 
     def _stage_bindings(self, bindings: dict) -> dict:
         """Stage host-decoded chunk bindings to device tables on the
@@ -1116,6 +1122,9 @@ class QueryServer:
                                 outofcore=runner),
                             cancel_token=token, held_bytes=held,
                             observer=_observe)
+                    ticket.tier = info["tier"]
+                    ticket.rung = info["rung"]
+                    ticket.steps = info["steps"]
                     ticket.latency_s = (
                         time.monotonic() - ticket._submitted_at)
                     lat_ms = ticket.latency_s * 1e3

@@ -62,10 +62,6 @@ _OPTIONS: dict[str, tuple[Any, type]] = {
     # geometrically by min(1 + max_waste_frac, 2). 1.0 = power-of-two
     # buckets (<= 50% padded rows); 0.0 = linear base-multiple buckets.
     "dispatch.max_waste_frac": (1.0, float),
-    # Directory for JAX's persistent (cross-process) compilation cache;
-    # "" = off. The short env var SPARK_RAPIDS_TPU_DISPATCH_CACHE is also
-    # honored (checked first by runtime/dispatch.py).
-    "dispatch.persistent_cache_dir": ("", str),
     # Pipelined out-of-core execution (runtime/pipeline.py): overlap host
     # read/decode with device transfer+compute through a bounded-queue
     # multi-stage executor. Off by default — the serial path stays the
@@ -158,8 +154,8 @@ _OPTIONS: dict[str, tuple[Any, type]] = {
     # reservation of a plan signature into future estimates
     # (new = (1-alpha)*old + alpha*measured). 0 disables learning.
     "server.estimate_alpha": (0.4, float),
-    # Where learned per-signature estimates persist ("" = beside the
-    # dispatch persistent cache when that is configured, else unpersisted).
+    # Where learned per-signature estimates persist ("" = in cache_dir()
+    # beside the compile cache; unpersisted when that is switched off).
     # Writes are crash-safe: tmp file + os.replace + fsync.
     "server.estimate_path": ("", str),
     # Minimum seconds between learned-estimate persistence writes on the
@@ -192,8 +188,10 @@ _OPTIONS: dict[str, tuple[Any, type]] = {
     # LRU capacity of the result cache in logical payload bytes (across
     # all tiers). Resident entries are charged against the MemoryLimiter
     # so cached results can never starve live queries; under pressure the
-    # high-watermark spiller sheds cache entries first.
-    "cache.max_bytes": (256 << 20, int),
+    # high-watermark spiller sheds cache entries first. 0 sizes it from
+    # the limiter's budget: an eighth of it, and no less than 256 MiB
+    # (one padded general-q3 result at TPC-H SF1 is 336 MB).
+    "cache.max_bytes": (0, int),
     # Subplan-prefix reuse: hash canonicalized scan+filter+project prefixes
     # of submitted plans so two distinct plans sharing a prefix execute the
     # shared region once and reuse the materialized intermediate. Gated
@@ -302,9 +300,12 @@ _OPTIONS: dict[str, tuple[Any, type]] = {
     # delay ~= the dial budget before TransportError surfaces.
     "exchange.peer_dial_retries": (8, int),
     "exchange.peer_dial_delay_s": (0.05, float),
-    # How long a destination waits for all manifest-listed peer flights
-    # before the merge fails classified (and the supervisor falls back
-    # to the routed path).
+    # How long a direct exchange may sit on the WIRE before it fails
+    # classified and the supervisor falls back to the routed path: a
+    # destination's wait for its manifest-listed peer flights, and the
+    # supervisor's wait on a worker that is flying, not computing. The
+    # pack and merge plans themselves (minutes of cold compile on a
+    # chip) are not timed by it; the caller's deadline bounds those.
     "exchange.direct_timeout_s": (30.0, float),
     # Planner-placed exchanges: when an interior Exchange node carries
     # parts=0 ("auto"), the partition count comes from the learned-
@@ -333,16 +334,39 @@ _OPTIONS: dict[str, tuple[Any, type]] = {
     # EMA blend weight for newly observed pass fractions (same role as
     # server.estimate_alpha for admission estimates).
     "rtfilter.alpha": (0.4, float),
-    # Where the selectivity EMAs persist ("" = beside the learned
-    # admission estimates, i.e. learned_selectivity.json in the dispatch
-    # persistent cache dir; in-memory only when neither exists). Shares
-    # the flock+merge write discipline with the estimate file.
+    # Where the selectivity EMAs persist ("" = learned_selectivity.json
+    # in cache_dir(), beside the learned admission estimates; in-memory
+    # only when that is switched off). Shares the flock+merge write
+    # discipline with the estimate file.
     "rtfilter.path": ("", str),
     # Debounce for selectivity-state writes, seconds.
     "rtfilter.save_interval_s": (5.0, float),
 }
 
 _overrides: dict[str, Any] = {}
+
+# Where the persistent compile cache lives when JAX_COMPILATION_CACHE_DIR
+# does not place it: one fixed, git-ignored directory at the root of the
+# checkout. The path is part of JAX's cache key, so it is a constant, never
+# built from a temporary name, a pid or the time.
+FIXED_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def cache_dir() -> str:
+    """The one directory this program persists to across processes: JAX's
+    compile cache, and beside it the learned admission estimates
+    (runtime/server.py) and runtime-filter selectivities
+    (runtime/rtfilter.py). ``JAX_COMPILATION_CACHE_DIR`` where it is set
+    (JAX reads it itself; no code sets another directory), else
+    ``FIXED_CACHE_DIR``. Empty when JAX's own switch
+    (``jax_enable_compilation_cache``) is off: then nothing persists."""
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
+        return ""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or FIXED_CACHE_DIR
 
 
 def _parse(raw: str, typ: type) -> Any:

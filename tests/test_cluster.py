@@ -201,6 +201,11 @@ def test_partition_map_routes_to_owner():
             assert t.replica == c._tables["lineitem"].owners[part]
         assert REGISTRY.counter("cluster.route_local").value == 2
         assert REGISTRY.counter("cluster.route_rehomed").value == 0
+        # each host worker's boot_ok named its platform and device
+        for r in c.inspect()["replicas"]:
+            assert r["device"]["platform"] == c.platform == "cpu"
+            assert r["device"]["device_kind"]
+            assert isinstance(r["device"]["device_id"], int)
         # shard_for_key agrees with the sharding: a single-row key table
         # built from row 0's key columns hashes to a valid partition and
         # routing by key_table reaches the same owner
@@ -219,6 +224,27 @@ def test_partition_map_routes_to_owner():
         # mis-keyed lookups are classified, never routed
         with pytest.raises(ValueError, match="key column"):
             c.shard_for_key("lineitem", Table([li.columns[4]]))
+
+
+def test_host_on_unassigned_platform_is_refused_never_live():
+    """The mesh shares the fleet's boot check: a host worker that dials
+    back from a platform it was not assigned is a classified failed boot,
+    host-stamped, never live."""
+    set_option("fleet.quarantine_after", 1)
+    with cluster.QueryCluster(
+            1, worker_env={"JAX_PLATFORMS": "tpu"},
+            per_replica_env={"h0": {"JAX_PLATFORMS": "cpu"}}) as c:
+        h0 = c._find("h0")
+        deadline = time.monotonic() + 60
+        while h0.state != "quarantined" and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert h0.state == "quarantined"
+        assert c.wait_live(timeout=0.1) == 0
+    refused = [r for r in ring_events() if r.get("event") == "boot_refused"]
+    assert len(refused) == 1 and refused[0]["host"] == "h0"
+    assert refused[0]["platform"] == "cpu"
+    assert not [r for r in ring_events() if r.get("event") == "live"]
+    assert REGISTRY.counter("fleet.boot_refused").value == 1
 
 
 def test_unregistered_table_is_classified():

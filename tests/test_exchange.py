@@ -879,3 +879,62 @@ def test_sigkill_host_mid_direct_flight_falls_back_bit_identical():
         assert REGISTRY.counter("cluster.host_deaths").value == 1
         time.sleep(0.3)
         assert c.leaked_bytes() == 0
+
+
+def _wait_slot(slot):
+    """One pending direct-exchange wait and the minimal supervisor state
+    ``QueryCluster._x_collect`` touches."""
+    import types
+
+    key = ("x", "xpack_done", 0)
+    sup = types.SimpleNamespace(_lock=threading.Lock(),
+                                _x_waits={key: None})
+    return sup, (key, threading.Event(), slot, "h0")
+
+
+def test_direct_timeout_times_the_wire_not_the_plan():
+    """``exchange.direct_timeout_s`` must not count a worker's plan (a
+    cold pack plan compiles for minutes on a chip, which sent a cold
+    four-chip cluster down the routed lane): while the worker reports
+    ``busy`` only the caller's deadline bounds the wait; on the wire the
+    cap trips the classified fallback."""
+    sup, wait = _wait_slot({"busy": True})
+    t0 = time.monotonic()
+    with pytest.raises(TimeoutError, match="caller deadline"):
+        cluster.QueryCluster._x_collect(sup, wait, t0 + 0.4, 0.1, "xpack")
+    assert time.monotonic() - t0 >= 0.4  # four caps long, never tripped
+    assert not sup._x_waits
+    sup, wait = _wait_slot({})  # not busy: the worker is flying
+    with pytest.raises(resilience.TransportError, match="stalled on the "
+                       "wire"):
+        cluster.QueryCluster._x_collect(sup, wait, None, 0.1, "xpack")
+    sup, wait = _wait_slot({"busy": True, "status": "ok", "fps": {}})
+    wait[1].set()
+    assert cluster.QueryCluster._x_collect(
+        sup, wait, None, 0.1, "xpack")["fps"] == {}
+
+
+@pytest.mark.slow
+def test_slow_pack_plan_stays_on_the_direct_lane():
+    """End to end: h0 holds its pack for 2.5 s under a 0.5 s
+    ``exchange.direct_timeout_s``. The hold is the worker's compute
+    window (``xbusy``), so the exchange completes direct, bit-identical,
+    with no fallback."""
+    orders = _orders()
+    ref_fp = _fp(tpch.tpch_q13_local(orders, 2))
+    pack, merge = tpch.q13_exchange_plans(2)
+    set_option("exchange.direct_timeout_s", 0.5)
+    try:
+        with cluster.QueryCluster(2, per_replica_env={
+                "h0": {SERVE_DELAY: "2500"}}) as c:
+            assert c.wait_live(timeout=120) == 2
+            c.register_table("orders", orders, keys=(tpch.O_ORDERKEY,))
+            xt = c.submit_exchange(
+                "s0", pack, merge, table="orders", binding="orders",
+                merge_binding="partials",
+                merge_valid_meta="merge.num_groups")
+            assert _fp(xt.result(timeout=120)) == ref_fp
+    finally:
+        reset_option("exchange.direct_timeout_s")
+    assert REGISTRY.counter("cluster.exchanges_direct").value == 1
+    assert REGISTRY.counter("cluster.exchange_direct_fallbacks").value == 0

@@ -240,6 +240,51 @@ def test_boot_crash_loop_quarantines_within_bound():
         assert _fp(got.table) == _fp(fusion.execute(plan, bindings).table)
 
 
+def test_worker_on_unassigned_platform_is_refused_never_live():
+    """The fleet assigns its workers a platform (here "tpu", through the
+    worker environment); a worker that comes up elsewhere (r0's chaos
+    override lands it on the CPU, the quiet way off the chip) is a
+    classified failed boot with a ring event, counted toward the breaker
+    — never live."""
+    set_option("fleet.quarantine_after", 2)
+    with fleet.QueryFleet(
+            1, worker_env={"JAX_PLATFORMS": "tpu"},
+            per_replica_env={"r0": {"JAX_PLATFORMS": "cpu"}}) as f:
+        assert f.platform == "tpu"
+        r0 = f._find("r0")
+        assert _wait(lambda: r0.state == "quarantined", 60), r0.state
+        assert f.wait_live(timeout=0.1) == 0
+    assert _fleet_events("live") == []
+    refused = _fleet_events("boot_refused")
+    assert len(refused) == 2
+    assert refused[0]["platform"] == "cpu"
+    assert "assigned 'tpu'" in refused[0]["reason"]
+    assert REGISTRY.counter("fleet.boot_refused").value == 2
+    deaths = _fleet_events("replica_death")
+    assert deaths and all(
+        d["error_kind"] == "ReplicaDeadError" for d in deaths)
+    assert "booted on platform 'cpu'" in deaths[0]["cause"]
+
+
+def test_accelerator_fleet_needs_a_cpu_supervisor():
+    """A chip belongs to one process: assigning workers an accelerator
+    from a supervisor that is not pinned to the CPU is refused at once,
+    not discovered as N boot timeouts."""
+    import jax
+
+    pinned = jax.config.jax_platforms
+    jax.config.update("jax_platforms", "")  # value only: backend is up
+    try:
+        with pytest.raises(ValueError, match="pinned to the CPU"):
+            fleet.QueryFleet(1, worker_env={"JAX_PLATFORMS": "tpu"})
+    finally:
+        jax.config.update("jax_platforms", pinned)
+    # tpu workers are bounded to one chip each, replica i -> chip i
+    pin = fleet._tpu_pin(2)
+    assert pin["TPU_VISIBLE_CHIPS"] == "2"
+    assert pin["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+
 # ---------------------------------------------------------------------------
 # 5. bounded failover, no-replica classification, duplicate drop
 # ---------------------------------------------------------------------------
@@ -352,6 +397,17 @@ def test_inspect_and_top_fleet_view():
         assert snap["fleet"] is True
         states = {r["replica"]: r["state"] for r in snap["replicas"]}
         assert states == {"r0": "live", "r1": "live"}
+        # boot_ok names what each worker actually runs on; the supervisor
+        # keeps it and stamps it on the "live" ring event
+        assert f.platform == "cpu"
+        for r in snap["replicas"]:
+            assert r["device"]["platform"] == "cpu"
+            assert r["device"]["device_kind"]
+            assert isinstance(r["device"]["device_id"], int)
+            assert r["device"]["device_count"] >= 1
+        for ev in _fleet_events("live"):
+            assert ev["platform"] == "cpu" and "device_kind" in ev
+            assert "device_id" in ev
         assert all(r["last_pong_age_s"] is not None
                    for r in snap["replicas"])
         snaps = tele_top.collect_fleet()

@@ -630,7 +630,8 @@ def test_groupby_and_q1_compile_scatter_free():
     """VERDICT r3 item 9: every aggregate (incl. var/std, float mean,
     nunique, numeric and string min/max) and the full q1 plan must lower
     with ZERO scatter instructions — scatters serialize on the TPU
-    (BASELINE.md measured 1.6-4x vs scan forms). `.at[static_slice].set`
+    (1.6-4x behind the scan forms on a v5e in 2026-07; not measured
+    since). `.at[static_slice].set`
     lowers to pad/dynamic-update-slice, which is fine; this counts real
     scatter HLO ops."""
     import re

@@ -316,6 +316,18 @@ def _key(i):
     return resultcache.CacheKey(f"sig-{i:04d}", f"fp-{i:04d}")
 
 
+def test_default_capacity_follows_the_limiter_budget():
+    # cache.max_bytes 0 (the default): an eighth of the budget entries are
+    # charged to, at least 256 MiB — a server given a v5e's 16.9 GB can
+    # cache one padded general-q3 result at SF1 (336 MB); set, it is exact
+    assert _bare_cache(None)[2].stats()["max_bytes"] == 256 << 20
+    assert _bare_cache(None, budget=16 << 30)[2].stats()["max_bytes"] \
+        == 2 << 30
+    set_option("cache.max_bytes", 1 << 20)
+    assert _bare_cache(None, budget=16 << 30)[2].stats()["max_bytes"] \
+        == 1 << 20
+
+
 def test_lru_bound_and_charge_release():
     res = _result(512, 1)
     per = _table_nbytes(res.table)

@@ -2,7 +2,7 @@
 
 Each rule encodes an invariant the stack already relies on implicitly;
 the docstring of each ``check_*`` names the bug class that motivated it
-(ADVICE.md round-5 findings, BASELINE.md reconciliations). Rules here
+(ADVICE.md round-5 findings, the r02 measurement reconciliation). Rules here
 are pure-AST heuristics judging one file at a time: they
 under-approximate anything that spans modules and occasionally
 over-approximate (a reviewed-legitimate site carries a
