@@ -15,7 +15,8 @@ Compilation is explicit — ``jax.jit(fn).lower(args).compile()`` — rather
 than delegated to jit's internal cache, so compiles and hits are exact,
 countable events (telemetry counters ``dispatch.compile`` /
 ``dispatch.hit``; ``dispatch.padded_waste_bytes`` accounts the padding
-tax; ``dispatch.compile_ms`` is the wall time spent lowering and
+tax and ``dispatch.padded_copy_bytes`` the bytes of the padded copy
+itself; ``dispatch.compile_ms`` is the wall time spent lowering and
 compiling). JAX's persistent compilation cache makes a second process
 start warm: it lives where ``JAX_COMPILATION_CACHE_DIR`` says, else at
 the fixed path ``utils/config.cache_dir()`` names (set once at package
@@ -142,11 +143,14 @@ def _has_tracer(tree: Any) -> bool:
 
 
 class _PadStats:
-    __slots__ = ("padded_bytes", "total_bytes")
+    __slots__ = ("padded_bytes", "total_bytes", "copied_bytes")
 
     def __init__(self) -> None:
         self.padded_bytes = 0
         self.total_bytes = 0
+        # bytes of the leaves that really were copied into a bucket-sized
+        # buffer (a leaf already on its bucket boundary is passed as it is)
+        self.copied_bytes = 0
 
 
 def _pad_array(x: Any, n: int, B: int, acc: _PadStats) -> Any:
@@ -161,6 +165,7 @@ def _pad_array(x: Any, n: int, B: int, acc: _PadStats) -> Any:
     acc.total_bytes += B * row_bytes
     if B == n:
         return jnp.asarray(x)
+    acc.copied_bytes += B * row_bytes
     pad = jnp.zeros((B - n,) + tuple(x.shape[1:]), dtype=x.dtype)
     return jnp.concatenate([jnp.asarray(x), pad], axis=0)
 
@@ -406,14 +411,16 @@ def call(
     buckets = tuple(bucket_for(n) for n in ns) if bucket_rows else ns
     acc = _PadStats()
     try:
-        padded = tuple(
-            _pad_tree(g, n, B, acc)
-            for g, n, B in zip(row_args, ns, buckets))
+        # eager device ops: the padded copy of every leaf and the masks
+        with spans.child("dispatch.pad", op=op):
+            padded = tuple(
+                _pad_tree(g, n, B, acc)
+                for g, n, B in zip(row_args, ns, buckets))
+            row_valids = tuple(
+                jnp.arange(B, dtype=jnp.int32) < jnp.int32(n)
+                for n, B in zip(ns, buckets))
     except Unbucketable:
         return _inline(op, "unbucketable", fn, row_args, aux_args)
-    row_valids = tuple(
-        jnp.arange(B, dtype=jnp.int32) < jnp.int32(n)
-        for n, B in zip(ns, buckets))
 
     key = (op, statics, donate_rows, _kernels_digest(),
            _signature((padded, aux_args, row_valids)),
@@ -477,6 +484,7 @@ def call(
     REGISTRY.counter("dispatch.padded_rows").inc(
         sum(B - n for n, B in zip(ns, buckets)))
     REGISTRY.counter("dispatch.padded_waste_bytes").inc(acc.padded_bytes)
+    REGISTRY.counter("dispatch.padded_copy_bytes").inc(acc.copied_bytes)
     REGISTRY.counter("dispatch.row_bytes_total").inc(acc.total_bytes)
     if donate_rows:
         REGISTRY.counter("dispatch.donated_bytes").inc(acc.total_bytes)

@@ -73,8 +73,10 @@ runs op-by-op, the staged reference path), ``fusion.donate``.
 
 from __future__ import annotations
 
+import re
 from typing import Any, Callable, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -1091,9 +1093,16 @@ def execute(plan: Plan, bindings: dict, *,
         tables = dict(zip(bucketed, row_args_))
         tables.update(zip(exact, aux_args_))
         rvmap = dict(zip(bucketed, rvs_))
-        value, side = _eval_plan(plan.root, tables, rvmap, resolved,
-                                 true_rows)
+        with jax.named_scope(f"region.{plan.name}"):
+            value, side = _eval_plan(plan.root, tables, rvmap, resolved,
+                                     true_rows)
         return value, tuple(v for _, v in side)
+
+    # jit names the compiled module after the function: a profiler trace
+    # then shows jit_region_<plan>, which a reader can key on, and not the
+    # same jit__region for every plan
+    _region.__name__ = _region.__qualname__ = "region_" + re.sub(
+        r"\W", "_", plan.name)
 
     donate = (bool(donate_inputs) and bool(get_option("fusion.donate"))
               and bool(bucketed))

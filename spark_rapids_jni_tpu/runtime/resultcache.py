@@ -142,10 +142,17 @@ def _hash_buffer(h, buf) -> None:
         h.update(repr(buf[2]).encode())
         h.update(buf[3])
         return
-    arr = np.ascontiguousarray(np.asarray(buf))
-    h.update(str(arr.dtype).encode())
-    h.update(repr(arr.shape).encode())
-    h.update(arr.tobytes())
+    # the two halves of a fresh batch's fingerprint, timed apart: bringing
+    # the buffer to the host, and hashing it there
+    with spans.child("cache.fingerprint.copy") as sp:
+        arr = np.ascontiguousarray(np.asarray(buf))
+        nbytes = int(arr.nbytes)
+        sp.annotate(nbytes=nbytes)
+    with spans.child("cache.fingerprint.hash", nbytes=nbytes):
+        h.update(str(arr.dtype).encode())
+        h.update(repr(arr.shape).encode())
+        h.update(arr.tobytes())
+    REGISTRY.counter("cache.fingerprint_bytes").inc(nbytes)
 
 
 def _hash_column(h, col) -> None:

@@ -28,7 +28,17 @@ Contracts, in order of importance:
   admitted/queued/rejected/served/failed counters count per session and
   globally, and the whole execution runs inside
   ``telemetry.session_scope(sid)`` so fallback/spill/resilience events
-  emitted by ANY inner layer carry ``session`` attribution.
+  emitted by ANY inner layer carry ``session`` attribution. Every ticket
+  has a ``request`` id and two span trees joined by it
+  (``telemetry/spans.py``): ``submit.<plan>`` on the client's thread
+  (``cache.fingerprint`` with its per-buffer ``.copy`` / ``.hash``,
+  ``cache.lookup``, ``admission.enqueue``) and ``query.<plan>`` on the
+  worker's (``admission.queue`` from the enqueue to the pickup,
+  ``admission.wait``, ``server.stage_bindings``, the degrade rungs and
+  regions, ``server.record_actual``, ``cache.put``), so nothing between
+  ``submit`` and the ticket's resolve runs outside a named span.
+  ``QueryTicket.queue_wait_s`` is the deadline's clock and starts at
+  submit, fingerprint included; the true wait is the spans'.
 * **No leaks** — a query that dies, however it dies, releases its
   reservation and its in-flight slot; the failure is classified through
   ``resilience.classify`` and recorded before the ticket resolves.
@@ -66,6 +76,7 @@ Config knobs (utils/config.py, env ``SPARK_RAPIDS_TPU_SERVER_*``):
 from __future__ import annotations
 
 import collections
+import itertools
 import os
 import threading
 
@@ -113,6 +124,11 @@ _log = get_logger("spark_rapids_jni_tpu.server")
 # spark_rapids_jni_tpu.telemetry top`` renders inspect() of each. Weak so
 # the registry never keeps a dropped server (and its limiter) alive.
 _LIVE_SERVERS: "weakref.WeakSet[QueryServer]" = weakref.WeakSet()
+
+
+# one id per submitted request, process-wide: what joins the client
+# thread's ``submit.<plan>`` span tree to the worker's ``query.<plan>``
+_REQUEST_IDS = itertools.count(1)
 
 
 def live_servers() -> list:
@@ -199,6 +215,7 @@ class QueryTicket:
                  deadline_ms: int = 0,
                  outofcore: Optional[Callable] = None):
         self.session = session_id
+        self.request = next(_REQUEST_IDS)
         self.plan = plan
         self.bindings = bindings
         self.estimate = int(estimate)
@@ -223,6 +240,11 @@ class QueryTicket:
         self.rung: Optional[int] = None
         self.steps: Optional[int] = None
         self._submitted_at = time.monotonic()
+        # set by submit: the id of its submit.<plan> span (None with
+        # telemetry off) and when the ticket entered its session's queue,
+        # which is where the worker's admission.queue span starts
+        self._submit_span: Optional[int] = None
+        self._enqueued_at: Optional[float] = None
         self._value: Any = None
         self._exc: Optional[BaseException] = None
         self._done = threading.Event()
@@ -419,7 +441,8 @@ class QueryServer:
         With ``cache.enabled``, a submission whose ``(plan signature,
         input fingerprint)`` matches a cached result resolves served
         IMMEDIATELY — no admission, no compile, no execution; the hit is
-        visible as a ``cache.hit`` span under the query's root span.
+        visible as ``query.<plan>`` / ``cache.hit`` under the request's
+        ``submit.<plan>`` span.
         ``cache_fingerprint`` overrides the content digest of the
         bindings (e.g. a :func:`resultcache.source_fingerprint` the
         client maintains for file-backed scans) — changing it is the
@@ -432,29 +455,50 @@ class QueryServer:
                   else get_option("server.deadline_ms"))
         ticket = QueryTicket(sid, plan, bindings, estimate, donate_inputs,
                              deadline_ms=ddl, outofcore=outofcore)
+        # the request's first root, on the CLIENT's thread: fingerprint,
+        # cache lookup and enqueue are its children; the worker's root
+        # query.<plan> carries the same request id and names this span
+        # in caused_by (a cache hit's query.<plan> nests right here)
+        with spans.span(f"submit.{plan.name}", session=sid, plan=plan.name,
+                        request=ticket.request) as sspan:
+            ticket._submit_span = sspan.id
+            self._submit(ticket, cache_fingerprint)
+        return ticket
+
+    def _submit(self, ticket: QueryTicket,
+                cache_fingerprint: Optional[str]) -> None:
+        """The body of :meth:`submit`, inside its ``submit.<plan>`` span:
+        resolve the ticket from the cache, reject it, or queue it."""
+        sid, plan, estimate = ticket.session, ticket.plan, ticket.estimate
         self._count("submitted", sid)
         record_server(plan.name, "submitted", session=sid,
                       estimate_bytes=estimate)
         if resultcache.enabled():
             try:
-                ticket.cache_key = resultcache.cache_key(
-                    plan, bindings, fingerprint=cache_fingerprint)
+                with spans.child("cache.fingerprint", session=sid) as fsp:
+                    ticket.cache_key = resultcache.cache_key(
+                        plan, ticket.bindings, fingerprint=cache_fingerprint)
+                    if fsp:
+                        fsp.annotate(nbytes=sum(
+                            c.attrs["nbytes"] for c in fsp.children
+                            if c.name == "cache.fingerprint.hash"))
             except (ValueError, KeyError, TypeError):
                 # unfingerprintable plan/bindings (local callables,
                 # non-table bindings): serve normally, never cache
                 ticket.cache_key = None
             if ticket.cache_key is not None:
-                hit = self.result_cache.get(ticket.cache_key)
+                with spans.child("cache.lookup", session=sid):
+                    hit = self.result_cache.get(ticket.cache_key)
                 if hit is not None:
                     self._serve_hit(ticket, hit)
-                    return ticket
+                    return
         if estimate > self.limiter.budget:
             self._reject(ticket,
                          f"estimate {estimate} exceeds the whole HBM "
                          f"budget ({self.limiter.budget}): can never fit",
                          retry_after_s=None)
-            return ticket
-        with self._cond:
+            return
+        with spans.child("admission.enqueue", session=sid), self._cond:
             if self._closed:
                 reject_why = "server closed"
                 retry_after: Optional[float] = None
@@ -471,15 +515,15 @@ class QueryServer:
             else:
                 reject_why = None
                 retry_after = None
+                ticket._enqueued_at = time.monotonic()
                 self._queues[sid].append(ticket)
                 self._cond.notify()
         if reject_why is not None:
             self._reject(ticket, reject_why, retry_after_s=retry_after)
-            return ticket
+            return
         self._count("queued", sid)
         record_server(plan.name, "queued", session=sid,
                       estimate_bytes=estimate)
-        return ticket
 
     def close(self, timeout: Optional[float] = 30.0) -> None:
         """Stop accepting work, drain the workers, reject the backlog."""
@@ -879,8 +923,8 @@ class QueryServer:
     def _serve_hit(self, ticket: QueryTicket, result) -> None:
         """Resolve a submit-time cache hit: the cached result is returned
         bit-identically with zero admission wait, zero compiles and zero
-        execution spans — one root span carrying a single ``cache.hit``
-        child is the query's whole trace."""
+        execution spans — ``query.<plan>`` carrying a single ``cache.hit``
+        child, nested under submit's span, is all the request executes."""
         sid = ticket.session
         with spans.span(f"query.{ticket.plan.name}", session=sid,
                         plan=ticket.plan.name,
@@ -1018,14 +1062,23 @@ class QueryServer:
         with self._inflight_lock:
             self._inflight[id(ticket)] = info
         try:
-            # ONE root span per query: every instrumented seam below
-            # (admission, degrade rungs, regions, pipeline chunks,
-            # spills) attaches to this tree via the thread-local stack
+            # ONE root span per query on this thread: every instrumented
+            # seam below (admission, degrade rungs, regions, pipeline
+            # chunks, spills) attaches to this tree via the thread-local
+            # stack; request/caused_by join it to submit's tree
+            joined = {"request": ticket.request}
+            if ticket._submit_span is not None:
+                joined["caused_by"] = ticket._submit_span
             with spans.span(f"query.{ticket.plan.name}", session=sid,
                             plan=ticket.plan.name,
-                            estimate_bytes=ticket.estimate) as qspan:
+                            estimate_bytes=ticket.estimate,
+                            **joined) as qspan:
                 info["span"] = qspan
                 try:
+                    # the true queue wait: from the client's enqueue (after
+                    # its fingerprint) to this pickup
+                    spans.record_child("admission.queue",
+                                       ticket._enqueued_at, session=sid)
                     faults.fire("server.admit", 0, session=sid,
                                 plan=ticket.plan.name)
                     if token.cancelled():
@@ -1098,7 +1151,8 @@ class QueryServer:
                         faults.fire("server.execute", 0, session=sid,
                                     plan=ticket.plan.name)
                         token.check("server.execute")
-                        bindings = self._stage_bindings(ticket.bindings)
+                        with spans.child("server.stage_bindings"):
+                            bindings = self._stage_bindings(ticket.bindings)
                         runner = None if ticket.outofcore is None \
                             else ticket.outofcore(bindings, self.limiter)
                         # subplan-prefix reuse: shared scan+filter+project
@@ -1135,10 +1189,13 @@ class QueryServer:
                     record_server(ticket.plan.name, "served", session=sid,
                                   wall_ms=lat_ms,
                                   wait_ms=ticket.queue_wait_s * 1e3)
-                    self._record_actual(ticket, bindings, result)
+                    with spans.child("server.record_actual", session=sid):
+                        self._record_actual(ticket, bindings, result)
                     if ticket.cache_key is not None:
                         try:
-                            self.result_cache.put(ticket.cache_key, result)
+                            with spans.child("cache.put", session=sid):
+                                self.result_cache.put(ticket.cache_key,
+                                                      result)
                         except Exception as exc:
                             # a cache-population failure must never fail
                             # a query that already served

@@ -1,15 +1,26 @@
-"""Hierarchical query spans: one causal tree per served query.
+"""Hierarchical query spans: one request, one span tree per thread it
+crosses, joined by the request's id.
 
 The reference answers "why was this query slow" with NVTX ranges
 (``CUDF_FUNC_RANGE()``) that nest into a causal timeline in Nsight; our
 flat counters and unordered JSONL events cannot. A span is a named,
 timestamped (``time.monotonic``) node with an id, a parent id and a
-status (``ok`` / ``degraded`` / ``cancelled`` / ``failed``); the serving
-path opens one root per query and every instrumented seam underneath
-(admission wait, degrade rung, fused region, out-of-core chunk stage,
-spill/unspill) attaches a child, so a single tree records
-``query -> admission.wait -> rung.* -> region.* / pipeline.chunk ->
-pipeline.{decode,staging,transfer,compute,merge} -> spill/unspill``.
+status (``ok`` / ``degraded`` / ``cancelled`` / ``failed``). A served
+request starts on the client's thread, where ``QueryServer.submit`` opens
+the root ``submit.<plan>`` (``request=<id>``), and goes on on a worker,
+whose root ``query.<plan>`` carries the same ``request`` and
+``caused_by=<the submit span's id>``; every instrumented seam underneath
+attaches a child, so the two trees record::
+
+    submit.<plan> -> cache.fingerprint -> cache.fingerprint.{copy,hash}
+                  -> cache.lookup -> admission.enqueue
+                  (a cache hit: -> query.<plan> -> cache.hit)
+    query.<plan>  -> admission.queue -> admission.wait
+                  -> server.stage_bindings -> rung.* -> region.<plan>
+                     -> dispatch.{pad,compile,execute} / pipeline.chunk ->
+                     pipeline.{decode,staging,transfer,compute,merge}
+                     -> spill/unspill
+                  -> server.record_actual -> cache.put
 
 Contracts:
 - **Zero overhead when disabled.** Every factory checks
@@ -18,6 +29,12 @@ Contracts:
 - **Never on the device path.** Spans only read the host clock and
   append to host-side structures; opening or closing one never forces a
   device sync or transfer.
+- **On the profiler's clock too.** Entering a span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name (stats ``span`` and, in a
+  request's tree, ``request``), so a profiler trace holds the program's
+  spans in its host plane beside the device plane. This module still
+  never imports jax: the class is taken from ``sys.modules`` once jax is
+  there, and outside a profiler session entering one costs a flag test.
 - **Emission through the one JSONL sink.** Closing a span emits a
   ``kind="span"`` record via events._emit — same ring buffer, same
   file, same never-raise posture as every other telemetry record.
@@ -43,6 +60,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -62,6 +80,7 @@ __all__ = [
     "NULL_SPAN",
     "span",
     "child",
+    "record_child",
     "current_span",
     "current_root",
     "validate",
@@ -98,6 +117,19 @@ def _stack() -> list:
         stack = []
         _ctx.stack = stack
     return stack
+
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` if the workload has imported jax,
+    else None. Never imports it (test_import_hygiene.py)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation_cls = getattr(profiler, "TraceAnnotation", None)
+    return _annotation_cls
 
 
 class _NullSpan:
@@ -145,7 +177,7 @@ class Span:
     """
 
     __slots__ = ("id", "parent", "root", "name", "status", "start", "end",
-                 "attrs", "children", "tid", "_entered",
+                 "attrs", "children", "tid", "_entered", "_annotation",
                  "_tree_lock", "_nodes", "_dropped", "_max_nodes")
 
     def __init__(self, name: str, parent: Optional["Span"],
@@ -160,6 +192,7 @@ class Span:
         self.end: Optional[float] = None
         self.tid = threading.get_ident()
         self._entered = False
+        self._annotation = None
         if parent is None:
             self.root = self
             # the in-memory tree backs the flight recorder and inspect();
@@ -181,6 +214,22 @@ class Span:
     def __enter__(self) -> "Span":
         if self._entered:
             raise RuntimeError(f"span {self.name!r} entered twice")
+        self._attach()
+        _stack().append(self)
+        self.start = time.monotonic()
+        cls = _trace_annotation()
+        if cls is not None:
+            stats = {"span": self.id}
+            request = self.root.attrs.get("request")
+            if request is not None:
+                stats["request"] = request
+            self._annotation = cls(self.name, **stats)
+            self._annotation.__enter__()
+        return self
+
+    def _attach(self) -> None:
+        """Mark the span entered on this thread and hang it under its
+        parent, while the tree has room."""
         self._entered = True
         self.tid = threading.get_ident()
         if self.parent is not None:
@@ -191,11 +240,11 @@ class Span:
                     self.parent.children.append(self)
                 else:
                     root._dropped += 1
-        _stack().append(self)
-        self.start = time.monotonic()
-        return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         self.end = time.monotonic()
         if exc_type is not None and self.status == "ok":
             names = {c.__name__ for c in getattr(exc_type, "__mro__", ())}
@@ -206,6 +255,12 @@ class Span:
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
+        self._emit()
+        return False
+
+    def _emit(self) -> None:
+        """The closed span's JSONL record; a root also goes to the flight
+        recorder."""
         if _events.enabled():
             rec = dict(self.attrs)
             rec.update({
@@ -228,7 +283,6 @@ class Span:
                     "root": self.id,
                     "tree": self.tree(),
                 })
-        return False
 
     # -- mutation ------------------------------------------------------------
 
@@ -308,6 +362,23 @@ def child(name: str, *, parent: Optional[Span] = None, **attrs: Any):
     if p is None or isinstance(p, _NullSpan):
         return NULL_SPAN
     return Span(name, p, attrs)
+
+
+def record_child(name: str, start: float, **attrs: Any) -> None:
+    """Record a child of this thread's current span that began at ``start``
+    (a ``time.monotonic`` reading, taken on whichever thread the wait
+    began on) and ends now. For an interval whose start no ``with`` on
+    this thread can see: a ticket's wait between the client's enqueue and
+    the worker's pickup. It lies before its parent's own start, and it
+    has no profiler annotation (the profiler takes no past start)."""
+    parent = current_span()
+    if parent is None or not _events.enabled():
+        return
+    sp = Span(name, parent, attrs)
+    sp._attach()
+    sp.start = float(start)
+    sp.end = time.monotonic()
+    sp._emit()
 
 
 def current_span() -> Optional[Span]:
@@ -599,7 +670,9 @@ def phase_breakdown(records: Iterable[dict]) -> dict:
             queue_s += float(r.get("wait_ms", 0.0)) / 1e3
     phases["queue"] = max(0.0, queue_s - phases["admission"])
     return {
-        "queries": len(roots),
+        # a request served by a worker has two roots (submit.* on the
+        # client's thread, and the worker's, which names it in caused_by)
+        "queries": sum("caused_by" not in r for r in roots),
         "total_s": round(total, 6),
         "phases_s": {k: round(v, 6) for k, v in phases.items()},
         "fractions": ({k: (round(v / total, 4) if total else 0.0)

@@ -17,9 +17,6 @@ _ENV_PREFIX = "SPARK_RAPIDS_TPU_"
 
 # option name -> (default, parser)
 _OPTIONS: dict[str, tuple[Any, type]] = {
-    # NVTX-equivalent trace annotations (ai.rapids.cudf.nvtx.enabled parity;
-    # default false like pom.xml:85).
-    "tracing.enabled": (False, bool),
     # Lift the reference's 1.5KB row-size contract check.
     "row_conversion.enforce_row_limit": (True, bool),
     # Log level for the thin runtime logger (slf4j-equivalent).
@@ -33,7 +30,8 @@ _OPTIONS: dict[str, tuple[Any, type]] = {
     "regex.force_engine": ("", str),
     # Execution telemetry (telemetry/): record op dispatches, device->host
     # fallbacks (with reasons), compile-cache hits, spills, bench staleness.
-    # Off by default — same posture as tracing.enabled.
+    # Also the NVTX-equivalent switch (ai.rapids.cudf.nvtx.enabled parity,
+    # default false like pom.xml:85): spans write profiler annotations.
     "telemetry.enabled": (False, bool),
     # JSONL sink for telemetry events; "" = in-process ring buffer only.
     "telemetry.path": ("", str),
@@ -392,7 +390,7 @@ def set_option(name: str, value: Any) -> None:
         raise KeyError(f"unknown option {name!r}")
     _, typ = _OPTIONS[name]
     # coerce through the same parser env values get, so
-    # set_option("tracing.enabled", "off") == env ..._ENABLED=off
+    # set_option("telemetry.enabled", "off") == env ..._ENABLED=off
     _overrides[name] = _parse(value, typ) if isinstance(value, str) else typ(value)
 
 

@@ -1,22 +1,20 @@
-"""Profiler trace annotations — the NVTX-range equivalent.
+"""Named ranges around instrumented ops — the NVTX-range equivalent.
 
 The reference opens an NVTX range (``CUDF_FUNC_RANGE()``) at the top of every
 nontrivial native function (e.g. NativeParquetJni.cpp:191,400,455,508) behind
-the ``ai.rapids.cudf.nvtx.enabled`` toggle (pom.xml:85,437). Here the same
-granularity is provided with ``jax.profiler.TraceAnnotation``, which lands in
-XLA/Perfetto traces captured via ``jax.profiler.trace``. Disabled by default,
-toggled by the ``tracing.enabled`` option (env
-``SPARK_RAPIDS_TPU_TRACING_ENABLED=1``).
+the ``ai.rapids.cudf.nvtx.enabled`` toggle (pom.xml:85,437). Here a range is a
+thin wrapper over ``spans.child`` (telemetry/spans.py) behind the one switch
+``telemetry.enabled``: when a query span is open on this thread the range
+attaches a child span, so every ``trace_range``-wrapped stage lands in the
+served query's causal tree and, through the span's own
+profiler annotation, in the host plane of a profiler trace.
+Outside a served query a range opens nothing.
 
-This is the one seam instrumented ops share: the profiler annotation, the
-telemetry dispatch record and the query span tree all hang off it. When a
-query span is open on this thread (telemetry/spans.py), the range attaches a
-child span — so every ``trace_range``-wrapped stage lands in the served
-query's causal tree without its own instrumentation. ``record=True``
-additionally times the range and records a ``dispatch`` telemetry event
-carrying ``wall_ms``; a body that raises still records, with
-``status="error"`` and the exception class, so failed dispatches are visible
-in the per-op report instead of silently dropping their timing.
+``record=True`` additionally times the range and records a ``dispatch``
+telemetry event carrying ``wall_ms`` (``telemetry report`` reads it); a body
+that raises still records, with ``status="error"`` and the exception class,
+so failed dispatches are visible in the per-op report instead of silently
+dropping their timing.
 """
 
 from __future__ import annotations
@@ -28,33 +26,25 @@ from typing import Callable, TypeVar
 
 from spark_rapids_jni_tpu import telemetry
 from spark_rapids_jni_tpu.telemetry import spans
-from spark_rapids_jni_tpu.utils.config import get_option
 
 F = TypeVar("F", bound=Callable)
 
 
 @contextlib.contextmanager
 def trace_range(name: str, record: bool = False):
-    """Context manager opening a named profiler range when tracing is on.
+    """Context manager attaching a child span named ``name`` to the query
+    span open on this thread (none open, or telemetry off: nothing).
 
     With ``record=True`` (and telemetry enabled), also times the body and
     records a ``dispatch`` telemetry event carrying ``wall_ms`` — with
     ``status="error"`` / ``error=<exception class>`` when the body raises.
-    With telemetry enabled and a query span open on this thread, the range
-    additionally attaches a child span to the query's tree.
     """
     if record:
         record = telemetry.enabled()
     t0 = time.perf_counter() if record else 0.0
     try:
         with spans.child(name):
-            if get_option("tracing.enabled"):
-                import jax.profiler
-
-                with jax.profiler.TraceAnnotation(name):
-                    yield
-            else:
-                yield
+            yield
     except BaseException as exc:
         if record:
             telemetry.record_dispatch(

@@ -14,22 +14,22 @@ def _reset():
 
 
 def test_defaults():
-    assert config.get_option("tracing.enabled") is False
+    assert config.get_option("telemetry.enabled") is False
     assert config.get_option("row_conversion.enforce_row_limit") is True
 
 
 def test_env_override(monkeypatch):
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_TRACING_ENABLED", "true")
-    assert config.get_option("tracing.enabled") is True
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_TRACING_ENABLED", "off")
-    assert config.get_option("tracing.enabled") is False
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_TELEMETRY_ENABLED", "true")
+    assert config.get_option("telemetry.enabled") is True
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_TELEMETRY_ENABLED", "off")
+    assert config.get_option("telemetry.enabled") is False
 
 
 def test_set_option_coerces_like_env():
-    config.set_option("tracing.enabled", "off")
-    assert config.get_option("tracing.enabled") is False
-    config.set_option("tracing.enabled", "1")
-    assert config.get_option("tracing.enabled") is True
+    config.set_option("telemetry.enabled", "off")
+    assert config.get_option("telemetry.enabled") is False
+    config.set_option("telemetry.enabled", "1")
+    assert config.get_option("telemetry.enabled") is True
 
 
 def test_unknown_option_rejected():

@@ -682,3 +682,159 @@ def test_warmup_builder_registration_validates():
         server.register_warmup_builder("", lambda rows: None)
     with pytest.raises(TypeError):
         server.register_warmup_builder("not_callable", 42)
+
+
+# ---------------------------------------------------------------------------
+# one request, one span tree from submit to resolve (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+
+def _request_spans(ticket, want_roots, timeout=10.0):
+    """The span records of ``ticket``'s request, once ``want_roots`` of its
+    roots have closed (the worker's root closes just after the ticket
+    resolves): ({op: record} of the roots, [records of their trees])."""
+    deadline = time.monotonic() + timeout
+    while True:
+        recs = [r for r in ring_events() if r.get("kind") == "span"]
+        roots = [r for r in recs if r["parent"] is None
+                 and r.get("request") == ticket.request]
+        if len(roots) >= want_roots or time.monotonic() > deadline:
+            break
+        time.sleep(0.005)
+    ids = {r["span"] for r in roots}
+    return ({r["op"]: r for r in roots},
+            [r for r in recs if r["root"] in ids])
+
+
+def _table_bytes(table):
+    return sum(np.asarray(buf).nbytes for col in table.columns
+               for buf in (col.data, col.validity, col.chars)
+               if buf is not None)
+
+
+def test_served_miss_is_two_trees_joined_by_request():
+    from spark_rapids_jni_tpu.telemetry import spans
+
+    set_option("telemetry.enabled", True)
+    plan, bindings = _q1_bindings(600)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        ticket = srv.session("s1").submit(plan, bindings)
+        ticket.result(timeout=60)
+        roots, tree = _request_spans(ticket, 2)
+    assert set(roots) == {"submit.tpch_q1", "query.tpch_q1"}
+    sub, qry = roots["submit.tpch_q1"], roots["query.tpch_q1"]
+    assert sub["request"] == qry["request"] == ticket.request
+    assert qry["caused_by"] == sub["span"] == ticket._submit_span
+    assert "caused_by" not in sub
+    assert sub["tid"] == threading.get_ident() != qry["tid"]
+    assert spans.validate(tree) == []
+
+    def names_under(root):
+        return {r["op"] for r in tree if r["root"] == root["span"]}
+
+    assert names_under(sub) == {
+        "submit.tpch_q1", "cache.fingerprint", "cache.fingerprint.copy",
+        "cache.fingerprint.hash", "cache.lookup", "admission.enqueue"}
+    assert names_under(qry) >= {
+        "query.tpch_q1", "admission.queue", "admission.wait",
+        "server.stage_bindings", "rung.fused", "region.tpch_q1",
+        "dispatch.pad", "dispatch.execute", "server.record_actual",
+        "cache.put"}
+    # what ran after the region is inside the client's latency and now
+    # inside a span: it ends before the root does
+    put = next(r for r in tree if r["op"] == "cache.put")
+    assert qry["t0"] <= put["t0"] <= put["t1"] <= qry["t1"]
+    # one request, counted once
+    assert spans.phase_breakdown(tree)["queries"] == 1
+
+
+def test_served_hit_is_one_tree():
+    set_option("telemetry.enabled", True)
+    plan, bindings = _q1_bindings(600)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        session = srv.session("s1")
+        session.submit(plan, bindings).result(timeout=60)
+        ticket = session.submit(plan, bindings)
+        assert ticket.done() and ticket.status == "served"
+        roots, tree = _request_spans(ticket, 1)
+    assert set(roots) == {"submit.tpch_q1"}
+    by_op = {r["op"]: r for r in tree}
+    assert set(by_op) == {"submit.tpch_q1", "cache.fingerprint",
+                          "cache.lookup", "query.tpch_q1", "cache.hit"}
+    assert by_op["query.tpch_q1"]["parent"] == roots["submit.tpch_q1"]["span"]
+    assert by_op["cache.hit"]["parent"] == by_op["query.tpch_q1"]["span"]
+    # the table's fingerprint is memoized: nothing came to the host again
+    assert by_op["cache.fingerprint"]["nbytes"] == 0
+
+
+def test_fingerprint_spans_and_counter_account_for_the_tables_bytes():
+    set_option("telemetry.enabled", True)
+    plan, bindings = _q1_bindings(700)
+    want = _table_bytes(bindings["lineitem"])
+    before = REGISTRY.counters().get("cache.fingerprint_bytes", 0)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        ticket = srv.session("s1").submit(plan, bindings)
+        ticket.result(timeout=60)
+        _, tree = _request_spans(ticket, 2)
+    for half in ("copy", "hash"):
+        assert sum(r["nbytes"] for r in tree
+                   if r["op"] == f"cache.fingerprint.{half}") == want
+    (whole,) = [r for r in tree if r["op"] == "cache.fingerprint"]
+    assert whole["nbytes"] == want
+    assert REGISTRY.counters()["cache.fingerprint_bytes"] - before == want
+
+
+@pytest.mark.parametrize("rows, padded", [(600, True), (1024, False)])
+def test_padded_copy_bytes_counts_the_whole_copy(rows, padded):
+    """Off a bucket boundary every data leaf is copied to a bucket-sized
+    buffer (bucket rows x row bytes); on one, none is."""
+    plan, bindings = _q1_bindings(rows)
+    row_bytes = sum(np.dtype(c.data.dtype).itemsize
+                    for c in bindings["lineitem"].columns)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        srv.session("s1").submit(plan, bindings).result(timeout=60)
+    c = REGISTRY.counters()
+    assert c.get("dispatch.padded_copy_bytes", 0) == (
+        1024 * row_bytes if padded else 0)
+    assert c.get("dispatch.padded_waste_bytes", 0) == (
+        (1024 - rows) * row_bytes)
+
+
+def test_admission_queue_starts_at_enqueue_not_at_ticket_creation(
+        monkeypatch):
+    """A slow fingerprint sits between the ticket's creation and its
+    enqueue: ``queue_wait_s`` (the deadline clock) counts it, the
+    ``admission.queue`` span does not."""
+    from spark_rapids_jni_tpu.runtime import resultcache
+
+    set_option("telemetry.enabled", True)
+    real = resultcache.cache_key
+
+    def slow_cache_key(*args, **kwargs):
+        time.sleep(0.25)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(resultcache, "cache_key", slow_cache_key)
+    plan, bindings = _q1_bindings(600)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        ticket = srv.session("s1").submit(plan, bindings)
+        ticket.result(timeout=60)
+        roots, tree = _request_spans(ticket, 2)
+    (queue,) = [r for r in tree if r["op"] == "admission.queue"]
+    assert queue["parent"] == roots["query.tpch_q1"]["span"]
+    assert queue["t0"] == ticket._enqueued_at
+    assert queue["t0"] - ticket._submitted_at >= 0.25
+    assert queue["t1"] <= roots["query.tpch_q1"]["t0"] + 1e-3
+    assert ticket.queue_wait_s >= 0.25
+
+
+def test_region_module_is_named_after_the_plan():
+    """The compiled module's name is what a device trace shows: it has to
+    tell one plan's region from another's."""
+    plan, bindings = _q1_bindings(600)
+    fusion.execute(plan, bindings)
+    (compiled,) = [v for k, v in dispatch._EXEC_CACHE.items()
+                   if k[0] == "fusion.tpch_q1"]
+    head = compiled.as_text().split("\n", 1)[0]
+    assert "jit_region_tpch_q1" in head, head
+    assert "jit__region" not in head

@@ -148,6 +148,62 @@ def test_child_without_parent_is_null(enabled):
     assert _span_records() == []
 
 
+def test_record_child_with_a_given_start(enabled):
+    """A wait that began on another thread: the child starts where it is
+    told, ends now, and opens nothing on this thread's stack."""
+    import time
+
+    spans.record_child("admission.queue", 1.0)   # no tree: nothing
+    assert _span_records() == []
+    began = time.monotonic() - 0.5
+    with spans.span("query.q") as q:
+        spans.record_child("admission.queue", began, session="s")
+        assert spans.current_span() is q
+        (node,) = q.tree()["children"]
+        assert node["name"] == "admission.queue" and node["status"] == "ok"
+    by_op = {r["op"]: r for r in _span_records()}
+    rec = by_op["admission.queue"]
+    assert rec["parent"] == by_op["query.q"]["span"]
+    assert rec["t0"] == began and rec["session"] == "s"
+    assert 0.5 <= rec["t1"] - rec["t0"] < 5.0
+    assert rec["t1"] <= by_op["query.q"]["t1"]
+    assert spans.validate(_span_records()) == []
+
+
+def test_spans_land_in_a_profiler_trace(enabled, tmp_path):
+    """Under a profiler session every span is also a TraceAnnotation of
+    its name in the host plane, carrying its id and the request's."""
+    import glob
+
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "trace"),
+                             profiler_options=options)
+    try:
+        with spans.span("submit.q", request=41) as root:
+            with spans.child("cache.fingerprint"):
+                with trace_range("cache.fingerprint.copy"):
+                    pass
+            spans.record_child("admission.queue", 0.0)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "trace" / "plugins" / "profile"
+                            / "*" / "*.xplane.pb"))
+    profile = jax.profiler.ProfileData.from_file(path)
+    found = {ev.name: dict(ev.stats) for plane in profile.planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events}
+    assert {"submit.q", "cache.fingerprint",
+            "cache.fingerprint.copy"} <= set(found)
+    assert found["submit.q"] == {"span": root.id, "request": 41}
+    assert found["cache.fingerprint"]["request"] == 41
+    # a recorded child has no past to annotate
+    assert "admission.queue" not in found
+
+
 def test_span_tree_node_cap(enabled):
     config.set_option("telemetry.max_spans_per_tree", 4)
     with spans.span("query.q"):
@@ -177,9 +233,28 @@ def test_disabled_emits_nothing():
         with spans.child("rung.fused") as c:
             c.set_status("degraded")
             c.annotate(x=1)
+    spans.record_child("admission.queue", 0.0)
     assert telemetry.events() == []
     assert spans.flight_records() == []
     assert not spans.dump_flight_record("failed")
+
+
+def test_disabled_submit_opens_nothing():
+    """The served path with telemetry off: ``submit`` opens no span on the
+    client's thread, the worker none, and the ticket names none."""
+    from spark_rapids_jni_tpu.models import tpch
+    from spark_rapids_jni_tpu.runtime.server import QueryServer
+
+    assert not telemetry.enabled()
+    with QueryServer(budget_bytes=1 << 28) as srv:
+        ticket = srv.session("s").submit(
+            tpch._q1_plan(), {"lineitem": tpch.lineitem_table(600, seed=1)})
+        ticket.result(timeout=60)
+    assert ticket.status == "served" and ticket.request >= 1
+    assert ticket._submit_span is None
+    assert spans.current_span() is None
+    assert telemetry.events() == []
+    assert spans.flight_records() == []
 
 
 def test_null_span_is_falsy_and_inert():
