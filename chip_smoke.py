@@ -240,6 +240,68 @@ def _table_bytes(table) -> list:
              np.asarray(c.valid_mask()).tobytes()) for c in table.columns]
 
 
+def _every_digested_dtype(rows: int, seed: int):
+    """A table with a column of every width and kind the buffer digest
+    takes, over the whole range of each (negative values, high halves,
+    signed zeros and infinities), half of them with a validity mask."""
+    import numpy as np
+
+    from spark_rapids_jni_tpu import types as t
+    from spark_rapids_jni_tpu.columnar import Column, Table
+
+    rng = np.random.default_rng(seed)
+    cols = []
+    for i, dtype in enumerate((t.INT64, t.UINT64, t.INT32, t.UINT32,
+                               t.INT16, t.UINT16, t.INT8, t.UINT8)):
+        info = np.iinfo(dtype.jnp_dtype)
+        cols.append(Column.from_numpy(
+            rng.integers(info.min, info.max, rows, dtype=dtype.jnp_dtype,
+                         endpoint=True), dtype,
+            validity=rng.random(rows) < 0.9 if i % 2 else None))
+    floats = rng.standard_normal(rows).astype(np.float32)
+    floats[::1001] = -0.0
+    floats[1::1001] = np.inf
+    cols.append(Column.from_numpy(floats, t.FLOAT32))
+    return Table(cols)
+
+
+def check_fingerprint(ctx: _Ctx, table, what: str) -> dict:
+    """The content fingerprint computed where the table lives has to be
+    the one numpy computes over its host copy: a fleet's supervisor, on
+    the CPU, compares its own with the one a replica took on its chip."""
+    from spark_rapids_jni_tpu.columnar import Table
+    from spark_rapids_jni_tpu.runtime import memory, resultcache
+
+    def timed(value):
+        before = _counters("cache.fingerprint")
+        t0 = time.perf_counter()
+        fp = resultcache.input_fingerprint({"t": value})
+        took = time.perf_counter() - t0
+        moved = {k: v - before.get(k, 0)
+                 for k, v in _counters("cache.fingerprint").items()}
+        return fp, took, moved
+
+    # the buffers under new Table objects: the memo is the object's, and
+    # the caller's table stays without one
+    first, first_s, _ = timed(Table(list(table.columns)))
+    again, again_s, moved = timed(Table(list(table.columns)))
+    chunk = memory.host_table_chunk(
+        [memory._col_to_host(c) for c in table.columns], table.num_rows)
+    host, host_s, _ = timed(chunk)
+    ctx.say(f"fingerprint {what}: {moved.get('cache.fingerprint_bytes', 0)} "
+            f"bytes, {moved.get('cache.fingerprint_device_bytes', 0)} of "
+            f"them digested on the device, in {again_s:.4f}s "
+            f"({first_s:.3f}s the first time, compiles included); numpy "
+            f"over the host copy {host_s:.3f}s; {first[:16]}")
+    if not first == again == host:
+        raise SmokeFailure(
+            f"fingerprint {what}: the device gives {first} then {again}, "
+            f"numpy over the host copy {host}: the fingerprint depends on "
+            f"where the bytes live")
+    return {"first_s": first_s, "warm_s": again_s, "host_s": host_s,
+            "moved": moved}
+
+
 # ---------------------------------------------------------------------------
 # phase: probe — is there a chip, and what is it
 # ---------------------------------------------------------------------------
@@ -470,6 +532,10 @@ def serve_phase(sizes: dict, platform: str, seed: int = 0,
         ctx.say(f"load: 4 x lineitem {n1} rows, 2 x (customer {ncust}, "
                 f"orders {nord}, lineitem {n1}) in "
                 f"{time.perf_counter() - t0:.1f}s")
+        report["fingerprint"] = check_fingerprint(
+            ctx, li_b, f"lineitem@{n1}")
+        check_fingerprint(ctx, _every_digested_dtype(n1, seed),
+                          f"every digested dtype@{n1}")
         # q1 on tables that came from Parquet through the native reader
         # (their own seeds: the result cache is content-addressed, and the
         # same rows as li_a would be served from it without executing)

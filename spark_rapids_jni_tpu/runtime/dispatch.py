@@ -60,6 +60,7 @@ __all__ = [
     "bucket_for",
     "quantize_capacity",
     "call",
+    "compiled",
     "rowwise",
     "sharded_call",
     "stats",
@@ -491,6 +492,32 @@ def call(
     if slice_rows:
         out = _slice_tree(out, ns[0], buckets[0])
     return out
+
+
+def compiled(op: str, fn: Callable, *args: Any) -> Callable:
+    """The executable of ``jax.jit(fn)`` for exactly these arguments: no
+    bucketing, no padding, no masks, for a function whose result depends
+    on every element of its inputs (a content digest). Same cache as
+    :func:`call` (single flight; keyed by op and the arguments' shapes,
+    dtypes and shardings) and the same counters, so a compile inside a
+    measured window shows as ``dispatch.compile`` like any other. Unlike
+    :func:`call` it raises what lowering or compiling raises: the caller
+    owns the fallback."""
+    key = (op, _signature(args))
+    executable, lead_ev = _cache_lookup(key)
+    if executable is not None:
+        REGISTRY.counter("dispatch.hit").inc()
+        REGISTRY.counter(f"dispatch.hit.{op}").inc()
+        return executable
+    try:
+        with spans.child("dispatch.compile", op=op), _compile_timer(op):
+            executable = jax.jit(fn).lower(*args).compile()
+    finally:
+        _cache_store(key, executable, lead_ev)
+    REGISTRY.counter("dispatch.compile").inc()
+    REGISTRY.counter(f"dispatch.compile.{op}").inc()
+    record_compile_cache(f"dispatch:{op}", hit=False)
+    return executable
 
 
 def rowwise(

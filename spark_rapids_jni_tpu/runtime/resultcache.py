@@ -28,6 +28,37 @@ for file-backed scans. Both halves are mandatory: a ``get``/``put`` whose
 key lacks the input fingerprint raises (tpulint rule 16
 ``cache-key-must-fingerprint`` enforces the static half at call sites).
 
+The content fingerprint. A table's fingerprint is a sha256 over its
+buffers in column order. What a buffer gives the sha256 is decided by its
+dtype and size alone, so the fingerprint is a function of content and
+never of where the bytes live (a CPU-pinned fleet supervisor compares its
+own with the one a replica took on its chip):
+
+* a buffer of ``_DIGEST_MIN_BYTES`` (1 MiB) or more of an integer, bool,
+  float32 or narrower dtype gives dtype, shape, byte length and a
+  ``DIGEST_BITS`` = 128 bit digest, computed where the buffer lives.
+  The digest (``_lane_sums``) is 32-bit integer arithmetic only: each
+  of four lanes sums, mod 2**32, a bijective multiply/xorshift mix of
+  ``word + index * A + S`` over every 32-bit word of the buffer, with
+  the lane's own constants. A sum is order-free, so XLA on the TPU, XLA
+  on the CPU and numpy give the same bits; every word's term depends on
+  its index, so a rolled or permuted buffer (the same multiset of words)
+  is a different buffer. A single-device ``jax.Array`` is digested by the
+  jit ``cache_digest`` on its device and 16 bytes come back; a host
+  array, a ``HostTableChunk`` snapshot or an array sharded over devices
+  (brought to the host as before) by the same function in numpy. All of a
+  table's device digests are enqueued before the first is waited for.
+  One executable a dtype, shape and device (``dispatch.compiled``: the
+  dispatch layer's cache, counted as a ``dispatch.compile``).
+* a smaller buffer gives dtype, shape and its bytes, as it always has (a
+  copy and a sha256 of under 1 MiB cost less than a dispatch, let alone
+  a compile for a shape seen once); so does float64, which the chip holds
+  as a float32 pair and cannot bitcast.
+
+The digest is NOT a cryptographic hash: an accidental collision is out of
+reach (2**-128 a pair), a constructed one is not. The cache is shared by
+the sessions of one executor; it is not a boundary between tenants.
+
 Storage. Entries live in the server's shared :class:`SpillStore` under
 the ``integrity.cache`` seam: a fresh entry shares the just-computed
 result's device buffers (zero copy) and rides the store's integrity-sealed
@@ -61,7 +92,7 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
-from spark_rapids_jni_tpu.runtime import fusion, resilience
+from spark_rapids_jni_tpu.runtime import dispatch, fusion, resilience
 from spark_rapids_jni_tpu.runtime.memory import (
     HostTableChunk,
     MemoryLimitExceeded,
@@ -133,60 +164,204 @@ def plan_signature(plan: fusion.Plan, bindings: dict) -> str:
     return hashlib.sha256(repr(fp).encode()).hexdigest()
 
 
-def _hash_buffer(h, buf) -> None:
+# -- the buffer digest ------------------------------------------------------
+# One definition, two evaluations (``xp`` is numpy or jax.numpy): every
+# 32-bit word of the buffer, with its index, goes through a per-lane
+# bijective mixer and the lane sums the results mod 2**32. A sum of uint32
+# is the same in any order, so the chip, XLA's CPU backend and numpy agree
+# to the bit. Lane l mixes ``word + index * A_l + S_l``: A_0 - A_3 is odd,
+# so two lanes' inputs already determine (word, index), and a word moved to
+# another index changes every lane (a rolled or permuted buffer is a new
+# buffer). Rows are (A, S, M1, M2); the mixer is the xorshift-multiply
+# finalizer of murmur3 / lowbias32 with each lane's own multipliers.
+_LANES = (
+    (0x9E3779B1, 0x7F4A7C15, 0x7FEB352D, 0x846CA68B),
+    (0x85EBCA77, 0x165667B1, 0x21F0AAAD, 0x735A2D97),
+    (0xC2B2AE3D, 0x27D4EB2F, 0x85EBCA6B, 0xC2B2AE35),
+    (0x27D4EB2E, 0x9E3779B9, 0x2C1B3C6D, 0x297A2D39),
+)
+DIGEST_BITS = 32 * len(_LANES)
+# below this a copy to the host and a sha256 cost less than a dispatch (or,
+# for a shape not seen before, a compile): small buffers keep the host path
+_DIGEST_MIN_BYTES = 1 << 20
+# the word index is 32 bits: beyond 2**32 words positions would alias
+_DIGEST_MAX_BYTES = 1 << 34
+# the numpy evaluation's block: its temporaries stay in the host's cache
+_HOST_BLOCK_WORDS = 1 << 15
+
+
+def _lane_sums(xp, words, index) -> list:
+    """The lanes' sums over ``words`` (uint32) at ``index`` (uint32)."""
+    sums = []
+    for a, s, m1, m2 in _LANES:
+        h = words + index * xp.uint32(a) + xp.uint32(s)
+        h = (h ^ (h >> 16)) * xp.uint32(m1)
+        h = (h ^ (h >> 15)) * xp.uint32(m2)
+        sums.append(xp.sum(h ^ (h >> 16), dtype=xp.uint32))
+    return sums
+
+
+def cache_digest(x):
+    """The digest of a device buffer, as uint32[lanes], computed where the
+    buffer lives (traced: this is the jit's body, and its name the
+    module's, ``jit_cache_digest``). Words are the buffer's bytes in
+    memory order: an 8-byte element is its low then its high half (the
+    chip keeps int64 as such a pair, so neither is a copy), a 4-byte
+    element is itself, a 1- or 2-byte element is widened to one word."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    x = x.reshape(-1)
+    size = x.dtype.itemsize
+    if x.dtype == jnp.bool_:
+        parts = (x.astype(jnp.uint32),)
+    elif size == 8:
+        parts = (x.astype(jnp.uint32), (x >> 32).astype(jnp.uint32))
+    elif size == 4:
+        parts = (lax.bitcast_convert_type(x, jnp.uint32),)
+    else:
+        parts = (lax.bitcast_convert_type(
+            x, jnp.uint8 if size == 1 else jnp.uint16).astype(jnp.uint32),)
+    index = lax.iota(jnp.uint32, x.shape[0]) * jnp.uint32(len(parts))
+    lanes = [_lane_sums(jnp, words, index + jnp.uint32(k))
+             for k, words in enumerate(parts)]
+    return jnp.stack([sum(lane[1:], lane[0]) for lane in zip(*lanes)])
+
+
+def _digest_numpy(arr: np.ndarray) -> np.ndarray:
+    """The same digest of a host buffer, in blocks of plain numpy."""
+    flat = np.ascontiguousarray(arr).reshape(-1)
+    words = flat.view(f"<u{min(flat.dtype.itemsize, 4)}")
+    total = np.zeros(len(_LANES), dtype=np.uint32)
+    for lo in range(0, words.size, _HOST_BLOCK_WORDS):
+        block = words[lo:lo + _HOST_BLOCK_WORDS].astype(
+            np.uint32, copy=False)
+        index = np.arange(lo, lo + block.size, dtype=np.uint32)
+        total += np.array(_lane_sums(np, block, index), dtype=np.uint32)
+    return total
+
+
+def _takes_digest(dtype, nbytes: int) -> bool:
+    """Whether a buffer is digested or hashed byte for byte: decided by
+    dtype and size alone, never by where the bytes live. float64 stays out
+    (the chip holds it as a float32 pair and cannot bitcast it)."""
+    dtype = np.dtype(dtype)
+    return (_DIGEST_MIN_BYTES <= nbytes < _DIGEST_MAX_BYTES
+            and (dtype.kind in "iub"
+                 or (dtype.kind == "f" and dtype.itemsize <= 4)))
+
+
+def _on_one_device(buf) -> bool:
+    import jax
+
+    return isinstance(buf, jax.Array) and len(buf.sharding.device_set) == 1
+
+
+def _stage_buffer(buf):
+    """First half of a buffer's fingerprint; returns the second half,
+    ``finish(h)``, which feeds the table's sha256. Staging a large
+    single-device ``jax.Array`` enqueues its digest and starts the
+    digest's copy to the host, so a table's columns are all in flight
+    before ``finish`` waits for the first. Everything else is done in
+    ``finish``: a host array, or one sharded over devices (brought to the
+    host first), is digested by numpy to the same value, and a small or
+    float64 buffer feeds its bytes to the sha256 as it always has.
+
+    Spans, one pair a buffer: ``cache.fingerprint.hash`` is the enqueue of
+    the digest, or the hashing on the host (``nbytes``: bytes
+    fingerprinted); ``cache.fingerprint.copy`` is what crosses to the
+    host, the digest or the whole buffer, and the wait for it
+    (``nbytes``: bytes that crossed)."""
     if buf is None:
-        h.update(b"\xff")
-        return
+        return lambda h: h.update(b"\xff")
     if isinstance(buf, tuple):  # packed ("zstd", dtype_str, shape, blob)
-        h.update(buf[1].encode())
-        h.update(repr(buf[2]).encode())
-        h.update(buf[3])
-        return
-    # the two halves of a fresh batch's fingerprint, timed apart: bringing
-    # the buffer to the host, and hashing it there
-    with spans.child("cache.fingerprint.copy") as sp:
-        arr = np.ascontiguousarray(np.asarray(buf))
-        nbytes = int(arr.nbytes)
-        sp.annotate(nbytes=nbytes)
-    with spans.child("cache.fingerprint.hash", nbytes=nbytes):
-        h.update(str(arr.dtype).encode())
-        h.update(repr(arr.shape).encode())
-        h.update(arr.tobytes())
+        def finish_packed(h):
+            h.update(buf[1].encode())
+            h.update(repr(buf[2]).encode())
+            h.update(buf[3])
+        return finish_packed
+    nbytes = int(buf.nbytes)
+    digested = _takes_digest(buf.dtype, nbytes)
+    pending = None
+    if digested and _on_one_device(buf):
+        with spans.child("cache.fingerprint.hash", nbytes=nbytes):
+            try:
+                pending = dispatch.compiled(
+                    "cache_digest", cache_digest, buf)(buf)
+                pending.copy_to_host_async()
+            except Exception as exc:
+                # no room for the fusion's temporaries, a compile that
+                # fails: numpy gives the same digest over a host copy, and
+                # the counter says the work left the device
+                REGISTRY.counter("dispatch.exec_error").inc()
+                _log.warning("cache_digest of %s%s failed on the device, "
+                             "digesting a host copy: %s",
+                             buf.dtype, tuple(buf.shape), exc)
+                pending = None
     REGISTRY.counter("cache.fingerprint_bytes").inc(nbytes)
+    REGISTRY.counter("cache.fingerprint_device_bytes").inc(
+        nbytes if pending is not None else 0)
+
+    def finish(h):
+        crossed = pending if pending is not None else buf
+        with spans.child("cache.fingerprint.copy") as sp:
+            arr = np.ascontiguousarray(np.asarray(crossed))
+            sp.annotate(nbytes=0 if isinstance(buf, np.ndarray)
+                        else int(arr.nbytes))
+        h.update(str(np.dtype(buf.dtype)).encode())
+        h.update(repr(tuple(buf.shape)).encode())
+        if pending is not None:
+            h.update(_digest_tag(nbytes, arr))
+            return
+        with spans.child("cache.fingerprint.hash", nbytes=nbytes):
+            h.update(_digest_tag(nbytes, _digest_numpy(arr)) if digested
+                     else arr.tobytes())
+    return finish
 
 
-def _hash_column(h, col) -> None:
-    h.update(repr(col.dtype).encode())
-    _hash_buffer(h, col.data)
-    _hash_buffer(h, col.validity)
-    _hash_buffer(h, col.chars)
-    for child in (col.children or ()):
-        _hash_column(h, child)
+def _digest_tag(nbytes: int, lanes: np.ndarray) -> bytes:
+    """What the sha256 takes for a digested buffer: byte length, lanes."""
+    return (b"digest" + nbytes.to_bytes(8, "little")
+            + lanes.astype("<u4").tobytes())
 
 
-def _hash_snap(h, snap) -> None:
-    dtype, data, validity, chars, children = snap
-    h.update(repr(dtype).encode())
-    _hash_buffer(h, data)
-    _hash_buffer(h, validity)
-    _hash_buffer(h, chars)
-    for ch in (children or ()):
-        _hash_snap(h, ch)
+def _stage_column(col) -> list:
+    """The staged parts of a column, or of a host snapshot of one (the
+    same five fields as a tuple), children included, in hashing order."""
+    dtype, data, validity, chars, children = (
+        col if isinstance(col, tuple)
+        else (col.dtype, col.data, col.validity, col.chars, col.children))
+    out = [repr(dtype).encode()]
+    out += [_stage_buffer(b) for b in (data, validity, chars)]
+    for child in (children or ()):
+        out += _stage_column(child)
+    return out
+
+
+def _finish(staged: list) -> str:
+    """The sha256 over a table's staged parts, in order: header bytes as
+    they are, buffers through their ``finish``."""
+    h = hashlib.sha256()
+    for part in staged:
+        if isinstance(part, bytes):
+            h.update(part)
+        else:
+            part(h)
+    return h.hexdigest()
 
 
 def table_fingerprint(table) -> str:
     """Content digest of a device Table: every column's data/validity/
-    chars buffers plus dtype and shape, recursively. Memoized on the
-    Table object (JAX arrays are immutable, so a table's content never
-    drifts under its fingerprint) — repeat submissions of the same bound
-    table hash once."""
+    chars buffers plus dtype and shape, recursively. Every buffer is
+    staged before any is finished, so the device digests column after
+    column while the host dispatches the next, and a few hundred bytes
+    come back. Memoized on the Table object (JAX arrays are immutable, so
+    a table's content never drifts under its fingerprint) — repeat
+    submissions of the same bound table hash once."""
     cached = getattr(table, "_resultcache_fp", None)
     if cached is not None:
         return cached
-    h = hashlib.sha256()
-    for col in table.columns:
-        _hash_column(h, col)
-    fp = h.hexdigest()
+    fp = _finish([p for col in table.columns for p in _stage_column(col)])
     try:
         table._resultcache_fp = fp
     except (AttributeError, TypeError):
@@ -195,10 +370,7 @@ def table_fingerprint(table) -> str:
 
 
 def _chunk_fingerprint(chunk: HostTableChunk) -> str:
-    h = hashlib.sha256()
-    for snap in chunk.cols:
-        _hash_snap(h, snap)
-    return h.hexdigest()
+    return _finish([p for snap in chunk.cols for p in _stage_column(snap)])
 
 
 def source_fingerprint(path: str) -> str:

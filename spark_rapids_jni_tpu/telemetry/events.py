@@ -26,9 +26,11 @@ caller-supplied ``op`` / ``rows`` / ``dtype_widths``.
 
 Sink: when ``telemetry.path`` is set, records append to that JSONL file (one
 json object per line, crash-tolerant — a torn final line is skipped by the
-reader). Always, the last 4096 records are kept in an in-process ring for the
-bench summary and tests. Emission never raises on I/O failure; dropped writes
-are counted in ``telemetry.dropped_writes``.
+reader). Always, the last 65,536 records are kept in an in-process ring for the
+bench summary, the benchmark's span readers and tests (a served request
+writes some 40; a 51 s window of a few hundred requests has to fit whole).
+Emission never raises on I/O failure; dropped writes are counted in
+``telemetry.dropped_writes``.
 """
 
 from __future__ import annotations
@@ -65,7 +67,7 @@ __all__ = [
     "summary",
 ]
 
-_RING_MAX = 4096
+_RING_MAX = 65536
 _ring: Deque[Dict[str, Any]] = collections.deque(maxlen=_RING_MAX)
 _ring_lock = threading.Lock()
 

@@ -53,8 +53,13 @@ def test_serve_phase_passes_tiny_on_cpu(tmp_path):
     # plans at the "SF1" size; every one cold, second and repeated
     assert {k.split("@")[0] for k in report} == {
         "q1_planned", "q6", "q1_general", "q1_parquet", "q3_general",
-        "q3_planned", "sync"}
+        "q3_planned", "sync", "fingerprint"}
     assert report["sync"]["four_s"] >= 2 * report["sync"]["one_s"]
+    # the table where it lives against numpy over its host copy (tiny: all
+    # of it under the digest's threshold, so none digested on the device)
+    assert report["fingerprint"]["moved"] == {
+        "cache.fingerprint_bytes": 5000 * 38,
+        "cache.fingerprint_device_bytes": 0}
     assert not list(tmp_path.iterdir())  # the Parquet files are removed
 
 

@@ -120,7 +120,7 @@ def test_compressible_columns_shrink_at_least_2x():
 
 
 def test_pack_unpack_tuple_shape_matches_legacy():
-    # the 4-tuple pack rides snaps_checksum/_hash_buffer unchanged: same
+    # the 4-tuple pack rides snaps_checksum/_stage_buffer unchanged: same
     # (tag, dtype_str, shape, blob) shape as the legacy ("zstd", ...) pack
     arr = np.arange(512, dtype=np.int32).reshape(2, 256)
     pack = compress.pack_array(arr, seam="integrity.spill")

@@ -318,3 +318,29 @@ def test_concurrent_first_compile_is_single_flight():
     assert results == [1000 * 999 // 2] * n_threads
     assert REGISTRY.counter("dispatch.compile").value == 1
     assert REGISTRY.counter("dispatch.hit").value == n_threads - 1
+
+
+def _total(x):
+    import jax.numpy as jnp
+
+    return jnp.sum(x, dtype=jnp.int64)
+
+
+@pytest.mark.parametrize("n", [5, 33])
+def test_compiled_memoizes_one_executable_an_exact_shape(n):
+    """``compiled``: no bucketing (a digest reads every element), the
+    same cache and the same counters as ``call``."""
+    import jax.numpy as jnp
+
+    x = jnp.arange(n, dtype=jnp.int64)
+    first = dispatch.compiled("total", _total, x)
+    assert int(first(x)) == n * (n - 1) // 2
+    assert dispatch.compiled("total", _total, x + 1) is first
+    c = REGISTRY.counters()
+    assert (c["dispatch.compile"], c["dispatch.compile.total"]) == (1, 1)
+    assert (c["dispatch.hit"], c["dispatch.hit.total"]) == (1, 1)
+    # another length in the same bucket, another dtype: new executables
+    dispatch.compiled("total", _total, jnp.arange(n + 1, dtype=jnp.int64))
+    dispatch.compiled("total", _total, x.astype(jnp.int32))
+    assert REGISTRY.counters()["dispatch.compile.total"] == 3
+    assert dispatch.cache_size() == 3

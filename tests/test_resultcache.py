@@ -255,6 +255,214 @@ def test_cache_key_requires_both_halves():
 
 
 # ---------------------------------------------------------------------------
+# 2b. the buffer digest: one definition, evaluated where the buffer lives
+# ---------------------------------------------------------------------------
+
+_BIG = 140_001   # int64 elements: over the digest's threshold, odd on purpose
+
+
+def _values(dtype, n, seed=0):
+    rng = np.random.default_rng(seed)
+    if np.dtype(dtype) == np.bool_:
+        return rng.integers(0, 2, n).astype(bool)
+    if np.dtype(dtype).kind == "f":
+        return rng.standard_normal(n).astype(dtype)
+    info = np.iinfo(dtype)
+    return rng.integers(info.min, info.max, n, dtype=dtype, endpoint=True)
+
+
+def _column(arr):
+    from spark_rapids_jni_tpu import types as t
+
+    kinds = {"int8": t.INT8, "int32": t.INT32, "int64": t.INT64,
+             "float32": t.FLOAT32, "float64": t.FLOAT64}
+    return Column(kinds[str(arr.dtype)], arr)
+
+
+def _fp(*arrays):
+    """The fingerprint of a new Table over the arrays as they are given."""
+    return resultcache.table_fingerprint(Table([_column(a) for a in arrays]))
+
+
+def _sha_of_bytes(table):
+    """What the fingerprint was before there was a digest: sha256 over
+    every buffer's dtype, shape and bytes."""
+    import hashlib
+
+    h = hashlib.sha256()
+    for col in table.columns:
+        h.update(repr(col.dtype).encode())
+        for buf in (col.data, col.validity, col.chars):
+            if buf is None:
+                h.update(b"\xff")
+                continue
+            arr = np.ascontiguousarray(np.asarray(buf))
+            h.update(str(arr.dtype).encode())
+            h.update(repr(arr.shape).encode())
+            h.update(arr.tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("n", [1, 4099, 70_003])
+@pytest.mark.parametrize("dtype", ["int8", "int16", "int32", "int64",
+                                   "uint8", "bool", "float32"])
+def test_device_digest_equals_the_numpy_twin(dtype, n):
+    """Lengths that are no multiple of a word, a lane count or the numpy
+    evaluation's block; every dtype the digest takes."""
+    import jax
+
+    arr = _values(dtype, n, seed=n)
+    on_device = np.asarray(jax.jit(resultcache.cache_digest)(
+        jnp.asarray(arr)))
+    twin = resultcache._digest_numpy(arr)
+    assert on_device.dtype == twin.dtype == np.uint32
+    assert on_device.shape == twin.shape == (4,)
+    assert resultcache.DIGEST_BITS == 128
+    assert on_device.tolist() == twin.tolist()
+
+
+def _flip_first_word(a):
+    a.view(np.uint8)[0] ^= 1
+
+
+def _flip_last_byte(a):
+    a.view(np.uint8)[-1] ^= 0x80
+
+
+def _flip_high_half(a):
+    a.view(np.uint32)[2 * (_BIG // 2) + 1] ^= 1 << 31
+
+
+def _swap_two(a):
+    i, j = 17, _BIG - 5
+    assert a[i] != a[j]
+    a[i], a[j] = a[j], a[i]
+
+
+@pytest.mark.parametrize("change", [_flip_first_word, _flip_last_byte,
+                                    _flip_high_half, _swap_two])
+def test_digest_sees_one_bit_and_one_swap(change):
+    base = _values("int64", _BIG)
+    other = base.copy()
+    change(other)
+    lanes, moved = (resultcache._digest_numpy(x) for x in (base, other))
+    # every lane moves, not one of four
+    assert all(a != b for a, b in zip(lanes.tolist(), moved.tolist()))
+    assert _fp(jnp.asarray(base)) != _fp(jnp.asarray(other))
+
+
+@pytest.mark.parametrize("stride", [1, 46_667, _BIG - 2])
+def test_a_rolled_buffer_is_a_new_buffer(stride):
+    """The benchmark's freshener: the same multiset of words, each at
+    another index. Low-entropy columns, like lineitem's flags."""
+    flags = np.frombuffer(b"ANR", dtype=np.int8)[
+        np.random.default_rng(3).integers(0, 3, 8 * _BIG)]
+    qty = np.random.default_rng(4).integers(100, 5100, _BIG)
+    for column in (flags, qty):
+        fps = {_fp(jnp.roll(jnp.asarray(column), k))
+               for k in (0, stride, 2 * stride)}
+        assert len(fps) == 3
+        lanes = [resultcache._digest_numpy(np.roll(column, k)).tolist()
+                 for k in (0, stride)]
+        assert all(a != b for a, b in zip(*lanes))
+
+
+def test_same_bytes_under_another_type_or_length_differ():
+    as64 = _values("int64", _BIG)
+    as32 = as64.view(np.int32)
+    assert as32.tobytes() == as64.tobytes()
+    padded = np.concatenate([as64, np.zeros(3, np.int64)])
+    fps = {_fp(jnp.asarray(x)) for x in (as64, as32, padded)}
+    assert len(fps) == 3
+    # the type is the sha256's to tell apart; trailing zeros the digest's
+    assert (resultcache._digest_numpy(as64).tolist()
+            == resultcache._digest_numpy(as32).tolist())
+    assert (resultcache._digest_numpy(as64).tolist()
+            != resultcache._digest_numpy(padded).tolist())
+
+
+def test_fingerprint_is_the_contents_not_the_placements():
+    """A single-device jax.Array, one sharded over the eight devices, a
+    numpy array and a HostTableChunk snapshot of one content."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from spark_rapids_jni_tpu.runtime import memory
+
+    big = _values("int64", 8 * 20_000)           # digested
+    small = _values("int8", 8 * 20_000, seed=1)  # bytes to the sha256
+    on_device = Table([_column(jnp.asarray(big)), _column(jnp.asarray(small))])
+    spread = NamedSharding(Mesh(np.array(jax.devices()), ("x",)),
+                           PartitionSpec("x"))
+    sharded = Table([_column(jax.device_put(big, spread)),
+                     _column(jax.device_put(small, spread))])
+    assert len(sharded.columns[0].data.sharding.device_set) == 8
+    on_host = Table([_column(big), _column(small)])
+    chunk = memory.host_table_chunk(
+        [memory._col_to_host(c) for c in on_device.columns], big.size)
+    assert all(isinstance(snap[1], np.ndarray) for snap in chunk.cols)
+    fps = {resultcache.input_fingerprint({"t": v})
+           for v in (on_device, sharded, on_host, chunk)}
+    assert len(fps) == 1
+    c = REGISTRY.counters()
+    assert c["cache.fingerprint_device_bytes"] == big.nbytes   # one of four
+    assert c["cache.fingerprint_bytes"] == 4 * (big.nbytes + small.nbytes)
+
+
+def test_small_buffers_and_float64_keep_the_sha256_of_their_bytes():
+    """Under the threshold nothing changed, to the hex; float64 is never
+    digested, whatever its size."""
+    small = _table(700, seed=3, null_tail=9)
+    assert resultcache.table_fingerprint(small) == _sha_of_bytes(small)
+    wide = Table([_column(jnp.asarray(_values("float64", _BIG)))])
+    assert wide.columns[0].data.nbytes > resultcache._DIGEST_MIN_BYTES
+    assert resultcache.table_fingerprint(wide) == _sha_of_bytes(wide)
+    assert REGISTRY.counters()["cache.fingerprint_device_bytes"] == 0
+    assert "dispatch.compile.cache_digest" not in REGISTRY.counters()
+    # the same size as float32 is
+    narrow = Table([_column(jnp.asarray(_values("float32", 2 * _BIG)))])
+    assert resultcache.table_fingerprint(narrow) != _sha_of_bytes(narrow)
+    assert REGISTRY.counters()["cache.fingerprint_device_bytes"] == (
+        narrow.columns[0].data.nbytes)
+
+
+def test_a_digest_that_fails_on_the_device_is_taken_on_the_host(monkeypatch):
+    """The same fingerprint, and a counter every chip check holds at 0."""
+    arr = jnp.asarray(_values("int64", _BIG))
+    want = _fp(arr)
+    assert REGISTRY.counters()["cache.fingerprint_device_bytes"] == arr.nbytes
+
+    def no_room(op, fn, *args):
+        raise RuntimeError("RESOURCE_EXHAUSTED: no room for the temporaries")
+
+    monkeypatch.setattr(dispatch, "compiled", no_room)
+    assert _fp(arr) == want
+    c = REGISTRY.counters()
+    assert c["dispatch.exec_error"] == 1
+    assert c["cache.fingerprint_device_bytes"] == arr.nbytes   # not twice
+    assert c["cache.fingerprint_bytes"] == 2 * arr.nbytes
+
+
+def test_two_submits_of_one_table_digest_once():
+    plan = _mask_plan()
+    table = _table(300_000, seed=1)
+    want = _table_nbytes(table)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        sess = srv.session("a")
+        sess.submit(plan, {"t": table}).result(timeout=120)
+        assert sess.submit(plan, {"t": table}).status == "served"
+    c = REGISTRY.counters()
+    assert c["cache.hit"] == 1
+    assert c["cache.fingerprint_bytes"] == want
+    assert c["cache.fingerprint_device_bytes"] == want
+    # one executable a dtype and shape, then none
+    assert c["dispatch.compile.cache_digest"] == 2
+    other = _table(300_000, seed=2)
+    resultcache.table_fingerprint(other)
+    assert REGISTRY.counters()["dispatch.compile.cache_digest"] == 2
+
+
+# ---------------------------------------------------------------------------
 # 3. subplan-prefix reuse
 # ---------------------------------------------------------------------------
 

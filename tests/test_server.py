@@ -740,6 +740,12 @@ def test_served_miss_is_two_trees_joined_by_request():
         "server.stage_bindings", "rung.fused", "region.tpch_q1",
         "dispatch.pad", "dispatch.execute", "server.record_actual",
         "cache.put"}
+    # every buffer of this table is under the digest's threshold: each
+    # crossed to the host whole, so the two halves carry the same bytes
+    for half in ("copy", "hash"):
+        assert sum(r["nbytes"] for r in tree
+                   if r["op"] == f"cache.fingerprint.{half}") == (
+            _table_bytes(bindings["lineitem"]))
     # what ran after the region is inside the client's latency and now
     # inside a span: it ends before the root does
     put = next(r for r in tree if r["op"] == "cache.put")
@@ -763,25 +769,50 @@ def test_served_hit_is_one_tree():
                           "cache.lookup", "query.tpch_q1", "cache.hit"}
     assert by_op["query.tpch_q1"]["parent"] == roots["submit.tpch_q1"]["span"]
     assert by_op["cache.hit"]["parent"] == by_op["query.tpch_q1"]["span"]
-    # the table's fingerprint is memoized: nothing came to the host again
+    # the table's fingerprint is memoized: nothing was fingerprinted
+    # again, on the device or on the host, and nothing crossed
     assert by_op["cache.fingerprint"]["nbytes"] == 0
 
 
-def test_fingerprint_spans_and_counter_account_for_the_tables_bytes():
+@pytest.mark.parametrize("rows, digested", [(700, 0), (150_000, 4)])
+def test_fingerprint_spans_and_counter_account_for_the_tables_bytes(
+        rows, digested):
+    """``.hash`` carries the bytes fingerprinted, ``.copy`` the bytes that
+    crossed to the host: the whole buffer under the digest's threshold, the
+    digest's 16 bytes over it (at 150,000 rows the four int64 columns)."""
     set_option("telemetry.enabled", True)
-    plan, bindings = _q1_bindings(700)
+    plan, bindings = _q1_bindings(rows)
     want = _table_bytes(bindings["lineitem"])
     before = REGISTRY.counters().get("cache.fingerprint_bytes", 0)
     with server.QueryServer(budget_bytes=1 << 28) as srv:
         ticket = srv.session("s1").submit(plan, bindings)
         ticket.result(timeout=60)
         _, tree = _request_spans(ticket, 2)
-    for half in ("copy", "hash"):
-        assert sum(r["nbytes"] for r in tree
-                   if r["op"] == f"cache.fingerprint.{half}") == want
+    halves = {half: [r["nbytes"] for r in tree
+                     if r["op"] == f"cache.fingerprint.{half}"]
+              for half in ("copy", "hash")}
+    assert len(halves["copy"]) == len(halves["hash"]) == 7
+    assert sum(halves["hash"]) == want
+    assert sum(halves["copy"]) == want - digested * (8 * rows - 16)
+    assert halves["copy"].count(16) == digested
     (whole,) = [r for r in tree if r["op"] == "cache.fingerprint"]
     assert whole["nbytes"] == want
     assert REGISTRY.counters()["cache.fingerprint_bytes"] - before == want
+
+
+@pytest.mark.parametrize("rows, digested", [(700, 0), (150_000, 4)])
+def test_fingerprint_device_bytes_counts_what_was_digested_in_place(
+        rows, digested):
+    """Written by every fingerprint (0 where every buffer is small), so a
+    reader can tell a program without the digest from one that did not
+    engage it."""
+    plan, bindings = _q1_bindings(rows)
+    assert "cache.fingerprint_device_bytes" not in REGISTRY.counters()
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        srv.session("s1").submit(plan, bindings).result(timeout=60)
+    c = REGISTRY.counters()
+    assert c["cache.fingerprint_device_bytes"] == digested * 8 * rows
+    assert c.get("dispatch.compile.cache_digest", 0) == (1 if digested else 0)
 
 
 @pytest.mark.parametrize("rows, padded", [(600, True), (1024, False)])
