@@ -71,6 +71,13 @@ if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 
     _jax.config.update("jax_compilation_cache_dir", _FIXED)
 
+# An executable's op names are part of what it is: every plan node lowers
+# under its own scope (runtime/fusion.node_scopes) and a device trace is read
+# by them. JAX's cache key leaves names and locations out by default, so a
+# cached executable of a commit with other scopes would be served with its
+# stale names; keyed in, a changed lowering compiles again, once.
+_jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 from spark_rapids_jni_tpu.types import DType, TypeId  # noqa: E402
 from spark_rapids_jni_tpu.columnar import Column, Table  # noqa: E402
 

@@ -853,8 +853,19 @@ def _q3_planned_plan(segment: int, cutoff: int) -> fusion.Plan:
     j2 = fusion.DensePkJoin(probe, build2, 0, 0, 1,
                             fusion.rows_of("orders"), clustered=True,
                             label="pk2")
-    g = fusion.GroupBy(fusion.Project(j2, _q3_planned_keyed_fn), (0, 1, 2),
-                       ((3, "sum"),), label="groupby")
+    # the dense keys declared above make two more facts the planner's:
+    # o_orderdate and o_shippriority are gathered by the order's primary
+    # key, so they are functions of l_orderkey: the groupby keys on it
+    # alone and carries the other two as the group's first row (the same
+    # groups, the same answer, one 64-bit sort key and not three keys);
+    # and there are at most |orders| groups and the null group of the
+    # unmatched rows, so the groupby, its boundary searches and the
+    # result's sort run over that many rows, not over the lineitem bucket
+    g = fusion.GroupBy(fusion.Project(j2, _q3_planned_keyed_fn), (0,),
+                       ((1, "first_include_nulls"),
+                        (2, "first_include_nulls"), (3, "sum")),
+                       max_groups=fusion.groups_of("orders"),
+                       label="groupby")
     return fusion.Plan("tpch_q3_planned", fusion.Sort(
         g, (3, 1), ascending=(False, True), nulls_first=(False, False)))
 
@@ -868,11 +879,14 @@ def tpch_q3_planned(customer: Table, orders: Table, lineitem: Table,
     TPC-H DDL + load-order facts). Both joins collapse to arithmetic +
     gather — the join phase compiles with ZERO sorts (HLO-pinned in
     tests), where the general q3 pays two build-side lexsorts + probe
-    searchsorteds on the sort-based machinery (~230 ns/row for general
-    q1 on a v5e in 2026-07, before the runtime stack; not measured since). The
+    searchsorteds on the sort-based machinery. On a v5e at SF1 (PERF.md
+    section 5, traced run of PR 28) the two joins take 0.65 s of a 3.81 s
+    request, nearly all of it pk2's gathers of the order's columns by
+    6,001,215 positions, the groupby 2.78 s and the result's sort 0.34 s. The
     orderkey groupby stays on the general (sort-based) path: its
     cardinality is data-dependent, which is exactly the boundary of
-    what a planner can declare.
+    what a planner can declare; what the declared keys do give it is
+    one key to sort on and a bound on its groups (``_q3_planned_plan``).
 
     Output rows are one per LINEITEM row (PK fanout <= 1): no join
     capacity estimate, no overflow retry — the static shape is the

@@ -237,8 +237,9 @@ def dense_pk_join(
       layout of a loaded dimension or generated key column). The join
       is then pure arithmetic + one row gather — ZERO sorts anywhere,
       and the general join's build-side lexsort + probe searchsorted
-      (the dominant terms of the unbounded pipeline, ~230 ns/row for
-      general q1 on a v5e in 2026-07; not measured since) vanish. The
+      vanish; what is left is the gather (planned q3 at SF1 on a v5e:
+      0.65 s for 6,001,215 probe rows against 1,500,000 build rows of
+      three columns, PERF.md section 5, PR 28). The
       declaration is VERIFIED, not trusted:
       each gathered build key is compared to the probe key, and a slot
       holding a different valid key raises ``pk_violation``.

@@ -3,10 +3,14 @@ vendored operator substrate (SURVEY.md section 2.2: libcudf sort is part of
 the capability surface; exercised by TPC-H q1's final ORDER BY).
 
 TPU-first design: no comparator kernels. Each key column is *encoded* into
-an order-preserving unsigned integer word (floats via sign-magnitude flip,
-signed ints via sign-bit flip, with a null indicator folded in), and the
-whole thing is one ``jnp.lexsort`` — XLA's native multi-pass radix-friendly
-sort — followed by a gather. Encoded keys also give Spark-compatible total
+order-preserving unsigned integer words (floats via sign-magnitude flip,
+signed ints via sign-bit flip, a 64-bit integer as its two 32-bit halves,
+with a null indicator folded in). Keys that pack into two 32-bit words
+are one ``jnp.argsort`` / ``jnp.lexsort`` (XLA's variadic sort, a
+comparator over every operand); wider keys are sorted one word at a time
+(``_radix_order``), because the TPU compiler's time for a variadic sort
+grows with about the square of its operand words (PERF.md section 6, PR
+28). A gather follows. Encoded keys also give Spark-compatible total
 float order (NaN sorts greatest, -0.0 == 0.0 is NOT collapsed: -0.0 < 0.0
 bitwise — documented deviation from Java's Double.compare only for -0.0).
 """
@@ -98,6 +102,16 @@ def _key_arrays(col: Column, ascending: bool, nulls_first: bool):
         # -(+inf) = -inf sorts first, matching Spark's NaN-greatest order.
         nan_rank = jnp.isnan(v)
         value_keys = [key, (~nan_rank if not ascending else nan_rank)]
+    elif np_dt.kind in "iu" and np_dt.itemsize == 8:
+        # a 64-bit integer key as its low and high 32-bit words (sign flip
+        # on the high word): uint order on the pair is the 64-bit order,
+        # with no emulated 64-bit compare, and _pack_lex_keys can fold
+        # null ranks and the row-valid bit into the words
+        flip = jnp.uint32(0x80000000 if np_dt.kind == "i" else 0)
+        value_keys = [col.data.astype(jnp.uint32),
+                      (col.data >> 32).astype(jnp.uint32) ^ flip]
+        if not ascending:
+            value_keys = [~k for k in value_keys]
     else:
         u = _as_unsigned_key(col.data, dtype)
         if not ascending:
@@ -165,6 +179,51 @@ def _pack_lex_keys(lex_keys: list[jnp.ndarray]) -> list[jnp.ndarray]:
     raise AssertionError("unreachable: total > 32 must split")
 
 
+def _pack_words(lex_keys: list[jnp.ndarray]) -> list[jnp.ndarray]:
+    """Minor->major packable keys (``_key_bits``) as one bit string, low
+    bits first, cut into uint32 words, minor -> major: as few words as the
+    keys' widths allow, a key straddling two words where it must. uint
+    order on the words, the last the most significant, is the keys'
+    lexicographic order."""
+    words, acc, used = [], None, 0
+    for a in lex_keys:
+        w, a32 = _key_bits(a), a.astype(jnp.uint32)
+        if acc is None or used == 32:
+            if acc is not None:
+                words.append(acc)
+            acc, used = a32, w
+        elif used + w <= 32:
+            acc, used = acc | (a32 << used), used + w
+        else:   # the key's low bits fill this word, the rest open the next
+            words.append(acc | (a32 << used))
+            acc, used = a32 >> (32 - used), used + w - 32
+    return words + [acc]
+
+
+def _radix_order(words: list[jnp.ndarray]) -> jnp.ndarray:
+    """The stable order by uint32 ``words`` (minor -> major) as one stable
+    single-key sort a word, least significant first, each over the rows
+    in the order the passes before it left: the same permutation as one
+    sort comparing every word, from one two-operand sort in a loop. XLA's
+    TPU compiler takes about the square of a sort's operand words in
+    compile time whatever the row count (chip readings: PERF.md section
+    6, PR 28), so a variadic sort of eight operands does not compile in a
+    time a cold start can pay; this compiles in the same time for any
+    number of words."""
+    stacked = jnp.stack(words)
+
+    def one_pass(i, order):
+        word = jax.lax.dynamic_index_in_dim(stacked, i, 0, keepdims=False)
+        return jax.lax.sort((word[order], order), num_keys=1,
+                            is_stable=True)[1]
+
+    # the rows' own order to start from; taken through a word so that under
+    # shard_map the carry varies over the same mesh axes going in as out
+    start = jax.lax.iota(jnp.int32, stacked.shape[1]) + (
+        words[0] & jnp.uint32(0)).astype(jnp.int32)
+    return jax.lax.fori_loop(0, len(words), one_pass, start)
+
+
 def _sort_order_impl(row_args, aux, rvs, *, keys, ascending, nulls_first):
     ((table, row_valid),) = row_args
     # phantom rows (padded tails, masked shuffle slots): rank them AFTER
@@ -181,9 +240,13 @@ def _sort_order_impl(row_args, aux, rvs, *, keys, ascending, nulls_first):
         lex_keys.extend(_key_arrays(table.column(k), asc, nf))
     if rv is not None:
         lex_keys.append(jnp.where(rv, jnp.uint8(0), jnp.uint8(1)))
+    packable = all(_key_bits(a) is not None for a in lex_keys)
     lex_keys = _pack_lex_keys(lex_keys)
     if len(lex_keys) == 1:
         return jnp.argsort(lex_keys[0], stable=True).astype(jnp.int32)
+    if packable and len(lex_keys) > 2:
+        # wider than the two words one variadic sort takes well
+        return _radix_order(_pack_words(lex_keys))
     return jnp.lexsort(tuple(lex_keys)).astype(jnp.int32)
 
 

@@ -103,6 +103,9 @@ __all__ = [
     "FusedResult",
     "rows_of",
     "min_rows_of",
+    "groups_of",
+    "node_scopes",
+    "meta_facts",
     "execute",
     "inject_runtime_filters",
     "estimate_hbm_bytes",
@@ -135,6 +138,15 @@ def min_rows_of(name: str, cap: int):
     return ("min_rows_of", name, int(cap))
 
 
+def groups_of(name: str):
+    """max_groups spec for a groupby whose key is a foreign key into the
+    bound table ``name`` (or columns gathered by it): its true row count,
+    and one for the null group of the rows that found no match. A bound
+    the data cannot pass while the declaration holds; ``overflowed`` stays
+    the guard for when it does not."""
+    return ("groups_of", name, 1)
+
+
 def _resolve(spec, true_rows: dict) -> Optional[int]:
     if spec is None or isinstance(spec, int):
         return spec
@@ -144,6 +156,8 @@ def _resolve(spec, true_rows: dict) -> Optional[int]:
             return int(true_rows[name]) * arg
         if kind == "min_rows_of":
             return min(arg, int(true_rows[name]))
+        if kind == "groups_of":
+            return int(true_rows[name]) + arg
     raise ValueError(f"unresolvable row spec {spec!r}")
 
 
@@ -194,7 +208,8 @@ class Project(NamedTuple):
 
 class GroupBy(NamedTuple):
     """``groupby_aggregate`` (or ``plan_groupby`` when ``domains`` is
-    given). ``max_groups`` may be an int, None, or a ``min_rows_of`` spec.
+    given). ``max_groups`` may be an int, None, or a ``min_rows_of`` /
+    ``groups_of`` spec.
     Side outputs land in the result meta under ``<label>.*``
     (num_groups/overflowed/sum_overflow, or present/domain_miss/lowered
     on the planned lowering)."""
@@ -391,6 +406,19 @@ def _topo(root) -> list:
     return order
 
 
+def node_scopes(nodes) -> dict:
+    """``{id(node): scope}`` of a plan's nodes (as ``_topo`` orders them):
+    the ``jax.named_scope`` every node lowers under, inside the region's
+    ``region.<plan>``, so a device trace can say which operator an
+    operation belongs to. A node's label if it has one (``pk1``,
+    ``groupby``), else its kind (``sort``); a name several nodes share
+    gets each node's position in the order (``project.2``)."""
+    names = [getattr(n, "label", None) or type(n).__name__.lower()
+             for n in nodes]
+    return {id(n): name if names.count(name) == 1 else f"{name}.{i}"
+            for i, (n, name) in enumerate(zip(nodes, names))}
+
+
 def _scan_names(nodes) -> tuple[list, list]:
     """(bucketed, exact) scan names in first-appearance order. A name
     must be scanned consistently (one bucket flag per table)."""
@@ -548,6 +576,20 @@ def _side_keys(nodes) -> list:
 # ---------------------------------------------------------------------------
 # evaluation — one shared walker for the fused trace AND the staged path
 # ---------------------------------------------------------------------------
+
+
+def _scanned_rows(node, true_rows: dict) -> Optional[int]:
+    """The true row count of ``node``'s output where it is a scan's rows,
+    one for one (filters null rows, they do not drop them); else None."""
+    while not isinstance(node, Scan):
+        if isinstance(node, (Filter, BloomProbe, Sort)) or (
+                isinstance(node, Project) and node.rowwise):
+            node = node.child
+        elif isinstance(node, DensePkJoin):
+            node = node.probe
+        else:
+            return None
+    return int(true_rows[node.name])
 
 
 def _null_all(table: Table, keep: jnp.ndarray) -> Table:
@@ -714,7 +756,12 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
         env[id(node)] = out
         return out
 
-    value, _ = ev(root)
+    # children first, each node under its own scope (not its parents')
+    order = _topo(root)
+    scopes = node_scopes(order)
+    for node in order:
+        with jax.named_scope(scopes[id(node)]):
+            value, _ = ev(node)
     return value, side
 
 
@@ -1053,6 +1100,14 @@ def execute(plan: Plan, bindings: dict, *,
         for n in nodes
         if isinstance(n, GroupBy) and n.domains is not None
     }
+    # a join whose probe side holds a scan's rows says how many probed it:
+    # the denominator of its ``<label>.total``
+    for n in nodes:
+        if isinstance(n, (Join, DensePkJoin)):
+            rows = _scanned_rows(
+                n.left if isinstance(n, Join) else n.probe, true_rows)
+            if rows is not None:
+                static_meta[f"{n.label}.probe_rows"] = rows
     side_keys = _side_keys(nodes)
 
     def _staged_eval() -> FusedResult:
@@ -1152,6 +1207,36 @@ def execute(plan: Plan, bindings: dict, *,
     meta.update(static_meta)
     _harvest_rtfilter(plan, nodes, meta)
     return FusedResult(value, meta)
+
+
+def meta_facts(plan: Plan, meta: dict) -> dict:
+    """What the joins and groupbys of ``plan`` report in a result's
+    ``meta``, summed over its nodes: rows that probed and rows that matched
+    (joins that say both), groups, and how many nodes broke what the plan
+    declares: a dense primary key that is not one (``pk_violation``), a
+    group bound that was too small (``overflowed``). A result with either
+    is a wrong answer; the served path refuses it
+    (``QueryServer._account_meta``). Converting a meta value waits for the
+    device, so call it where the meta is wanted on the host anyway."""
+    facts = {"join.probe_rows": 0, "join.matched_rows": 0,
+             "join.pk_violation": 0, "groupby.groups": 0,
+             "groupby.overflowed": 0}
+    for node in _topo(plan.root):
+        if isinstance(node, (Join, DensePkJoin)):
+            total = meta.get(f"{node.label}.total")
+            rows = meta.get(f"{node.label}.probe_rows")
+            if total is not None and rows is not None:
+                facts["join.probe_rows"] += int(rows)
+                facts["join.matched_rows"] += int(total)
+            facts["join.pk_violation"] += bool(
+                meta.get(f"{node.label}.pk_violation", False))
+        elif isinstance(node, GroupBy):
+            groups = meta.get(f"{node.label}.num_groups")
+            if groups is not None:
+                facts["groupby.groups"] += int(groups)
+            facts["groupby.overflowed"] += bool(
+                meta.get(f"{node.label}.overflowed", False))
+    return facts
 
 
 def plan_fingerprint(plan: Plan, bindings: dict) -> tuple:

@@ -619,12 +619,19 @@ class ResultCache:
         except MemoryLimitExceeded:
             return False
 
-    def put(self, key: CacheKey, result: fusion.FusedResult) -> bool:
+    def put(self, key: CacheKey, result: fusion.FusedResult,
+            accept=None) -> bool:
         """Memoize one result. The entry shares the result's device
         buffers (zero copy) and is charged against the limiter while
         resident; when the charge cannot fit it is demoted to the
         integrity-sealed host tier immediately instead of starving live
-        queries. Returns True when the entry was stored."""
+        queries. Returns True when the entry was stored.
+
+        ``accept(meta)``, if given, gets the host copy of the result's
+        meta the entry keeps (taking it is the wait for the device) before
+        any lookup can see the entry; if it raises, nothing stays stored
+        and the exception propagates. It is not called where nothing new
+        is stored (cache off, the key already there, too big)."""
         if not enabled():
             return False
         self._validate_key(key)
@@ -666,6 +673,12 @@ class ResultCache:
             else:
                 # already demoted: account the compressed footprint now
                 self._refresh_stored_locked(entry)
+            if accept is not None:
+                try:
+                    accept(entry["meta"])
+                except BaseException:
+                    self._discard_locked(key, entry, "refused")
+                    raise
         self._count("put")
         record_cache("result_cache", "put", key=key.short, nbytes=nbytes)
         return True

@@ -902,6 +902,28 @@ class QueryServer:
                 or time.monotonic() - self._last_save >= interval):
             self._save_learned()
 
+    def _account_meta(self, plan: fusion.Plan, meta: dict) -> None:
+        """Count, once a request, what the plan's joins and groupbys report
+        in the result's meta (its host copy; ``fusion.meta_facts``:
+        counters ``join.probe_rows``, ``join.matched_rows``,
+        ``groupby.groups``, ``join.pk_violation``, ``groupby.overflowed``),
+        and refuse a result that broke what its plan declares: rows were
+        dropped or merged, so it must not resolve as a success."""
+        if not meta:
+            return
+        facts = fusion.meta_facts(plan, meta)
+        for name, value in facts.items():
+            REGISTRY.counter(name).inc(value)
+        if facts["groupby.overflowed"]:
+            raise resilience.CapacityOverflow(
+                f"plan {plan.name!r}: a groupby found more groups than its "
+                f"bound; the result is not the query's answer",
+                groups=facts["groupby.groups"])
+        if facts["join.pk_violation"]:
+            raise resilience.FatalExecutionError(
+                f"plan {plan.name!r}: a declared dense primary key is not "
+                f"one (pk_violation); the result is not the query's answer")
+
     def _default_estimate(self, plan: fusion.Plan, bindings: dict) -> int:
         """Headroom x the measured-truth EMA for this plan signature when
         one exists, else headroom x the static plan-aware input+output
@@ -1185,24 +1207,46 @@ class QueryServer:
                     REGISTRY.histogram("server.latency_ms").observe(lat_ms)
                     REGISTRY.histogram(
                         f"server.latency_ms.{sid}").observe(lat_ms)
-                    self._count("served", sid)
-                    record_server(ticket.plan.name, "served", session=sid,
-                                  wall_ms=lat_ms,
-                                  wait_ms=ticket.queue_wait_s * 1e3)
                     with spans.child("server.record_actual", session=sid):
                         self._record_actual(ticket, bindings, result)
+                    # what the plan's nodes report is read where the meta
+                    # comes to the host anyway: in cache.put, before the
+                    # entry can be looked up; a result that broke its
+                    # plan's declaration raises there and is not kept
+                    accounted = []
+
+                    def _account(meta: dict) -> None:
+                        accounted.append(None)
+                        try:
+                            self._account_meta(run_plan, meta)
+                        except resilience.ResilienceError as refused:
+                            accounted.append(refused)
+                            raise
+
                     if ticket.cache_key is not None:
                         try:
                             with spans.child("cache.put", session=sid):
-                                self.result_cache.put(ticket.cache_key,
-                                                      result)
+                                self.result_cache.put(
+                                    ticket.cache_key, result,
+                                    accept=_account)
                         except Exception as exc:
+                            if accounted[1:]:
+                                raise
                             # a cache-population failure must never fail
                             # a query that already served
                             REGISTRY.counter("cache.put_error").inc()
                             _log.warning(
                                 "result-cache put failed for %s: %s",
                                 ticket.plan.name, exc)
+                    if not accounted:   # nothing was stored: read it here
+                        with spans.child("server.account_meta",
+                                         session=sid):
+                            _account(resultcache._snap_meta(
+                                getattr(result, "meta", None)))
+                    self._count("served", sid)
+                    record_server(ticket.plan.name, "served", session=sid,
+                                  wall_ms=lat_ms,
+                                  wait_ms=ticket.queue_wait_s * 1e3)
                     ticket._resolve("served", value=result)
                 except resilience.QueryCancelled as exc:
                     # a deliberate stop, not a failure: the reservation

@@ -1,0 +1,322 @@
+"""64-bit sort keys as 32-bit words (``ops/sort.py``): ``sort_order`` and
+the sort-path groupby against plain oracles, the shape of the sorts XLA is
+given (what a cold compile of the planned q3 region costs goes with it),
+every plan node named in the lowered region, and a served request whose
+plan declaration broke resolving as a failure."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_jni_tpu import types as t
+from spark_rapids_jni_tpu.columnar import Column, Table
+from spark_rapids_jni_tpu.models import tpch
+from spark_rapids_jni_tpu.ops import sort as so
+from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate
+from spark_rapids_jni_tpu.runtime import fusion, resilience
+from spark_rapids_jni_tpu.runtime.server import QueryServer
+from spark_rapids_jni_tpu.telemetry import REGISTRY
+
+DTYPES = {"int64": (t.INT64, np.int64), "decimal64": (t.decimal64(-2), np.int64),
+          "int32": (t.INT32, np.int32), "int8": (t.INT8, np.int8)}
+
+
+def _values(rng, np_dt, n):
+    """Few distinct values (ties), negatives, and the type's extremes."""
+    info = np.iinfo(np_dt)
+    pool = np.array([info.min, info.min + 1, -2, -1, 0, 1, 2, info.max - 1,
+                     info.max] + list(rng.integers(
+                         info.min, info.max, 6, dtype=np_dt)), dtype=np_dt)
+    return pool[rng.integers(0, len(pool), n)]
+
+
+def _oracle_order(cols, ascending, nulls_first, row_valid):
+    """The stable order by Python tuples: phantom rows last, then per key
+    its null rank and its value (a null's value counts as equal)."""
+    def key(i):
+        out = [0 if row_valid is None or row_valid[i] else 1]
+        for (vals, valid), asc, nf in zip(cols, ascending, nulls_first):
+            if valid[i]:
+                v = int(vals[i])
+                out += [1 if nf else 0, v if asc else -v]
+            else:
+                out += [0 if nf else 1, 0]
+        return tuple(out)
+    n = len(cols[0][0])
+    return sorted(range(n), key=key)
+
+
+@pytest.mark.parametrize("phantoms", [False, True])
+@pytest.mark.parametrize("nulls_first", [True, False])
+@pytest.mark.parametrize("ascending", [True, False])
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
+def test_sort_order_matches_a_stable_lexicographic_oracle(
+        dtype, ascending, nulls_first, phantoms):
+    dt, np_dt = DTYPES[dtype]
+    rng = np.random.default_rng([sorted(DTYPES).index(dtype), ascending, nulls_first])
+    n = 700
+    major = (_values(rng, np_dt, n), rng.random(n) > 0.15)
+    minor = (rng.integers(-3, 3, n).astype(np.int32), rng.random(n) > 0.1)
+    table = Table([Column(dt, jnp.asarray(major[0]), jnp.asarray(major[1])),
+                   Column(t.INT32, jnp.asarray(minor[0]),
+                          jnp.asarray(minor[1]))])
+    row_valid = rng.random(n) > 0.2 if phantoms else None
+    got = so.sort_order(
+        table, [0, 1], [ascending, not ascending],
+        [nulls_first, not nulls_first],
+        row_valid=None if row_valid is None else jnp.asarray(row_valid))
+    want = _oracle_order([major, minor], [ascending, not ascending],
+                         [nulls_first, not nulls_first], row_valid)
+    # phantom rows rank after every real row, in no promised order
+    real = n if row_valid is None else int(row_valid.sum())
+    assert np.asarray(got).tolist()[:real] == want[:real]
+
+
+def test_sort_order_single_int64_key_matches_numpy_lexsort():
+    """No nulls, no phantoms: numpy's own stable lexsort is the oracle."""
+    rng = np.random.default_rng(11)
+    a = _values(rng, np.int64, 1000)
+    b = rng.integers(-2, 2, 1000).astype(np.int64)
+    table = Table([Column(t.INT64, jnp.asarray(a)),
+                   Column(t.INT64, jnp.asarray(b))])
+    got = np.asarray(so.sort_order(table, [0, 1]))
+    assert got.tolist() == np.lexsort((b, a)).tolist()
+
+
+def test_pack_words_is_the_keys_bit_string():
+    """Keys of 8, 32, 32, 8 and 8 bits are 88 bits: three words, the
+    second key straddling the first two."""
+    rng = np.random.default_rng(5)
+    keys = [rng.integers(0, 2**w, 50, dtype=np.uint64).astype(dt)
+            for w, dt in ((8, np.uint8), (32, np.uint32), (32, np.uint32),
+                          (8, np.uint8), (8, np.uint8))]
+    words = [np.asarray(w) for w in so._pack_words(
+        [jnp.asarray(k) for k in keys])]
+    assert len(words) == 3 and all(w.dtype == np.uint32 for w in words)
+    for i in range(50):
+        whole, shift = 0, 0
+        for k in keys:
+            whole |= int(k[i]) << shift
+            shift += k.dtype.itemsize * 8
+        assert [int(w[i]) for w in words] == [
+            (whole >> s) & 0xFFFFFFFF for s in (0, 32, 64)]
+
+
+@pytest.mark.parametrize("max_groups", [None, 64])
+def test_groupby_three_keys_int64_int32_int32_matches_a_dict(max_groups):
+    rng = np.random.default_rng(7)
+    n = 900
+    k0 = _values(rng, np.int64, n)[rng.integers(0, 5, n)]
+    k1 = rng.integers(-2, 2, n).astype(np.int32)
+    k2 = rng.integers(0, 2, n).astype(np.int32)
+    v0 = rng.random(n) > 0.1
+    val = rng.integers(-10**6, 10**6, n).astype(np.int64)
+    table = Table([Column(t.INT64, jnp.asarray(k0), jnp.asarray(v0)),
+                   Column(t.INT32, jnp.asarray(k1)),
+                   Column(t.INT32, jnp.asarray(k2)),
+                   Column(t.decimal64(-2), jnp.asarray(val))])
+    res = groupby_aggregate(table, [0, 1, 2], [(3, "sum"), (3, "count")],
+                            max_groups=max_groups)
+    want: dict = {}
+    for i in range(n):
+        key = (int(k0[i]) if v0[i] else None, int(k1[i]), int(k2[i]))
+        s, c = want.get(key, (0, 0))
+        want[key] = (s + int(val[i]), c + 1)
+    assert not bool(res.overflowed)
+    g = int(res.num_groups)
+    assert g == len(want)
+    cols = [c.to_pylist() for c in res.table.columns]
+    got = {(cols[0][i], cols[1][i], cols[2][i]): (cols[3][i], cols[4][i])
+           for i in range(g)}
+    assert got == want
+    # groups come out in key order, the null key first
+    keys = [(cols[0][i] is not None, cols[0][i] or 0, cols[1][i], cols[2][i])
+            for i in range(g)]
+    assert keys == sorted(keys)
+
+
+def _sorts(hlo: str) -> list:
+    """The result types of the sort instructions of an HLO text."""
+    return [m.group(1) for m in re.finditer(
+        r"= (\([^)]*\)|\S+) sort\(", hlo)]
+
+
+def _q3_tables(n_cust=64, n_ord=256, n=4000):
+    return {"customer": tpch.customer_table(n_cust),
+            "orders": tpch.orders_table(n_ord, n_cust),
+            "lineitem": tpch.lineitem_q3_table(n, n_ord)}
+
+
+def _region_hlo(plan, bindings) -> str:
+    def region(b):
+        res = fusion.execute(plan, b)
+        return res.table, res.meta
+
+    return jax.jit(region).lower(bindings).compile().as_text()
+
+
+def test_planned_q3_region_sorts_one_word_at_a_time():
+    """XLA's TPU compiler takes about the square of a sort's operand words
+    in compile time: the region's two sorts (the groupby's, the result's)
+    are each one 32-bit key and the int32 order, in a loop, and none has a
+    64-bit operand."""
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
+    sorts = _sorts(hlo)
+    assert len(sorts) == 2, sorts
+    for result in sorts:
+        assert re.fullmatch(r"\(u32\[\d+\]\{0\}, s32\[\d+\]\{0\}\)", result), \
+            result
+    assert not re.search(r"[us]64\[[^\]]*\][^=\n]* sort\(", hlo)
+
+
+def test_general_q1_sort_keeps_its_two_packed_words():
+    """General q1's keys (two int8 flags, their null ranks, the row-valid
+    bit: 40 bits) pack into two uint32 words sorted by one variadic sort
+    with jnp.lexsort's int64 iota, as before 64-bit keys became words: the
+    accepted cell ``sf1_q1_general_fresh`` compiles the module it compiled."""
+    n = 4096
+    rng = np.random.default_rng(3)
+    flags = Table([Column(t.INT8, jnp.asarray(
+        rng.integers(65, 70, n).astype(np.int8)), jnp.asarray(
+            rng.random(n) > 0.1)) for _ in range(2)])
+
+    def order(tb, rv):
+        return so._sort_order_impl(
+            ((tb, rv),), None, None, keys=(0, 1), ascending=(True, True),
+            nulls_first=(True, True))
+
+    rv = jnp.asarray(rng.random(n) > 0.2)
+    hlo = jax.jit(order).lower(flags, rv).compile().as_text()
+    assert _sorts(hlo) == [f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s64[{n}]{{0}})"]
+
+
+def test_every_plan_node_names_its_heavy_operations():
+    """Each node of a region lowers under ``region.<plan>/<node scope>``:
+    the sorts, the binary searches' loops and the gathers of planned q3
+    carry the scope of the node they belong to."""
+    plan = tpch._q3_planned_plan(0, 9204)
+    nodes = fusion._topo(plan.root)
+    scopes = fusion.node_scopes(nodes)
+    assert [scopes[id(n)] for n in nodes] == [
+        "scan.0", "project.1", "scan.2", "project.3", "scan.4", "project.5",
+        "pk1", "project.7", "pk2", "project.9", "groupby", "sort"]
+    # the staged walk of fusion.execute under an outer trace has the nodes'
+    # scopes too; the served region adds its own ``region.<plan>`` above
+    hlo = _region_hlo(plan, _q3_tables())
+    ops = re.findall(r'= [^\n]*? (sort|while|gather)\([^\n]*?op_name="([^"]*)"',
+                     hlo)
+    where = {}
+    for kind, name in ops:
+        match = re.search(r"region\.tpch_q3_planned/([^/]+)/", name)
+        where.setdefault(kind, set()).add(match.group(1) if match else None)
+    assert where["sort"] == {"groupby", "sort"}
+    assert where["while"] >= {"groupby", "sort"} and None not in where["while"]
+    assert where["gather"] >= {"pk1", "pk2", "groupby", "sort"}
+    assert None not in where["gather"]
+
+
+def _serve(plan, bindings):
+    with QueryServer(budget_bytes=4 << 30) as srv:
+        ticket = srv.session("t").submit(plan, bindings)
+        try:
+            return ticket, ticket.result(), None
+        except Exception as exc:   # what the client sees
+            return ticket, None, exc
+
+
+def test_served_pk_violation_is_a_failed_request():
+    """An ``orders`` table whose keys are not 1..n in load order breaks the
+    dense clustered primary key planned q3 declares: the request must not
+    resolve as served at ("fused", 0, 0)."""
+    tables = _q3_tables()
+    before = REGISTRY.counters().get("join.pk_violation", 0)
+    ticket, result, exc = _serve(tpch._q3_planned_plan(0, 9204), tables)
+    assert exc is None and ticket.status == "served"
+    assert REGISTRY.counters().get("join.pk_violation", 0) == before
+    assert REGISTRY.counters()["join.probe_rows"] >= 256 + 4000
+
+    orders = tables["orders"]
+    keys = np.asarray(orders.column(0).data)[::-1].copy()   # n..1
+    tables["orders"] = Table([Column(t.INT64, jnp.asarray(keys))]
+                             + list(orders.columns[1:]))
+    served = REGISTRY.counters().get("server.served", 0)
+    ticket, result, exc = _serve(tpch._q3_planned_plan(0, 9204), tables)
+    assert result is None and ticket.status == "failed"
+    assert REGISTRY.counters().get("server.served", 0) == served
+    assert isinstance(exc, resilience.FatalExecutionError)
+    assert "pk_violation" in str(exc)
+    assert REGISTRY.counters()["join.pk_violation"] == before + 1
+
+
+@pytest.mark.parametrize("cache", [True, False])
+def test_served_groupby_overflow_is_a_failed_request(cache):
+    """With the result cache on the meta is read inside ``cache.put``
+    (nothing stays cached: the same request fails again and is no hit);
+    with it off the server reads it itself."""
+    from spark_rapids_jni_tpu.utils.config import reset_option, set_option
+
+    set_option("cache.enabled", cache)
+    try:
+        _overflow_is_a_failed_request()
+    finally:
+        reset_option("cache.enabled")
+
+
+def _overflow_is_a_failed_request():
+    rng = np.random.default_rng(2)
+    table = Table([Column(t.INT64, jnp.asarray(rng.integers(0, 50, 500))),
+                   Column(t.INT64, jnp.asarray(rng.integers(0, 9, 500)))])
+
+    def plan(bound):
+        return fusion.Plan("overflow_probe", fusion.GroupBy(
+            fusion.Scan("t"), (0,), ((1, "sum"),), max_groups=bound,
+            label="groupby"))
+
+    before = REGISTRY.counters().get("groupby.overflowed", 0)
+    ticket, result, exc = _serve(plan(64), {"t": table})
+    assert exc is None and ticket.status == "served"
+    assert int(result.meta["groupby.num_groups"]) == 50
+    hits = REGISTRY.counters().get("cache.hit", 0)
+    with QueryServer(budget_bytes=4 << 30) as srv:
+        for again in (1, 2):
+            ticket = srv.session("t").submit(plan(8), {"t": table})
+            with pytest.raises(resilience.CapacityOverflow):
+                ticket.result()
+            assert ticket.status == "failed"
+            assert REGISTRY.counters()[
+                "groupby.overflowed"] == before + again
+    assert REGISTRY.counters().get("cache.hit", 0) == hits
+
+
+def test_groups_of_bounds_planned_q3_by_its_orders():
+    """``fusion.groups_of("orders")``: |orders| groups and the null group;
+    with every order matched and an unmatched lineitem row the bound is
+    met exactly and nothing overflows."""
+    n_ord = 32
+    customer = Table([Column(t.INT64, jnp.arange(1, 5, dtype=jnp.int64)),
+                      Column(t.INT8, jnp.zeros(4, jnp.int8))])
+    orders = Table([
+        Column(t.INT64, jnp.arange(1, n_ord + 1, dtype=jnp.int64)),
+        Column(t.INT64, jnp.asarray(np.arange(n_ord) % 4 + 1)),
+        Column(t.TIMESTAMP_DAYS, jnp.full(n_ord, 9000, jnp.int32)),
+        Column(t.INT32, jnp.zeros(n_ord, jnp.int32))])
+    keys = np.r_[np.arange(1, n_ord + 1), np.arange(1, n_ord + 1)]
+    ship = np.full(2 * n_ord, 9300, np.int32)
+    ship[-1] = 9000      # one row the shipdate filter drops: the null group
+    lineitem = Table([
+        Column(t.INT64, jnp.asarray(keys)),
+        Column(t.decimal64(-2), jnp.full(2 * n_ord, 100_000, jnp.int64)),
+        Column(t.decimal64(-2), jnp.full(2 * n_ord, 5, jnp.int64)),
+        Column(t.TIMESTAMP_DAYS, jnp.asarray(ship))])
+    res = tpch.tpch_q3_planned(customer, orders, lineitem)
+    assert res.result.table.num_rows == n_ord + 1
+    assert int(res.result.num_groups) == n_ord + 1
+    assert not bool(res.pk_violation)
+    ticket, result, exc = _serve(
+        tpch._q3_planned_plan(0, 9204),
+        {"customer": customer, "orders": orders, "lineitem": lineitem})
+    assert exc is None and ticket.status == "served"
+    assert not bool(result.meta["groupby.overflowed"])
