@@ -880,9 +880,12 @@ def tpch_q3_planned(customer: Table, orders: Table, lineitem: Table,
     gather — the join phase compiles with ZERO sorts (HLO-pinned in
     tests), where the general q3 pays two build-side lexsorts + probe
     searchsorteds on the sort-based machinery. On a v5e at SF1 (PERF.md
-    section 5, traced run of PR 28) the two joins take 0.65 s of a 3.81 s
+    section 5, traced run of PR 29) the two joins take 0.45 s of a 1.85 s
     request, nearly all of it pk2's gathers of the order's columns by
-    6,001,215 positions, the groupby 2.78 s and the result's sort 0.34 s. The
+    6,001,215 positions, the groupby 1.08 s (its two boundary searches
+    0.51 s, its key sort 0.27 s, the key and the revenue brought into key
+    order as five packed words 0.07 s; date and priority are read at the
+    group's first row) and the result's sort 0.27 s. The
     orderkey groupby stays on the general (sort-based) path: its
     cardinality is data-dependent, which is exactly the boundary of
     what a planner can declare; what the declared keys do give it is

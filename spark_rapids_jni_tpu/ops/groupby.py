@@ -4,10 +4,12 @@ workload, BASELINE.json config #3).
 
 TPU-first design: no device hash table (no CUDA-style concurrent hash map
 idiom on the VPU — SURVEY.md section 7 "hard parts" calls this out). Instead
-sort-based grouping: stable-sort rows by the encoded keys, mark segment
-boundaries, turn them into dense group ids with a cumulative sum, and run
-null-aware ``jax.ops.segment_*`` reductions — all static-shape, all fused by
-XLA. Output is padded to the input row count with ``num_groups`` reported
+sort-based grouping: stable-sort rows by the encoded keys, bring the words
+the aggregates read at every row into that order (``ops/sort.py permute``:
+packed 32-bit words moved once; what is read at one row a group stays where
+it is), mark segment boundaries, and reduce between them with prefix sums
+and segmented scans, no scatter — all static-shape, all fused by XLA.
+Output is padded to the input row count with ``num_groups`` reported
 alongside (static shapes are the price of jit; callers slice on host).
 
 Null semantics are Spark's: null keys form their own group; aggregates skip
@@ -26,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_jni_tpu.columnar import Column, Table
-from spark_rapids_jni_tpu.ops.sort import gather, sort_order
+from spark_rapids_jni_tpu.ops.sort import permute, sort_order
 from spark_rapids_jni_tpu.types import DType, TypeId, decimal128
 from spark_rapids_jni_tpu.utils.tracing import func_range
 
@@ -548,6 +550,29 @@ def _gather_group_keys(sorted_tbl: Table, keys: Sequence[int],
     return out_cols
 
 
+# aggregates that pick one row a group and read the column there alone
+_ONE_ROW_AGGS = ("first", "last", "first_include_nulls",
+                 "last_include_nulls")
+
+
+def _row_reads(keys, aggs) -> tuple:
+    """What the sort path reads at EVERY row in key order: ``(columns
+    whose data and validity are read, columns whose validity alone is)``.
+    Keys, for the group boundaries; the operands of every aggregate that
+    runs over the rows; the bare validity for ``count`` and for the
+    non-null scan of ``first`` / ``last``. The ``*_include_nulls`` picks
+    read nothing there, and ``nunique`` sorts its own copy."""
+    data, masks = list(keys), []
+    for col_idx, op in aggs:
+        if isinstance(op, tuple):
+            data += [col_idx, op[1]]
+        elif op in ("first", "last", "count"):
+            masks.append(col_idx)
+        elif op not in _ONE_ROW_AGGS and op != "nunique":
+            data.append(col_idx)
+    return list(dict.fromkeys(data)), list(dict.fromkeys(masks))
+
+
 def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                             max_groups) -> GroupByResult:
     ((table, row_valid),) = row_args
@@ -557,9 +582,34 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
     n = table.num_rows
     m = n if max_groups is None else int(max_groups)
     order = sort_order(table, keys, row_valid=rv)
-    sorted_tbl = gather(table, order)
+    # Only what is read at every row comes into key order, as packed words
+    # moved once (ops/sort.py ``permute``): the keys, the operands of the
+    # aggregates that run over the rows, and the bare validity of a column
+    # that is only counted or scanned for its first / last non-null row.
+    # What is read at one row a group (the cells first / last pick) is
+    # fetched from the unsorted table through ``order`` at m rows; a
+    # column no key and no aggregate names does not move at all.
+    data_at, mask_at = _row_reads(keys, aggs)
+    mask_at = [i for i in mask_at
+               if i not in data_at and table.column(i).validity is not None]
+    moved, moved_masks = permute(
+        [table.column(i) for i in data_at], order,
+        [table.column(i).validity for i in mask_at]
+        + ([] if rv is None else [rv]))
+    sorted_col = dict(zip(data_at, moved))
+    sorted_mask = dict(zip(mask_at, moved_masks))
 
-    same = _rows_equal_prev(sorted_tbl, keys)
+    def sorted_valid(col_idx: int) -> jnp.ndarray:
+        """The validity of column ``col_idx`` in key order."""
+        if col_idx in sorted_col:
+            return sorted_col[col_idx].valid_mask()
+        if col_idx in sorted_mask:
+            return sorted_mask[col_idx]
+        return jnp.ones((n,), jnp.bool_)     # a column without nulls
+
+    sorted_keys = Table([sorted_col[k] for k in keys])
+    key_at = range(len(keys))
+    same = _rows_equal_prev(sorted_keys, key_at)
     if rv is not None:
         # phantom rows (bucketed padding tails / masked shuffle slots)
         # sort LAST and never start a group: they merge into the final
@@ -568,7 +618,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         # first/last skip-null scans pass over them). The one positional
         # exception, last_include_nulls, is kept off the bucketed path by
         # the public wrapper (bucket_rows=False).
-        same = same | ~rv[order]
+        same = same | ~moved_masks[-1]
     # small-m boundary path: locate group starts with block popcounts and
     # defer (often skip entirely) the full-length group-id scan. Gated on
     # the boundary work (2*m*block rows) actually undercutting the scan.
@@ -593,7 +643,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
     overflowed = num_groups > m
     # first row of each group (n = absent, matching the old scatter-min)
     first_idx = jnp.where(g_hi > g_lo, g_lo, n)
-    out_cols = _gather_group_keys(sorted_tbl, keys, first_idx, m, n)
+    out_cols = _gather_group_keys(sorted_keys, key_at, first_idx, m, n)
 
     # Sum-form reductions (sums of ints/decimals/floats, all counts) batch
     # into ONE (n, k) prefix pass per accumulator dtype + per-group
@@ -652,7 +702,21 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
 
     plan = []  # (op, column, acc_dt / other column, lane ids / None)
     for col_idx, op in aggs:
-        c = sorted_tbl.column(col_idx)
+        if op in _ONE_ROW_AGGS:
+            # the UNSORTED column, read at one row a group through
+            # ``order``; first / last scan the validity in key order
+            plan.append((op, table.column(col_idx), None,
+                         None if op.endswith("_include_nulls")
+                         else sorted_valid(col_idx), None))
+            continue
+        if op == "nunique":     # sorts its own copy of keys and values
+            plan.append((op, None, DType(TypeId.INT64), col_idx, None))
+            continue
+        if op == "count":       # reads the validity alone
+            plan.append((op, None, None, None, lane(
+                sorted_valid(col_idx), memo_key=(col_idx, "count"))))
+            continue
+        c = sorted_col[col_idx]
         valid = c.valid_mask()
         if isinstance(op, tuple):
             # binary aggregates (covar_samp/covar_pop/corr): Spark counts
@@ -660,7 +724,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             # dedicated pairwise-masked sum + count lanes (memoized per
             # column pair — corr shares them with sibling covar aggs).
             kind, oidx = op
-            cy = sorted_tbl.column(oidx)
+            cy = sorted_col[oidx]
             for cc in (c, cy):
                 if cc.dtype.is_string or (
                         not cc.dtype.is_decimal128
@@ -740,7 +804,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                     else flane(vv, memo_key=mk))
             plan.append((kind, c, cy, tuple(specs), both_lane))
             continue
-        count_lane = lane(valid, memo_key=(id(c), "count"))
+        count_lane = lane(valid, memo_key=(col_idx, "count"))
         if op in ("sum", "mean") and c.dtype.is_decimal128:
             # exact 128-bit sum: split (lo, hi) into four 32-bit limb
             # lanes so no int64 lane can overflow (sums bounded by
@@ -797,9 +861,6 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             else:
                 sum_spec = flane(vv, memo_key=(id(c), "sum_f"))
             plan.append((op, c, None, sum_spec, count_lane))
-            continue
-        if op == "nunique":
-            plan.append((op, c, DType(TypeId.INT64), col_idx, count_lane))
             continue
         if op in ("sum", "mean"):
             acc_dt = _sum_dtype(c.dtype)
@@ -880,8 +941,8 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
 
     sum128_overflow = jnp.bool_(False)
     for op, c, acc_dt, val_lane, count_lane in plan:
-        valid = c.valid_mask()
-        vcount = seg_col(count_lane)
+        # first / last and nunique open no count lane: they never read it
+        vcount = None if count_lane is None else seg_col(count_lane)
         if op in ("sum128", "mean128"):
             s0, s1, s2, s3 = (seg_col(i) for i in val_lane)
             # shared carry recombination + Spark-ANSI overflow check
@@ -976,7 +1037,8 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                     / denom
                 if n:
                     x = c.data.astype(jnp.float64) * scale_f
-                    centered = jnp.where(valid, x - mean_g[_row_gid()], 0.0)
+                    centered = jnp.where(
+                        c.valid_mask(), x - mean_g[_row_gid()], 0.0)
                     m2 = _seg_sums((centered * centered)[:, None])[:, 0]
                 else:
                     m2 = jnp.zeros((m,), jnp.float64)
@@ -1088,7 +1150,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                 mean_x = seg_col(spec_x).astype(jnp.float64) * sfx / denom
                 mean_y = seg_col(spec_y).astype(jnp.float64) * sfy / denom
                 if n:
-                    both = valid & cy.valid_mask()
+                    both = c.valid_mask() & cy.valid_mask()
                     gid = _row_gid()
                     cxv = jnp.where(
                         both,
@@ -1127,15 +1189,16 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             nf = [True] * len(keys) + [False]
             order2 = sort_order(table, list(keys) + [col_idx2],
                                 nulls_first=nf, row_valid=rv)
-            sub = gather(
-                Table([table.column(k) for k in keys]
-                      + [table.column(col_idx2)]), order2)
+            sub_cols, sub_rv = permute(
+                [table.column(k) for k in keys] + [table.column(col_idx2)],
+                order2, [] if rv is None else [rv])
+            sub = Table(sub_cols)
             kix = list(range(len(keys)))
             same_k = _rows_equal_prev(sub, kix)
             if rv is not None:
                 # phantom rows merge into the last group here too, so
                 # gid2's group numbering stays aligned with gid's
-                same_k = same_k | ~rv[order2]
+                same_k = same_k | ~sub_rv[0]
             vcol = sub.column(len(keys))
             vvalid2 = vcol.valid_mask()
             eqv = _col_values_equal_prev(vcol)
@@ -1166,6 +1229,11 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             # (Spark's DEFAULT ignoreNulls=false) are simply the group's
             # first/last ROW: g_lo / g_hi - 1, no scan at all. Rows are
             # key-sorted STABLY, so order within a group is input order.
+            # ``c`` is the column as it came, unsorted: the winning sorted
+            # row ``row`` is its row ``order[row]``, so its data and (for
+            # the *_include_nulls variants) its validity are read at one
+            # row a group and never brought into key order.
+            valid = val_lane
             if op.endswith("_include_nulls"):
                 if op.startswith("first"):
                     win = jnp.where(g_hi > g_lo, g_lo.astype(jnp.int64),
@@ -1176,8 +1244,9 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                                     jnp.int64(-1))
                 has = (win >= 0)
                 if n:
-                    row = jnp.clip(win, 0, n - 1).astype(jnp.int32)
-                    has = has & valid[row]
+                    row = order[jnp.clip(win, 0, n - 1).astype(jnp.int32)]
+                    if c.validity is not None:
+                        has = has & c.validity[row]
                 else:
                     row = jnp.zeros((m,), jnp.int32)
                     has = jnp.zeros((m,), jnp.bool_)
@@ -1201,7 +1270,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                 run, _ = jax.lax.associative_scan(combine, (cand, ~same))
                 win = run[jnp.clip(g_hi - 1, 0, n - 1)]
                 has = (win >= 0) & (g_hi > g_lo)
-                row = jnp.clip(win, 0, n - 1).astype(jnp.int32)
+                row = order[jnp.clip(win, 0, n - 1).astype(jnp.int32)]
             else:
                 has = jnp.zeros((m,), jnp.bool_)
                 row = jnp.zeros((m,), jnp.int32)
@@ -1228,7 +1297,8 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             out_cols.append(_rank_minmax(c, op, vcount))
             continue
         sentinel = minmax_sentinel(c.dtype, op)
-        vv = jnp.where(valid, c.data, jnp.asarray(sentinel, c.data.dtype))
+        vv = jnp.where(c.valid_mask(), c.data,
+                       jnp.asarray(sentinel, c.data.dtype))
         if n:
             run = _segmented_extremum(vv, ~same, op)
             red = run[jnp.clip(g_hi - 1, 0, n - 1)]
@@ -1359,15 +1429,17 @@ def groupby_percentile(
     order = sort_order(
         table, sort_keys,
         nulls_first=[True] * len(keys) + [False])
-    sorted_tbl = gather(table, order)
-    same = _rows_equal_prev(sorted_tbl, keys)
+    # the keys and the value in that order; no other column moves
+    *sorted_keys, c = permute(
+        [table.column(i) for i in sort_keys], order)[0]
+    sorted_keys, key_at = Table(sorted_keys), range(len(keys))
+    same = _rows_equal_prev(sorted_keys, key_at)
     group_id = (jnp.cumsum(~same) - 1).astype(jnp.int32) if n else None
     num_groups, g_lo, g_hi = _dense_group_bounds(group_id, n, m)
     overflowed = num_groups > m
     first_idx = jnp.where(g_hi > g_lo, g_lo, n)
-    out_cols = _gather_group_keys(sorted_tbl, keys, first_idx, m, n)
+    out_cols = _gather_group_keys(sorted_keys, key_at, first_idx, m, n)
 
-    c = sorted_tbl.column(value_col)
     if n:
         vcum = jnp.cumsum(c.valid_mask().astype(jnp.int64))
         upper = vcum[jnp.clip(g_hi - 1, 0, n - 1)]

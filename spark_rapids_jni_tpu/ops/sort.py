@@ -10,9 +10,16 @@ are one ``jnp.argsort`` / ``jnp.lexsort`` (XLA's variadic sort, a
 comparator over every operand); wider keys are sorted one word at a time
 (``_radix_order``), because the TPU compiler's time for a variadic sort
 grows with about the square of its operand words (PERF.md section 6, PR
-28). A gather follows. Encoded keys also give Spark-compatible total
-float order (NaN sorts greatest, -0.0 == 0.0 is NOT collapsed: -0.0 < 0.0
-bitwise — documented deviation from Java's Double.compare only for -0.0).
+28). Encoded keys also give Spark-compatible total float order (NaN
+sorts greatest, -0.0 == 0.0 is NOT collapsed: -0.0 < 0.0 bitwise —
+documented deviation from Java's Double.compare only for -0.0).
+
+What follows the sort is a row permutation. ``gather`` takes any indices
+and moves every buffer and every byte-wide mask by an element gather of
+its own; on a v5e each costs 0.17-0.18 s over 8,388,608 rows inside a
+region, whatever its width (PERF.md section 5, PR 29). ``permute`` takes
+a permutation only (``sort_order``'s result) and moves packed 32-bit
+words once: the sort-path groupby reads through it.
 """
 
 from __future__ import annotations
@@ -108,8 +115,8 @@ def _key_arrays(col: Column, ascending: bool, nulls_first: bool):
         # with no emulated 64-bit compare, and _pack_lex_keys can fold
         # null ranks and the row-valid bit into the words
         flip = jnp.uint32(0x80000000 if np_dt.kind == "i" else 0)
-        value_keys = [col.data.astype(jnp.uint32),
-                      (col.data >> 32).astype(jnp.uint32) ^ flip]
+        lo, hi = _split64(col.data)
+        value_keys = [lo, hi ^ flip]
         if not ascending:
             value_keys = [~k for k in value_keys]
     else:
@@ -288,6 +295,164 @@ def gather(table: Table, indices: jnp.ndarray) -> Table:
         validity = None if c.validity is None else c.validity[indices]
         cols.append(Column(c.dtype, c.data[indices], validity))
     return Table(cols)
+
+
+# ``_move_words`` brings uint32 words into a permutation's order by one
+# gather of k-word rows, or, from this many words in all (rows times words
+# a row, 64 MiB of them) by sort passes. On a v5e (PERF.md section 6, PR
+# 29: k words of n rows, gather / sort passes, seconds) the gather's time
+# a word triples once the words no longer fit: 5 words of 2,097,152 rows
+# 0.0123 / 0.0145 and of 4,194,304 rows 0.0634 / 0.0347; 11 words of
+# 2,097,152 rows 0.0487 / 0.0318; 2 words of 8,388,608 rows 0.0524 /
+# 0.0456, 5 words 0.126 / 0.073, 11 words 0.191 / 0.145. A sort takes the
+# time of the next power of two of its rows, so between two powers the
+# gather may still be ahead (5 words of 6,001,215 rows 0.045 / 0.079); a
+# region's rows are a bucket's, a power of two.
+_SORT_MOVE_MIN_WORDS = 1 << 24
+
+
+def _data_words(c: Column):
+    """A fixed-width column's data as bit fields for ``permute``:
+    ``(fields, rebuild)`` with ``fields`` a list of unsigned arrays of 8,
+    16 or 32 bits a row (a 64-bit integer is two of 32, a decimal128 four)
+    and ``rebuild(fields) -> data``; ``None`` for data that has no exact
+    32-bit form here (float64: the TPU has no 64-bit float bitcast)."""
+    data, dt = c.data, c.data.dtype
+    if c.dtype.is_decimal128:
+        halves = [data[:, 0], data[:, 1]]
+
+        def rebuild128(f):
+            return jnp.stack([_join64(f[0], f[1], dt), _join64(f[2], f[3], dt)],
+                             axis=-1)
+
+        return [w for h in halves for w in _split64(h)], rebuild128
+    if data.ndim != 1 or dt.kind not in "iuf" or dt == jnp.float64:
+        return None
+    if dt.itemsize == 8:
+        return _split64(data), lambda f: _join64(f[0], f[1], dt)
+    unsigned = jnp.dtype(f"uint{dt.itemsize * 8}")
+    return ([jax.lax.bitcast_convert_type(data, unsigned)],
+            lambda f: jax.lax.bitcast_convert_type(f[0], dt))
+
+
+def _split64(x: jnp.ndarray) -> list:
+    return [x.astype(jnp.uint32), (x >> 32).astype(jnp.uint32)]
+
+
+def _join64(lo: jnp.ndarray, hi: jnp.ndarray, dt) -> jnp.ndarray:
+    return ((hi.astype(jnp.uint64) << 32) | lo.astype(jnp.uint64)).astype(dt)
+
+
+def _pack_fields(fields: list) -> tuple:
+    """Bit fields (bool = 1 bit, uint8, uint16, uint32) packed into as few
+    uint32 words as their bits take: ``(words, places)`` with ``places[i]
+    = (word, shift, bits)`` of field ``i``. Widest first, so every field
+    starts on a multiple of its own width and none straddles two words."""
+    by_width = sorted(range(len(fields)),
+                      key=lambda i: -_key_bits(fields[i]))
+    words, places, used = [], [None] * len(fields), 32
+    for i in by_width:
+        bits = _key_bits(fields[i])
+        f32 = fields[i].astype(jnp.uint32)
+        if used + bits > 32:
+            words.append(f32)
+            used = 0
+        else:
+            words[-1] = words[-1] | (f32 << used)
+        places[i] = (len(words) - 1, used, bits)
+        used += bits
+    return words, places
+
+
+def _unpack_field(words: list, place: tuple, like: jnp.ndarray) -> jnp.ndarray:
+    word, shift, bits = place
+    w = words[word] >> shift if shift else words[word]
+    if bits == 1:
+        return (w & jnp.uint32(1)).astype(jnp.bool_)
+    return w.astype(like.dtype)      # the cast keeps the low ``bits`` bits
+
+
+def _move_words(words: list, order: jnp.ndarray) -> list:
+    """uint32 ``words`` of n rows each, in the order ``order`` (a
+    permutation of 0..n-1): ``[w[order] for w in words]``, exactly."""
+    n = order.shape[0]
+    if not words:
+        return []
+    stacked = jnp.stack(words)
+    if n * len(words) < _SORT_MOVE_MIN_WORDS:
+        moved = stacked[:, order]             # one gather of k-word rows
+        return [moved[i] for i in range(len(words))]
+    # rank[j] is where row j goes (order's inverse); a sort of (rank, w)
+    # by rank then leaves w[order[i]] at i. Pass 0 sorts (order, iota) and
+    # gives the rank, pass i moves word i - 1: one two-operand sort
+    # instruction whatever the number of words (what a sort instruction
+    # costs the TPU compiler: ``_radix_order``). Pass 0 leaves the rank in
+    # the first word's place, which pass 1 then fills. The carries start
+    # from ``order`` and the words themselves, so under shard_map they
+    # vary over the same mesh axes going in as out.
+    key0 = order.astype(jnp.uint32)
+    iota = jax.lax.iota(jnp.uint32, n)
+
+    def one_pass(i, carry):
+        rank, out = carry
+        at = jnp.maximum(i - 1, 0)
+        first = i == 0
+        word = jax.lax.dynamic_index_in_dim(stacked, at, 0, keepdims=False)
+        moved = jax.lax.sort(
+            (jnp.where(first, key0, rank), jnp.where(first, iota, word)),
+            num_keys=1, is_stable=False)[1]
+        return (jnp.where(first, moved, rank),
+                jax.lax.dynamic_update_index_in_dim(out, moved, at, 0))
+
+    _, out = jax.lax.fori_loop(0, len(words) + 1, one_pass, (key0, stacked))
+    return [out[i] for i in range(len(words))]
+
+
+def permute(columns: Sequence[Column], order: jnp.ndarray,
+            masks: Sequence[jnp.ndarray] = ()) -> tuple:
+    """``columns`` and the row masks ``masks`` (bool[n]: a row-valid mask,
+    a validity wanted without its data) in the order ``order``, which must
+    be a permutation of 0..n-1 as ``sort_order`` returns one: ``(columns,
+    masks)``, each what ``gather`` / ``mask[order]`` gives, bit for bit.
+
+    Where ``gather`` moves every data buffer and every byte-wide mask by a
+    gather of its own, this cuts the fixed-width data into 32-bit words (a
+    64-bit integer is two, a decimal128 four), packs the 8- and 16-bit
+    data and every validity and mask bit together into as few words as
+    their bits take, materialises the words once and moves them once
+    (``_move_words``). ``validity is None`` stays ``None``. Strings keep
+    ``gather_strings``; float64 and nested data keep their gather."""
+    out_cols: list = [None] * len(columns)
+    fields: list = []     # every bit field that moves, data and masks
+    parts = []            # (column, validity's field, data's fields, rebuild)
+    for i, c in enumerate(columns):
+        if c.dtype.is_string:
+            from spark_rapids_jni_tpu.ops import strings as s
+
+            out_cols[i] = s.gather_strings(c, order)
+            continue
+        vfield = None
+        if c.validity is not None:
+            vfield = len(fields)
+            fields.append(c.validity)
+        cut, rebuild = (None if c.children is not None
+                        else _data_words(c)) or ([], None)
+        parts.append((i, vfield, slice(len(fields), len(fields) + len(cut)),
+                      rebuild))
+        fields.extend(cut)
+    mask_at = len(fields)
+    fields.extend(masks)
+    words, places = _pack_fields(fields)
+    moved_words = _move_words(words, order)
+    moved = [_unpack_field(moved_words, place, f)
+             for place, f in zip(places, fields)]
+    for i, vfield, span, rebuild in parts:
+        c = columns[i]
+        out_cols[i] = Column(
+            c.dtype,
+            c.data[order] if rebuild is None else rebuild(moved[span]),
+            None if vfield is None else moved[vfield])
+    return out_cols, moved[mask_at:]
 
 
 @func_range("sort_table")

@@ -158,18 +158,59 @@ def _region_hlo(plan, bindings) -> str:
     return jax.jit(region).lower(bindings).compile().as_text()
 
 
-def test_planned_q3_region_sorts_one_word_at_a_time():
+@pytest.fixture(params=["gathers", "sort_passes"])
+def moved_by(request, monkeypatch):
+    """``permute``'s two ways to move its words, at the tests' sizes: the
+    one gather of k-word rows a small table takes, and (the threshold
+    dropped to nothing) the sort passes a table of millions of rows takes."""
+    if request.param == "sort_passes":
+        monkeypatch.setattr(so, "_SORT_MOVE_MIN_WORDS", 0)
+    return request.param
+
+
+def test_planned_q3_region_sorts_one_word_at_a_time(moved_by):
     """XLA's TPU compiler takes about the square of a sort's operand words
-    in compile time: the region's two sorts (the groupby's, the result's)
-    are each one 32-bit key and the int32 order, in a loop, and none has a
-    64-bit operand."""
+    in compile time: the region's sorts (the groupby's, the result's and,
+    where the groupby's words move by sort passes, that loop's one) are
+    each two operands of 32 bits, in a loop, and none has a 64-bit
+    operand."""
     hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
     sorts = _sorts(hlo)
-    assert len(sorts) == 2, sorts
+    assert len(sorts) == (3 if moved_by == "sort_passes" else 2), sorts
     for result in sorts:
-        assert re.fullmatch(r"\(u32\[\d+\]\{0\}, s32\[\d+\]\{0\}\)", result), \
-            result
+        assert re.fullmatch(
+            r"\(u32\[\d+\]\{0\}, [us]32\[\d+\]\{0\}\)", result), result
     assert not re.search(r"[us]64\[[^\]]*\][^=\n]* sort\(", hlo)
+
+
+def _gathers(hlo: str, node: str | None = None) -> list:
+    """(element type, dimensions but the 1s, inside a loop?) of the
+    gathers' results, all of them or those under a plan node's scope."""
+    out = []
+    for m in re.finditer(
+            r'= (\w+)\[([\d,]*)\]\S* gather\([^\n]*?op_name="([^"]*)"', hlo):
+        if node is None or re.search(rf"region\.[^/]+/{node}/", m.group(3)):
+            dims = sorted(int(d) for d in m.group(2).split(",") if d != "1")
+            out.append((m.group(1), dims, "/while/" in m.group(3)))
+    return out
+
+
+def test_planned_q3_groupby_moves_no_column_it_reads_at_one_row(moved_by):
+    """The groupby brings into key order the key, the revenue and their
+    bits: five words. ``o_orderdate`` / ``o_shippriority`` are read at the
+    group's first row through ``order`` (m = |orders| + 1 rows), so under
+    the node's scope no gather outside the key sort's loop has n rows but
+    the one of the five packed words, and with sort passes none at all."""
+    n, m = 4000, 257
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
+    found = _gathers(hlo, "groupby")
+    rows_n = [(t, dims) for t, dims, looped in found
+              if not looped and n in dims]
+    assert rows_n == ([] if moved_by == "sort_passes"
+                      else [("u32", [5, n])]), rows_n
+    # the first-row picks: int32 data and a mask, at m rows
+    picks = [dims for t, dims, _ in found if t == "s32"]
+    assert picks and all(dims == [m] for dims in picks), picks
 
 
 def test_general_q1_sort_keeps_its_two_packed_words():
@@ -191,6 +232,33 @@ def test_general_q1_sort_keeps_its_two_packed_words():
     rv = jnp.asarray(rng.random(n) > 0.2)
     hlo = jax.jit(order).lower(flags, rv).compile().as_text()
     assert _sorts(hlo) == [f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s64[{n}]{{0}})"]
+
+
+def test_general_q1_groupby_keeps_its_key_sort(moved_by):
+    """General q1's groupby over a padded batch: its key sort is the
+    variadic ``(u32, u32, s64)`` it was (the accepted cell's cold compile
+    is this instruction's 60 s), and what it moves are eleven words: two
+    int8 keys with seven validities and the row-valid bit in one, five
+    int64 columns in ten. No column and no mask has a gather of its own."""
+    from spark_rapids_jni_tpu.ops import groupby as gb
+
+    n = 4096
+    work = tpch._q1_work_table(tpch.lineitem_table(n))
+    rv = jnp.arange(n) < 4000
+
+    def groupby(tb, row_valid):
+        return gb._groupby_aggregate_impl(
+            ((tb, row_valid),), None, None, keys=(0, 1),
+            aggs=tuple(tpch._Q1_AGGS), max_groups=tpch._Q1_GROUP_BUDGET)
+
+    hlo = jax.jit(groupby).lower(work, rv).compile().as_text()
+    sorts = _sorts(hlo)
+    assert f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s64[{n}]{{0}})" in sorts, sorts
+    moved = [(t, dims) for t, dims, _ in _gathers(hlo) if n in dims]
+    if moved_by == "sort_passes":
+        assert moved == [] and f"(u32[{n}]{{0}}, u32[{n}]{{0}})" in sorts
+    else:
+        assert moved == [("u32", [11, n])], moved
 
 
 def test_every_plan_node_names_its_heavy_operations():
