@@ -30,7 +30,7 @@ cd "$(dirname "$0")/.."
 # fail loud if a refactor moves it out from under the lint root
 test -d spark_rapids_jni_tpu/telemetry
 
-python -m tools.tpulint spark_rapids_jni_tpu bench.py tools
+python -m tools.tpulint spark_rapids_jni_tpu tools
 
 # dispatch smoke: the jit-via-dispatch rule only proves ops ROUTE through
 # runtime/dispatch — this proves the cache actually coalesces shapes.

@@ -4,8 +4,8 @@ The reference's flagship workloads are Spark SQL queries running through the
 RAPIDS accelerator (BASELINE.json configs: RowConversion on the lineitem
 schema; TPC-H q1 groupby-aggregate + sort). Here the same queries are
 expressed directly against the operator substrate, serving three roles:
-benchmark pipelines (bench.py), the driver's compile-check entry
-(__graft_entry__.py), and integration tests of the operator stack.
+the plans ``benchmark/`` and ``chip_smoke.py`` serve, the driver's compile
+check (__graft_entry__.py), and integration tests of the operator stack.
 
 TPC-H q1 (pricing summary report):
 
@@ -1222,10 +1222,13 @@ def tpch_q3_outofcore(path, customer: Table, orders: Table, *,
         cols = list(chunk.columns)
         cols[1] = Column(t.decimal64(-2), cols[1].data, cols[1].validity)
         cols[2] = Column(t.decimal64(-2), cols[2].data, cols[2].validity)
+        # pk2 is decided once, above, for the host side of the staging
+        # boundary: a chunk is not probed again inside its region, where
+        # masking drops no row
         res = fusion.execute(
             _q3_partial_plan(cutoff),
             {"chunk": Table(cols), "build2": build2},
-            donate_inputs=True)
+            donate_inputs=True, runtime_filters=False)
         if bool(res.meta["pk2.pk_violation"]):
             raise ValueError("orders PK declaration violated")
         return trim_table(res.table, int(res.meta["partial.num_groups"]))
@@ -1852,17 +1855,6 @@ def tpch_q12_planned_result(orders: Table, lineitem: Table,
                         domains=[string_domain(modes)])
 
 
-def tpch_q12_planned(orders: Table, lineitem: Table,
-                     modes: tuple = ("MAIL", "SHIP"),
-                     year_start: int = _Q12_YEAR_START,
-                     year_end: int = _Q12_YEAR_END) -> Table:
-    """Planned q12, table only — [l_shipmode, high_line_count,
-    low_line_count], mode-sorted with the null pseudo-group last (the
-    bounded plan's static order; same ordering contract as tpch_q12)."""
-    return tpch_q12_planned_result(
-        orders, lineitem, modes, year_start, year_end).table
-
-
 # ---------------------------------------------------------------------------
 # q14 — promotion effect (join + LIKE + global conditional ratio)
 # ---------------------------------------------------------------------------
@@ -2477,15 +2469,6 @@ def tpch_q4_planned_result(orders: Table, lineitem: Table,
     ])
     return plan_groupby(keyed, keys=[0], aggs=[(1, "sum")],
                         domains=[string_domain(_Q12_PRIORITIES)])
-
-
-def tpch_q4_planned(orders: Table, lineitem: Table,
-                    qtr_start: int = _Q4_QTR_START,
-                    qtr_end: int = _Q4_QTR_END) -> Table:
-    """Planned q4, table only — [o_orderpriority, order_count] in
-    priority order, null pseudo-group last (same contract as tpch_q4)."""
-    return tpch_q4_planned_result(
-        orders, lineitem, qtr_start, qtr_end).table
 
 
 # ---------------------------------------------------------------------------

@@ -1,13 +1,13 @@
-"""Bounded-domain groupby planning — the facility behind the 125x q1 win.
+"""Bounded-domain groupby planning — the facility behind planned q1.
 
-Hardware measurements (bench_tpu_ledger.jsonl: a v5e in 2026-07, before
-the runtime stack; not measured since) showed planner-declared key
-domains beat the general sort-based groupby by 125x at 16M rows: when every
-key column's candidate values are known at plan time, grouping lowers to
-``groupby_aggregate_bounded`` — zero sort, zero gather, zero scan, zero
-scatter; one streaming masked-reduction pass the TPU backend fuses. That
-win was hand-wired into q1 (``_Q1_RF_DOMAIN``); this module makes it a
-planner facility any query can use (VERDICT r4 item 3).
+When every key column's candidate values are known at plan time, grouping
+lowers to ``groupby_aggregate_bounded`` — zero sort, zero gather, zero scan,
+zero scatter; one streaming masked-reduction pass the TPU backend fuses —
+where the general groupby sorts every row. What that is worth is measured by
+the cells ``sf1_q1_planned_fresh`` and ``sf1_q1_general_fresh`` (PERF.md:
+planned over general q1 at SF1 on one chip, 6.4 times in rows/s). It was
+hand-wired into q1 (``_Q1_RF_DOMAIN``); this module makes it a planner
+facility any query can use (VERDICT r4 item 3).
 
 Domain sources mirror what a production Spark planner sees:
 

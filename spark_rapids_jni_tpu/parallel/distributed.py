@@ -258,9 +258,9 @@ def distributed_groupby_bounded(
     ``distributed_groupby_aggregate`` pays hash_shuffle (all_to_all of
     whole rows over ICI) plus per-device sort machinery, this path pays
     a per-device streaming masked-reduction pass plus an m-row
-    collective: the single-chip 125x win (bench_tpu_ledger.jsonl, a v5e
-    in 2026-07, before the runtime stack; not measured since) composes
-    with an m-vs-n bytes-on-wire win on the mesh.
+    collective: the single-chip gain of the bounded plan (PERF.md:
+    planned over general q1 at SF1, 6.4 times in rows/s) composes with
+    m rows on the wire instead of n. No cell measures the mesh yet.
 
     ``table`` must already be sharded row-wise over ``mesh``. Output is
     REPLICATED (every device holds the global m-slot answer) — m is

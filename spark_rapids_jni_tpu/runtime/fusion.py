@@ -828,9 +828,9 @@ def inject_runtime_filters(plan: Plan, bindings: dict) -> Plan:
     :class:`BloomBuild` over the build child and a :class:`BloomProbe`
     over the probe child. The probe sits INSIDE the region — below the
     fusion boundary — so the pruned scan fuses with everything above it;
-    chunked/out-of-core paths prune per chunk on the host side instead
-    (``rtfilter.prune_chunk``), where compaction is free. Results are
-    bit-identical with the pass on or off (see :class:`BloomProbe`);
+    chunked paths prune per chunk on the host side instead, where compaction
+    is free, and ``execute`` their regions ``runtime_filters=False``. Results
+    are bit-identical with the pass on or off (see :class:`BloomProbe`);
     what changes is the dispatch fingerprint, so filtered and unfiltered
     plans never alias an executable."""
     from spark_rapids_jni_tpu.runtime import rtfilter
@@ -1030,7 +1030,7 @@ def execute(plan: Plan, bindings: dict, *,
             donate_inputs: bool = False,
             force_staged: bool = False,
             surface_pressure: bool = False,
-            cancel_token=None) -> FusedResult:
+            cancel_token=None, runtime_filters: bool = True) -> FusedResult:
     """Run one fusible region.
 
     ``bindings`` maps every Scan name to a Table. With ``fusion.enabled``
@@ -1083,7 +1083,7 @@ def execute(plan: Plan, bindings: dict, *,
             force_staged=force_staged,
             surface_pressure=surface_pressure,
             cancel_token=cancel_token)
-    if get_option("rtfilter.enabled"):
+    if runtime_filters and get_option("rtfilter.enabled"):  # see the pass
         plan = inject_runtime_filters(plan, bindings)
     nodes = _topo(plan.root)
     bucketed, exact = _scan_names(nodes)

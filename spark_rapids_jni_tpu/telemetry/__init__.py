@@ -5,8 +5,8 @@ The reference answers "where did GPU time go" with NVTX ranges
 port's equivalent *and* closes the gap NVTX never covered: counting where
 execution actually landed. Every device→host fallback (regex NUL byteset,
 unsupported regex atom, cast-strings host assembly, out-of-core spill,
-shuffle overflow reroute) records an event with a mandatory ``reason``; the
-bench stamps a telemetry summary into every BENCH_*.json; and
+shuffle overflow reroute) records an event with a mandatory ``reason``;
+``benchmark/run.py --trace 1`` reports the counters per layer; and
 ``python -m spark_rapids_jni_tpu.telemetry report run.jsonl`` renders the
 per-op device/host split with p50/p95 wall times and bytes moved.
 

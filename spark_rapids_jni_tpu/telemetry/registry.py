@@ -3,9 +3,10 @@
 The reference exposes per-operator NVTX ranges plus RMM/cuDF counters that
 operators scrape to see where GPU time goes; this is the TPU-side analogue,
 deliberately dependency-free (no prometheus_client, no jax import) so it can
-be pulled in from any layer — including ``bench.py``'s no-jax parent process —
-without cost. All state is process-local and guarded by a single lock;
-instruments are created on first use and live for the life of the process.
+be pulled in from any layer — including a parent process that must not
+touch jax (``chip_smoke.py``'s) — without cost. All state is process-local
+and guarded by a single lock; instruments are created on first use and live
+for the life of the process.
 
 Cost model: when telemetry is disabled the record_* helpers in ``events.py``
 return before touching the registry, so the only steady-state overhead is one

@@ -86,7 +86,6 @@ __all__ = [
     "validate",
     "chrome_trace",
     "write_chrome_trace",
-    "phase_breakdown",
     "flight_records",
     "dump_flight_record",
     "reset",
@@ -603,78 +602,3 @@ def write_chrome_trace(records: Iterable[dict], out_path: str) -> int:
     with open(out_path, "w", encoding="utf-8") as fh:
         json.dump(doc, fh, sort_keys=True)
     return sum(1 for e in doc["traceEvents"] if e.get("ph") == "X")
-
-
-# span name -> bench phase. region.* spans also count as compute, and a
-# span nested under an already-attributed ancestor contributes nothing
-# (e.g. the merge region inside outofcore.merge, or per-chunk regions
-# inside pipeline.compute) — each wall-clock second lands in ONE phase.
-_PHASE_OF = {
-    "admission.wait": "admission",
-    "pipeline.decode": "decode",
-    "pipeline.staging": "staging",
-    "pipeline.transfer": "transfer",
-    "pipeline.compute": "compute",
-    "pipeline.merge": "merge",
-    "outofcore.merge": "merge",
-}
-
-PHASES = ("admission", "queue", "decode", "staging", "transfer",
-          "compute", "merge")
-
-
-def phase_breakdown(records: Iterable[dict]) -> dict:
-    """Span-derived per-phase wall attribution for the bench blocks:
-    seconds (and fractions of total root-span wall) spent in admission
-    wait, pre-admission queueing, decode/staging/transfer, compute and
-    merge. Queue time comes from the server's ``admitted`` events
-    (submit-to-grant wait) minus the admission-wait spans nested in it."""
-    records = list(records)
-    recs = _span_records(records)
-    by_id = {r.get("span"): r for r in recs}
-
-    def _phase_of(rec: dict) -> Optional[str]:
-        op = str(rec.get("op", ""))
-        phase = _PHASE_OF.get(op)
-        if phase is None and op.startswith("region."):
-            phase = "compute"
-        return phase
-
-    def _ancestor_attributed(rec: dict) -> bool:
-        hops = 0
-        cur = rec
-        while hops < 64:
-            pid = cur.get("parent")
-            if pid is None:
-                return False
-            cur = by_id.get(pid)
-            if cur is None:
-                return False
-            if _phase_of(cur) is not None:
-                return True
-            hops += 1
-        return False
-
-    roots = [r for r in recs if r.get("parent") is None]
-    total = sum(max(0.0, float(r["t1"]) - float(r["t0"])) for r in roots)
-    phases = {p: 0.0 for p in PHASES}
-    for r in recs:
-        dur = max(0.0, float(r["t1"]) - float(r["t0"]))
-        phase = _phase_of(r)
-        if phase is not None and not _ancestor_attributed(r):
-            phases[phase] += dur
-    queue_s = 0.0
-    for r in records:
-        if (isinstance(r, dict) and r.get("kind") == "server"
-                and r.get("event") == "admitted"):
-            queue_s += float(r.get("wait_ms", 0.0)) / 1e3
-    phases["queue"] = max(0.0, queue_s - phases["admission"])
-    return {
-        # a request served by a worker has two roots (submit.* on the
-        # client's thread, and the worker's, which names it in caused_by)
-        "queries": sum("caused_by" not in r for r in roots),
-        "total_s": round(total, 6),
-        "phases_s": {k: round(v, 6) for k, v in phases.items()},
-        "fractions": ({k: (round(v / total, 4) if total else 0.0)
-                       for k, v in phases.items()} if roots else {}),
-    }

@@ -496,9 +496,17 @@ FileMeta parse_meta(uint8_t const* file, uint64_t len) {
   for (auto const* f : footer.fields(kFtStripes)) {
     meta.stripe_bufs.emplace_back(f->bytes);
   }
+  // the footer states the row count twice; a stripe list that lost an
+  // entry (or a stripe whose count changed) would otherwise decode to a
+  // shorter table of the right schema
+  uint64_t stripe_rows = 0;
   for (auto const& buf : meta.stripe_bufs) {
     meta.stripes.push_back(Message::parse(
         reinterpret_cast<uint8_t const*>(buf.data()), buf.size()));
+    stripe_rows += meta.stripes.back().u64(kSiNumRows);
+  }
+  if (stripe_rows != footer.u64(kFtNumRows)) {
+    fail("footer numberOfRows disagrees with the sum over its stripes");
   }
   return meta;
 }
@@ -582,10 +590,13 @@ ColumnStreams gather_streams(uint8_t const* file, FileMeta const& meta,
       out.secondary = std::move(decoded);
     }
   }
-  if (col < dir.encodings.size()) {
-    out.encoding = dir.encodings[col];
-    out.dict_size = dir.dict_sizes[col];
+  // one ColumnEncoding per column id (root included): a column without
+  // one must not be read as DIRECT by default
+  if (col >= dir.encodings.size()) {
+    fail("stripe footer has no encoding for the column");
   }
+  out.encoding = dir.encodings[col];
+  out.dict_size = dir.dict_sizes[col];
   return out;
 }
 

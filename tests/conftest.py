@@ -98,8 +98,7 @@ _MEDIUM_TIER = {
     "tests/test_planner.py::test_q12_planned_matches_oracle",
     "tests/test_planner.py::test_q4_planned_matches_oracle",
     # second round-5 durations pass (>=9.5 s): 8-device shard_map
-    # compiles and oracle sweeps; bench-ledger tests stay in premerge
-    # (they protect the driver artifact and their cost is module import)
+    # compiles and oracle sweeps
     "tests/test_cast_strings.py::test_date_roundtrip_through_strings",
     "tests/test_cast_strings.py::test_string_to_timestamp_vs_python_oracle",
     "tests/test_decimal128_ops.py::test_decimal128_sum_small_m_path_matches",

@@ -1,8 +1,8 @@
 """Import-order hygiene: the package must be importable BEFORE a platform
 pin without initializing any jax backend.
 
-tests/conftest.py, __graft_entry__.dryrun_multichip, bench.py's CPU child
-and a fleet supervisor all do ``import spark_rapids_jni_tpu...`` and only
+tests/conftest.py, __graft_entry__.dryrun_multichip and a fleet
+supervisor all do ``import spark_rapids_jni_tpu...`` and only
 then call ``force_cpu_platform()``. That is only sound while nothing in the
 package's import graph creates a jax array / queries devices at module
 level — the moment one does, the default backend (on a TPU machine: the

@@ -588,20 +588,14 @@ def test_orc_envelope_malformed_variants_classified():
 
 
 def test_valid_envelopes_pass_pure_python_preflight():
-    """A well-formed file must NOT be rejected by the preflight; on this
-    build it then reaches the native loader, which is absent (OSError) —
-    the acceptable needs-native outcome, never a MalformedFileError."""
+    """A well-formed file must NOT be rejected by the preflight: it goes
+    on to the native parse and decodes."""
     from spark_rapids_jni_tpu.orc.reader import read_table as orc_read
     from spark_rapids_jni_tpu.parquet.reader import read_table as pq_read
 
     for reader, blob in ((pq_read, _parquet_bytes()),
                          (orc_read, _orc_bytes())):
-        try:
-            reader(blob)
-        except MalformedInputError:  # pragma: no cover - the regression
-            pytest.fail("preflight rejected a well-formed file")
-        except OSError:
-            pass  # libtpudf.so not built here: preflight already passed
+        assert reader(blob).num_rows == 32
     assert REGISTRY.counter("integrity.malformed").value == 0
 
 
@@ -616,13 +610,18 @@ def test_envelope_checks_also_cover_path_inputs(tmp_path):
 
 def test_ingest_preflight_disabled_is_passthrough():
     """integrity.enabled=false: no preflight — malformed bytes reach the
-    native loader exactly as before this layer existed."""
+    native parse exactly as before this layer existed, and it refuses them."""
     from spark_rapids_jni_tpu.parquet.reader import read_table
 
     config.set_option("integrity.enabled", False)
-    with pytest.raises(OSError):  # load_native, not MalformedFileError
+    # the preflight would say "bad leading magic"; off, the native parse
+    # refuses the bytes itself and the reader's _check classifies that
+    with pytest.raises(MalformedFileError, match="op=parquet.parquet read"):
         read_table(b"not parquet at all")
-    assert REGISTRY.counter("integrity.malformed").value == 0
+    assert REGISTRY.counter(
+        "integrity.malformed.parquet.envelope").value == 0
+    assert REGISTRY.counter(
+        "integrity.malformed.parquet.parquet read").value == 1
 
 
 def _malformed_ingest(tbl, *args):

@@ -18,10 +18,13 @@ single invariant, asserted per case:
 
 Every mutation derives from ``CorruptionSpec(seed=...)`` — reproducible
 case-by-case: a failure names its (family, mode, seed) triple and replays
-standalone. Ingestion cases whose mutation survives the pure-Python
-envelope preflight proceed to the native loader, which this build does
-not ship — those raise ``OSError`` (needs-native), counted as such: the
-contract "never garbage" still holds because nothing was decoded.
+standalone. Ingestion differs in one point: the files are a foreign
+format without page checksums, so one outcome more is admissible there
+and no other — a bit flipped inside a column's value bytes decodes to a
+table of the file's schema, row count and validity that differs from
+the original in that column's values alone. A mutation anywhere else
+that passes the envelope preflight is refused by the native parse (built
+from ``src/native`` on first touch, so it is never absent), classified.
 """
 
 import socket
@@ -237,64 +240,110 @@ def test_fuzz_checkpoint_corruption_replays_bit_identical(seed):
 
 # ---------------------------------------------------------------------------
 # family 5: untrusted ingestion — 40 seeded mutations of well-formed
-# Parquet/ORC files; every case classifies (MalformedInputError), stops at
-# the absent native loader (OSError — preflight passed, nothing decoded),
-# or recovers the original bytes. Never an unclassified crash.
+# Parquet/ORC files. Every case classifies (MalformedInputError, from the
+# envelope preflight or from the native parse) or, where the flipped bit
+# lies inside a column's value bytes (neither file carries a checksum
+# over them), decodes to the original but for that column's values.
+# Never an unclassified crash, never another shape, never another column.
 # ---------------------------------------------------------------------------
 
 
 def _parquet_file():
-    from tests.parquet_util import ColumnSpec, write_parquet
+    """-> (file, [(start, stop, column)] of each column's PLAIN values)."""
+    from tests.parquet_util import ColumnSpec, plain_encode, write_parquet
 
-    return write_parquet([
+    cols = [
         ColumnSpec("a", 2, list(range(48))),            # INT64
         ColumnSpec("b", 5, [i / 7 for i in range(48)]),  # DOUBLE
-    ])
+    ]
+    blob = write_parquet(cols)
+    spans = []
+    for i, c in enumerate(cols):
+        raw = plain_encode(c.physical, c.values)
+        at = blob.index(raw)
+        spans.append((at, at + len(raw), i))
+    return blob, spans
 
 
 def _orc_file():
-    from tests.orc_util import ColumnSpec, write_orc
+    """-> (file, [(start, stop, column)] of the DATA stream's varints:
+    one literal run of RLEv1, its control byte not among them)."""
+    from tests.orc_util import ColumnSpec, rle_v1_literals, write_orc
 
-    return write_orc([
-        ColumnSpec("a", 4, list(range(48))),  # LONG
-    ])
+    values = list(range(48))
+    blob = write_orc([ColumnSpec("a", 4, values)])  # LONG
+    run = rle_v1_literals(values)
+    at = blob.index(run)
+    return blob, [(at + 1, at + len(run), 0)]
 
 
-def _fuzz_ingest(read_table, blob, mode, seed):
+def _buffers(col):
+    return (col.dtype, np.asarray(col.data).tobytes(),
+            np.asarray(col.valid_mask()).tobytes())
+
+
+def _fuzz_ingest(read_table, blob, value_spans, mode, seed):
+    want = read_table(blob)
     script = faults.FaultScript(corruptions=[
         faults.CorruptionSpec("integrity.ingest", mode=mode, seed=seed)])
     with faults.inject(script):
         try:
-            read_table(blob)
+            got = read_table(blob)
         except MalformedInputError:
             assert REGISTRY.counter("integrity.malformed").value >= 1
             return "classified"
-        except OSError:
-            # the mutation survived the envelope preflight; the decode
-            # would run inside the hardened native parse, absent here
-            return "needs-native"
         except (CorruptDataError, FatalExecutionError):  # pragma: no cover
             return "classified"
-    pytest.fail(  # pragma: no cover - native lib absent on this build
-        f"{mode}/{seed}: corrupted file decoded without native engine")
+    assert script.fired, f"{mode}/{seed}: corruption window never fired"
+    # the file decoded: the same mutation again, to see where it fell
+    mutated = faults.CorruptionSpec(
+        "integrity.ingest", mode=mode, seed=seed).apply(blob, 0)
+    assert len(mutated) == len(blob), \
+        f"{mode}/{seed}: a file cut short decoded"
+    hit = [i for i in range(len(blob)) if blob[i] != mutated[i]]
+    col = next((c for a, b, c in value_spans
+                if len(hit) == 1 and a <= hit[0] < b), None)
+    assert col is not None, (f"{mode}/{seed}: bytes {hit} hold no column's "
+                             "values and the file decoded")
+    assert (got.num_rows, got.num_columns) == (
+        want.num_rows, want.num_columns), f"{mode}/{seed}"
+    for i, (g, w) in enumerate(zip(got.columns, want.columns)):
+        (g_dtype, g_data, g_valid), (w_dtype, w_data, w_valid) = (
+            _buffers(g), _buffers(w))
+        assert g_dtype == w_dtype and g_valid == w_valid, \
+            f"{mode}/{seed}: column {i} changed type or validity"
+        assert (g_data != w_data) == (i == col), \
+            f"{mode}/{seed}: byte {hit[0]} is column {col}'s, column {i}"
+    return "value-flip"
 
 
 @pytest.mark.parametrize("case", range(20))
 def test_fuzz_ingest_parquet(case):
     from spark_rapids_jni_tpu.parquet.reader import read_table
 
-    outcome = _fuzz_ingest(read_table, _parquet_file(),
+    outcome = _fuzz_ingest(read_table, *_parquet_file(),
                            MODES[case % len(MODES)], 400 + case)
-    assert outcome in ("classified", "needs-native")
+    assert outcome in ("classified", "value-flip")
+
+
+# flips that fell in the file's structure and once decoded all the same:
+# 503 turns the footer's one `stripes` entry into an unknown field (a
+# 0-row table), 512 the root's ColumnEncoding in the stripe footer (the
+# column was then read as DIRECT by default). The native parse holds the
+# footer's numberOfRows against its stripes and wants an encoding a column.
+_ORC_STRUCTURE_FLIPS = (503, 512)
 
 
 @pytest.mark.parametrize("case", range(20))
 def test_fuzz_ingest_orc(case):
     from spark_rapids_jni_tpu.orc.reader import read_table
 
-    outcome = _fuzz_ingest(read_table, _orc_file(),
-                           MODES[case % len(MODES)], 500 + case)
-    assert outcome in ("classified", "needs-native")
+    seed = 500 + case
+    outcome = _fuzz_ingest(read_table, *_orc_file(),
+                           MODES[case % len(MODES)], seed)
+    assert outcome in ("classified", "value-flip")
+    if seed in _ORC_STRUCTURE_FLIPS:
+        assert outcome == "classified"
 
 
 # ---------------------------------------------------------------------------

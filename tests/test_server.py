@@ -750,8 +750,9 @@ def test_served_miss_is_two_trees_joined_by_request():
     # inside a span: it ends before the root does
     put = next(r for r in tree if r["op"] == "cache.put")
     assert qry["t0"] <= put["t0"] <= put["t1"] <= qry["t1"]
-    # one request, counted once
-    assert spans.phase_breakdown(tree)["queries"] == 1
+    # one request, counted once: exactly one root no other span caused
+    assert [r["op"] for r in tree if r.get("parent") is None
+            and "caused_by" not in r] == ["submit.tpch_q1"]
 
 
 def test_served_hit_is_one_tree():

@@ -419,25 +419,6 @@ def test_registry_exposition_format():
     assert text.endswith("\n")
 
 
-def test_phase_breakdown_attribution(enabled):
-    with spans.span("query.q"):
-        with spans.child("admission.wait"):
-            pass
-        with spans.child("rung.outofcore"):
-            with spans.child("outofcore.merge"):
-                # nested region must NOT double-count as compute
-                with spans.child("region.q_merge"):
-                    pass
-    telemetry.record_server("q", "admitted", session="s",
-                            wait_ms=50.0)
-    pb = spans.phase_breakdown(telemetry.events())
-    assert pb["queries"] == 1
-    assert pb["phases_s"]["merge"] > 0
-    assert pb["phases_s"]["compute"] == 0.0
-    assert pb["phases_s"]["queue"] >= 0.0
-    assert set(pb["fractions"]) == set(spans.PHASES)
-
-
 def test_render_top_snapshot():
     text = render_top({
         "limiter": {"used": 1 << 20, "budget": 1 << 22, "peak": 1 << 21,

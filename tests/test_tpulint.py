@@ -5,8 +5,8 @@ Two halves:
 1. **Rule regression** — each seeded-violation fixture under
    tests/tpulint_fixtures/ must produce exactly its rule's findings
    (and none on the clean counterparts in the same file).
-2. **Whole-tree gate** — linting spark_rapids_jni_tpu + bench.py +
-   tools with the checked-in baseline must be clean, both through the
+2. **Whole-tree gate** — linting spark_rapids_jni_tpu + tools with
+   the checked-in baseline must be clean, both through the
    library and through the real CLI (`python -m tools.tpulint`), which
    is what ci/lint.sh runs.
 
@@ -803,7 +803,7 @@ def test_parse_error_is_a_finding(tmp_path):
 # whole-tree gate (what ci/lint.sh enforces)
 # ---------------------------------------------------------------------------
 
-_TREE = ["spark_rapids_jni_tpu", "bench.py", "tools"]
+_TREE = ["spark_rapids_jni_tpu", "tools"]
 
 
 def test_package_tree_is_clean_via_library():

@@ -607,7 +607,7 @@ def check_jit_via_dispatch(ctx: FileContext) -> List[RawFinding]:
     ``runtime/dispatch.py`` — exactly the per-shape compile storm the
     dispatch layer exists to absorb, and its padded-waste / hit-rate
     telemetry never sees the op. Scope: ops/*.py and any *_device.py
-    (host-side drivers like bench.py measure whole pipelines and stay out
+    (host-side drivers like chip_smoke.py measure whole pipelines and stay out
     of scope; runtime/dispatch.py itself owns the one legitimate jit).
     A deliberate jit — e.g. a Pallas kernel wrapper whose shapes are
     block-quantized already — carries a
