@@ -859,7 +859,7 @@ def _q3_planned_plan(segment: int, cutoff: int) -> fusion.Plan:
     # alone and carries the other two as the group's first row (the same
     # groups, the same answer, one 64-bit sort key and not three keys);
     # and there are at most |orders| groups and the null group of the
-    # unmatched rows, so the groupby, its boundary searches and the
+    # unmatched rows, so the groupby's look-ups, its results and the
     # result's sort run over that many rows, not over the lineitem bucket
     g = fusion.GroupBy(fusion.Project(j2, _q3_planned_keyed_fn), (0,),
                        ((1, "first_include_nulls"),
@@ -880,12 +880,12 @@ def tpch_q3_planned(customer: Table, orders: Table, lineitem: Table,
     gather — the join phase compiles with ZERO sorts (HLO-pinned in
     tests), where the general q3 pays two build-side lexsorts + probe
     searchsorteds on the sort-based machinery. On a v5e at SF1 (PERF.md
-    section 5, traced run of PR 29) the two joins take 0.45 s of a 1.85 s
+    section 5, traced run of PR 31) the two joins take 0.45 s of a 1.34 s
     request, nearly all of it pk2's gathers of the order's columns by
-    6,001,215 positions, the groupby 1.08 s (its two boundary searches
-    0.51 s, its key sort 0.27 s, the key and the revenue brought into key
-    order as five packed words 0.07 s; date and priority are read at the
-    group's first row) and the result's sort 0.27 s. The
+    6,001,215 positions, the groupby 0.57 s (its key sort 0.27 s, its
+    look-ups at 1,500,001 rows 0.21 s, the key and the revenue brought
+    into key order as five packed words 0.07 s; date and priority are read
+    at the group's first row) and the result's sort 0.28 s. The
     orderkey groupby stays on the general (sort-based) path: its
     cardinality is data-dependent, which is exactly the boundary of
     what a planner can declare; what the declared keys do give it is

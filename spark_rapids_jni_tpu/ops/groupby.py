@@ -495,22 +495,40 @@ def _sum_dtype(dt: DType) -> DType:
     return dt
 
 
-def _dense_group_bounds(group_id: jnp.ndarray | None, n: int,
-                        m: int) -> tuple:
-    """(num_groups, g_lo, g_hi) from sorted dense group ids: every
-    per-group boundary is a binary search, not a scatter — scatters
-    serialize on the TPU (4x slower than the scan/searchsorted
-    formulation at 4M rows on a v5e in 2026-07, before the runtime stack;
-    not measured since). ``group_id`` is None
-    only when n == 0."""
-    garange = jnp.arange(m, dtype=jnp.int32)
-    if group_id is None or n == 0:
-        return (jnp.int32(0), jnp.zeros((m,), jnp.int32),
-                jnp.zeros((m,), jnp.int32))
-    num_groups = (group_id[-1] + 1).astype(jnp.int32)
-    g_lo = jnp.searchsorted(group_id, garange, side="left").astype(jnp.int32)
-    g_hi = jnp.searchsorted(group_id, garange, side="right").astype(jnp.int32)
-    return num_groups, g_lo, g_hi
+def _group_bounds(same: jnp.ndarray, m: int) -> tuple:
+    """``(num_groups, g_lo, g_hi)`` of the first ``m`` groups over sorted
+    keys, from ONE compaction of the group-start mask ``~same``
+    (``same[i]``: sorted row i has the key of row i-1, or is a phantom
+    row). With ``starts`` the ascending positions where ``same`` is False
+    and ``s[g] = starts[g]`` for ``g < num_groups``, else n: ``g_lo =
+    s[:m]``, ``g_hi = s[1:m + 1]``; ``num_groups`` counts every start, so
+    it exceeds ``m`` on overflow. Absent groups are empty at n; phantom
+    rows start no group and end the last real one at n. What
+    ``_group_starts(same, m + 1, block)`` returns for few groups.
+
+    The compaction is a one-operand sort of ``where(same, n, iota)``: the
+    starts come first, ascending, then n's (all equal, so the sort need
+    not be stable: left at ``is_stable=True`` XLA adds an iota operand).
+    On a v5e (PERF.md section 6, PR 31: alone in a jit, 8,388,608 rows,
+    m = 1,500,001, 88,000 starts; device s / cold compile s): this sort
+    0.0061 / 5.9 (stable 0.0140 / 20.2); the pair of binary searches over
+    ``cumsum(~same) - 1`` it replaced 0.5180 / 3.2 (24 steps of a gather
+    of m each; 0.513 s inside the planned q3 region, the sort 0.0049); a
+    stable two-operand ``sort((same, iota))`` 0.0229 / 29.1;
+    ``jnp.nonzero`` 0.5850 / 14.6; a scatter of ``iota`` at the group id
+    of the start rows (``unique_indices``, ``mode="drop"``) 0.0416 / 1.6;
+    ``_group_starts`` at m + 1 0.8466 / 37.8; ``searchsorted(method=
+    "sort")`` 0.2597 / 42.9. With m = 2,000 of 6,001,215 rows the
+    searches (0.0030) and ``_group_starts`` (0.0018) are ahead of the
+    sort (0.0066)."""
+    n = same.shape[0]
+    num_groups = jnp.sum(~same, dtype=jnp.int32)
+    starts = jax.lax.sort(jnp.where(
+        same, jnp.uint32(n), jax.lax.iota(jnp.uint32, n)),
+        is_stable=False)[:m + 1]
+    starts = jnp.pad(starts.astype(jnp.int32), (0, m + 1 - starts.shape[0]),
+                     constant_values=n)
+    return num_groups, starts[:m], starts[1:]
 
 
 def _gather_group_keys(sorted_tbl: Table, keys: Sequence[int],
@@ -619,27 +637,20 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         # exception, last_include_nulls, is kept off the bucketed path by
         # the public wrapper (bucket_rows=False).
         same = same | ~moved_masks[-1]
-    # small-m boundary path: locate group starts with block popcounts and
-    # defer (often skip entirely) the full-length group-id scan. Gated on
-    # the boundary work (2*m*block rows) actually undercutting the scan.
+    # The first m + 1 group starts give every bound; neither way builds a
+    # per-row group id. Few groups (the small-m path): block popcounts,
+    # gated on the boundary work (2*m*block rows) undercutting a pass over
+    # the rows. Otherwise one sort of the start mask (_group_bounds: 0.005
+    # s in the planned q3 region where two binary searches of 1,500,001
+    # bounds took 0.513 s; PERF.md section 6, PR 31).
     small = n > 0 and m <= _SMALL_M and 2 * m * _MIN_BLOCK <= n
     block = _pick_block(n, m) if small else 0
-    _gid_cache: list = []
-
-    def _gid() -> jnp.ndarray:
-        """Per-row dense group id — materialized only for aggregates with
-        no boundary-difference form (float sums, min/max, string ranks)."""
-        if not _gid_cache:
-            _gid_cache.append((jnp.cumsum(~same) - 1).astype(jnp.int32))
-        return _gid_cache[0]
-
     garange = jnp.arange(m, dtype=jnp.int32)
     if small:
         starts, num_groups = _group_starts(same, m + 1, block)
         g_lo, g_hi = starts[:m], starts[1:]
     else:
-        num_groups, g_lo, g_hi = _dense_group_bounds(
-            _gid() if n else None, n, m)
+        num_groups, g_lo, g_hi = _group_bounds(same, m)
     overflowed = num_groups > m
     # first row of each group (n = absent, matching the old scatter-min)
     first_idx = jnp.where(g_hi > g_lo, g_lo, n)
@@ -679,7 +690,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
     def _seg_sums(stack: jnp.ndarray) -> jnp.ndarray:
         """(n, k) lane stack -> (m, k) per-group sums. int64 lanes ride
         prefix differencing (exact, so cancellation is a non-issue): block
-        prefixes when small, full cumsum + searchsorted differences
+        prefixes when small, a full cumsum read at the group bounds
         otherwise. Float lanes instead ride a segmented scan that resets
         at group boundaries — prefix differencing would cancel the global
         running total and absorb small groups that follow large ones
@@ -929,15 +940,18 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         kind, idx = spec
         return seg_i[:, idx] if kind == "i" else seg_f[:, idx]
 
+    _gid_cache: list = []
+
     def _row_gid() -> jnp.ndarray:
-        """Per-row dense group id for the centered variance pass. In the
-        small-m path group starts are already known, so a searchsorted
-        replaces the full-length cumsum scan."""
-        if small:
-            return (jnp.searchsorted(
+        """Per-row dense group id, built only where an aggregate reads it
+        (the centered variance / covariance pass; the bounds need none).
+        In the small-m path group starts are already known, so a
+        searchsorted replaces the full-length cumsum scan."""
+        if not _gid_cache:
+            _gid_cache.append((jnp.searchsorted(
                 g_lo, jnp.arange(n, dtype=jnp.int32), side="right"
-            ) - 1).astype(jnp.int32)
-        return _gid()
+            ) - 1 if small else jnp.cumsum(~same) - 1).astype(jnp.int32))
+        return _gid_cache[0]
 
     sum128_overflow = jnp.bool_(False)
     for op, c, acc_dt, val_lane, count_lane in plan:
@@ -1196,8 +1210,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             kix = list(range(len(keys)))
             same_k = _rows_equal_prev(sub, kix)
             if rv is not None:
-                # phantom rows merge into the last group here too, so
-                # gid2's group numbering stays aligned with gid's
+                # phantom rows merge into the last group here too
                 same_k = same_k | ~sub_rv[0]
             vcol = sub.column(len(keys))
             vvalid2 = vcol.valid_mask()
@@ -1205,15 +1218,13 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             prev_same_valid = jnp.concatenate(
                 [jnp.zeros((1,), jnp.bool_), eqv & vvalid2[:-1]])
             flag = vvalid2 & (~same_k | ~prev_same_valid)
-            # gid2 is monotone over its own sort, so per-group flag counts
-            # are cumsum boundary differences — same idiom as the lanes,
-            # no scatter
+            # this sort orders the keys as the first did and only the rows
+            # inside a group differently, so its groups lie at the same
+            # rows: per-group flag counts are cumsum differences at the
+            # bounds the function already holds, no scatter
             if n:
-                gid2 = (jnp.cumsum(~same_k) - 1).astype(jnp.int32)
-                lo2 = jnp.searchsorted(gid2, garange, side="left")
-                hi2 = jnp.searchsorted(gid2, garange, side="right")
                 cnt = _range_sums_from_cumsum(
-                    jnp.cumsum(flag.astype(jnp.int64)), lo2, hi2)
+                    jnp.cumsum(flag.astype(jnp.int64)), g_lo, g_hi)
             else:
                 cnt = jnp.zeros((m,), jnp.int64)
             out_cols.append(
@@ -1434,8 +1445,7 @@ def groupby_percentile(
         [table.column(i) for i in sort_keys], order)[0]
     sorted_keys, key_at = Table(sorted_keys), range(len(keys))
     same = _rows_equal_prev(sorted_keys, key_at)
-    group_id = (jnp.cumsum(~same) - 1).astype(jnp.int32) if n else None
-    num_groups, g_lo, g_hi = _dense_group_bounds(group_id, n, m)
+    num_groups, g_lo, g_hi = _group_bounds(same, m)
     overflowed = num_groups > m
     first_idx = jnp.where(g_hi > g_lo, g_lo, n)
     out_cols = _gather_group_keys(sorted_keys, key_at, first_idx, m, n)

@@ -33,7 +33,7 @@ import numpy as np
 
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.ops.groupby import (
-    _dense_group_bounds,
+    _group_bounds,
     _gather_group_keys,
     _rows_equal_prev,
     _col_values_equal_prev,
@@ -177,11 +177,7 @@ def groupby_collect(table: Table, keys: Sequence[int], value_col: int,
         order = sort_order(sub, kix)
     ssub = gather(sub, order)
     same = _rows_equal_prev(ssub, kix)
-    if n:
-        gid = (jnp.cumsum(~same) - 1).astype(jnp.int32)
-    else:
-        gid = None
-    num_groups, g_lo, g_hi = _dense_group_bounds(gid, n, m)
+    num_groups, g_lo, g_hi = _group_bounds(same, m)
     first_idx = jnp.where(g_hi > g_lo, g_lo, n)
     out_cols = _gather_group_keys(ssub, kix, first_idx, m, n)
 

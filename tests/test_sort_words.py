@@ -172,15 +172,38 @@ def test_planned_q3_region_sorts_one_word_at_a_time(moved_by):
     """XLA's TPU compiler takes about the square of a sort's operand words
     in compile time: the region's sorts (the groupby's, the result's and,
     where the groupby's words move by sort passes, that loop's one) are
-    each two operands of 32 bits, in a loop, and none has a 64-bit
+    each two operands of 32 bits, in a loop; the groupby's compaction of
+    its group starts is one operand of 32 bits; none has a 64-bit
     operand."""
     hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
     sorts = _sorts(hlo)
-    assert len(sorts) == (3 if moved_by == "sort_passes" else 2), sorts
+    assert len(sorts) == (4 if moved_by == "sort_passes" else 3), sorts
+    assert sorts.count("u32[4000]{0}") == 1, sorts
     for result in sorts:
         assert re.fullmatch(
-            r"\(u32\[\d+\]\{0\}, [us]32\[\d+\]\{0\}\)", result), result
+            r"\(u32\[\d+\]\{0\}, [us]32\[\d+\]\{0\}\)|u32\[\d+\]\{0\}",
+            result), result
     assert not re.search(r"[us]64\[[^\]]*\][^=\n]* sort\(", hlo)
+
+
+def _scoped(hlo: str, kind: str, node: str) -> list:
+    """The op names of the ``kind`` instructions under a plan node."""
+    return [name for name in re.findall(
+        rf'= [^\n]*? {kind}\([^\n]*?op_name="([^"]*)"', hlo)
+        if re.search(rf"region\.[^/]+/{node}/", name)]
+
+
+def test_planned_q3_groupby_finds_its_bounds_by_one_compaction(moved_by):
+    """The bounds of the 257 groups come from one sort of the group-start
+    mask and a slice: under the node's scope no ``while`` steps a binary
+    search over per-row group ids (there were two, of 24 gathers of
+    1,500,001 each in the cell), the loops left are the key sort's and
+    ``permute``'s, and there is the one sort more."""
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
+    loops = _scoped(hlo, "while", "groupby")
+    assert not [name for name in loops if "searchsorted" in name], loops
+    assert len(loops) == (2 if moved_by == "sort_passes" else 1), loops
+    assert len(_scoped(hlo, "sort", "groupby")) == len(loops) + 1
 
 
 def _gathers(hlo: str, node: str | None = None) -> list:
@@ -263,8 +286,8 @@ def test_general_q1_groupby_keeps_its_key_sort(moved_by):
 
 def test_every_plan_node_names_its_heavy_operations():
     """Each node of a region lowers under ``region.<plan>/<node scope>``:
-    the sorts, the binary searches' loops and the gathers of planned q3
-    carry the scope of the node they belong to."""
+    the sorts, the sorts' loops and the gathers of planned q3 carry the
+    scope of the node they belong to."""
     plan = tpch._q3_planned_plan(0, 9204)
     nodes = fusion._topo(plan.root)
     scopes = fusion.node_scopes(nodes)
