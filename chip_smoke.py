@@ -950,6 +950,8 @@ def mesh_phase(sizes: dict, platform: str, seed: int = 0,
             f"{in_use}")
     del sharded
 
+    # the Plan a client submits (tpch._q1_distributed_plan through
+    # fusion.execute), bound to the table sharded over the mesh
     t0 = time.perf_counter()
     out = tpch.tpch_q1_distributed(li, mesh)
     ctx.sync(out)

@@ -477,66 +477,48 @@ def q1_row_chunked_fns():
     return partial_fn, merge_fn
 
 
-def q1_distributed_step(local: Table):
-    """Per-executor q1 step; must run inside shard_map over EXEC_AXIS.
+def _q1_distributed_plan() -> fusion.Plan:
+    """q1 as a cluster runs it, as ONE plan a client submits: the
+    work-table projection, a groupby of the aggregates that merge across a
+    shuffle (sums and counts: ``_q1_partial_plan``'s), the finalize that
+    makes the averages of them (``_q1_merge_plan``'s) and the ORDER BY.
+    Bound to a table whose rows are sharded over a mesh axis
+    (``parallel/mesh.py``), ``fusion.execute`` lowers the groupby as a
+    partial a chip, ``hash_shuffle``, merge and collect; bound to a table
+    on one chip it is one more general q1. Same result layout as
+    ``_q1_plan`` (two keys, eight aggregates, the group budget's rows)."""
+    return fusion.Plan("tpch_q1_distributed", fusion.Sort(
+        fusion.Project(
+            fusion.GroupBy(
+                fusion.Project(fusion.Scan("lineitem"), _q1_work_table),
+                (0, 1), tuple(_Q1_PARTIAL_AGGS),
+                max_groups=_Q1_GROUP_BUDGET, label="groupby"),
+            _q1_finalize),
+        (0, 1), nulls_first=(False, False)))
 
-    local partial groupby -> head-truncate to the group budget -> ICI
-    all-to-all shuffle by (returnflag, linestatus) -> merge groupby.
-    Afterward each executor owns a disjoint slice of the key space. Both
-    halves are the SAME fusion plans the out-of-core path runs; inside
-    the shard_map trace ``fusion.execute`` takes its staged walk (tracer
-    inputs), so the region boundary at the shuffle is explicit.
-    """
+
+def q1_distributed_step(local: Table) -> Table:
+    """One executor's part of the distributed q1, for a caller already
+    inside ``shard_map`` over ``EXEC_AXIS`` (a mesh that spans processes):
+    ``_q1_distributed_plan`` lowered over the axis. Every executor returns
+    the whole sorted answer."""
     from spark_rapids_jni_tpu.parallel.mesh import EXEC_AXIS
-    from spark_rapids_jni_tpu.parallel.shuffle import hash_shuffle
 
-    budget = min(_Q1_GROUP_BUDGET, local.num_rows)
-    # the budget-bounded partial IS the head truncation: its output is
-    # padded to exactly `budget` rows, real groups first
-    partial = fusion.execute(_q1_partial_plan(), {"chunk": local})
-    # only the real groups cross the wire: the budget-padding rows (null
-    # keys, zero aggregates) would all hash to one partition and waste the
-    # null-key receiver's capacity on ~90% phantom payload
-    real = (jnp.arange(budget, dtype=jnp.int32)
-            < partial.meta["partial.num_groups"])
-    sh = hash_shuffle(partial.table, [0, 1], EXEC_AXIS, capacity=budget,
-                      row_valid=real)
-    # merge with max_groups=None: m = the shuffle buffer size (every sender
-    # contributed <= budget rows), which can never overflow — the receiving
-    # device may own up to sender_count * budget distinct partial groups
-    merged = fusion.execute(_q1_merge_plan(), {"partials": sh.table})
-    return merged.table, merged.meta["merge.num_groups"].reshape(1)
+    return fusion.mesh_step(
+        _q1_distributed_plan(), {"lineitem": local}, EXEC_AXIS).table
 
 
 def tpch_q1_distributed(lineitem: Table, mesh) -> Table:
-    """Multi-executor q1: shard rows over the mesh, run the shuffle-backed
-    step jitted across it, then collect + globally sort the (tiny) result —
-    the driver-side collect of the Spark job."""
-    import jax as _jax
-    from jax.sharding import PartitionSpec as P
+    """Multi-executor q1: ``_q1_distributed_plan`` bound to ``lineitem``
+    with its rows sharded over ``mesh`` (sharded here unless it already
+    is). The served path takes the same plan and bindings."""
+    from spark_rapids_jni_tpu.parallel.distributed import shard_table
+    from spark_rapids_jni_tpu.parallel.mesh import table_row_mesh
 
-    from spark_rapids_jni_tpu.parallel.distributed import (
-        _mesh_fingerprint,
-        collect,
-        shard_table,
-    )
-    from spark_rapids_jni_tpu.parallel.mesh import EXEC_AXIS
-    from spark_rapids_jni_tpu.runtime import dispatch
-
-    sharded = shard_table(lineitem, mesh)
-    per_dev, num_groups = dispatch.sharded_call(
-        "tpch_q1_distributed.step",
-        lambda: _jax.shard_map(
-            q1_distributed_step,
-            mesh=mesh,
-            in_specs=(P(EXEC_AXIS),),
-            out_specs=(P(EXEC_AXIS), P(EXEC_AXIS)),
-        ),
-        (sharded,),
-        statics=(_mesh_fingerprint(mesh),),
-    )
-    result = collect(per_dev, num_groups, mesh)
-    return sort_table(result, [0, 1], nulls_first=[False, False])
+    if table_row_mesh(lineitem) is None:
+        lineitem = shard_table(lineitem, mesh)
+    return fusion.execute(
+        _q1_distributed_plan(), {"lineitem": lineitem}).table
 
 
 def tpch_q1_outofcore(path, *, budget_bytes: int,

@@ -26,15 +26,12 @@ from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate
 from spark_rapids_jni_tpu.parallel.mesh import EXEC_AXIS
 from spark_rapids_jni_tpu.parallel.shuffle import hash_shuffle
+from spark_rapids_jni_tpu.runtime.dispatch import (
+    mesh_fingerprint as _mesh_fingerprint,
+)
 from spark_rapids_jni_tpu.utils.tracing import func_range
 
 
-def _mesh_fingerprint(mesh: Mesh) -> tuple:
-    """Hashable mesh identity for the dispatch executable cache: axis
-    layout plus the concrete device assignment — a compiled shard_map
-    program is specialized to both."""
-    return (tuple(mesh.shape.items()),
-            tuple(str(d) for d in mesh.devices.flat))
 
 
 def head_table(table: Table, k: int) -> Table:
@@ -239,6 +236,45 @@ class DistributedBoundedGroupBy(NamedTuple):
     domain_miss: jnp.ndarray  # scalar bool — any device saw an OOD key
 
 
+def merge_bounded_slots(res, aggs: Sequence[tuple[int, str]], nk: int,
+                        axis: str):
+    """``(table, present, domain_miss)`` of one chip's bounded-plan result
+    ``res`` (``plan_groupby``, lowered ``bounded``) merged over the mesh
+    axis, inside ``shard_map``: a slot's sum/count/min/max aggregates are
+    associative, so the merge is one ``psum`` / ``pmin`` / ``pmax`` over
+    the slot table and no row crosses. The same on every chip. Shared by
+    ``distributed_groupby_bounded`` and the served path's lowering of a
+    declared-domain ``GroupBy`` over sharded rows (``runtime/fusion.py``)."""
+    from spark_rapids_jni_tpu.ops.groupby import minmax_sentinel
+
+    present_g = jax.lax.psum(res.present.astype(jnp.int32), axis) > 0
+    miss_g = jax.lax.psum(res.domain_miss.astype(jnp.int32), axis) > 0
+    out_cols: list[Column] = []
+    for pos, c in enumerate(res.table.columns):
+        valid_g = jax.lax.psum(c.valid_mask().astype(jnp.int32), axis) > 0
+        if pos < nk:
+            # key data is a trace-time constant, identical on every
+            # device — only the validity needs combining
+            out_cols.append(Column(c.dtype, c.data, valid_g, chars=c.chars))
+            continue
+        if c.dtype.is_decimal128:
+            raise NotImplementedError(
+                "DECIMAL128 aggregates need carry-aware merges — use "
+                "distributed_groupby_aggregate")
+        op = aggs[pos - nk][1]
+        if op in ("sum", "count"):
+            # absent slots hold the 0 neutral already
+            data = jax.lax.psum(c.data, axis)
+        else:
+            guarded = jnp.where(
+                c.valid_mask(), c.data,
+                jnp.asarray(minmax_sentinel(c.dtype, op), c.data.dtype))
+            data = (jax.lax.pmin(guarded, axis) if op == "min"
+                    else jax.lax.pmax(guarded, axis))
+        out_cols.append(Column(c.dtype, data, valid_g))
+    return Table(out_cols), present_g, miss_g
+
+
 @func_range("distributed_groupby_bounded")
 def distributed_groupby_bounded(
     table: Table,
@@ -260,7 +296,9 @@ def distributed_groupby_bounded(
     a per-device streaming masked-reduction pass plus an m-row
     collective: the single-chip gain of the bounded plan (PERF.md:
     planned over general q1 at SF1, 6.4 times in rows/s) composes with
-    m rows on the wire instead of n. No cell measures the mesh yet.
+    m rows on the wire instead of n. The served path takes the same
+    merge for a declared-domain ``GroupBy`` over sharded rows
+    (``merge_bounded_slots``); no cell measures it yet.
 
     ``table`` must already be sharded row-wise over ``mesh``. Output is
     REPLICATED (every device holds the global m-slot answer) — m is
@@ -304,35 +342,7 @@ def distributed_groupby_bounded(
         res = plan_groupby(local, list(keys), aggs, domains,
                            budget=budget, row_valid=rv)
         assert res.lowered == "bounded"  # guaranteed by the checks above
-        present_g = jax.lax.psum(
-            res.present.astype(jnp.int32), EXEC_AXIS) > 0
-        miss_g = jax.lax.psum(
-            res.domain_miss.astype(jnp.int32), EXEC_AXIS) > 0
-        out_cols: list[Column] = []
-        for pos, c in enumerate(res.table.columns):
-            valid_g = jax.lax.psum(
-                c.valid_mask().astype(jnp.int32), EXEC_AXIS) > 0
-            if pos < nk:
-                # key data is a trace-time constant, identical on every
-                # device — only the validity needs combining
-                out_cols.append(Column(c.dtype, c.data, valid_g,
-                                       chars=c.chars))
-                continue
-            op = aggs[pos - nk][1]
-            if op in ("sum", "count"):
-                # absent slots hold the 0 neutral already
-                data = jax.lax.psum(c.data, EXEC_AXIS)
-            else:
-                from spark_rapids_jni_tpu.ops.groupby import minmax_sentinel
-
-                sentinel = minmax_sentinel(c.dtype, op)
-                guarded = jnp.where(
-                    c.valid_mask(), c.data,
-                    jnp.asarray(sentinel, c.data.dtype))
-                data = (jax.lax.pmin(guarded, EXEC_AXIS) if op == "min"
-                        else jax.lax.pmax(guarded, EXEC_AXIS))
-            out_cols.append(Column(c.dtype, data, valid_g))
-        return Table(out_cols), present_g, miss_g
+        return merge_bounded_slots(res, aggs, nk, EXEC_AXIS)
 
     if row_valid is None:
         row_valid = jax.device_put(

@@ -63,6 +63,8 @@ __all__ = [
     "compiled",
     "rowwise",
     "sharded_call",
+    "pad_sharded",
+    "mesh_fingerprint",
     "stats",
     "clear",
 ]
@@ -494,16 +496,19 @@ def call(
     return out
 
 
-def compiled(op: str, fn: Callable, *args: Any) -> Callable:
+def compiled(op: str, fn: Callable, *args: Any,
+             statics: tuple = ()) -> Callable:
     """The executable of ``jax.jit(fn)`` for exactly these arguments: no
     bucketing, no padding, no masks, for a function whose result depends
     on every element of its inputs (a content digest). Same cache as
-    :func:`call` (single flight; keyed by op and the arguments' shapes,
-    dtypes and shardings) and the same counters, so a compile inside a
+    :func:`call` (single flight; keyed by op, ``statics`` and the
+    arguments' shapes, dtypes and shardings; a function over a mesh puts
+    the mesh's devices among its statics, which a sharding's ``repr``
+    leaves out) and the same counters, so a compile inside a
     measured window shows as ``dispatch.compile`` like any other. Unlike
     :func:`call` it raises what lowering or compiling raises: the caller
     owns the fallback."""
-    key = (op, _signature(args))
+    key = (op, statics, _signature(args))
     executable, lead_ev = _cache_lookup(key)
     if executable is not None:
         REGISTRY.counter("dispatch.hit").inc()
@@ -532,6 +537,68 @@ def rowwise(
     """``call`` for the common single-row-group op."""
     return call(op, fn, (group,), aux_args, statics=statics,
                 slice_rows=slice_rows)
+
+
+def mesh_fingerprint(mesh) -> tuple:
+    """Hashable mesh identity for the executable cache: axis layout plus
+    the concrete device assignment; a compiled shard_map program is
+    specialized to both."""
+    return (tuple(mesh.shape.items()),
+            tuple(str(d) for d in mesh.devices.flat))
+
+
+def pad_sharded(op: str, row_args: tuple, mesh, axis: str) -> tuple:
+    """The bucketed pad of :func:`call` for row groups whose buffers are
+    row-sharded over ``axis`` of ``mesh``: every chip pads ITS rows to the
+    bucket of a chip's row count, with its own row-valid mask, in one
+    jitted ``shard_map`` that moves no row between chips (an eager
+    ``concatenate`` of a sharded buffer with a global tail is a reshard).
+    Returns ``(padded groups, row_valids)``, both row-sharded as the input
+    is, a chip holding ``bucket`` rows of each. One executable an exact
+    shape (it is a copy and compiles in a fraction of a second); the region
+    that takes its output is keyed on the bucket, as on one chip. Same
+    span and counters as the one-chip pad; the byte counters are summed
+    over the chips. Raises ``Unbucketable`` for what cannot be padded."""
+    from jax.sharding import PartitionSpec as P
+
+    chips = int(mesh.shape[axis])
+    ns = tuple(_group_rows(g) for g in row_args)
+    if any(n == 0 or n % chips for n in ns):
+        raise Unbucketable(f"row counts {ns} do not split over {chips}")
+    local = tuple(n // chips for n in ns)
+    buckets = tuple(bucket_for(n) for n in local)
+    acc = _PadStats()
+
+    def step(groups):   # traced once, by the call that compiles it
+        padded = tuple(_pad_tree(g, n, B, acc)
+                       for g, n, B in zip(groups, local, buckets))
+        return padded, tuple(
+            jnp.arange(B, dtype=jnp.int32) < jnp.int32(n)
+            for n, B in zip(local, buckets))
+
+    def pad(groups):
+        return jax.shard_map(step, mesh=mesh, in_specs=(P(axis),),
+                             out_specs=P(axis))(groups)
+
+    pad.__name__ = pad.__qualname__ = "pad_sharded"
+    with spans.child("dispatch.pad", op=op):
+        executable = compiled(
+            "pad_sharded", pad, row_args,
+            statics=(op, local, buckets, mesh_fingerprint(mesh)))
+        stats = _PAD_STATS.setdefault(executable, (
+            acc.padded_bytes, acc.copied_bytes, acc.total_bytes))
+        out = executable(row_args)
+    REGISTRY.counter("dispatch.padded_rows").inc(
+        chips * sum(B - n for n, B in zip(local, buckets)))
+    REGISTRY.counter("dispatch.padded_waste_bytes").inc(chips * stats[0])
+    REGISTRY.counter("dispatch.padded_copy_bytes").inc(chips * stats[1])
+    REGISTRY.counter("dispatch.row_bytes_total").inc(chips * stats[2])
+    return out
+
+
+# a pad_sharded executable -> the bytes one chip pads, copies and holds
+# (counted while it was traced; a cached executable traces no more)
+_PAD_STATS: dict = {}
 
 
 def sharded_call(
@@ -638,3 +705,4 @@ def clear() -> None:
     owned by the registry and are NOT reset here."""
     with _lock:
         _EXEC_CACHE.clear()
+        _PAD_STATS.clear()

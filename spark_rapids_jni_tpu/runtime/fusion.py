@@ -17,11 +17,13 @@ bucket.
 Region discipline
 -----------------
 ``execute`` runs ONE fusible region. Genuine host boundaries — out-of-core
-partial compaction (``trim_table`` between chunk and merge), the shuffle
-collective between distributed partial and merge, the planner
+partial compaction (``trim_table`` between chunk and merge), the planner
 ``domain_miss`` / ``pk_violation`` re-plan check — stay in the model's
 host wrapper, which composes one plan per region (see
-``models/tpch.tpch_q1_outofcore`` for the two-region shape). Inside a
+``models/tpch.tpch_q1_outofcore`` for the two-region shape). The shuffle
+between a distributed partial and its merge is NOT one: a region whose
+scans are row-sharded over a mesh axis is one ``shard_map`` across the
+chips, the ``all_to_all`` inside it (see "lowering over a mesh"). Inside a
 region every op is inlined into the single trace: the per-op
 ``dispatch.call`` sites detect the tracer inputs and take their inline
 path, so the op implementations themselves are byte-for-byte the staged
@@ -551,8 +553,10 @@ def _spaces(nodes) -> dict:
     return spaces
 
 
-def _side_keys(nodes) -> list:
-    """Deterministic (label, field) order of traced side outputs."""
+def _side_keys(nodes, placement: Optional[dict] = None) -> list:
+    """Deterministic (label, field) order of traced side outputs;
+    ``placement`` (``_mesh_placement``) adds what a groupby lowered over a
+    mesh reports of its shuffle."""
     keys: list = []
     for node in nodes:
         if isinstance(node, GroupBy):
@@ -564,6 +568,9 @@ def _side_keys(nodes) -> list:
                 keys += [f"{node.label}.num_groups",
                          f"{node.label}.overflowed",
                          f"{node.label}.sum_overflow"]
+                if placement and placement[id(node.child)] == SHARDED:
+                    keys += [f"{node.label}.shuffle_rows",
+                             f"{node.label}.shuffle_bytes"]
         elif isinstance(node, Join):
             keys.append(f"{node.label}.total")
         elif isinstance(node, DensePkJoin):
@@ -610,12 +617,16 @@ def _head(table: Table, k: int) -> Table:
 
 
 def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
-               true_rows: dict):
+               true_rows: dict, mesh_axis: Optional[str] = None,
+               placement: Optional[dict] = None):
     """Evaluate the DAG. ``tables``/``rvs`` hold the (possibly padded)
     input tables and their region row_valid masks. Returns
     (root table, [(side key, traced value), ...]). Called with tracer
     tables inside the fused region fn and with concrete tables on the
-    staged path — the SAME per-op calls either way."""
+    staged path — the SAME per-op calls either way. With ``mesh_axis``
+    the caller is one chip of a ``shard_map`` over that axis holding its
+    rows of every scan, and a groupby whose input is ``SHARDED`` in
+    ``placement`` (``_mesh_placement``) lowers across the chips."""
     from spark_rapids_jni_tpu import types as _t
     from spark_rapids_jni_tpu.ops import bloom_filter as _bloom
     from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate
@@ -640,6 +651,13 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                 out = (node.fn(tbl, *node.params), rv)
             else:
                 out = (node.fn(tbl, rv, *node.params), None)
+        elif isinstance(node, GroupBy) and placement is not None \
+                and placement[id(node.child)] == SHARDED:
+            tbl, rv = ev(node.child)
+            gtbl, gside = _mesh_groupby(node, tbl, rv, resolved[id(node)],
+                                        mesh_axis)
+            side.extend(gside)
+            out = (gtbl, None)
         elif isinstance(node, GroupBy):
             tbl, rv = ev(node.child)
             if node.domains is not None:
@@ -763,6 +781,194 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
         with jax.named_scope(scopes[id(node)]):
             value, _ = ev(node)
     return value, side
+
+
+# ---------------------------------------------------------------------------
+# lowering over a mesh — a region whose scans are row-sharded over one axis
+# ---------------------------------------------------------------------------
+#
+# The sharding of the bound buffers is the only signal (``parallel/mesh.py``
+# ``table_row_mesh``): no option, no second entry point. Each chip of the
+# axis is one Spark executor holding its partition of every scan. Filters
+# and row-wise projections run on a chip's own rows; a groupby is where the
+# chips meet: a partial aggregate a chip bounded at the plan's group
+# budget, ``hash_shuffle`` of the real partial rows over the axis (an
+# ``all_to_all`` over ICI), the merge of what a chip then owns, and the
+# collect of every chip's groups into one table that every chip holds.
+# Whatever stands above the groupby (q1's finalize and ORDER BY) then runs
+# on that small table as it does on one chip.
+
+SHARDED, WHOLE = "sharded", "whole"
+# what merges a partial aggregate of each kind across the shuffle
+_MERGE_OF = {"sum": "sum", "count": "sum", "min": "min", "max": "max"}
+
+
+def _mesh_placement(nodes, resolved: dict) -> Optional[dict]:
+    """``{id(node): SHARDED | WHOLE}``: whether a node's output is a
+    chip's share of the rows or the one table every chip holds, when every
+    bucketed scan is row-sharded over one mesh axis; None where the plan
+    has no lowering over a mesh (an exact scan, a join, sort or limit of
+    sharded rows, an aggregate with no associative merge, a groupby with
+    neither a group bound nor declared domains, a root that is still
+    sharded): ``execute`` then runs it as it always has."""
+    place: dict = {}
+    for node in nodes:
+        kids = [place[id(c)] for c in _children(node)]
+        if isinstance(node, Scan):
+            if not node.bucket:
+                return None
+            here = SHARDED
+        elif isinstance(node, Filter) or (
+                isinstance(node, Project) and node.rowwise):
+            here = kids[0]
+        elif isinstance(node, GroupBy) and kids[0] == SHARDED:
+            if not all(op in _MERGE_OF for _, op in node.aggs):
+                return None
+            if node.domains is None:
+                if not isinstance(resolved[id(node)], int):
+                    return None
+            elif _planned_lowering(node) != "bounded":
+                return None
+            here = WHOLE
+        elif SHARDED in kids:
+            return None
+        else:
+            here = WHOLE
+        place[id(node)] = here
+    return place if here == WHOLE else None
+
+
+def _gather_rows(col: Column, axis: str) -> Column:
+    """Every chip's rows of ``col``, chip after chip, on every chip."""
+    def every(buf):
+        return None if buf is None else jax.lax.all_gather(
+            buf, axis, tiled=True)
+
+    return Column(col.dtype, every(col.data), every(col.valid_mask()),
+                  chars=every(col.chars))
+
+
+def _mesh_groupby(node: GroupBy, tbl: Table, rv, bound, axis: str):
+    """One chip's part of a groupby over rows sharded across ``axis``
+    (inside ``shard_map``): ``(the whole result table, side outputs)``,
+    the same on every chip. The sub-scopes ``partial`` / ``exchange`` /
+    ``merge`` / ``collect`` are what a device trace splits its time by."""
+    from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate
+    from spark_rapids_jni_tpu.ops.planner import plan_groupby
+    from spark_rapids_jni_tpu.parallel.distributed import merge_bounded_slots
+    from spark_rapids_jni_tpu.parallel.shuffle import hash_shuffle
+    from spark_rapids_jni_tpu.parallel.wire import shuffle_wire_bytes
+
+    def anywhere(flag):
+        return jax.lax.psum(jnp.asarray(flag).astype(jnp.int32), axis) > 0
+
+    label, nk = node.label, len(node.keys)
+    if node.domains is not None:
+        # declared domains: the slot table is static and its aggregates
+        # associative a slot, so the merge is one collective over the
+        # slots and no row crosses
+        with jax.named_scope("partial"):
+            res = plan_groupby(tbl, list(node.keys), list(node.aggs),
+                               list(node.domains), budget=node.budget,
+                               row_valid=rv)
+        with jax.named_scope("merge"):
+            out, present, miss = merge_bounded_slots(
+                res, list(node.aggs), nk, axis)
+        return out, [(f"{label}.present", present),
+                     (f"{label}.domain_miss", miss),
+                     (f"{label}.overflowed", anywhere(res.overflowed))]
+    chips = jax.lax.axis_size(axis)
+    budget = min(int(bound), tbl.num_rows)
+    with jax.named_scope("partial"):
+        part = groupby_aggregate(tbl, list(node.keys), list(node.aggs),
+                                 max_groups=budget, row_valid=rv)
+    with jax.named_scope("exchange"):
+        # only the real groups cross the wire: the budget's padding rows
+        # would all hash to one chip
+        sent = jnp.minimum(part.num_groups, budget)
+        sh = hash_shuffle(
+            part.table, list(range(nk)), axis, capacity=budget,
+            row_valid=jnp.arange(budget, dtype=jnp.int32) < sent)
+    with jax.named_scope("merge"):
+        # max_groups None: a chip may own up to chips * budget partial
+        # groups, the shuffle buffer's size, which cannot overflow
+        merged = groupby_aggregate(
+            sh.table, list(range(nk)),
+            [(nk + i, _MERGE_OF[op]) for i, (_, op) in enumerate(node.aggs)],
+            row_valid=sh.row_valid)
+    with jax.named_scope("collect"):
+        # the driver-side collect: every chip's groups, chip after chip,
+        # real rows first, bounded at the plan's group budget
+        each = merged.table.num_rows
+        counts = jax.lax.all_gather(
+            merged.num_groups.reshape(1).astype(jnp.int32), axis, tiled=True)
+        ends = jnp.cumsum(counts)
+        row = jnp.arange(int(bound), dtype=jnp.int32)
+        chip = jnp.clip(jnp.searchsorted(ends, row, side="right"),
+                        0, chips - 1).astype(jnp.int32)
+        real = row < ends[-1]
+        src = jnp.where(real, chip * each + row - (ends - counts)[chip], 0)
+        cols = []
+        for col in merged.table.columns:
+            g = _gather_rows(col, axis)
+            # rows past the last group: null, their bytes zero as a
+            # groupby's own padding is
+            def taken(buf):
+                keep = real.reshape((-1,) + (1,) * (buf.ndim - 1))
+                return jnp.where(keep, buf[src], jnp.zeros((), buf.dtype))
+
+            cols.append(Column(
+                g.dtype, taken(g.data), g.validity[src] & real,
+                chars=None if g.chars is None else taken(g.chars)))
+        flags = (anywhere(part.overflowed) | anywhere(sh.overflowed)
+                 | anywhere(merged.overflowed) | (ends[-1] > int(bound)))
+        side = [(f"{label}.num_groups", ends[-1]),
+                (f"{label}.overflowed", flags),
+                (f"{label}.sum_overflow",
+                 anywhere(part.sum_overflow) | anywhere(merged.sum_overflow)),
+                (f"{label}.shuffle_rows", jax.lax.psum(sent, axis)),
+                # what the all_to_all carries between chips (a chip keeps
+                # its own share): a fact of the partial's schema and the
+                # bound, known when the region is traced
+                (f"{label}.shuffle_bytes", jnp.asarray(
+                    (chips - 1) * shuffle_wire_bytes(
+                        part.table, None, budget, chips)["wire_bytes"],
+                    jnp.int64))]
+    return Table(cols), side
+
+
+def _bindings_mesh(bindings: dict, names: list) -> Optional[tuple]:
+    """``(mesh, axis)`` when every table bound under ``names`` is
+    row-sharded over the same axis of the same mesh; else None."""
+    from spark_rapids_jni_tpu.parallel.mesh import table_row_mesh
+
+    found = None
+    for name in names:
+        here = table_row_mesh(bindings[name])
+        if here is None or (found is not None and here != found):
+            return None
+        found = here
+    return found
+
+
+def mesh_step(plan: Plan, local: dict, axis: str) -> FusedResult:
+    """A plan's lowering over a mesh as seen by ONE chip, for a caller
+    already inside ``shard_map`` over ``axis`` (a mesh that spans
+    processes, which builds its own program): ``local`` binds every scan
+    to this chip's rows, the result is the whole answer on every chip.
+    ``execute`` builds the same step around row-sharded bindings."""
+    nodes = _topo(plan.root)
+    chips = jax.lax.axis_size(axis)
+    true_rows = {name: tbl.num_rows * chips for name, tbl in local.items()}
+    resolved = _resolve_statics(nodes, true_rows)
+    placement = _mesh_placement(nodes, resolved)
+    if placement is None:
+        raise NotImplementedError(
+            f"plan {plan.name!r} has no lowering over a mesh")
+    with jax.named_scope(f"region.{plan.name}"):
+        value, side = _eval_plan(plan.root, local, {}, resolved, true_rows,
+                                 mesh_axis=axis, placement=placement)
+    return FusedResult(value, dict(side))
 
 
 def _limit_bound(nodes, resolved: dict, spaces: dict,
@@ -1108,7 +1314,11 @@ def execute(plan: Plan, bindings: dict, *,
                 n.left if isinstance(n, Join) else n.probe, true_rows)
             if rows is not None:
                 static_meta[f"{n.label}.probe_rows"] = rows
-    side_keys = _side_keys(nodes)
+    # rows sharded over a mesh axis are the signal, and the only one, that
+    # the region runs across chips (see "lowering over a mesh" above)
+    over = _bindings_mesh(bindings, bucketed)
+    placement = _mesh_placement(nodes, resolved) if over else None
+    side_keys = _side_keys(nodes, placement)
 
     def _staged_eval() -> FusedResult:
         # the staged reference path (the bit-identity oracle): the same
@@ -1162,12 +1372,46 @@ def execute(plan: Plan, bindings: dict, *,
     donate = (bool(donate_inputs) and bool(get_option("fusion.donate"))
               and bool(bucketed))
 
+    def _mesh_region():
+        """The region as one ``shard_map`` over the bindings' mesh: every
+        chip pads its own rows to a chip's bucket (``dispatch.pad_sharded``)
+        and runs the plan on them; the result is whole on every chip."""
+        from jax.sharding import PartitionSpec as P
+
+        mesh, axis = over
+        padded, row_valids = dispatch.pad_sharded(
+            f"fusion.{plan.name}", row_args, mesh, axis)
+
+        def build():
+            def step(groups, rvs):
+                with jax.named_scope(f"region.{plan.name}"):
+                    value, side = _eval_plan(
+                        plan.root, dict(zip(bucketed, groups)),
+                        dict(zip(bucketed, rvs)), resolved, true_rows,
+                        mesh_axis=axis, placement=placement)
+                return value, tuple(v for _, v in side)
+
+            # all_gather gives every chip the same rows, which the
+            # replication check cannot see: it is off
+            region = jax.shard_map(
+                step, mesh=mesh, in_specs=(P(axis), P(axis)),
+                out_specs=P(), check_vma=False)
+            region.__name__ = region.__qualname__ = _region.__name__
+            return region
+
+        return dispatch.sharded_call(
+            f"fusion.{plan.name}", build, (padded, row_valids),
+            statics=("fusion", fingerprint, axis,
+                     dispatch.mesh_fingerprint(mesh)))
+
     def _dispatch_region():
         # the seam fires BEFORE dispatch.call touches (and possibly
         # donates) the bound buffers, so both the retry and the staged
         # fallback below replay against intact inputs
         faults.fire("fusion.region", 0, plan=plan.name)
         with spans.child(f"region.{plan.name}", mode="fused"):
+            if placement is not None:
+                return _mesh_region()
             return dispatch.call(
                 f"fusion.{plan.name}", _region, row_args, aux_args,
                 statics=("fusion", fingerprint), slice_rows=False,
@@ -1212,7 +1456,9 @@ def execute(plan: Plan, bindings: dict, *,
 def meta_facts(plan: Plan, meta: dict) -> dict:
     """What the joins and groupbys of ``plan`` report in a result's
     ``meta``, summed over its nodes: rows that probed and rows that matched
-    (joins that say both), groups, and how many nodes broke what the plan
+    (joins that say both), groups, what a groupby lowered over a mesh
+    shuffled (exchanges, the partial rows it sent and the bytes its
+    ``all_to_all`` put between chips), and how many nodes broke what the plan
     declares: a dense primary key that is not one (``pk_violation``), a
     group bound that was too small (``overflowed``). A result with either
     is a wrong answer; the served path refuses it
@@ -1220,7 +1466,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     device, so call it where the meta is wanted on the host anyway."""
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
              "join.pk_violation": 0, "groupby.groups": 0,
-             "groupby.overflowed": 0}
+             "groupby.overflowed": 0, "shuffle.exchanges": 0,
+             "shuffle.rows": 0, "shuffle.bytes": 0}
     for node in _topo(plan.root):
         if isinstance(node, (Join, DensePkJoin)):
             total = meta.get(f"{node.label}.total")
@@ -1236,6 +1483,12 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["groupby.groups"] += int(groups)
             facts["groupby.overflowed"] += bool(
                 meta.get(f"{node.label}.overflowed", False))
+            sent = meta.get(f"{node.label}.shuffle_rows")
+            if sent is not None:   # lowered over a mesh: one all_to_all
+                facts["shuffle.exchanges"] += 1
+                facts["shuffle.rows"] += int(sent)
+                facts["shuffle.bytes"] += int(
+                    meta[f"{node.label}.shuffle_bytes"])
     return facts
 
 
@@ -1340,8 +1593,16 @@ def estimate_hbm_bytes(plan: Plan, bindings: dict) -> int:
     not a hard bound: ``runtime/server.py`` applies the configured
     ``server.estimate_headroom`` multiplier on top for intermediates this
     static walk cannot see.
+
+    The bytes are ONE chip's, as the limiter's budget is: a binding whose
+    buffers are sharded over a mesh costs a chip its shard
+    (``memory.table_chip_nbytes``), so a table that would not fit one chip
+    is admitted when its shard does, and rejected when even that does not.
     """
-    from spark_rapids_jni_tpu.runtime.memory import _table_nbytes
+    from spark_rapids_jni_tpu.runtime.memory import (
+        _table_nbytes,
+        table_chip_nbytes,
+    )
 
     nodes = _topo(plan.root)
     bucketed, exact = _scan_names(nodes)
@@ -1352,9 +1613,11 @@ def estimate_hbm_bytes(plan: Plan, bindings: dict) -> int:
     true_rows = {name: bindings[name].num_rows for name in bucketed + exact}
     resolved = _resolve_statics(nodes, true_rows)
     input_bytes = sum(
-        _table_nbytes(bindings[name]) for name in bucketed + exact)
+        table_chip_nbytes(bindings[name]) for name in bucketed + exact)
     total_rows = max(1, sum(true_rows.values()))
-    row_width = max(1, input_bytes // total_rows)
+    row_width = max(1, sum(
+        _table_nbytes(bindings[name])
+        for name in bucketed + exact) // total_rows)
     out_rows = 0
     extra_bytes = 0
     for node in nodes:

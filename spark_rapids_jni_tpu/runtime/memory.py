@@ -505,19 +505,33 @@ def default_staging_pool() -> HostStagingPool:
 # ---- spill store (the RMM arena's overflow valve) --------------------------
 
 
-def _col_nbytes(c) -> int:
-    total = int(np.prod(c.data.shape)) * c.data.dtype.itemsize
-    if c.validity is not None:
-        total += int(c.validity.shape[0])
-    if c.chars is not None:
-        total += int(np.prod(c.chars.shape))
-    for child in (c.children or ()):
-        total += _col_nbytes(child)
-    return total
+def _col_nbytes(c, chip: bool = False) -> int:
+    """A column's bytes, children included; with ``chip`` what ONE chip
+    holds of them: a buffer sharded over a mesh counts a shard
+    (``sharding.shard_shape``), any other buffer counts whole."""
+    def nbytes(buf) -> int:
+        if buf is None:
+            return 0
+        shape = buf.shape
+        sharding = getattr(buf, "sharding", None)
+        if chip and sharding is not None and len(sharding.device_set) > 1:
+            shape = sharding.shard_shape(shape)
+        return int(np.prod(shape)) * buf.dtype.itemsize
+
+    return (sum(nbytes(b) for b in (c.data, c.validity, c.chars))
+            + sum(_col_nbytes(child, chip) for child in (c.children or ())))
 
 
 def _table_nbytes(table) -> int:
     return sum(_col_nbytes(c) for c in table.columns)
+
+
+def table_chip_nbytes(table) -> int:
+    """The bytes of ``table`` that ONE chip holds: all of them for a table
+    on one device or on the host, a shard's for buffers row-sharded over a
+    mesh. What admission reserves: the limiter's budget is one chip's
+    memory, and a table sharded over four chips costs each a quarter."""
+    return sum(_col_nbytes(c, chip=True) for c in table.columns)
 
 
 def _pack_array(arr, cctx, codec_seam=None):

@@ -44,12 +44,16 @@ own with the one a replica took on its chip):
   on the CPU and numpy give the same bits; every word's term depends on
   its index, so a rolled or permuted buffer (the same multiset of words)
   is a different buffer. A single-device ``jax.Array`` is digested by the
-  jit ``cache_digest`` on its device and 16 bytes come back; a host
-  array, a ``HostTableChunk`` snapshot or an array sharded over devices
-  (brought to the host as before) by the same function in numpy. All of a
-  table's device digests are enqueued before the first is waited for.
-  One executable a dtype, shape and device (``dispatch.compiled``: the
-  dispatch layer's cache, counted as a ``dispatch.compile``).
+  jit ``cache_digest`` on its device and 16 bytes come back; one whose
+  rows are sharded over a mesh axis is digested shard by shard, each on
+  the chip that holds it with its words indexed from the shard's place in
+  the buffer, and the lanes of the shards summed mod 2**32 are the lanes
+  of the whole (16 bytes a chip come back); a host array, a
+  ``HostTableChunk`` snapshot or an array placed any other way (brought
+  to the host) by the same function in numpy. All of a table's device
+  digests are enqueued before the first is waited for. One executable a
+  dtype, shape and placement (``dispatch.compiled``: the dispatch layer's
+  cache, counted as a ``dispatch.compile``).
 * a smaller buffer gives dtype, shape and its bytes, as it always has (a
   copy and a sha256 of under 1 MiB cost less than a dispatch, let alone
   a compile for a shape seen once); so does float64, which the chip holds
@@ -201,13 +205,16 @@ def _lane_sums(xp, words, index) -> list:
     return sums
 
 
-def cache_digest(x):
+def cache_digest(x, first=0):
     """The digest of a device buffer, as uint32[lanes], computed where the
     buffer lives (traced: this is the jit's body, and its name the
     module's, ``jit_cache_digest``). Words are the buffer's bytes in
     memory order: an 8-byte element is its low then its high half (the
     chip keeps int64 as such a pair, so neither is a copy), a 4-byte
-    element is itself, a 1- or 2-byte element is widened to one word."""
+    element is itself, a 1- or 2-byte element is widened to one word.
+    ``first`` is the index of the buffer's first word where ``x`` is a
+    part of it: the lanes of the parts, summed mod 2**32, are the lanes of
+    the whole."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -222,10 +229,35 @@ def cache_digest(x):
     else:
         parts = (lax.bitcast_convert_type(
             x, jnp.uint8 if size == 1 else jnp.uint16).astype(jnp.uint32),)
-    index = lax.iota(jnp.uint32, x.shape[0]) * jnp.uint32(len(parts))
+    index = (lax.iota(jnp.uint32, x.shape[0]) * jnp.uint32(len(parts))
+             + jnp.asarray(first, jnp.uint32))
     lanes = [_lane_sums(jnp, words, index + jnp.uint32(k))
              for k, words in enumerate(parts)]
     return jnp.stack([sum(lane[1:], lane[0]) for lane in zip(*lanes)])
+
+
+def _sharded_digest(mesh, axis: str):
+    """``cache_digest`` of a buffer whose rows are sharded over ``axis`` of
+    ``mesh``: every chip digests the shard it holds, its words indexed
+    from where the shard starts in the buffer, and uint32[chips, lanes]
+    comes back, 16 bytes a chip; no element leaves its chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    def cache_digest_sharded(x):
+        def shard(local):
+            words = local.size * max(1, local.dtype.itemsize // 4)
+            first = jax.lax.axis_index(axis).astype(jnp.uint32) * jnp.uint32(
+                words)
+            return cache_digest(local, first)[None, :]
+
+        return jax.shard_map(shard, mesh=mesh, in_specs=P(axis),
+                             out_specs=P(axis))(x)
+
+    cache_digest_sharded.__name__ = cache_digest_sharded.__qualname__ = \
+        "cache_digest"
+    return cache_digest_sharded
 
 
 def _digest_numpy(arr: np.ndarray) -> np.ndarray:
@@ -251,23 +283,42 @@ def _takes_digest(dtype, nbytes: int) -> bool:
                  or (dtype.kind == "f" and dtype.itemsize <= 4)))
 
 
-def _on_one_device(buf) -> bool:
+def _device_digest(buf):
+    """How to enqueue the digest of ``buf`` where it lives, as a function
+    of no arguments: uint32[lanes] of a ``jax.Array`` on a single device,
+    uint32[chips, lanes] of one whose rows are sharded over a mesh axis
+    (``parallel/mesh.py`` ``row_mesh``). None for a host array and for
+    any other placement: those are digested over a host copy."""
     import jax
 
-    return isinstance(buf, jax.Array) and len(buf.sharding.device_set) == 1
+    from spark_rapids_jni_tpu.parallel.mesh import row_mesh
+
+    if not isinstance(buf, jax.Array):
+        return None
+    if len(buf.sharding.device_set) == 1:
+        return lambda: dispatch.compiled(
+            "cache_digest", cache_digest, buf)(buf)
+    over = row_mesh(buf)
+    if over is None:
+        return None
+    return lambda: dispatch.compiled(
+        "cache_digest", _sharded_digest(*over), buf,
+        statics=(dispatch.mesh_fingerprint(over[0]),))(buf)
 
 
 def _stage_buffer(buf):
     """First half of a buffer's fingerprint; returns the second half,
     ``finish(h)``, which feeds the table's sha256. Staging a large
-    single-device ``jax.Array`` enqueues its digest and starts the
-    digest's copy to the host, so a table's columns are all in flight
-    before ``finish`` waits for the first. Everything else is done in
-    ``finish``: a host array, or one sharded over devices (brought to the
-    host first), is digested by numpy to the same value, and a small or
-    float64 buffer feeds its bytes to the sha256 as it always has.
+    ``jax.Array`` on one device, or row-sharded over a mesh axis, enqueues
+    its digest where it lives and starts the digest's copy to the host,
+    so a table's columns are all in flight before ``finish`` waits for
+    the first. Everything else is done in ``finish``: a host array, or a
+    device array placed any other way (brought to the host first), is
+    digested by numpy to the same value, and a small or float64 buffer
+    feeds its bytes to the sha256 as it always has.
 
-    Spans, one pair a buffer: ``cache.fingerprint.hash`` is the enqueue of
+    Spans, one pair a buffer (a sharded buffer's shards are one enqueue
+    and one copy): ``cache.fingerprint.hash`` is the enqueue of
     the digest, or the hashing on the host (``nbytes``: bytes
     fingerprinted); ``cache.fingerprint.copy`` is what crosses to the
     host, the digest or the whole buffer, and the wait for it
@@ -283,11 +334,11 @@ def _stage_buffer(buf):
     nbytes = int(buf.nbytes)
     digested = _takes_digest(buf.dtype, nbytes)
     pending = None
-    if digested and _on_one_device(buf):
+    enqueue = _device_digest(buf) if digested else None
+    if enqueue is not None:
         with spans.child("cache.fingerprint.hash", nbytes=nbytes):
             try:
-                pending = dispatch.compiled(
-                    "cache_digest", cache_digest, buf)(buf)
+                pending = enqueue()
                 pending.copy_to_host_async()
             except Exception as exc:
                 # no room for the fusion's temporaries, a compile that
@@ -311,7 +362,9 @@ def _stage_buffer(buf):
         h.update(str(np.dtype(buf.dtype)).encode())
         h.update(repr(tuple(buf.shape)).encode())
         if pending is not None:
-            h.update(_digest_tag(nbytes, arr))
+            # a sharded buffer's lanes: the sum of its shards', mod 2**32
+            h.update(_digest_tag(nbytes, arr.reshape(-1, len(_LANES)).sum(
+                axis=0, dtype=np.uint32)))
             return
         with spans.child("cache.fingerprint.hash", nbytes=nbytes):
             h.update(_digest_tag(nbytes, _digest_numpy(arr)) if digested

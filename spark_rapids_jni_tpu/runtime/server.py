@@ -63,6 +63,12 @@ Contracts, in order of importance:
   ``utils/config.cache_dir()``), so a fresh process admits from measured
   truth. Persistence is debounced off the hot path (at most one write
   per ``server.estimate_save_interval_s``; ``close()`` flushes).
+* **The budget is one chip's** — every estimate, learned or static, is the
+  bytes the request needs on ONE chip (``memory.table_chip_nbytes``): a
+  binding row-sharded over a mesh costs each chip its shard, so a server
+  in front of four chips is given one chip's ``bytes_limit``, admits a
+  sharded table whose shard fits it, and rejects as oversize one whose
+  shard does not. A request on one device reckons as it always has.
 
 Config knobs (utils/config.py, env ``SPARK_RAPIDS_TPU_SERVER_*``):
 ``server.max_inflight``, ``server.hbm_budget_bytes``,
@@ -100,7 +106,7 @@ from spark_rapids_jni_tpu.runtime.memory import (
     HostTableChunk,
     MemoryLimiter,
     SpillStore,
-    _table_nbytes,
+    table_chip_nbytes,
 )
 from spark_rapids_jni_tpu.telemetry.events import (
     events as _ring_events,
@@ -884,10 +890,10 @@ class QueryServer:
         two synchronous fsyncs per served query is tail latency the hot
         path does not owe a warm-start optimization."""
         try:
-            actual = _table_nbytes(result.table)
+            actual = table_chip_nbytes(result.table)
             for v in bindings.values():
                 actual += v.nbytes if isinstance(v, HostTableChunk) \
-                    else _table_nbytes(v)
+                    else table_chip_nbytes(v)
         except (TypeError, AttributeError):
             return  # non-table result (nothing measurable to learn from)
         sig = self._plan_signature(ticket.plan, ticket.bindings)
@@ -906,9 +912,11 @@ class QueryServer:
         """Count, once a request, what the plan's joins and groupbys report
         in the result's meta (its host copy; ``fusion.meta_facts``:
         counters ``join.probe_rows``, ``join.matched_rows``,
-        ``groupby.groups``, ``join.pk_violation``, ``groupby.overflowed``),
-        and refuse a result that broke what its plan declares: rows were
-        dropped or merged, so it must not resolve as a success."""
+        ``groupby.groups``, ``join.pk_violation``, ``groupby.overflowed``,
+        and of a groupby lowered over a mesh ``shuffle.exchanges``,
+        ``shuffle.rows`` and ``shuffle.bytes``: the served path's shuffle
+        telemetry), and refuse a result that broke what its plan declares:
+        rows were dropped or merged, so it must not resolve as a success."""
         if not meta:
             return
         facts = fusion.meta_facts(plan, meta)
@@ -936,7 +944,7 @@ class QueryServer:
         if any(isinstance(v, HostTableChunk) for v in bindings.values()):
             base = sum(
                 v.nbytes if isinstance(v, HostTableChunk)
-                else _table_nbytes(v)
+                else table_chip_nbytes(v)
                 for v in bindings.values())
         else:
             base = fusion.estimate_hbm_bytes(plan, bindings)

@@ -63,46 +63,32 @@ def main() -> None:
     mesh = jax.sharding.Mesh(np.array(jax.devices()), (EXEC_AXIS,))
     sharded = shard_table_multiprocess(local, mesh)
 
+    # every executor returns the whole sorted answer (the plan's collect
+    # runs inside the program: an all_gather across the processes)
     step = jax.jit(jax.shard_map(
         q1_distributed_step,
         mesh=mesh,
         in_specs=(P(EXEC_AXIS),),
-        out_specs=(P(EXEC_AXIS), P(EXEC_AXIS)),
+        out_specs=P(),
+        check_vma=False,
     ))
-    per_dev, num_groups = step(sharded)
-
-    # gather the global result into every process (tiled = concatenate
-    # the shards in mesh order)
-    cols = [
-        np.asarray(multihost_utils.process_allgather(c.data, tiled=True))
-        for c in per_dev.columns
-    ]
-    valids = [
-        np.asarray(multihost_utils.process_allgather(
-            c.valid_mask(), tiled=True))
-        for c in per_dev.columns
-    ]
-    counts = np.asarray(
-        multihost_utils.process_allgather(num_groups, tiled=True)
-    ).reshape(-1)
-    rows_per_dev = cols[0].shape[0] // n_global_devices
+    result = step(sharded)
+    cols = [np.asarray(c.data) for c in result.columns]
+    valids = [np.asarray(c.valid_mask()) for c in result.columns]
 
     got = {}
-    for d in range(n_global_devices):
-        base = d * rows_per_dev
-        for i in range(int(counts[d])):
-            r = base + i
-            if not (valids[0][r] and valids[1][r]):
-                continue  # the all-null-key phantom group
-            key = (int(cols[0][r]), int(cols[1][r]))
-            assert key not in got, f"key {key} on two devices"
-            got[key] = {
-                "sum_qty": int(cols[2][r]),
-                "sum_base_price": int(cols[3][r]),
-                "sum_disc_price": int(cols[4][r]),
-                "sum_charge": int(cols[5][r]),
-                "count": int(cols[9][r]),
-            }
+    for r in range(result.num_rows):
+        if not (valids[0][r] and valids[1][r]):
+            continue  # the all-null-key group and the budget's padding
+        key = (int(cols[0][r]), int(cols[1][r]))
+        assert key not in got, f"key {key} twice"
+        got[key] = {
+            "sum_qty": int(cols[2][r]),
+            "sum_base_price": int(cols[3][r]),
+            "sum_disc_price": int(cols[4][r]),
+            "sum_charge": int(cols[5][r]),
+            "count": int(cols[9][r]),
+        }
 
     oracle = tpch_q1_numpy(full)
     assert set(got) == set(oracle), (

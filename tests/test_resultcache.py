@@ -382,8 +382,9 @@ def test_same_bytes_under_another_type_or_length_differ():
 
 
 def test_fingerprint_is_the_contents_not_the_placements():
-    """A single-device jax.Array, one sharded over the eight devices, a
-    numpy array and a HostTableChunk snapshot of one content."""
+    """A single-device jax.Array, one sharded over the eight devices (each
+    digested where it lives), a numpy array and a HostTableChunk snapshot
+    of one content."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -405,7 +406,7 @@ def test_fingerprint_is_the_contents_not_the_placements():
            for v in (on_device, sharded, on_host, chunk)}
     assert len(fps) == 1
     c = REGISTRY.counters()
-    assert c["cache.fingerprint_device_bytes"] == big.nbytes   # one of four
+    assert c["cache.fingerprint_device_bytes"] == 2 * big.nbytes  # of four
     assert c["cache.fingerprint_bytes"] == 4 * (big.nbytes + small.nbytes)
 
 
