@@ -9,7 +9,10 @@ the aggregates read at every row into that order (``ops/sort.py permute``:
 packed 32-bit words moved once; what is read at one row a group stays where
 it is), mark segment boundaries, and reduce between them with prefix sums
 and segmented scans, no scatter — all static-shape, all fused by XLA.
-Output is padded to the input row count with ``num_groups`` reported
+Under a small group bound whose aggregates are sums, counts and extrema
+(``_aggregates_in_place``) only the keys are sorted: the rows stay where
+they lie and are summed by slot (``_KeySlots``), the integer lanes on the
+MXU. Output is padded to the input row count with ``num_groups`` reported
 alongside (static shapes are the price of jit; callers slice on host).
 
 Null semantics are Spark's: null keys form their own group; aggregates skip
@@ -28,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_jni_tpu.columnar import Column, Table
-from spark_rapids_jni_tpu.ops.sort import permute, sort_order
+from spark_rapids_jni_tpu.ops.sort import (permute, sort_key_words,
+                                            sort_order)
 from spark_rapids_jni_tpu.types import DType, TypeId, decimal128
 from spark_rapids_jni_tpu.utils.tracing import func_range
 
@@ -51,6 +55,10 @@ class GroupByResult(NamedTuple):
     # Spark ANSI overflow posture, surfaced like the shuffle codec's
     # narrowing_overflow rather than corrupting data).
     sum_overflow: jnp.ndarray | bool = False
+    # True when the aggregates were taken over the rows where they lie (a
+    # small group bound: ``_KeySlots``), False when the value words were
+    # brought into key order first. A fact of the lowering, not of the data.
+    in_place: jnp.ndarray | bool = False
 
     def compact(self) -> Table:
         """Host-side trim to the real group count."""
@@ -591,6 +599,156 @@ def _row_reads(keys, aggs) -> tuple:
     return list(dict.fromkeys(data)), list(dict.fromkeys(masks))
 
 
+# aggregates a sum, a count, a minimum or a maximum by slot makes: every
+# one but those that need the rows in key order or one row a group
+_SLOT_AGGS = ("sum", "count", "mean", "var", "std", "var_pop", "std_pop",
+              "min", "max")
+
+
+def _aggregates_in_place(table: Table, keys, aggs, m: int) -> bool:
+    """Whether a groupby bounded at ``m`` groups (small: the caller's gate)
+    takes its aggregates over the rows where they lie (``_KeySlots``):
+    the keys are integers of fixed width (their sort words then say
+    "same key" exactly as ``_rows_equal_prev`` does; a float key's -0.0
+    and 0.0 are one group and two words), and every aggregate is a sum, a
+    count, a minimum or a maximum by slot. What must see the rows in key
+    order (``first`` / ``last``, ``nunique``, a string's or decimal128's
+    ``min`` / ``max`` by rank) keeps the word-moving path. Sums of
+    integers go through the MXU at any such ``m``; float sums and
+    extrema are one masked reduction a slot and lane, whose cost goes
+    with ``m``: those are held to ``_SLOT_REDUCE_M``."""
+    for k in keys:
+        dt = table.column(k).dtype
+        if (dt.is_string or dt.is_decimal128
+                or dt.storage_dtype.kind not in "iu"):
+            return False
+    reduces = False
+    for col_idx, op in aggs:
+        c = table.column(col_idx)
+        if isinstance(op, tuple):
+            # the centered moments are float lanes; the decimal128 form is
+            # exact integer lanes throughout
+            reduces |= not (c.dtype.is_decimal128
+                            or table.column(op[1]).dtype.is_decimal128)
+            continue
+        if op not in _SLOT_AGGS:
+            return False
+        if op in ("min", "max"):
+            if c.dtype.is_string or c.dtype.is_decimal128:
+                return False
+            reduces = True
+        elif op in ("var", "std", "var_pop", "std_pop"):
+            reduces |= not c.dtype.is_decimal128
+        elif op in ("sum", "mean"):
+            reduces |= (not c.dtype.is_decimal128
+                        and c.dtype.storage_dtype.kind == "f")
+    return m <= _SLOT_REDUCE_M or not reduces
+
+
+# The MXU accumulate's chunk: partial sums of 8-bit limbs over this many
+# rows stay under 2**24, exact in float32 (255 * 65,536 < 16,777,216). One
+# step of the accumulate's loop is one chunk: what it holds of the one-hot
+# and the limbs is a chunk's, not n rows', and one plain matrix product a
+# step was ahead of eight batched (PERF.md section 6, PR 33).
+_MXU_CHUNK_ROWS = 1 << 16
+# Largest bound at which float sums and extrema are reduced slot by slot
+# (``_KeySlots.float_sums`` / ``.extremum``).
+_SLOT_REDUCE_M = 64
+
+
+class _KeySlots:
+    """The rows of a table matched to the first m groups of its keys, where
+    the rows lie: row r belongs to slot g when its key words
+    (``ops/sort.py sort_key_words``) equal group g's. Phantom rows (their
+    row-valid bit is one of the words) and the rows of groups past the
+    bound equal no slot's words and join none. Every method is a pass over
+    the rows in their own order; none needs a group id a row."""
+
+    def __init__(self, words, group_words, real):
+        self._words = words            # k x uint32[n], minor -> major
+        self._group_words = group_words  # k x uint32[m]
+        self._real = real              # bool[m]: the slot holds a group
+        self._n = words[0].shape[0]
+        self._m = real.shape[0]
+
+    def _match(self, words=None) -> jnp.ndarray:
+        """bool[..., m, rows] for words of [..., rows]: the row's words
+        are the slot's."""
+        hit = self._real[:, None]
+        for w, g in zip(words or self._words, self._group_words):
+            hit = hit & (w[..., None, :] == g[:, None])
+        return hit
+
+    def int_sums(self, lanes, kinds) -> jnp.ndarray:
+        """int64[m, k]: the sum by slot of each ``lanes[j]`` (int64[n]),
+        exact modulo 2**64 as an int64 cumsum is. A one-hot contraction on
+        the MXU, a chunk of ``_MXU_CHUNK_ROWS`` rows a loop step: the lanes
+        as unsigned 8-bit limbs in bf16 (exact) against ``one_hot(slot)``
+        in bf16, accumulated in float32 (every partial sum an integer
+        under 2**24), the chunks added in int64 and the limbs recombined
+        in wrapping int64. ``kinds[j]`` is the dtype the lane was widened
+        from: a bool has one limb, an unsigned of b bytes b, a signed one
+        all eight (its sign extension)."""
+        n, m = self._n, self._m
+        limbs_of = [1 if k == jnp.bool_ else
+                    k.itemsize if k.kind == "u" else 8 for k in kinds]
+        rows = min(_MXU_CHUNK_ROWS, n)
+        chunks = -(-n // rows)
+        pad = chunks * rows - n             # 0 for a bucket's power of two
+
+        def cut(a):     # (chunks, rows); the tail's zero limbs add 0
+            return jnp.pad(a, (0, pad)).reshape(chunks, rows)
+
+        words = [cut(w) for w in self._words]
+        cut_lanes = [cut(lane) for lane in lanes]
+
+        def one_chunk(i, acc):
+            hot = self._match([w[i] for w in words]).astype(jnp.bfloat16)
+            limbs = jnp.stack([
+                ((lane[i] >> (8 * b)) & 0xFF).astype(jnp.int32)
+                .astype(jnp.bfloat16)
+                for lane, nb in zip(cut_lanes, limbs_of) for b in range(nb)])
+            part = jnp.einsum("gr,lr->gl", hot, limbs,
+                              preferred_element_type=jnp.float32)
+            return acc + part.astype(jnp.int32).astype(jnp.int64)
+
+        # the carry starts from a word, so that under shard_map it varies
+        # over the same mesh axes going in as out
+        start = jnp.zeros((m, sum(limbs_of)), jnp.int64) + (
+            self._words[0][:1] & jnp.uint32(0)).astype(jnp.int64)
+        by_limb = jax.lax.fori_loop(0, chunks, one_chunk, start)
+        out, at = [], 0
+        for nb in limbs_of:
+            total = by_limb[:, at]
+            for b in range(1, nb):
+                total = total + (by_limb[:, at + b] << (8 * b))
+            out.append(total)
+            at += nb
+        return jnp.stack(out, axis=1)
+
+    def float_sums(self, stack: jnp.ndarray) -> jnp.ndarray:
+        """float64[m, k]: the sum by slot of each column of ``stack``
+        (float64[n, k]); one masked reduction a slot and lane, each adding
+        its own group's values only, in an order of XLA's choosing."""
+        hit = self._match()
+        return jnp.stack([
+            jnp.sum(jnp.where(hit, stack[None, :, j], 0.0), axis=1)
+            for j in range(stack.shape[1])], axis=1)
+
+    def extremum(self, vv: jnp.ndarray, op: str, sentinel) -> jnp.ndarray:
+        """[m]: the minimum / maximum by slot of ``vv`` ([n], nulls
+        already at ``sentinel``); an empty slot reads ``sentinel``."""
+        pick = jnp.min if op == "min" else jnp.max
+        return pick(jnp.where(self._match(), vv[None, :],
+                              jnp.asarray(sentinel, vv.dtype)), axis=1)
+
+    def at_rows(self, per_slot: jnp.ndarray) -> jnp.ndarray:
+        """[n]: each row's own slot's value of ``per_slot`` ([m]); 0 for a
+        row in no slot. A select a slot, no gather."""
+        return jnp.sum(jnp.where(self._match(), per_slot[:, None],
+                                 jnp.zeros((), per_slot.dtype)), axis=0)
+
+
 def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                             max_groups) -> GroupByResult:
     ((table, row_valid),) = row_args
@@ -599,51 +757,76 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         rv = rvs[0]
     n = table.num_rows
     m = n if max_groups is None else int(max_groups)
-    order = sort_order(table, keys, row_valid=rv)
-    # Only what is read at every row comes into key order, as packed words
-    # moved once (ops/sort.py ``permute``): the keys, the operands of the
-    # aggregates that run over the rows, and the bare validity of a column
-    # that is only counted or scanned for its first / last non-null row.
-    # What is read at one row a group (the cells first / last pick) is
-    # fetched from the unsorted table through ``order`` at m rows; a
-    # column no key and no aggregate names does not move at all.
+    # Few groups (the small-m path): the group starts come from block
+    # popcounts, gated on the boundary work (2*m*block rows) undercutting
+    # a pass over the rows; and where every aggregate is a sum, a count or
+    # an extremum by slot, nothing but the keys comes into key order.
+    small = n > 0 and m <= _SMALL_M and 2 * m * _MIN_BLOCK <= n
+    in_place = small and _aggregates_in_place(table, keys, aggs, m)
     data_at, mask_at = _row_reads(keys, aggs)
     mask_at = [i for i in mask_at
                if i not in data_at and table.column(i).validity is not None]
-    moved, moved_masks = permute(
-        [table.column(i) for i in data_at], order,
-        [table.column(i).validity for i in mask_at]
-        + ([] if rv is None else [rv]))
-    sorted_col = dict(zip(data_at, moved))
-    sorted_mask = dict(zip(mask_at, moved_masks))
+    if in_place:
+        # The sum of a group does not depend on the order of its rows. The
+        # key sort hands back the keys' words in key order, which give the
+        # group starts; the rows stay where they lie and are matched to
+        # the m groups by their words (``_KeySlots``). No value word
+        # moves: at 8,388,608 rows and eleven value words that was twelve
+        # sort passes, 0.15 of the 0.19 s this function took; it takes
+        # 0.039 s (PERF.md section 6, PR 33).
+        order, words, sorted_words = sort_key_words(table, keys, rv)
+        read_col = {i: table.column(i) for i in data_at}
+        read_mask = {i: table.column(i).validity for i in mask_at}
+        eq_prev = sorted_words[0][1:] == sorted_words[0][:-1]
+        for w in sorted_words[1:]:
+            eq_prev = eq_prev & (w[1:] == w[:-1])
+        same = jnp.concatenate([jnp.zeros((1,), jnp.bool_), eq_prev])
+        if rv is not None:
+            # phantom rows sort last and start no group
+            same = same | (jax.lax.iota(jnp.int32, n)
+                           >= jnp.sum(rv, dtype=jnp.int32))
+    else:
+        order = sort_order(table, keys, row_valid=rv)
+        # Only what is read at every row comes into key order, as packed
+        # words moved once (ops/sort.py ``permute``): the keys, the
+        # operands of the aggregates that run over the rows, and the bare
+        # validity of a column that is only counted or scanned for its
+        # first / last non-null row. What is read at one row a group (the
+        # cells first / last pick) is fetched from the unsorted table
+        # through ``order`` at m rows; a column no key and no aggregate
+        # names does not move at all.
+        moved, moved_masks = permute(
+            [table.column(i) for i in data_at], order,
+            [table.column(i).validity for i in mask_at]
+            + ([] if rv is None else [rv]))
+        read_col = dict(zip(data_at, moved))
+        read_mask = dict(zip(mask_at, moved_masks))
+        sorted_keys = Table([read_col[k] for k in keys])
+        same = _rows_equal_prev(sorted_keys, range(len(keys)))
+        if rv is not None:
+            # phantom rows (bucketed padding tails / masked shuffle slots)
+            # sort LAST and never start a group: they merge into the final
+            # real group, where their all-null cells are neutral for every
+            # aggregate (sums add 0, counts skip, min/max see sentinels,
+            # first/last skip-null scans pass over them). The one
+            # positional exception, last_include_nulls, is kept off the
+            # bucketed path by the public wrapper (bucket_rows=False).
+            same = same | ~moved_masks[-1]
 
-    def sorted_valid(col_idx: int) -> jnp.ndarray:
-        """The validity of column ``col_idx`` in key order."""
-        if col_idx in sorted_col:
-            return sorted_col[col_idx].valid_mask()
-        if col_idx in sorted_mask:
-            return sorted_mask[col_idx]
+    def read_valid(col_idx: int) -> jnp.ndarray:
+        """The validity of column ``col_idx`` as the aggregates read it:
+        in key order, or where ``in_place`` as the rows lie."""
+        if col_idx in read_col:
+            return read_col[col_idx].valid_mask()
+        if col_idx in read_mask:
+            return read_mask[col_idx]
         return jnp.ones((n,), jnp.bool_)     # a column without nulls
 
-    sorted_keys = Table([sorted_col[k] for k in keys])
-    key_at = range(len(keys))
-    same = _rows_equal_prev(sorted_keys, key_at)
-    if rv is not None:
-        # phantom rows (bucketed padding tails / masked shuffle slots)
-        # sort LAST and never start a group: they merge into the final
-        # real group, where their all-null cells are neutral for every
-        # aggregate (sums add 0, counts skip, min/max see sentinels,
-        # first/last skip-null scans pass over them). The one positional
-        # exception, last_include_nulls, is kept off the bucketed path by
-        # the public wrapper (bucket_rows=False).
-        same = same | ~moved_masks[-1]
     # The first m + 1 group starts give every bound; neither way builds a
-    # per-row group id. Few groups (the small-m path): block popcounts,
-    # gated on the boundary work (2*m*block rows) undercutting a pass over
-    # the rows. Otherwise one sort of the start mask (_group_bounds: 0.005
-    # s in the planned q3 region where two binary searches of 1,500,001
-    # bounds took 0.513 s; PERF.md section 6, PR 31).
-    small = n > 0 and m <= _SMALL_M and 2 * m * _MIN_BLOCK <= n
+    # per-row group id. Few groups: block popcounts (_group_starts).
+    # Otherwise one sort of the start mask (_group_bounds: 0.005 s in the
+    # planned q3 region where two binary searches of 1,500,001 bounds took
+    # 0.513 s; PERF.md section 6, PR 31).
     block = _pick_block(n, m) if small else 0
     garange = jnp.arange(m, dtype=jnp.int32)
     if small:
@@ -654,7 +837,18 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
     overflowed = num_groups > m
     # first row of each group (n = absent, matching the old scatter-min)
     first_idx = jnp.where(g_hi > g_lo, g_lo, n)
-    out_cols = _gather_group_keys(sorted_keys, key_at, first_idx, m, n)
+    if in_place:
+        # a group's first row where it lies: its keys are read there, and
+        # its words are what the rows are matched against
+        first_row = jnp.where(
+            first_idx < n, order[jnp.clip(first_idx, 0, n - 1)], n)
+        out_cols = _gather_group_keys(table, keys, first_row, m, n)
+        slots = _KeySlots(
+            words, [w[jnp.clip(first_row, 0, n - 1)] for w in words],
+            first_idx < n)
+    else:
+        out_cols = _gather_group_keys(
+            sorted_keys, range(len(keys)), first_idx, m, n)
 
     # Sum-form reductions (sums of ints/decimals/floats, all counts) batch
     # into ONE (n, k) prefix pass per accumulator dtype + per-group
@@ -664,6 +858,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
     # same non-guarantee). Min/max ride a segmented log-depth scan
     # (_segmented_extremum) instead of segment_* scatters.
     int_lanes: list[jnp.ndarray] = []    # (n,) int64 each
+    int_kinds: list = []                 # the dtype each was widened from
     float_lanes: list[jnp.ndarray] = []  # (n,) float64 each
     # sibling aggs on one column (sum+mean+var, every agg's count) must
     # share lanes, not stack identical copies into the streaming pass
@@ -673,6 +868,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         if memo_key is not None and memo_key in _lane_memo:
             return _lane_memo[memo_key]
         int_lanes.append(arr.astype(jnp.int64))
+        int_kinds.append(arr.dtype)
         spec = ("i", len(int_lanes) - 1)
         if memo_key is not None:
             _lane_memo[memo_key] = spec
@@ -697,6 +893,8 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         (catastrophic cancellation, worse under TPU's f32-pair f64)."""
         if n == 0:
             return jnp.zeros((m, stack.shape[1]), stack.dtype)
+        if in_place:      # the float lanes; the int lanes: slots.int_sums
+            return slots.float_sums(stack)
         if stack.dtype.kind == "f":
             run = _segmented_sum_scan(stack, ~same)
             out = run[jnp.clip(g_hi - 1, 0, n - 1)]
@@ -718,16 +916,16 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             # ``order``; first / last scan the validity in key order
             plan.append((op, table.column(col_idx), None,
                          None if op.endswith("_include_nulls")
-                         else sorted_valid(col_idx), None))
+                         else read_valid(col_idx), None))
             continue
         if op == "nunique":     # sorts its own copy of keys and values
             plan.append((op, None, DType(TypeId.INT64), col_idx, None))
             continue
         if op == "count":       # reads the validity alone
             plan.append((op, None, None, None, lane(
-                sorted_valid(col_idx), memo_key=(col_idx, "count"))))
+                read_valid(col_idx), memo_key=(col_idx, "count"))))
             continue
-        c = sorted_col[col_idx]
+        c = read_col[col_idx]
         valid = c.valid_mask()
         if isinstance(op, tuple):
             # binary aggregates (covar_samp/covar_pop/corr): Spark counts
@@ -735,7 +933,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             # dedicated pairwise-masked sum + count lanes (memoized per
             # column pair — corr shares them with sibling covar aggs).
             kind, oidx = op
-            cy = sorted_col[oidx]
+            cy = read_col[oidx]
             for cc in (c, cy):
                 if cc.dtype.is_string or (
                         not cc.dtype.is_decimal128
@@ -931,8 +1129,11 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
             return Column(c.dtype, g.data, has_any, chars=g.chars)
         return Column(c.dtype, c.data[winner_row], has_any)
 
-    seg_i = (_seg_sums(jnp.stack(int_lanes, axis=1)) if int_lanes
-             else jnp.zeros((m, 1), jnp.int64))
+    if in_place and int_lanes:
+        seg_i = slots.int_sums(int_lanes, int_kinds)
+    else:
+        seg_i = (_seg_sums(jnp.stack(int_lanes, axis=1)) if int_lanes
+                 else jnp.zeros((m, 1), jnp.int64))
     seg_f = (_seg_sums(jnp.stack(float_lanes, axis=1)) if float_lanes
              else jnp.zeros((m, 1), jnp.float64))
 
@@ -942,16 +1143,20 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
 
     _gid_cache: list = []
 
-    def _row_gid() -> jnp.ndarray:
-        """Per-row dense group id, built only where an aggregate reads it
-        (the centered variance / covariance pass; the bounds need none).
-        In the small-m path group starts are already known, so a
-        searchsorted replaces the full-length cumsum scan."""
+    def per_row(per_group: jnp.ndarray) -> jnp.ndarray:
+        """[n]: each row's own group's value of ``per_group`` ([m]), for
+        the centered variance / covariance pass (the bounds need no group
+        id a row). In key order a dense group id is built once: in the
+        small-m path group starts are already known, so a searchsorted
+        replaces the full-length cumsum scan. Where the rows lie
+        (``in_place``) it is a select a slot."""
+        if in_place:
+            return slots.at_rows(per_group)
         if not _gid_cache:
             _gid_cache.append((jnp.searchsorted(
                 g_lo, jnp.arange(n, dtype=jnp.int32), side="right"
             ) - 1 if small else jnp.cumsum(~same) - 1).astype(jnp.int32))
-        return _gid_cache[0]
+        return per_group[_gid_cache[0]]
 
     sum128_overflow = jnp.bool_(False)
     for op, c, acc_dt, val_lane, count_lane in plan:
@@ -1052,7 +1257,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                 if n:
                     x = c.data.astype(jnp.float64) * scale_f
                     centered = jnp.where(
-                        c.valid_mask(), x - mean_g[_row_gid()], 0.0)
+                        c.valid_mask(), x - per_row(mean_g), 0.0)
                     m2 = _seg_sums((centered * centered)[:, None])[:, 0]
                 else:
                     m2 = jnp.zeros((m,), jnp.float64)
@@ -1165,13 +1370,13 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                 mean_y = seg_col(spec_y).astype(jnp.float64) * sfy / denom
                 if n:
                     both = c.valid_mask() & cy.valid_mask()
-                    gid = _row_gid()
                     cxv = jnp.where(
                         both,
-                        c.data.astype(jnp.float64) * sfx - mean_x[gid], 0.0)
+                        c.data.astype(jnp.float64) * sfx - per_row(mean_x),
+                        0.0)
                     cyv = jnp.where(
                         both,
-                        cy.data.astype(jnp.float64) * sfy - mean_y[gid],
+                        cy.data.astype(jnp.float64) * sfy - per_row(mean_y),
                         0.0)
                     moments = _seg_sums(jnp.stack(
                         [cxv * cyv, cxv * cxv, cyv * cyv], axis=1))
@@ -1310,7 +1515,9 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         sentinel = minmax_sentinel(c.dtype, op)
         vv = jnp.where(c.valid_mask(), c.data,
                        jnp.asarray(sentinel, c.data.dtype))
-        if n:
+        if in_place:
+            red = slots.extremum(vv, op, sentinel)
+        elif n:
             run = _segmented_extremum(vv, ~same, op)
             red = run[jnp.clip(g_hi - 1, 0, n - 1)]
         else:
@@ -1318,7 +1525,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         out_cols.append(Column(c.dtype, red, vcount > 0))
 
     return GroupByResult(Table(out_cols), num_groups, overflowed,
-                         sum128_overflow)
+                         sum128_overflow, in_place)
 
 
 @func_range("groupby_aggregate")
@@ -1696,9 +1903,11 @@ def groupby_aggregate_bounded(
     The general groupby's cost on TPU is the key sort + row gather +
     boundary machinery (sort 55 ms + gather 32 ms of the ~280 ms q1
     iteration at 4M rows on a v5e in 2026-07, before the runtime stack;
-    not measured since). When the planner knows each key
-    column's candidate values (dictionary stats; CHAR(1) flag domains in
-    TPC-H q1), dense group ids come from a searchsorted against the tiny
+    since PR 33 the general path bounded at q1's 64 groups takes 0.039 s
+    of device time for 8,388,608 padded rows, 0.023 s of it the key sort,
+    the planned region 0.0058 s: PERF.md section 5). When the planner
+    knows each key column's candidate values (dictionary stats; CHAR(1)
+    flag domains in TPC-H q1), dense group ids come from a searchsorted against the tiny
     sorted domain and every aggregate is a masked whole-column reduction
     per group — XLA fuses the per-group masked sums into one multi-output
     reduction pass over the lanes.

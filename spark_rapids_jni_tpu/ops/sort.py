@@ -231,15 +231,13 @@ def _radix_order(words: list[jnp.ndarray]) -> jnp.ndarray:
     return jax.lax.fori_loop(0, len(words), one_pass, start)
 
 
-def _sort_order_impl(row_args, aux, rvs, *, keys, ascending, nulls_first):
-    ((table, row_valid),) = row_args
+def _lex_keys(table: Table, keys, ascending, nulls_first, rv) -> tuple:
+    """``(lex keys minor -> major, packed where they pack into 64 bits,
+    whether every key is a packable unsigned field)``."""
     # phantom rows (padded tails, masked shuffle slots): rank them AFTER
     # every real row with one extra most-significant key; the sort is
     # stable, so the leading entries are exactly the real rows' stable
     # permutation — bit-identical to the unpadded sort after slicing.
-    rv = row_valid
-    if rv is None and rvs is not None:
-        rv = rvs[0]
     lex_keys: list[jnp.ndarray] = []
     # jnp.lexsort treats the LAST key as primary; build minor -> major.
     for k, asc, nf in zip(reversed(list(keys)), reversed(list(ascending)),
@@ -248,7 +246,15 @@ def _sort_order_impl(row_args, aux, rvs, *, keys, ascending, nulls_first):
     if rv is not None:
         lex_keys.append(jnp.where(rv, jnp.uint8(0), jnp.uint8(1)))
     packable = all(_key_bits(a) is not None for a in lex_keys)
-    lex_keys = _pack_lex_keys(lex_keys)
+    return _pack_lex_keys(lex_keys), packable
+
+
+def _sort_order_impl(row_args, aux, rvs, *, keys, ascending, nulls_first):
+    ((table, row_valid),) = row_args
+    rv = row_valid
+    if rv is None and rvs is not None:
+        rv = rvs[0]
+    lex_keys, packable = _lex_keys(table, keys, ascending, nulls_first, rv)
     if len(lex_keys) == 1:
         return jnp.argsort(lex_keys[0], stable=True).astype(jnp.int32)
     if packable and len(lex_keys) > 2:
@@ -280,6 +286,40 @@ def sort_order(
                 ascending=tuple(ascending), nulls_first=tuple(nulls_first)),
         ((table, row_valid),),
         statics=(tuple(keys), tuple(ascending), tuple(nulls_first)))
+
+
+def sort_key_words(table: Table, keys: Sequence[int],
+                   row_valid: jnp.ndarray | None = None) -> tuple:
+    """``sort_order(table, keys, row_valid=row_valid)`` with what it sorted
+    by: ``(order, words, sorted_words)``. ``words`` are the keys of every
+    row (null ranks and the row-valid bit folded in) as uint32 words, minor
+    -> major, where the rows lie; ``sorted_words`` the same in the order
+    ``order``. Two rows have the same words exactly when they have the same
+    key tuple, null-ness included; a phantom row's words equal no real
+    row's. For keys of fixed-width fields only (``_key_bits``): float64
+    and decimal128 have no such words and raise.
+
+    Keys of one or two words are the operands of the one variadic sort
+    ``sort_order`` runs, which brings them into order whether or not
+    anybody reads them: here they are read. Wider keys are sorted word by
+    word (``_radix_order``) and moved after it (``_move_words``)."""
+    ones = [True] * len(keys)
+    lex_keys, packable = _lex_keys(table, keys, ones, ones, row_valid)
+    if not packable:
+        raise TypeError("sort_key_words: a key has no fixed-width sort word")
+    if len(lex_keys) <= 2:
+        # jnp.argsort / jnp.lexsort's own sort with its keys kept, but for
+        # the iota: theirs is int64 under x64, two words more to compare
+        # and move (8,388,608 rows of two key words on a v5e, PERF.md
+        # section 6, PR 33: 0.0366 s and 63 s of cold compile with an
+        # int64 iota, 0.0240 s and 41 s with this one)
+        iota = jax.lax.iota(jnp.int32, lex_keys[0].shape[0])
+        *major_first, order = jax.lax.sort(
+            (*lex_keys[::-1], iota), num_keys=len(lex_keys))
+        return order, lex_keys, major_first[::-1]
+    words = _pack_words(lex_keys)
+    order = _radix_order(words)
+    return order, words, _move_words(words, order)
 
 
 def gather(table: Table, indices: jnp.ndarray) -> Table:

@@ -567,7 +567,8 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
             else:
                 keys += [f"{node.label}.num_groups",
                          f"{node.label}.overflowed",
-                         f"{node.label}.sum_overflow"]
+                         f"{node.label}.sum_overflow",
+                         f"{node.label}.in_place"]
                 if placement and placement[id(node.child)] == SHARDED:
                     keys += [f"{node.label}.shuffle_rows",
                              f"{node.label}.shuffle_bytes"]
@@ -680,6 +681,7 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                     (f"{node.label}.overflowed", jnp.asarray(g.overflowed)),
                     (f"{node.label}.sum_overflow",
                      jnp.asarray(g.sum_overflow)),
+                    (f"{node.label}.in_place", jnp.asarray(g.in_place)),
                 ])
                 # a None budget pads to the input rows: still positional
                 rv_out = rv if resolved[id(node)] is None else None
@@ -926,6 +928,9 @@ def _mesh_groupby(node: GroupBy, tbl: Table, rv, bound, axis: str):
                 (f"{label}.overflowed", flags),
                 (f"{label}.sum_overflow",
                  anywhere(part.sum_overflow) | anywhere(merged.sum_overflow)),
+                # how the partial was lowered (ops/groupby.py): a fact of
+                # the trace, the same on every chip
+                (f"{label}.in_place", jnp.asarray(part.in_place)),
                 (f"{label}.shuffle_rows", jax.lax.psum(sent, axis)),
                 # what the all_to_all carries between chips (a chip keeps
                 # its own share): a fact of the partial's schema and the
@@ -1460,14 +1465,17 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     shuffled (exchanges, the partial rows it sent and the bytes its
     ``all_to_all`` put between chips), and how many nodes broke what the plan
     declares: a dense primary key that is not one (``pk_violation``), a
-    group bound that was too small (``overflowed``). A result with either
-    is a wrong answer; the served path refuses it
+    group bound that was too small (``overflowed``); and how many groupbys
+    took their aggregates over the rows where they lie, no value word
+    brought into key order (``groupby.in_place``: a fact of the lowering,
+    ``ops/groupby.py``). A result with a broken declaration is a wrong
+    answer; the served path refuses it
     (``QueryServer._account_meta``). Converting a meta value waits for the
     device, so call it where the meta is wanted on the host anyway."""
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
              "join.pk_violation": 0, "groupby.groups": 0,
-             "groupby.overflowed": 0, "shuffle.exchanges": 0,
-             "shuffle.rows": 0, "shuffle.bytes": 0}
+             "groupby.overflowed": 0, "groupby.in_place": 0,
+             "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0}
     for node in _topo(plan.root):
         if isinstance(node, (Join, DensePkJoin)):
             total = meta.get(f"{node.label}.total")
@@ -1483,6 +1491,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["groupby.groups"] += int(groups)
             facts["groupby.overflowed"] += bool(
                 meta.get(f"{node.label}.overflowed", False))
+            facts["groupby.in_place"] += bool(
+                meta.get(f"{node.label}.in_place", False))
             sent = meta.get(f"{node.label}.shuffle_rows")
             if sent is not None:   # lowered over a mesh: one all_to_all
                 facts["shuffle.exchanges"] += 1

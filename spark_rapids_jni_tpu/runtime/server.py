@@ -912,10 +912,10 @@ class QueryServer:
         """Count, once a request, what the plan's joins and groupbys report
         in the result's meta (its host copy; ``fusion.meta_facts``:
         counters ``join.probe_rows``, ``join.matched_rows``,
-        ``groupby.groups``, ``join.pk_violation``, ``groupby.overflowed``,
-        and of a groupby lowered over a mesh ``shuffle.exchanges``,
-        ``shuffle.rows`` and ``shuffle.bytes``: the served path's shuffle
-        telemetry), and refuse a result that broke what its plan declares:
+        ``groupby.groups``, ``groupby.in_place``, ``join.pk_violation``,
+        ``groupby.overflowed``, and of a groupby lowered over a mesh
+        ``shuffle.exchanges``, ``shuffle.rows`` and ``shuffle.bytes``: the
+        served path's shuffle telemetry), and refuse a result that broke what its plan declares:
         rows were dropped or merged, so it must not resolve as a success."""
         if not meta:
             return

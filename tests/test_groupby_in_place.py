@@ -1,0 +1,490 @@
+"""The sort-path groupby under a small group bound (``ops/groupby.py``:
+``_aggregates_in_place``, ``_KeySlots``): the aggregates are taken over the
+rows where they lie, matched to the first m groups by their key words, no
+value word brought into key order. Against the same call without a bound
+(the word-moving path) trimmed to m rows and against a plain dict oracle,
+bit for bit on integer lanes; the shape of the lowered regions; the
+``groupby.in_place`` counter of the served path."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_jni_tpu import types as t
+from spark_rapids_jni_tpu.columnar import Column, Table
+from spark_rapids_jni_tpu.models import tpch
+from spark_rapids_jni_tpu.ops import groupby as gb
+from spark_rapids_jni_tpu.ops import sort as so
+from spark_rapids_jni_tpu.parallel.mesh import EXEC_AXIS, executor_mesh
+from spark_rapids_jni_tpu.runtime import dispatch, fusion, resilience
+from spark_rapids_jni_tpu.runtime.server import QueryServer
+from spark_rapids_jni_tpu.telemetry import REGISTRY
+from tests.test_sort_words import _gathers, _q3_tables, _region_hlo, _sorts
+
+M = 8
+N = 2048      # the gate: 2 * m * 32 <= n
+
+
+def _col(dt, values, valid=None):
+    return Column(dt, jnp.asarray(values),
+                  None if valid is None else jnp.asarray(valid))
+
+
+def _keys(rng, groups, n=N, dt=(t.INT8, np.int8)):
+    return rng.integers(0, groups, n).astype(dt[1]) - dt[1](groups // 2)
+
+
+def _case(name):
+    """``(table, key columns, aggregates, row_valid, bound, true groups or
+    None, in place?)`` of one named case."""
+    rng = np.random.default_rng(sorted(CASES).index(name))
+    val = rng.integers(-10**9, 10**9, N).astype(np.int64)
+    vvalid = rng.random(N) > 0.2
+    sums = ((1, "sum"), (1, "count"), (1, "mean"), (1, "min"), (1, "max"))
+    one_key = lambda k, kv=None: Table(  # noqa: E731
+        [_col(t.INT8, k, kv), _col(t.INT64, val, vvalid)])
+    if name == "null_keys":
+        return (one_key(_keys(rng, 5), rng.random(N) > 0.3), [0], sums,
+                None, M, 6, True)
+    if name == "phantom_rows":
+        return (one_key(_keys(rng, 5)), [0], sums, rng.random(N) > 0.4, M, 5,
+                True)
+    if name == "all_phantom":
+        return (one_key(_keys(rng, 5)), [0], sums, np.zeros(N, bool), M, 0,
+                True)
+    if name == "empty":
+        return (Table([_col(t.INT8, np.zeros(0, np.int8)),
+                       _col(t.INT64, np.zeros(0, np.int64))]), [0], sums,
+                None, M, 0, False)
+    if name in ("exactly_m", "m_plus_1", "ten_m"):
+        groups = {"exactly_m": M, "m_plus_1": M + 1, "ten_m": 10 * M}[name]
+        k = np.concatenate([np.arange(groups), rng.integers(
+            0, groups, N - groups)]).astype(np.int8)
+        return (one_key(rng.permutation(k) - 40), [0], sums,
+                rng.random(N) > 0.1, M, None, True)
+    if name == "wrapping_sums":
+        big = rng.integers(2**62, 2**63 - 1, N).astype(np.int64)
+        big[::3] = -2**63
+        return (Table([_col(t.INT8, _keys(rng, 3)), _col(t.INT64, big),
+                       _col(t.UINT64, big.astype(np.uint64) * 2 + 1)]),
+                [0], ((1, "sum"), (2, "sum"), (1, "count")), None, M, 3, True)
+    if name == "decimal_sums":
+        d64 = rng.integers(-10**15, 10**15, N).astype(np.int64)
+        d128 = rng.integers(-2**63, 2**63 - 1, (N, 2), dtype=np.int64)
+        return (Table([_col(t.INT8, _keys(rng, 4)),
+                       _col(t.decimal64(-2), d64, vvalid),
+                       _col(t.decimal128(-3), d128, vvalid),
+                       _col(t.INT32, rng.integers(-2**31, 2**31 - 1, N)
+                            .astype(np.int32), vvalid)]),
+                [0], ((1, "sum"), (1, "mean"), (2, "sum"), (2, "mean"),
+                      (2, "var"), (3, "sum"), (3, "count"),
+                      (1, ("covar_samp", 2))), None, M, 4, True)
+    if name == "minmax_all_null_groups":
+        k = _keys(rng, 6)
+        return (Table([_col(t.INT8, k),
+                       _col(t.INT64, val, vvalid & (k > 0)),
+                       _col(t.FLOAT64, rng.standard_normal(N), k % 2 == 0),
+                       _col(t.INT16, rng.integers(-2**15, 2**15 - 1, N)
+                            .astype(np.int16), vvalid)]),
+                [0], ((1, "min"), (1, "max"), (2, "min"), (2, "max"),
+                      (3, "min"), (3, "max"), (1, "count")), None, M, 6, True)
+    if name == "float_lanes":
+        f = rng.standard_normal(N) * 10.0 ** rng.integers(-3, 6, N)
+        return (Table([_col(t.INT8, _keys(rng, 5)),
+                       _col(t.FLOAT64, f, vvalid),
+                       _col(t.FLOAT32, f.astype(np.float32), vvalid),
+                       _col(t.INT64, val, vvalid)]),
+                [0], ((1, "sum"), (1, "mean"), (2, "sum"), (3, "var"),
+                      (3, "std_pop"), (1, "std"), (1, ("corr", 3)),
+                      (3, ("covar_pop", 1))), None, M, 5, True)
+    if name == "two_keys":
+        return (Table([_col(t.INT8, _keys(rng, 2), rng.random(N) > 0.1),
+                       _col(t.INT16, _keys(rng, 3, dt=(t.INT16, np.int16))),
+                       _col(t.INT64, val, vvalid)]),
+                [0, 1], ((2, "sum"), (2, "count"), (2, "max")), None, M,
+                None, True)
+    if name == "three_keys_wide":     # over two words: sorted word by word
+        return (Table([_col(t.INT64, rng.integers(-2, 0, N) * 2**40,
+                            rng.random(N) > 0.1),
+                       _col(t.INT32, rng.integers(0, 2, N).astype(np.int32)),
+                       _col(t.UINT16, rng.integers(0, 2, N).astype(np.uint16)),
+                       _col(t.INT64, val, vvalid)]),
+                [0, 1, 2], ((3, "sum"), (3, "count"), (3, "min")),
+                rng.random(N) > 0.2, 16, None, True)
+    if name in ("first_last", "nunique", "float_key", "string_minmax"):
+        k = _keys(rng, 4)
+        if name == "float_key":     # -0.0 and 0.0: one group, two words
+            fk = np.array([-0.0, 0.0, 1.5, np.nan], np.float32)[k + 2]
+            return (Table([_col(t.FLOAT32, fk), _col(t.INT64, val, vvalid)]),
+                    [0], sums, None, M, 3, False)
+        if name == "string_minmax":
+            words = ["a", "bb", "", "zz", "m"]
+            return (Table([_col(t.INT8, k), Column.from_pylist(
+                [words[i] for i in rng.integers(0, 5, N)], t.STRING)]),
+                [0], ((1, "min"), (1, "max")), None, M, 4, False)
+        extra = {"first_last": ((1, "first"), (1, "last"),
+                                (1, "first_include_nulls")),
+                 "nunique": ((1, "nunique"),)}[name]
+        small = rng.integers(0, 7, N).astype(np.int64)
+        return (Table([_col(t.INT8, k), _col(t.INT64, small, vvalid)]),
+                [0], sums + extra, rng.random(N) > 0.2, M, 4, False)
+    raise KeyError(name)
+
+
+CASES = ("null_keys", "phantom_rows", "all_phantom", "empty", "exactly_m",
+         "m_plus_1", "ten_m", "wrapping_sums", "decimal_sums",
+         "minmax_all_null_groups", "float_lanes", "two_keys",
+         "three_keys_wide", "first_last", "nunique", "float_key",
+         "string_minmax")
+
+
+def _same_columns(got: Table, want: Table, rows: int):
+    """The first ``rows`` rows: validity equal; where valid, integers and
+    decimals bit for bit and floats within 1e-9 (NaNs alike)."""
+    assert got.num_columns == want.num_columns
+    for i, (g, w) in enumerate(zip(got.columns, want.columns)):
+        assert g.dtype == w.dtype, i
+        gv = np.asarray(g.valid_mask())[:rows]
+        wv = np.asarray(w.valid_mask())[:rows]
+        assert gv.tolist() == wv.tolist(), (i, g.dtype)
+        if g.dtype.is_string:
+            assert [x for x, v in zip(g.to_pylist()[:rows], gv) if v] == [
+                x for x, v in zip(w.to_pylist()[:rows], wv) if v], i
+            continue
+        gd, wd = np.asarray(g.data)[:rows][gv], np.asarray(w.data)[:rows][wv]
+        if gd.dtype.kind == "f":
+            np.testing.assert_allclose(gd, wd, rtol=1e-9, atol=0,
+                                       err_msg=f"column {i}")
+        else:
+            assert gd.tobytes() == wd.tobytes(), (i, g.dtype)
+
+
+def _oracle(table, keys, aggs, row_valid):
+    """``{key tuple: {(column, op): value}}`` in key order (nulls first,
+    then ascending), sums modulo 2**64, for sum / count / min / max of
+    integer columns; the other aggregates have the unbounded path alone."""
+    cols = [(np.asarray(c.data), np.asarray(c.valid_mask()))
+            for c in table.columns]
+    groups: dict = {}
+    for r in range(table.num_rows):
+        if row_valid is not None and not row_valid[r]:
+            continue
+        key = tuple((1, int(cols[k][0][r])) if cols[k][1][r] else (0, 0)
+                    for k in keys)
+        acc = groups.setdefault(key, {})
+        for ci, op in aggs:
+            data, valid = cols[ci]
+            if (isinstance(op, tuple) or op not in ("sum", "count", "min",
+                                                    "max")
+                    or data.dtype.kind not in "iu" or data.ndim != 1
+                    or table.column(ci).dtype.is_string):
+                continue
+            slot = acc.setdefault((ci, op), [])
+            if valid[r]:
+                slot.append(int(data[r]))
+    out = {}
+    for key in sorted(groups):
+        out[key] = {}
+        for (ci, op), vals in groups[key].items():
+            if op == "count":
+                out[key][ci, op] = len(vals)
+            elif not vals:
+                out[key][ci, op] = None
+            elif op == "sum":
+                total = sum(vals) % 2**64
+                out[key][ci, op] = total - 2**64 if total >= 2**63 else total
+            else:
+                out[key][ci, op] = min(vals) if op == "min" else max(vals)
+    return out
+
+
+@pytest.fixture(params=["one_chunk", "many_chunks"])
+def chunks(request, monkeypatch):
+    """The MXU accumulate over one chunk of all the rows, and (its size
+    dropped) over seven chunks with a padded tail."""
+    if request.param == "many_chunks":
+        monkeypatch.setattr(gb, "_MXU_CHUNK_ROWS", 300)
+        dispatch.clear()
+    yield request.param
+    dispatch.clear()
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_small_bound_against_unbounded_and_a_dict(case, chunks):
+    table, keys, aggs, rv, m, groups, in_place = _case(case)
+    row_valid = None if rv is None else jnp.asarray(rv)
+    if rv is not None:
+        # a phantom row's cells are null, as a padded tail's and a masked
+        # shuffle slot's are (the word-moving path folds phantom rows into
+        # the last real group and counts on it; the slots take no notice)
+        table = Table([c if i in keys else Column(
+            c.dtype, c.data, c.valid_mask() & row_valid, chars=c.chars)
+            for i, c in enumerate(table.columns)])
+    got = gb.groupby_aggregate(table, keys, aggs, max_groups=m,
+                               row_valid=row_valid)
+    want = gb.groupby_aggregate(table, keys, aggs, row_valid=row_valid)
+    assert bool(got.in_place) == in_place and not bool(want.in_place)
+    true_groups = int(want.num_groups)
+    if groups is not None:
+        assert true_groups == groups
+    # the true count even past the bound; the first m groups in key order
+    assert int(got.num_groups) == true_groups
+    assert bool(got.overflowed) == (true_groups > m)
+    assert got.table.num_rows == m
+    _same_columns(got.table, want.table, min(m, true_groups))
+    for c in got.table.columns:     # nothing past the last group
+        assert not np.asarray(c.valid_mask())[true_groups:].any()
+    assert bool(got.sum_overflow) == bool(want.sum_overflow)
+
+    if table.column(keys[0]).dtype.storage_dtype.kind == "f":
+        return      # -0.0 == 0.0 == one group: the unbounded path's word
+    oracle = _oracle(table, keys, aggs, rv)
+    assert len(oracle) == true_groups
+    nk = len(keys)
+    for row, (key, acc) in enumerate(list(oracle.items())[:m]):
+        for pos in range(nk):
+            c = got.table.column(pos)
+            valid = bool(np.asarray(c.valid_mask())[row])
+            assert valid == bool(key[pos][0])
+            if valid:
+                assert int(np.asarray(c.data)[row]) == key[pos][1]
+        for j, (ci, op) in enumerate(aggs):
+            if (ci, op) not in acc:
+                continue
+            c = got.table.column(nk + j)
+            if acc[ci, op] is None:
+                assert not np.asarray(c.valid_mask())[row], (key, ci, op)
+            else:
+                assert np.asarray(c.valid_mask())[row], (key, ci, op)
+                assert int(np.asarray(c.data).astype(np.int64)[row]) == (
+                    acc[ci, op] if acc[ci, op] < 2**63
+                    else acc[ci, op] - 2**64), (key, ci, op)
+
+
+@pytest.mark.parametrize("keys", ["one_word", "two_words", "three_words",
+                                  "no_words"])
+def test_sort_key_words_is_sort_order_with_its_words(keys):
+    """The order is ``sort_order``'s, the sorted words are the rows' words
+    in that order, and equal words are equal keys; a float64 key raises."""
+    rng = np.random.default_rng(len(keys))
+    n = 3000
+    cols = {"one_word": [_col(t.INT8, _keys(rng, 5, n), rng.random(n) > 0.2)],
+            "two_words": [_col(t.INT8, _keys(rng, 3, n)),
+                          _col(t.INT16, _keys(rng, 4, n, (t.INT16, np.int16)),
+                               rng.random(n) > 0.2)],
+            "three_words": [_col(t.INT64, rng.integers(-3, 3, n) * 2**33),
+                            _col(t.INT32, rng.integers(0, 3, n)
+                                 .astype(np.int32), rng.random(n) > 0.2)],
+            "no_words": [_col(t.FLOAT64, rng.standard_normal(n))],
+            }[keys]
+    table, at = Table(cols), list(range(len(cols)))
+    rv = jnp.asarray(rng.random(n) > 0.3)
+    if keys == "no_words":
+        with pytest.raises(TypeError):
+            so.sort_key_words(table, at, rv)
+        return
+    order, words, sorted_words = so.sort_key_words(table, at, rv)
+    real = int(np.asarray(rv).sum())
+    want = np.asarray(so.sort_order(table, at, row_valid=rv))
+    assert np.asarray(order)[:real].tolist() == want[:real].tolist()
+    assert len(words) == len(sorted_words) == {
+        "one_word": 1, "two_words": 2, "three_words": 4}[keys]
+    for w, sw in zip(words, sorted_words):
+        assert w.dtype == jnp.uint32
+        assert np.asarray(sw).tolist() == np.asarray(w)[
+            np.asarray(order)].tolist()
+    tuples = [tuple((bool(np.asarray(c.valid_mask())[r]),
+                     int(np.asarray(c.data)[r]) if np.asarray(
+                         c.valid_mask())[r] else 0) for c in cols)
+              for r in range(n)]
+    stacked = np.stack([np.asarray(w) for w in words], axis=1)
+    rows = [r for r in range(n) if bool(rv[r])][:400]
+    for a, b in zip(rows, rows[1:]):
+        assert (stacked[a] == stacked[b]).all() == (tuples[a] == tuples[b])
+    assert not (stacked[np.asarray(rv)][:, None] == stacked[
+        ~np.asarray(rv)][None, :40]).all(axis=2).any()    # phantom words
+
+
+@pytest.mark.parametrize("m", [1, 64, 65, 1024, 1025])
+def test_the_gate_is_the_small_bound_and_its_aggregates(m):
+    """In place up to ``_SMALL_M`` groups where the rows pay for the block
+    path; with a minimum or a float sum beside the integer sums only up to
+    ``_SLOT_REDUCE_M``; never without a bound."""
+    n = 2 * 1024 * 32
+    rng = np.random.default_rng(m)
+    table = Table([_col(t.INT32, rng.integers(0, 50, n).astype(np.int32)),
+                   _col(t.INT64, rng.integers(0, 9, n)),
+                   _col(t.FLOAT64, rng.random(n))])
+    run = lambda aggs, bound: bool(gb.groupby_aggregate(  # noqa: E731
+        table, [0], aggs, max_groups=bound).in_place)
+    assert run(((1, "sum"), (1, "count")), m) == (m <= gb._SMALL_M)
+    assert run(((1, "sum"), (1, "min")), m) == (m <= gb._SLOT_REDUCE_M)
+    assert run(((2, "sum"),), m) == (m <= gb._SLOT_REDUCE_M)
+    if m == 1:
+        assert not run(((1, "sum"),), None)
+        few = Table([_col(c.dtype, np.asarray(c.data)[:20])    # a bucket of 32
+                     for c in table.columns])
+        assert not bool(gb.groupby_aggregate(
+            few, [0], ((1, "sum"),), max_groups=1).in_place)
+
+
+def test_auto_grows_past_the_gate_and_says_so():
+    """``groupby_aggregate_auto``: its first small bounds take the slots
+    and overflow; the bound it ends on is over the gate."""
+    n = 2 * 1024 * 32
+    rng = np.random.default_rng(9)
+    table = Table([_col(t.INT32, rng.integers(0, 3000, n).astype(np.int32)),
+                   _col(t.INT64, rng.integers(-9, 9, n))])
+    first = gb.groupby_aggregate(table, [0], ((1, "sum"),), max_groups=16)
+    assert bool(first.in_place) and bool(first.overflowed)
+    assert int(first.num_groups) == 3000
+    res = gb.groupby_aggregate_auto(table, [0], ((1, "sum"),), 16)
+    want = gb.groupby_aggregate(table, [0], ((1, "sum"),))
+    assert not bool(res.overflowed) and not bool(res.in_place)
+    _same_columns(res.table, want.table, 3000)
+
+
+# -- through fusion.execute: the table and every side output the parent's --
+
+@pytest.fixture
+def parent_path(monkeypatch):
+    """Call it to run with the small-bound path off, as the parent ran."""
+    def switch(off: bool):
+        if off:
+            monkeypatch.setattr(gb, "_aggregates_in_place",
+                                lambda *a: False)
+        else:
+            monkeypatch.undo()
+        dispatch.clear()
+    yield switch
+    monkeypatch.undo()
+    dispatch.clear()
+
+
+def _same_result(got, want, skip=("groupby.in_place",)):
+    rows = got.table.num_rows
+    assert rows == want.table.num_rows
+    _same_columns(got.table, want.table, rows)
+    assert sorted(got.meta) == sorted(want.meta)
+    for name in got.meta:
+        if name not in skip:
+            assert np.asarray(got.meta[name]).tolist() == np.asarray(
+                want.meta[name]).tolist(), name
+
+
+def test_general_q1_through_fusion_is_the_parents(parent_path):
+    lineitem = tpch.lineitem_table(6000, seed=33)
+    got = fusion.execute(tpch._q1_plan(), {"lineitem": lineitem})
+    assert bool(got.meta["groupby.in_place"])
+    parent_path(True)
+    want = fusion.execute(tpch._q1_plan(), {"lineitem": lineitem})
+    assert not bool(want.meta["groupby.in_place"])
+    _same_result(got, want)
+    assert int(got.meta["groupby.num_groups"]) == 7
+
+
+def test_distributed_q1_on_four_devices_is_the_parents(parent_path):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = executor_mesh(4)
+    lineitem = tpch.lineitem_table(4 * 4096, seed=34)
+    sharded = jax.device_put(lineitem, NamedSharding(mesh, P(EXEC_AXIS)))
+    plan = tpch._q1_distributed_plan()
+    got = fusion.execute(plan, {"lineitem": sharded})
+    assert bool(got.meta["groupby.in_place"])
+    assert int(got.meta["groupby.shuffle_rows"]) > 0    # it went over the mesh
+    parent_path(True)
+    want = fusion.execute(plan, {"lineitem": sharded})
+    assert not bool(want.meta["groupby.in_place"])
+    _same_result(got, want)
+    one_chip = fusion.execute(plan, {"lineitem": lineitem})
+    _same_columns(got.table, one_chip.table, got.table.num_rows)
+
+
+# -- the lowered regions: what a later edit must not bring back unseen -----
+
+def _loops_that_sort(hlo: str) -> list:
+    """Names of the computations a ``while`` runs that hold a sort."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", hlo))
+    found = []
+    for name in bodies:
+        text = re.search(
+            rf"^%?{re.escape(name)} [^\n]*\{{\n(.*?)^\}}", hlo, re.S | re.M)
+        if text and " sort(" in text.group(1):
+            found.append(name)
+    return found
+
+
+def test_general_q1_region_sorts_its_keys_and_nothing_else():
+    """One bucket of the served general q1: the n-row sorts are the key
+    sort and at most one more, no ``while`` sorts (the parent's ``permute``
+    ran twelve passes in one), and no n-row gather."""
+    n = 5000      # under an outer trace the region is walked unpadded
+    hlo = _region_hlo(tpch._q1_plan(), {"lineitem": tpch.lineitem_table(n)})
+    rows_n = [s for s in _sorts(hlo) if f"[{n}]" in s]
+    # two int8 flags and their null ranks: one word beside a 32-bit iota
+    assert f"(u32[{n}]{{0}}, s32[{n}]{{0}})" in rows_n
+    assert len(rows_n) <= 2, rows_n
+    assert _loops_that_sort(hlo) == []
+    assert [g for g in _gathers(hlo, "groupby") if n in g[1]] == []
+
+
+def test_planned_q3_region_keeps_its_sorts(monkeypatch):
+    """The bound of planned q3 (257 here, 1,500,001 in the cell) is a
+    groups-of-orders spec over the block path's gate in the cell; at the
+    tests' size it is held over it by hand: the region's sorts are the
+    parent's in number and kind, and ``permute``'s loop is still there."""
+    monkeypatch.setattr(so, "_SORT_MOVE_MIN_WORDS", 0)
+    monkeypatch.setattr(gb, "_SMALL_M", 64)
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
+    sorts = _sorts(hlo)
+    assert len(sorts) == 4 and sorts.count("u32[4000]{0}") == 1, sorts
+    assert len(_loops_that_sort(hlo)) == 3     # key sort, permute, ORDER BY
+
+
+# -- the served path's counter --------------------------------------------
+
+def _served(plan, bindings):
+    before = REGISTRY.counters().get("groupby.in_place", 0)
+    with QueryServer(budget_bytes=4 << 30) as srv:
+        ticket = srv.session("t").submit(plan, bindings)
+        try:
+            result, exc = ticket.result(), None
+        except Exception as caught:
+            result, exc = None, caught
+    return (ticket, result, exc,
+            REGISTRY.counters().get("groupby.in_place", 0) - before)
+
+
+@pytest.mark.parametrize("plan,moves", [
+    ("general_q1", 1), ("planned_q1", 0), ("planned_q3", 0), ("overflow", 1)])
+def test_served_requests_count_groupby_in_place(plan, moves):
+    """Once a request, like ``groupby.groups``: 1 for general q1, 0 for
+    the declared-domain plan and for q3's bound over the gate; a bound
+    that overflowed in place is still a refused request."""
+    if plan == "overflow":
+        rng = np.random.default_rng(4)
+        table = Table([_col(t.INT64, rng.integers(0, 50, N)),
+                       _col(t.INT64, rng.integers(0, 9, N))])
+        probe = fusion.Plan("in_place_overflow", fusion.GroupBy(
+            fusion.Scan("t"), (0,), ((1, "sum"),), max_groups=M,
+            label="groupby"))
+        ticket, result, exc, moved = _served(probe, {"t": table})
+        assert isinstance(exc, resilience.CapacityOverflow)
+        assert ticket.status == "failed" and moved == moves
+        return
+    made = {"general_q1": (tpch._q1_plan(),
+                           {"lineitem": tpch.lineitem_table(5000, seed=1)}),
+            "planned_q1": (tpch._q1_planned_plan(),
+                           {"lineitem": tpch.lineitem_table(5000, seed=2)}),
+            "planned_q3": (tpch._q3_planned_plan(0, 9204),
+                           _q3_tables(n_ord=2100, n=70000))}[plan]
+    ticket, result, exc, moved = _served(*made)
+    assert exc is None and ticket.status == "served"
+    assert (ticket.tier, ticket.rung, ticket.steps) == ("fused", 0, 0)
+    assert moved == moves
+    facts = fusion.meta_facts(made[0], result.meta)
+    assert facts["groupby.in_place"] == moves

@@ -257,12 +257,16 @@ def test_general_q1_sort_keeps_its_two_packed_words():
     assert _sorts(hlo) == [f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s64[{n}]{{0}})"]
 
 
-def test_general_q1_groupby_keeps_its_key_sort(moved_by):
-    """General q1's groupby over a padded batch: its key sort is the
-    variadic ``(u32, u32, s64)`` it was (the accepted cell's cold compile
-    is this instruction's 60 s), and what it moves are eleven words: two
-    int8 keys with seven validities and the row-valid bit in one, five
-    int64 columns in ten. No column and no mask has a gather of its own."""
+@pytest.mark.parametrize("bound", [tpch._Q1_GROUP_BUDGET, 2049])
+def test_general_q1_groupby_keeps_its_key_sort(moved_by, bound):
+    """General q1's groupby over a padded batch. Bounded at the plan's 64
+    groups its one sort is the key sort, the two packed words and a 32-bit
+    iota, and it moves nothing (PR 33: the aggregates are taken where the
+    rows lie). Bounded over the small-bound gate (2,049 groups) the key
+    sort is the variadic ``(u32, u32, s64)`` it was (60 s of cold compile
+    on the chip) and it moves eleven words: two int8 keys with seven
+    validities and the row-valid bit in one, five int64 columns in ten. No
+    column and no mask has a gather of its own."""
     from spark_rapids_jni_tpu.ops import groupby as gb
 
     n = 4096
@@ -272,12 +276,16 @@ def test_general_q1_groupby_keeps_its_key_sort(moved_by):
     def groupby(tb, row_valid):
         return gb._groupby_aggregate_impl(
             ((tb, row_valid),), None, None, keys=(0, 1),
-            aggs=tuple(tpch._Q1_AGGS), max_groups=tpch._Q1_GROUP_BUDGET)
+            aggs=tuple(tpch._Q1_AGGS), max_groups=bound)
 
     hlo = jax.jit(groupby).lower(work, rv).compile().as_text()
     sorts = _sorts(hlo)
-    assert f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s64[{n}]{{0}})" in sorts, sorts
     moved = [(t, dims) for t, dims, _ in _gathers(hlo) if n in dims]
+    if bound == tpch._Q1_GROUP_BUDGET:
+        assert moved == [] and sorts == [
+            f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s32[{n}]{{0}})"], (moved, sorts)
+        return
+    assert f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s64[{n}]{{0}})" in sorts, sorts
     if moved_by == "sort_passes":
         assert moved == [] and f"(u32[{n}]{{0}}, u32[{n}]{{0}})" in sorts
     else:
