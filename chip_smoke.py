@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import json
+import math
 import os
 import signal
 import subprocess
@@ -428,15 +429,17 @@ def _write_parquet(li, path: str) -> None:
     }), path, compression="snappy")
 
 
-def _read_parquet(path: str):
+def _parquet_split(path: str):
+    """The file as one scan task's split: all of it, the seven columns by
+    name, the unscaled int64 read as the money decimals q1 wants. The
+    server reads the footer, admits, decodes and stages it itself."""
     from spark_rapids_jni_tpu import types as t
-    from spark_rapids_jni_tpu.columnar import Column, Table
-    from spark_rapids_jni_tpu.parquet.reader import read_table
+    from spark_rapids_jni_tpu.parquet import ParquetSplit
 
-    cols = list(read_table(path).columns)
-    for i in range(4):  # unscaled int64 -> the money decimals q1 wants
-        cols[i] = Column(t.decimal64(-2), cols[i].data, cols[i].validity)
-    return Table(cols)
+    return ParquetSplit(
+        path, ("l_quantity", "l_extendedprice", "l_discount", "l_tax",
+               "l_returnflag", "l_linestatus", "l_shipdate"),
+        dtypes=(t.decimal64(-2),) * 4 + (None,) * 3)
 
 
 def serve_phase(sizes: dict, platform: str, seed: int = 0,
@@ -536,19 +539,18 @@ def serve_phase(sizes: dict, platform: str, seed: int = 0,
             ctx, li_b, f"lineitem@{n1}")
         check_fingerprint(ctx, _every_digested_dtype(n1, seed),
                           f"every digested dtype@{n1}")
-        # q1 on tables that came from Parquet through the native reader
-        # (their own seeds: the result cache is content-addressed, and the
-        # same rows as li_a would be served from it without executing)
+        # q1 on tables that arrive as Parquet files: the server decodes
+        # them itself through parquet/split.py (their own seeds, so the
+        # oracle of each is its own)
         t0 = time.perf_counter()
         parquet = []
         for k, src in (("a", pq_a), ("b", pq_b)):
             path = os.path.join(scratch, f"lineitem_{k}.parquet")
             _write_parquet(src, path)
-            parquet.append(_read_parquet(path))
-            os.unlink(path)
+            parquet.append(_parquet_split(path))
         del pq_a, pq_b
-        ctx.say(f"parquet: 2 x {n1} rows written (pyarrow, snappy) and read "
-                f"back by parquet/reader.py in {time.perf_counter() - t0:.1f}s")
+        ctx.say(f"parquet: 2 x {n1} rows written (pyarrow, snappy) in "
+                f"{time.perf_counter() - t0:.1f}s, bound as splits")
         binds = [dict(zip(("customer", "orders", "lineitem"), tabs))
                  for tabs in q3_tabs]
         cutoff = tpch._Q3_CUTOFF_DAYS
@@ -580,14 +582,21 @@ def serve_phase(sizes: dict, platform: str, seed: int = 0,
 
         for run in reversed(runs):  # the quick compiles first
             run.finish(tickets, report)
+        for split in parquet:
+            os.unlink(split.path)
         ctx.say(f"oracle: numpy q3 has {len(q3_ref('a'))} groups")
 
-        # timed honestly, once: if four enqueued runs cost less than twice
-        # one run, block_until_ready does not wait for the device
+        # timed honestly: if four enqueued runs cost less than twice one
+        # run, block_until_ready does not wait for the device. One run is
+        # the quickest of three: a host busy with other work (the tests'
+        # six workers) can only lengthen a run, and one lengthened sample
+        # of "one" failed the check at a ratio of 1.97
         plan = tpch._q1_plan()
-        t0 = time.perf_counter()
-        ctx.sync(fusion.execute(plan, {"lineitem": li_a}).table)
-        one = time.perf_counter() - t0
+        one = math.inf
+        for _ in range(3):
+            t0 = time.perf_counter()
+            ctx.sync(fusion.execute(plan, {"lineitem": li_a}).table)
+            one = min(one, time.perf_counter() - t0)
         t0 = time.perf_counter()
         for _ in range(4):
             last = fusion.execute(plan, {"lineitem": li_a})

@@ -116,6 +116,63 @@ class ParquetFooter:
             raise NativeError(lib.last_error())
         return out
 
+    @property
+    def file_columns(self) -> int:
+        """The leaves the file's schema had before the prune."""
+        lib = load_native()
+        out = lib.tpudf_footer_file_leaves(self._require_open())
+        if out < 0:
+            raise NativeError(lib.last_error())
+        return out
+
+    def row_groups(self) -> list:
+        """``[(index, num_rows)]`` of the row groups the split filter
+        kept, ``index`` being the FILE's own numbering: what
+        ``read_table(row_groups=...)`` takes."""
+        lib = load_native()
+        cap = 64
+        while True:
+            index = (ctypes.c_int32 * cap)()
+            rows = (ctypes.c_int64 * cap)()
+            n = lib.tpudf_footer_row_groups(self._require_open(), index,
+                                            rows, cap)
+            if n < 0:
+                raise NativeError(lib.last_error())
+            if n <= cap:
+                return [(index[i], rows[i]) for i in range(n)]
+            cap = n
+
+    def leaves(self) -> list:
+        """One ``(request position, file leaf index, physical, converted,
+        scale, type_length, repetition)`` per leaf the prune kept, in the
+        REQUEST's order; a position that is absent is a requested name the
+        file lacks. The leaf index is what ``read_table(columns=...)``
+        takes."""
+        lib = load_native()
+        cap = 64
+        while True:
+            asked = (ctypes.c_int32 * cap)()
+            index = (ctypes.c_int32 * cap)()
+            meta = (ctypes.c_int32 * (5 * cap))()
+            n = lib.tpudf_footer_leaves(self._require_open(), asked, index,
+                                        meta, cap)
+            if n < 0:
+                raise NativeError(lib.last_error())
+            if n <= cap:
+                return [(asked[i], index[i], *meta[5 * i:5 * i + 5])
+                        for i in range(n)]
+            cap = n
+
+    @property
+    def compressed_bytes(self) -> int:
+        """Compressed bytes of the column chunks the prune and the split
+        filter kept: what a reader of exactly those will touch."""
+        lib = load_native()
+        out = lib.tpudf_footer_compressed_bytes(self._require_open())
+        if out < 0:
+            raise NativeError(lib.last_error())
+        return out
+
     @func_range("ParquetFooter.serializeThriftFile")
     def serialize_thrift_file(self) -> bytes:
         """Emit a legal footer file image: PAR1 + thrift + length + PAR1

@@ -436,11 +436,29 @@ def source_fingerprint(path: str) -> str:
     return hashlib.sha256(token.encode()).hexdigest()
 
 
+def split_fingerprint(split) -> str:
+    """The key of a file-backed binding (``parquet.split.ParquetSplit``):
+    :func:`source_fingerprint` of its path with the projection, the types
+    it is read as and the split's byte range mixed in. No byte of the file
+    is digested: a rewrite of the file (size, mtime), another path, another
+    projection or another range is another key; the same source again is
+    the same key."""
+    token = repr((source_fingerprint(split.path), split.columns,
+                  split.dtypes, int(split.part_offset),
+                  int(split.part_length)))
+    return hashlib.sha256(token.encode()).hexdigest()
+
+
 def input_fingerprint(bindings: dict) -> str:
     """Content digest over every bound input, name-keyed and
     order-independent. Device tables hash their buffers (memoized);
-    host-decoded chunks hash their snapshots. Raises ``TypeError`` for
-    bindings that are neither."""
+    host-decoded chunks hash their snapshots; a Parquet split (resolved
+    or not) is keyed by its source (:func:`split_fingerprint`). Raises
+    ``TypeError`` for bindings that are none of these."""
+    # imported here, not at the top: a line added above ``cache_digest``
+    # would move its source lines, which the persistent compile cache keys on
+    from spark_rapids_jni_tpu.parquet.split import ParquetScan, ParquetSplit
+
     h = hashlib.sha256()
     for name in sorted(bindings):
         value = bindings[name]
@@ -448,6 +466,9 @@ def input_fingerprint(bindings: dict) -> str:
         h.update(b"\0")
         if isinstance(value, HostTableChunk):
             h.update(_chunk_fingerprint(value).encode())
+        elif isinstance(value, (ParquetScan, ParquetSplit)):
+            h.update(split_fingerprint(
+                getattr(value, "split", value)).encode())
         elif hasattr(value, "columns"):
             h.update(table_fingerprint(value).encode())
         else:

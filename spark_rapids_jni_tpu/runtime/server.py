@@ -39,6 +39,18 @@ Contracts, in order of importance:
   ``submit`` and the ticket's resolve runs outside a named span.
   ``QueryTicket.queue_wait_s`` is the deadline's clock and starts at
   submit, fingerprint included; the true wait is the spans'.
+* **A table may arrive as a file** — a scan bound to a
+  ``parquet.split.ParquetSplit`` (one Spark scan task: path, byte range,
+  read schema by name) is served like a bound ``Table``; the binding's type
+  is the only signal. ``submit`` reads the footer (``scan.footer``) and
+  binds what it resolves to, so the cache key (the source's: path, size,
+  mtime, projection, range; no content digest), the plan signature and the
+  estimate (the decoded bytes the footer states) exist before a page is
+  read; an oversize split is rejected like any oversize request, a footer
+  that cannot be read fails the ticket classified. After admission
+  ``_stage_bindings`` decodes the row groups on the shared pool and writes
+  each into the device table as it is ready (``scan``); a group that fails
+  to decode fails that request alone, reservation released.
 * **No leaks** — a query that dies, however it dies, releases its
   reservation and its in-flight slot; the failure is classified through
   ``resilience.classify`` and recorded before the ticket resolves.
@@ -94,6 +106,7 @@ import time
 import weakref
 from typing import Any, Callable, Optional
 
+from spark_rapids_jni_tpu.parquet.split import ParquetScan, ParquetSplit
 from spark_rapids_jni_tpu.runtime import (
     degrade,
     faults,
@@ -131,6 +144,10 @@ _log = get_logger("spark_rapids_jni_tpu.server")
 # the registry never keeps a dropped server (and its limiter) alive.
 _LIVE_SERVERS: "weakref.WeakSet[QueryServer]" = weakref.WeakSet()
 
+
+# bindings that reach the device only after admission (``_stage_bindings``):
+# ``nbytes`` is the device footprint the reservation has to cover
+_HOST_SIDE = (HostTableChunk, ParquetScan)
 
 # one id per submitted request, process-wide: what joins the client
 # thread's ``submit.<plan>`` span tree to the worker's ``query.<plan>``
@@ -455,8 +472,11 @@ class QueryServer:
         invalidation handle."""
         sid = str(session_id)
         self.session(sid)  # idempotent registration
+        # a Parquet split is costed from its footer, read below inside the
+        # request's span; everything else is costed here, as it always was
+        splits = any(isinstance(v, ParquetSplit) for v in bindings.values())
         estimate = int(estimate_bytes) if estimate_bytes is not None \
-            else self._default_estimate(plan, bindings)
+            else 0 if splits else self._default_estimate(plan, bindings)
         ddl = int(deadline_ms if deadline_ms is not None
                   else get_option("server.deadline_ms"))
         ticket = QueryTicket(sid, plan, bindings, estimate, donate_inputs,
@@ -468,8 +488,36 @@ class QueryServer:
         with spans.span(f"submit.{plan.name}", session=sid, plan=plan.name,
                         request=ticket.request) as sspan:
             ticket._submit_span = sspan.id
-            self._submit(ticket, cache_fingerprint)
+            if not splits or self._resolve_splits(
+                    ticket, sspan, costed=estimate_bytes is not None):
+                self._submit(ticket, cache_fingerprint)
         return ticket
+
+    def _resolve_splits(self, ticket: QueryTicket, sspan,
+                        costed: bool) -> bool:
+        """Read the footer of every Parquet split the request binds (span
+        ``scan.footer``, one a split) and bind what it resolves to: the
+        row groups and columns to decode, and the rows and decoded bytes
+        the cache key's plan half and the estimate need. No page is read.
+        False: a footer could not be read or lacks a column, and the
+        ticket has resolved ``failed``, classified."""
+        resolved = dict(ticket.bindings)
+        try:
+            for name, value in ticket.bindings.items():
+                if isinstance(value, ParquetSplit):
+                    with spans.child("scan.footer", session=ticket.session,
+                                     path=value.path):
+                        resolved[name] = value.resolve()
+        except (resilience.MalformedInputError, OSError,
+                NotImplementedError) as exc:
+            # a file that fails validation or lacks a column, one that
+            # cannot be opened, a column type a split does not stage
+            self._failed(ticket, exc, sspan)
+            return False
+        ticket.bindings = resolved
+        if not costed:
+            ticket.estimate = self._default_estimate(ticket.plan, resolved)
+        return True
 
     def _submit(self, ticket: QueryTicket,
                 cache_fingerprint: Optional[str]) -> None:
@@ -936,14 +984,15 @@ class QueryServer:
         """Headroom x the measured-truth EMA for this plan signature when
         one exists, else headroom x the static plan-aware input+output
         estimate; host-staged chunk bindings are costed at their exact
-        device footprint."""
+        device footprint, a resolved Parquet split at the decoded bytes its
+        footer states."""
         with self._learned_lock:
             learned = self._learned.get(self._plan_signature(plan, bindings))
         if learned is not None:
             return int(self.estimate_headroom * learned)
-        if any(isinstance(v, HostTableChunk) for v in bindings.values()):
+        if any(isinstance(v, _HOST_SIDE) for v in bindings.values()):
             base = sum(
-                v.nbytes if isinstance(v, HostTableChunk)
+                v.nbytes if isinstance(v, _HOST_SIDE)
                 else table_chip_nbytes(v)
                 for v in bindings.values())
         else:
@@ -1022,18 +1071,29 @@ class QueryServer:
             # (at SF10 that is 2.3 GB of HBM per waiting worker)
             ticket = None
 
-    def _stage_bindings(self, bindings: dict) -> dict:
+    def _stage_bindings(self, bindings: dict, cancel_token=None) -> dict:
         """Stage host-decoded chunk bindings to device tables on the
-        SHARED decode pool, concurrently across tables. Runs after
-        admission: the reservation already covers these bytes."""
+        SHARED decode pool, concurrently across tables, and decode and
+        stage every resolved Parquet split (``ParquetScan.stage``: its row
+        groups decode on the same pool while this thread copies the ones
+        that are ready). Runs after admission: the reservation already
+        covers these bytes."""
         futs = {
             name: self.decode_pool.submit(val.stage)
             for name, val in bindings.items()
             if isinstance(val, HostTableChunk)
         }
-        if not futs:
+        scans = {name: val for name, val in bindings.items()
+                 if isinstance(val, ParquetScan)}
+        if not futs and not scans:
             return bindings
         staged = dict(bindings)
+        for name, scan in scans.items():
+            table = scan.stage(self.decode_pool, cancel_token)
+            # the table is keyed by its source, as the request was: whoever
+            # fingerprints it later (a subplan prefix) digests nothing
+            table._resultcache_fp = resultcache.split_fingerprint(scan.split)
+            staged[name] = table
         for name, fut in futs.items():
             staged[name] = fut.result()
         return staged
@@ -1056,6 +1116,35 @@ class QueryServer:
         _log.info("query %s (session %s) cancelled: %s",
                   ticket.plan.name, sid, reason)
         ticket._resolve("cancelled", exc=exc)
+
+    def _failed(self, ticket: QueryTicket, exc: BaseException,
+                root) -> None:
+        """Resolve ``ticket`` ``failed`` with ``exc``, classified and
+        recorded under ``root`` (the request's open root span: the
+        worker's ``query.<plan>``, or ``submit.<plan>`` for a split whose
+        footer could not be read)."""
+        sid = ticket.session
+        kind = resilience.classify(exc, seam="server.execute").__name__
+        if isinstance(exc, resilience.MalformedInputError):
+            # untrusted-input rejection: this one query dies clean (no
+            # retry, no degradation); count it so operators can tell
+            # hostile inputs from bugs
+            REGISTRY.counter("integrity.malformed_rejects").inc()
+            record_integrity(ticket.plan.name, "malformed",
+                             seam="integrity.ingest", session=sid)
+        root.set_status("failed")
+        root.annotate(error_kind=kind)
+        flight = spans.dump_flight_record(
+            "failed", root=root, state=self._state_snapshot())
+        ticket.latency_s = time.monotonic() - ticket._submitted_at
+        self._count("failed", sid)
+        extra = {"flight_record": flight} if flight else {}
+        record_server(ticket.plan.name, "failed", session=sid,
+                      error_kind=kind,
+                      reason=str(exc) or type(exc).__name__, **extra)
+        _log.warning("query %s (session %s) failed classified as %s",
+                     ticket.plan.name, sid, kind)
+        ticket._resolve("failed", exc=exc)
 
     def _state_snapshot(self) -> dict:
         """Runtime state stamped into flight-recorder dumps: limiter
@@ -1182,7 +1271,8 @@ class QueryServer:
                                     plan=ticket.plan.name)
                         token.check("server.execute")
                         with spans.child("server.stage_bindings"):
-                            bindings = self._stage_bindings(ticket.bindings)
+                            bindings = self._stage_bindings(
+                                ticket.bindings, token)
                         runner = None if ticket.outofcore is None \
                             else ticket.outofcore(bindings, self.limiter)
                         # subplan-prefix reuse: shared scan+filter+project
@@ -1270,32 +1360,7 @@ class QueryServer:
                     # a dying query releases everything it holds (the
                     # finally below) and resolves CLASSIFIED — never a
                     # silent wedge
-                    kind = resilience.classify(
-                        exc, seam="server.execute").__name__
-                    if isinstance(exc, resilience.MalformedInputError):
-                        # untrusted-input rejection: this one query dies
-                        # clean (no retry, no degradation); count it so
-                        # operators can tell hostile inputs from bugs
-                        REGISTRY.counter("integrity.malformed_rejects").inc()
-                        record_integrity(
-                            ticket.plan.name, "malformed",
-                            seam="integrity.ingest", session=sid)
-                    qspan.set_status("failed")
-                    qspan.annotate(error_kind=kind)
-                    flight = spans.dump_flight_record(
-                        "failed", root=qspan, state=self._state_snapshot())
-                    ticket.latency_s = (
-                        time.monotonic() - ticket._submitted_at)
-                    self._count("failed", sid)
-                    extra = {"flight_record": flight} if flight else {}
-                    record_server(ticket.plan.name, "failed", session=sid,
-                                  error_kind=kind,
-                                  reason=str(exc) or type(exc).__name__,
-                                  **extra)
-                    _log.warning(
-                        "query %s (session %s) failed classified as %s",
-                        ticket.plan.name, sid, kind)
-                    ticket._resolve("failed", exc=exc)
+                    self._failed(ticket, exc, qspan)
                     if not isinstance(exc, Exception):
                         # KeyboardInterrupt etc: not the server's to absorb
                         raise

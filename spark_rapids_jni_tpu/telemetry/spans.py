@@ -12,11 +12,14 @@ whose root ``query.<plan>`` carries the same ``request`` and
 ``caused_by=<the submit span's id>``; every instrumented seam underneath
 attaches a child, so the two trees record::
 
-    submit.<plan> -> cache.fingerprint -> cache.fingerprint.{copy,hash}
+    submit.<plan> -> scan.footer (a Parquet split: parquet/split.py)
+                  -> cache.fingerprint -> cache.fingerprint.{copy,hash}
                   -> cache.lookup -> admission.enqueue
                   (a cache hit: -> query.<plan> -> cache.hit)
     query.<plan>  -> admission.queue -> admission.wait
-                  -> server.stage_bindings -> rung.* -> region.<plan>
+                  -> server.stage_bindings (-> scan -> scan.decode ->
+                     scan.decode.chunk on the pool's threads; scan.stage)
+                  -> rung.* -> region.<plan>
                      -> dispatch.{pad,compile,execute} / pipeline.chunk ->
                      pipeline.{decode,staging,transfer,compute,merge}
                      -> spill/unspill

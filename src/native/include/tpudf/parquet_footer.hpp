@@ -37,8 +37,12 @@ constexpr int16_t kRowGroups = 4;
 constexpr int16_t kColumnOrders = 7;
 // SchemaElement
 constexpr int16_t kSeType = 1;
+constexpr int16_t kSeTypeLength = 2;
+constexpr int16_t kSeRepetition = 3;
 constexpr int16_t kSeName = 4;
 constexpr int16_t kSeNumChildren = 5;
+constexpr int16_t kSeConvertedType = 6;
+constexpr int16_t kSeScale = 7;
 // RowGroup
 constexpr int16_t kRgColumns = 1;
 constexpr int16_t kRgNumRows = 3;
@@ -57,6 +61,15 @@ constexpr int16_t kCmDictionaryPageOffset = 11;
 // locale-dependent and self-described as "probably good enough"
 // (NativeParquetJni.cpp:40-77); this one is deterministic.
 std::string utf8_to_lower(std::string const& in);
+
+// One leaf of a (pruned) schema, as a reader maps it to a column type.
+struct LeafInfo {
+  int32_t physical = 0;
+  int32_t converted = -1;   // parquet ConvertedType, -1 = absent
+  int32_t scale = 0;
+  int32_t type_length = 0;
+  int32_t repetition = 0;   // 0 required, 1 optional, 2 repeated
+};
 
 // A parsed footer plus the operations the JNI surface exposes.
 class Footer {
@@ -87,6 +100,25 @@ class Footer {
   // prior prune_columns call.
   void filter_columns();
 
+  // What a reader needs to decode exactly what this footer kept: the
+  // file's indices of the surviving row groups and of the pruned leaves (in
+  // request order; a requested name the file lacks leaves no entry). Both
+  // are the file's own numbering, which parquet::read_file takes.
+  std::vector<int32_t> const& kept_row_groups() const { return kept_groups_; }
+  std::vector<int> const& kept_leaves() const { return chunk_gather_; }
+  // For each kept leaf, its position among the REQUEST's leaves: a
+  // position that is absent names a requested column the file lacks.
+  std::vector<int> const& kept_requests() const { return chunk_request_; }
+  int32_t file_leaves() const { return file_leaves_; }  // before any prune
+
+  // The schema's leaves in order (after prune_columns: request order).
+  std::vector<LeafInfo> leaves() const;
+  // Row counts of the remaining row groups, in order.
+  std::vector<int64_t> row_group_rows() const;
+  // Sum of total_compressed_size over every remaining column chunk (after
+  // filter_columns: the pruned leaves' chunks of the kept row groups).
+  int64_t compressed_bytes() const;
+
   int64_t num_rows() const;     // sum of remaining row-group num_rows
   int32_t num_columns() const;  // root schema element's num_children
 
@@ -101,6 +133,9 @@ class Footer {
   explicit Footer(thrift::Value meta) : meta_(std::move(meta)) {}
   thrift::Value meta_;
   std::vector<int> chunk_gather_;
+  std::vector<int> chunk_request_;
+  std::vector<int32_t> kept_groups_;  // file indices of meta_'s row groups
+  int32_t file_leaves_ = 0;
   bool pruned_ = false;
 };
 

@@ -150,6 +150,84 @@ int32_t tpudf_footer_num_columns(int64_t handle) {
   }
 }
 
+// What a filtered footer kept, in the file's own numbering (what
+// tpudf_parquet_read* takes as rgs / cols): the surviving row groups with
+// their row counts, then the pruned leaves in request order. Each returns
+// the count (which may exceed `cap`: nothing past `cap` is written), -1
+// on error.
+int32_t tpudf_footer_row_groups(int64_t handle, int32_t* index,
+                                int64_t* num_rows, int32_t cap) {
+  try {
+    auto f = footers().get(handle);
+    if (f == nullptr) throw std::invalid_argument("invalid footer handle");
+    auto const& kept = f->kept_row_groups();
+    auto rows = f->row_group_rows();
+    if (rows.size() != kept.size()) {
+      throw std::logic_error("row group bookkeeping out of step");
+    }
+    int32_t n = static_cast<int32_t>(kept.size());
+    for (int32_t i = 0; i < n && i < cap; ++i) {
+      index[i] = kept[i];
+      num_rows[i] = rows[i];
+    }
+    return n;
+  } catch (std::exception const& e) {
+    set_error(e.what());
+    return -1;
+  }
+}
+
+// For each kept leaf: request[k] its position among the request's leaves,
+// leaf_index[k] the file's leaf index, meta[5k..] physical, converted
+// (-1 = absent), scale, type_length, repetition.
+int32_t tpudf_footer_leaves(int64_t handle, int32_t* request,
+                            int32_t* leaf_index, int32_t* meta, int32_t cap) {
+  try {
+    auto f = footers().get(handle);
+    if (f == nullptr) throw std::invalid_argument("invalid footer handle");
+    auto const& kept = f->kept_leaves();
+    auto const& asked = f->kept_requests();
+    auto leaves = f->leaves();
+    if (leaves.size() != kept.size() || asked.size() != kept.size()) {
+      throw std::logic_error("footer was not pruned by name");
+    }
+    int32_t n = static_cast<int32_t>(kept.size());
+    for (int32_t i = 0; i < n && i < cap; ++i) {
+      request[i] = asked[i];
+      leaf_index[i] = kept[i];
+      meta[5 * i + 0] = leaves[i].physical;
+      meta[5 * i + 1] = leaves[i].converted;
+      meta[5 * i + 2] = leaves[i].scale;
+      meta[5 * i + 3] = leaves[i].type_length;
+      meta[5 * i + 4] = leaves[i].repetition;
+    }
+    return n;
+  } catch (std::exception const& e) {
+    set_error(e.what());
+    return -1;
+  }
+}
+
+// The leaves the file's schema had before any prune.
+int32_t tpudf_footer_file_leaves(int64_t handle) {
+  auto f = footers().get(handle);
+  if (f == nullptr) {
+    set_error("invalid footer handle");
+    return -1;
+  }
+  return f->file_leaves();
+}
+
+// Compressed bytes of the column chunks the footer kept; -1 on error.
+int64_t tpudf_footer_compressed_bytes(int64_t handle) {
+  auto f = footers().get(handle);
+  if (f == nullptr) {
+    set_error("invalid footer handle");
+    return -1;
+  }
+  return f->compressed_bytes();
+}
+
 // Serialize with PAR1 framing into a malloc'd buffer the caller frees with
 // tpudf_free_buffer. Returns 0 on success.
 int32_t tpudf_footer_serialize(int64_t handle, uint8_t** out,

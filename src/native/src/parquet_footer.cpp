@@ -100,7 +100,14 @@ std::string utf8_to_lower(std::string const& in) {
 }
 
 Footer Footer::parse(uint8_t const* buf, uint64_t len) {
-  return Footer(thrift::parse_struct(buf, len));
+  Footer footer(thrift::parse_struct(buf, len));
+  if (Value const* groups = footer.meta_.field(fid::kRowGroups)) {
+    for (size_t g = 0; g < groups->elems.size(); ++g) {
+      footer.kept_groups_.push_back(static_cast<int32_t>(g));
+    }
+  }
+  footer.file_leaves_ = static_cast<int32_t>(footer.leaves().size());
+  return footer;
 }
 
 namespace {
@@ -155,6 +162,7 @@ struct PruneMaps {
   std::vector<int> schema_gather;       // output schema pos -> input index
   std::vector<int> schema_num_children; // new num_children per output pos
   std::vector<int> chunk_gather;        // output chunk pos -> input leaf idx
+  std::vector<int> chunk_request;       // output chunk pos -> request leaf id
 };
 
 // One pass over the flattened file schema, matching against the request
@@ -217,7 +225,10 @@ PruneMaps compute_prune_maps(Value const& schema_list, RequestNode& request,
   for (auto const& [_, v] : num_children_map) {
     maps.schema_num_children.push_back(v);
   }
-  for (auto const& [_, v] : chunk_map) maps.chunk_gather.push_back(v);
+  for (auto const& [c_id, v] : chunk_map) {
+    maps.chunk_gather.push_back(v);
+    maps.chunk_request.push_back(c_id);
+  }
   return maps;
 }
 
@@ -270,6 +281,7 @@ void Footer::prune_columns(std::vector<std::string> const& names,
   }
 
   chunk_gather_ = std::move(maps.chunk_gather);
+  chunk_request_ = std::move(maps.chunk_request);
   pruned_ = true;
 }
 
@@ -313,7 +325,9 @@ void Footer::filter_row_groups(int64_t part_offset, int64_t part_length) {
   int64_t prev_start = 0;
   int64_t prev_compressed = 0;
   std::vector<Value> kept;
-  for (Value& rg : groups->elems) {
+  std::vector<int32_t> kept_index;
+  for (size_t g = 0; g < groups->elems.size(); ++g) {
+    Value& rg = groups->elems[g];
     int64_t start;
     if (use_chunk_meta) {
       Value const* cols = rg.field(fid::kRgColumns);
@@ -354,9 +368,67 @@ void Footer::filter_row_groups(int64_t part_offset, int64_t part_length) {
     int64_t mid_point = start + total_size / 2;
     if (mid_point >= part_offset && mid_point < part_offset + part_length) {
       kept.push_back(std::move(rg));
+      kept_index.push_back(kept_groups_[g]);
     }
   }
   groups->elems = std::move(kept);
+  kept_groups_ = std::move(kept_index);
+}
+
+std::vector<LeafInfo> Footer::leaves() const {
+  std::vector<LeafInfo> out;
+  Value const* schema = meta_.field(fid::kSchema);
+  if (schema == nullptr) return out;
+  for (size_t idx = 1; idx < schema->elems.size(); ++idx) {
+    Value const& se = schema->elems[idx];
+    Value const* type = se.field(fid::kSeType);
+    if (type == nullptr) continue;  // a group, not a leaf
+    LeafInfo leaf;
+    leaf.physical = static_cast<int32_t>(type->i);
+    if (Value const* f = se.field(fid::kSeConvertedType)) {
+      leaf.converted = static_cast<int32_t>(f->i);
+    }
+    if (Value const* f = se.field(fid::kSeScale)) {
+      leaf.scale = static_cast<int32_t>(f->i);
+    }
+    if (Value const* f = se.field(fid::kSeTypeLength)) {
+      leaf.type_length = static_cast<int32_t>(f->i);
+    }
+    if (Value const* f = se.field(fid::kSeRepetition)) {
+      leaf.repetition = static_cast<int32_t>(f->i);
+    }
+    out.push_back(std::move(leaf));
+  }
+  return out;
+}
+
+std::vector<int64_t> Footer::row_group_rows() const {
+  std::vector<int64_t> out;
+  if (Value const* groups = meta_.field(fid::kRowGroups)) {
+    for (Value const& rg : groups->elems) {
+      Value const* n = rg.field(fid::kRgNumRows);
+      out.push_back(n ? n->i : 0);
+    }
+  }
+  return out;
+}
+
+int64_t Footer::compressed_bytes() const {
+  int64_t total = 0;
+  Value const* groups = meta_.field(fid::kRowGroups);
+  if (groups == nullptr) return total;
+  for (Value const& rg : groups->elems) {
+    Value const* cols = rg.field(fid::kRgColumns);
+    if (cols == nullptr) continue;
+    for (Value const& cc : cols->elems) {
+      if (Value const* md = cc.field(fid::kCcMetaData)) {
+        if (Value const* f = md->field(fid::kCmTotalCompressedSize)) {
+          total += f->i;
+        }
+      }
+    }
+  }
+  return total;
 }
 
 int64_t Footer::num_rows() const {
