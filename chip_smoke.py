@@ -156,8 +156,9 @@ def check_counters(*, tickets=(), native_kernels: bool = True) -> None:
     a query and the device stayed unused."""
     c = _counters()
     for name in ("dispatch.compile_error", "dispatch.exec_error",
-                 "dispatch.inline.compile_error",
-                 "dispatch.inline.exec_error", "fusion.staged_regions",
+                 "dispatch.pad_error", "dispatch.inline.compile_error",
+                 "dispatch.inline.exec_error", "dispatch.inline.pad_error",
+                 "fusion.staged_regions",
                  "resilience.rung.host_fallback",
                  "resilience.rung.staged_fallback", "degrade.step"):
         if c.get(name, 0) != 0:

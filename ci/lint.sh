@@ -35,8 +35,9 @@ python -m tools.tpulint spark_rapids_jni_tpu tools
 # dispatch smoke: the jit-via-dispatch rule only proves ops ROUTE through
 # runtime/dispatch — this proves the cache actually coalesces shapes.
 # Two row counts in one bucket (513 and 1000 both pad to 1024) must
-# produce exactly ONE compile; a second compile means bucketing broke
+# produce exactly ONE compile of the op; a second means bucketing broke
 # and every distinct row count is back to paying full trace+compile.
+# (The pad before it is one small executable an exact row count.)
 JAX_PLATFORMS=cpu python - <<'EOF'
 import numpy as np
 
@@ -48,8 +49,8 @@ for n in (513, 1000):
     total, ok = red.sum_(Column.from_numpy(np.arange(n, dtype=np.int64)))
     assert bool(ok) and int(total) == n * (n - 1) // 2, n
 
-compiles = REGISTRY.counter("dispatch.compile").value
-hits = REGISTRY.counter("dispatch.hit").value
+compiles = REGISTRY.counter("dispatch.compile.reduce_sum").value
+hits = REGISTRY.counter("dispatch.hit.reduce_sum").value
 assert compiles == 1, f"expected 1 compile for one bucket, got {compiles}"
 assert hits == 1, f"expected 1 cache hit, got {hits}"
 print(f"dispatch smoke OK: 2 row counts, {compiles} compile, {hits} hit")

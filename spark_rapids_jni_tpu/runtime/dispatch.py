@@ -9,7 +9,12 @@ from a geometric schedule, an explicit ``row_valid`` mask (the ``n_valid``
 scalar in vector form) keeps padded tail rows out of results and
 reductions, and the compiled executable is memoized under
 ``(op, statics digest, leaf shapes/dtypes/shardings, backend)`` so every
-batch size inside a bucket reuses one executable.
+batch size inside a bucket reuses one executable. The pad is itself one
+cached executable a call (``_pad_groups``, device module ``jit_pad``: one
+host call whatever the number of columns), keyed on the rows it pads and
+not on the op, one an exact row count (a copy: a fraction of a second to
+compile); ``dispatch.pad.jitted`` / ``.passthrough`` count the calls whose
+rows went through it and those whose rows all sat on their bucket.
 
 Compilation is explicit — ``jax.jit(fn).lower(args).compile()`` — rather
 than delegated to jit's internal cache, so compiles and hits are exact,
@@ -24,9 +29,10 @@ import), with JAX's own persistence thresholds.
 
 Fail-safe posture: anything this layer cannot bucket or compile — tracer
 inputs (the op is already inside a caller's trace), Arrow-layout strings,
-nested columns, zero-row batches, lowering errors — falls back to calling
-the op's implementation directly, with the reason counted. Dispatch must
-never change what an op computes, only how often XLA compiles it.
+nested columns, zero-row batches, lowering errors, a pad that fails —
+falls back to calling the op's implementation directly, with the reason
+counted. Dispatch must never change what an op computes, only how often
+XLA compiles it.
 
 Config knobs (utils/config.py): ``dispatch.enabled``,
 ``dispatch.bucket_base``, ``dispatch.max_waste_frac``.
@@ -40,7 +46,7 @@ import threading
 import time
 import warnings
 from functools import partial
-from typing import Any, Callable, Optional, Sequence
+from typing import Any, Callable, Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -156,24 +162,36 @@ class _PadStats:
         self.copied_bytes = 0
 
 
-def _pad_array(x: Any, n: int, B: int, acc: _PadStats) -> Any:
+def _row_bytes(x: Any, n: int) -> int:
+    """Bytes a row of one data leaf of an ``n``-row group."""
     if not _is_array(x):
         raise Unbucketable(f"non-array leaf {type(x).__name__}")
     if x.ndim < 1 or x.shape[0] != n:
         raise Unbucketable(
             f"leading dim {x.shape} != row count {n}")
-    row_bytes = int(np.dtype(x.dtype).itemsize) * int(
+    return int(np.dtype(x.dtype).itemsize) * int(
         math.prod(x.shape[1:]) if x.ndim > 1 else 1)
+
+
+def _zero_tail(x: jax.Array, B: int) -> jax.Array:
+    """``x`` at the head of ``B`` zeroed rows. Written into zeros rather
+    than concatenated with them: alone in a jit the least device time of
+    the forms measured (``PERF.md`` section 6, PR 35)."""
+    return jax.lax.dynamic_update_slice(
+        jnp.zeros((B,) + tuple(x.shape[1:]), x.dtype), x, (0,) * x.ndim)
+
+
+def _pad_array(x: Any, n: int, B: int, acc: _PadStats) -> Any:
+    row_bytes = _row_bytes(x, n)
     acc.padded_bytes += (B - n) * row_bytes
     acc.total_bytes += B * row_bytes
     if B == n:
         return jnp.asarray(x)
     acc.copied_bytes += B * row_bytes
-    pad = jnp.zeros((B - n,) + tuple(x.shape[1:]), dtype=x.dtype)
-    return jnp.concatenate([jnp.asarray(x), pad], axis=0)
+    return _zero_tail(jnp.asarray(x), B)
 
 
-def _pad_column(col: Column, n: int, B: int, acc: _PadStats) -> Column:
+def _check_column(col: Column, n: int) -> None:
     if col.children is not None or col.dtype.type_id in (
             TypeId.LIST, TypeId.STRUCT):
         raise Unbucketable("nested (LIST/STRUCT) column")
@@ -181,35 +199,67 @@ def _pad_column(col: Column, n: int, B: int, acc: _PadStats) -> Column:
         raise Unbucketable("arrow-layout string column")
     if col.size != n:
         raise Unbucketable(f"column size {col.size} != row count {n}")
+
+
+def _pad_column(col: Column, n: int, B: int, acc: _PadStats,
+                fills: Optional[Iterator] = None) -> Column:
+    _check_column(col, n)
     data = _pad_array(col.data, n, B, acc)
-    # padded tail rows are NULL rows: every op's null semantics already
-    # neutralize them (sums add 0, min/max see sentinels, sorts rank them
-    # by the row_valid key, counts skip them)
-    validity = jnp.concatenate(
-        [col.valid_mask(), jnp.zeros((B - n,), jnp.bool_)])
+    if fills is not None:
+        # on its bucket: the validity it has, or a ready all-true mask
+        validity = col.validity if col.validity is not None else next(fills)
+    else:
+        # padded tail rows are NULL rows: every op's null semantics already
+        # neutralize them (sums add 0, min/max see sentinels, sorts rank
+        # them by the row_valid key, counts skip them)
+        validity = _zero_tail(col.valid_mask(), B)
     chars = None
     if col.chars is not None:
         chars = _pad_array(col.chars, n, B, acc)
     return Column(col.dtype, data, validity, chars=chars)
 
 
-def _pad_tree(x: Any, n: int, B: int, acc: _PadStats) -> Any:
+def _pad_tree(x: Any, n: int, B: int, acc: _PadStats,
+              fills: Optional[Iterator] = None) -> Any:
+    """``x`` with every leaf padded from ``n`` to ``B`` rows. ``fills`` (only
+    with ``B == n``, on the host): every leaf is handed on as it is and a
+    Column without a validity takes the next mask of ``fills``."""
     if x is None:
         return None
     if isinstance(x, Column):
-        return _pad_column(x, n, B, acc)
+        return _pad_column(x, n, B, acc, fills)
     if isinstance(x, Table):
-        return Table([_pad_column(c, n, B, acc) for c in x.columns])
+        return Table([_pad_column(c, n, B, acc, fills) for c in x.columns])
     if _is_array(x):
         return _pad_array(x, n, B, acc)
     if isinstance(x, tuple):
-        vals = [_pad_tree(v, n, B, acc) for v in x]
+        vals = [_pad_tree(v, n, B, acc, fills) for v in x]
         return type(x)(*vals) if hasattr(x, "_fields") else tuple(vals)
     if isinstance(x, list):
-        return [_pad_tree(v, n, B, acc) for v in x]
+        return [_pad_tree(v, n, B, acc, fills) for v in x]
     if isinstance(x, dict):
-        return {k: _pad_tree(v, n, B, acc) for k, v in x.items()}
+        return {k: _pad_tree(v, n, B, acc, fills) for k, v in x.items()}
     raise Unbucketable(f"non-array leaf {type(x).__name__}")
+
+
+def _survey(group: Any, n: int) -> tuple:
+    """What the host reads off one ``n``-row group before the pad runs:
+    ``(bytes a row of its data leaves, Columns without a validity)``. The
+    data leaves are what ``_pad_array`` copies (a Column's data and chars, a
+    bare array; no mask). Raises ``Unbucketable`` for what ``_pad_tree``
+    refuses, so such a group costs neither a trace nor a compile."""
+    row_bytes = bare = 0
+    for x in jax.tree_util.tree_leaves(
+            group, is_leaf=lambda v: isinstance(v, Column)):
+        if isinstance(x, Column):
+            _check_column(x, n)
+            bare += x.validity is None
+            row_bytes += _row_bytes(x.data, n)
+            if x.chars is not None:
+                row_bytes += _row_bytes(x.chars, n)
+        else:
+            row_bytes += _row_bytes(x, n)
+    return row_bytes, bare
 
 
 def _slice_column(col: Column, n: int, B: int) -> Column:
@@ -355,6 +405,50 @@ def _inline(op: str, reason: str, fn: Callable, row_args: tuple,
     return fn(row_args, aux_args, None)
 
 
+def _pad_groups(row_args: tuple, ns: tuple, buckets: tuple) -> tuple:
+    """The bucketed pad of :func:`call`: ``(padded groups, row_valids, bytes
+    a row of each group's data leaves)``, the first two from ONE cached
+    executable: one host call whatever the number of leaves. A group off
+    its bucket goes through it whole (``_pad_tree``, traced once). A group
+    on its bucket stays out of its data path: its leaves are handed on as
+    they are (a jit would copy them, and ``donate_rows`` relies on the
+    alias) and the executable builds only its masks, an all-true validity
+    for each of its Columns without one among them. Keyed on what a pad
+    depends on and nothing else: the groups' signature, the row counts, the
+    buckets and the backend, not the op, so two ops over one column share
+    it. One executable an exact row count: it is a copy and compiles in a
+    fraction of a second. Raises ``Unbucketable`` for what cannot be
+    padded, and what compiling or running it raises."""
+    surveyed = tuple(_survey(g, n) for g, n in zip(row_args, ns))
+    off = tuple(g if B != n else None
+                for g, n, B in zip(row_args, ns, buckets))
+    bare = tuple(k if B == n else 0
+                 for (_, k), n, B in zip(surveyed, ns, buckets))
+
+    def pad(groups):   # traced once, by the call that compiles it
+        unread = _PadStats()   # call reckons the bytes from the shapes
+        padded = tuple(None if g is None else _pad_tree(g, n, B, unread)
+                       for g, n, B in zip(groups, ns, buckets))
+        row_valids = tuple(jnp.arange(B, dtype=jnp.int32) < jnp.int32(n)
+                           for n, B in zip(ns, buckets))
+        fills = tuple(tuple(jnp.ones((n,), jnp.bool_) for _ in range(k))
+                      for n, k in zip(ns, bare))
+        return padded, row_valids, fills
+
+    pad.__name__ = pad.__qualname__ = "pad"   # the device module: jit_pad
+    executable = compiled("pad", pad, off, statics=(
+        ns, buckets, bare, jax.default_backend()))
+    padded, row_valids, fills = executable(off)
+    padded = list(padded)
+    for i, (group, n, B, fill) in enumerate(
+            zip(row_args, ns, buckets, fills)):
+        if B == n:
+            padded[i] = _pad_tree(group, n, n, _PadStats(), iter(fill))
+    REGISTRY.counter("dispatch.pad.jitted" if any(
+        g is not None for g in off) else "dispatch.pad.passthrough").inc()
+    return tuple(padded), row_valids, tuple(row for row, _ in surveyed)
+
+
 def call(
     op: str,
     fn: Callable,
@@ -412,18 +506,16 @@ def call(
         return _inline(op, "empty", fn, row_args, aux_args)
 
     buckets = tuple(bucket_for(n) for n in ns) if bucket_rows else ns
-    acc = _PadStats()
     try:
-        # eager device ops: the padded copy of every leaf and the masks
         with spans.child("dispatch.pad", op=op):
-            padded = tuple(
-                _pad_tree(g, n, B, acc)
-                for g, n, B in zip(row_args, ns, buckets))
-            row_valids = tuple(
-                jnp.arange(B, dtype=jnp.int32) < jnp.int32(n)
-                for n, B in zip(ns, buckets))
+            padded, row_valids, row_bytes = _pad_groups(
+                row_args, ns, buckets)
     except Unbucketable:
         return _inline(op, "unbucketable", fn, row_args, aux_args)
+    except Exception:
+        # a pad that does not compile or run: the op still answers
+        REGISTRY.counter("dispatch.pad_error").inc()
+        return _inline(op, "pad_error", fn, row_args, aux_args)
 
     key = (op, statics, donate_rows, _kernels_digest(),
            _signature((padded, aux_args, row_valids)),
@@ -484,13 +576,18 @@ def call(
         REGISTRY.counter("dispatch.exec_error").inc()
         return _inline(op, "exec_error", fn, row_args, aux_args)
 
+    # arithmetic on shapes: a leaf on its bucket is handed on, not copied
+    sized = tuple(zip(ns, buckets, row_bytes))
+    total_bytes = sum(B * row for _, B, row in sized)
     REGISTRY.counter("dispatch.padded_rows").inc(
-        sum(B - n for n, B in zip(ns, buckets)))
-    REGISTRY.counter("dispatch.padded_waste_bytes").inc(acc.padded_bytes)
-    REGISTRY.counter("dispatch.padded_copy_bytes").inc(acc.copied_bytes)
-    REGISTRY.counter("dispatch.row_bytes_total").inc(acc.total_bytes)
+        sum(B - n for n, B, _ in sized))
+    REGISTRY.counter("dispatch.padded_waste_bytes").inc(
+        sum((B - n) * row for n, B, row in sized))
+    REGISTRY.counter("dispatch.padded_copy_bytes").inc(
+        sum(B * row for n, B, row in sized if B != n))
+    REGISTRY.counter("dispatch.row_bytes_total").inc(total_bytes)
     if donate_rows:
-        REGISTRY.counter("dispatch.donated_bytes").inc(acc.total_bytes)
+        REGISTRY.counter("dispatch.donated_bytes").inc(total_bytes)
     if slice_rows:
         out = _slice_tree(out, ns[0], buckets[0])
     return out

@@ -18,6 +18,8 @@ Three invariant families:
    the config knobs that drive them.
 """
 
+import collections
+
 import numpy as np
 import pytest
 
@@ -182,8 +184,12 @@ def test_groupby_all_null_tail_rows(rng):
 
 
 def test_one_bucket_compiles_exactly_once(rng):
-    """>=8 distinct row counts inside one bucket -> exactly 1 compile;
-    the un-migrated path would have compiled once per row count."""
+    """>=8 distinct row counts inside one bucket -> the op compiles exactly
+    ONCE; the un-migrated path would have compiled once per row count. The
+    pad compiles eight times: it is one executable an exact row count (a
+    copy, a fraction of a second each), as the eager ``zeros`` and
+    ``concatenate`` it replaces compiled once a shape unseen by any
+    counter. 1024 sits on its bucket: its pad builds masks only."""
     counts = (513, 600, 649, 700, 801, 900, 1000, 1024)  # all -> bucket 1024
     results = []
     for n in counts:
@@ -192,8 +198,14 @@ def test_one_bucket_compiles_exactly_once(rng):
         results.append(int(total))
         assert bool(ok)
     assert results == [n * (n - 1) // 2 for n in counts]
-    assert REGISTRY.counter("dispatch.compile").value == 1
-    assert REGISTRY.counter("dispatch.hit").value == len(counts) - 1
+    c = REGISTRY.counters("dispatch.")
+    assert c["dispatch.compile.reduce_sum"] == 1
+    assert c["dispatch.hit.reduce_sum"] == len(counts) - 1
+    assert c["dispatch.compile.pad"] == len(counts)
+    assert "dispatch.hit.pad" not in c
+    assert c["dispatch.compile"] == 1 + len(counts)
+    assert (c["dispatch.pad.jitted"], c["dispatch.pad.passthrough"]) == (
+        len(counts) - 1, 1)
 
 
 def test_distinct_buckets_and_dtypes_compile_separately():
@@ -202,12 +214,16 @@ def test_distinct_buckets_and_dtypes_compile_separately():
     c = Column.from_numpy(np.arange(10, dtype=np.int32))   # other dtype
     for col in (a, b, c):
         red.sum_(col)
-    assert REGISTRY.counter("dispatch.compile").value == 3
-    # same shapes again: all hits
+    counters = REGISTRY.counters("dispatch.")
+    assert counters["dispatch.compile.reduce_sum"] == 3
+    assert counters["dispatch.compile.pad"] == 3
+    # same shapes again: all hits, of the op and of its pad
     for col in (a, b, c):
         red.sum_(col)
-    assert REGISTRY.counter("dispatch.compile").value == 3
-    assert REGISTRY.counter("dispatch.hit").value == 3
+    counters = REGISTRY.counters("dispatch.")
+    assert counters["dispatch.compile"] == 6
+    assert counters["dispatch.hit.reduce_sum"] == 3
+    assert counters["dispatch.hit.pad"] == 3
 
 
 def test_statics_change_recompiles(rng):
@@ -216,14 +232,17 @@ def test_statics_change_recompiles(rng):
     sort_order(tbl, [0], ascending=[True])
     before = REGISTRY.counter("dispatch.compile").value
     # same shapes + op, different static (sort direction): a fresh compile
+    # of the op; the pad knows no statics and is a hit
     sort_order(tbl, [0], ascending=[False])
     assert REGISTRY.counter("dispatch.compile").value == before + 1
-    # and re-running either direction is a pure hit
+    assert REGISTRY.counter("dispatch.compile.pad").value == 1
+    # and re-running either direction is a pure hit (the op's and the pad's)
     hits = REGISTRY.counter("dispatch.hit").value
     sort_order(tbl, [0], ascending=[True])
     sort_order(tbl, [0], ascending=[False])
     assert REGISTRY.counter("dispatch.compile").value == before + 1
-    assert REGISTRY.counter("dispatch.hit").value == hits + 2
+    assert REGISTRY.counter("dispatch.hit").value == hits + 4
+    assert REGISTRY.counter("dispatch.hit.pad").value == 3
 
 
 def test_disabled_dispatch_never_compiles(rng):
@@ -316,8 +335,13 @@ def test_concurrent_first_compile_is_single_flight():
             th.join(60)
     assert not errors
     assert results == [1000 * 999 // 2] * n_threads
-    assert REGISTRY.counter("dispatch.compile").value == 1
-    assert REGISTRY.counter("dispatch.hit").value == n_threads - 1
+    # the op and, before it, its pad: one leader each
+    c = REGISTRY.counters("dispatch.")
+    assert (c["dispatch.compile.reduce_sum"], c["dispatch.compile.pad"]) == (
+        1, 1)
+    assert c["dispatch.hit.reduce_sum"] == n_threads - 1
+    assert c["dispatch.hit.pad"] == n_threads - 1
+    assert c["dispatch.compile"] == 2
 
 
 def _total(x):
@@ -344,3 +368,164 @@ def test_compiled_memoizes_one_executable_an_exact_shape(n):
     dispatch.compiled("total", _total, x.astype(jnp.int32))
     assert REGISTRY.counters()["dispatch.compile.total"] == 3
     assert dispatch.cache_size() == 3
+
+
+# ---------------------------------------------------------------------------
+# 4. the pad: one cached executable a call, equal to the eager pad
+# ---------------------------------------------------------------------------
+
+
+def _ints(n, dtype=np.int64, seed=7):
+    return np.random.default_rng(seed + n).integers(-99, 99, n).astype(dtype)
+
+
+def _col(n, nulls):
+    validity = (np.arange(n) % 5 != 3) if nulls else None
+    return Column.from_numpy(_ints(n), validity=validity)
+
+
+def _strings(n):
+    from spark_rapids_jni_tpu.ops.strings import pad_strings
+
+    return pad_strings(Column.from_pylist(
+        [None if i % 7 == 2 else "ab" * (i % 4) for i in range(n)], t.STRING))
+
+
+def _arrays(n):
+    import jax.numpy as jnp
+
+    return (jnp.asarray(_ints(n, np.int32)),
+            jnp.asarray(_ints(2 * n).reshape(n, 2).astype(np.float64)))
+
+
+_Pair = collections.namedtuple("_Pair", "left right")
+
+# name -> the row groups of one call (built inside the test: device arrays)
+_PAD_CASES = {
+    "column_with_validity": lambda: (_col(21, True),),
+    "column_without_validity": lambda: (_col(21, False),),
+    "padded_string_column": lambda: (_strings(19),),
+    "table": lambda: (Table([_col(40, True), _col(40, False),
+                             Column.from_numpy(_ints(40, np.int32))]),),
+    "tuple": lambda: (_arrays(33) + (None,),),
+    "namedtuple": lambda: (_Pair(*_arrays(33)),),
+    "list": lambda: (list(_arrays(17)),),
+    "dict": lambda: (dict(zip("ba", _arrays(100))),),
+    "numpy_leaf": lambda: ((_ints(50), _arrays(50)[0]),),
+    "two_groups": lambda: (Table([_col(21, True)]), _col(100, False)),
+    "on_its_bucket": lambda: (Table([_col(32, True), _col(32, False),
+                                     _strings(32)]), _arrays(64)),
+    "one_group_on_its_bucket": lambda: ((_col(64, False), _arrays(64)[0]),
+                                        _col(65, False)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PAD_CASES))
+def test_jitted_pad_equals_the_eager_pad_leaf_for_leaf(case):
+    """``_pad_groups`` (one executable) against ``_pad_tree`` run eagerly,
+    the pad as it was: the same tree, every leaf the same shape, dtype and
+    bits, the same masks; the bytes ``call`` reckons from the shapes are
+    those the eager pad counted; a group on its bucket keeps its buffers."""
+    import jax
+
+    row_args = _PAD_CASES[case]()
+    ns = tuple(dispatch._group_rows(g) for g in row_args)
+    buckets = tuple(dispatch.bucket_for(n) for n in ns)
+    padded, row_valids, row_bytes = dispatch._pad_groups(
+        row_args, ns, buckets)
+
+    acc = dispatch._PadStats()
+    want = tuple(dispatch._pad_tree(g, n, B, acc)
+                 for g, n, B in zip(row_args, ns, buckets))
+    got_leaves, got_tree = jax.tree_util.tree_flatten(padded)
+    want_leaves, want_tree = jax.tree_util.tree_flatten(want)
+    assert got_tree == want_tree
+    for got, leaf in zip(got_leaves, want_leaves):
+        assert isinstance(got, jax.Array)
+        assert (got.shape, got.dtype) == (leaf.shape, leaf.dtype)
+        assert np.array_equal(np.asarray(got), np.asarray(leaf))
+    for out, n in zip(padded, ns):   # the tail is zeros: NULL rows
+        for got in jax.tree_util.tree_leaves(out):
+            assert not np.asarray(got)[n:].any()
+    assert len(row_valids) == len(row_args)
+    for mask, n, B in zip(row_valids, ns, buckets):
+        assert mask.dtype == np.bool_
+        assert np.array_equal(np.asarray(mask), np.arange(B) < n)
+
+    sized = list(zip(ns, buckets, row_bytes))
+    assert sum(B * row for _, B, row in sized) == acc.total_bytes
+    assert sum((B - n) * row for n, B, row in sized) == acc.padded_bytes
+    assert sum(B * row for n, B, row in sized if B != n) == acc.copied_bytes
+
+    for group, out, n, B in zip(row_args, padded, ns, buckets):
+        if n == B:   # handed on, not copied: donate_rows relies on it
+            held = {id(x) for x in jax.tree_util.tree_leaves(out)}
+            assert all(id(x) in held
+                       for x in jax.tree_util.tree_leaves(group))
+    on_bucket = all(n == B for n, B in zip(ns, buckets))
+    c = REGISTRY.counters("dispatch.pad.")
+    assert c == {"dispatch.pad.passthrough" if on_bucket
+                 else "dispatch.pad.jitted": 1}
+    assert REGISTRY.counter("dispatch.compile.pad").value == 1
+    # again: the same executable, whatever the values
+    dispatch._pad_groups(row_args, ns, buckets)
+    assert REGISTRY.counter("dispatch.compile.pad").value == 1
+    assert REGISTRY.counter("dispatch.hit.pad").value == 1
+
+
+def _unbucketable(case):
+    import jax.numpy as jnp
+
+    if case == "nested":
+        return Column(t.DType(t.TypeId.LIST),
+                      jnp.asarray([0, 2, 2, 5], jnp.int32), None,
+                      children=[Column.from_numpy(_ints(5))])
+    if case == "arrow_string":
+        return Column.from_pylist(["a", "bc", None], t.STRING)
+    if case == "mismatched_rows":
+        return (_arrays(20)[0], _arrays(21)[0])
+    return (_arrays(20)[0], 3)   # a leaf that is no array
+
+
+@pytest.mark.parametrize("case", ["nested", "arrow_string",
+                                  "mismatched_rows", "scalar_leaf"])
+def test_unbucketable_inputs_still_go_inline(case):
+    """What the pad cannot represent is found on the host before anything
+    is traced: the op runs inline, nothing compiles."""
+    group = _unbucketable(case)
+    out = dispatch.call("probe", lambda rows, aux, rvs: (rows, rvs), (group,))
+    assert out[0][0] is group and out[1] is None
+    c = REGISTRY.counters("dispatch.")
+    assert c["dispatch.inline.unbucketable"] == 1
+    assert "dispatch.compile" not in c and "dispatch.pad.jitted" not in c
+
+
+def test_two_ops_over_one_column_share_one_pad(rng):
+    """The pad is keyed on the rows it pads, not on the op."""
+    col = _int_col(rng, 600)
+    red.sum_(col)
+    red.min_(col)
+    e.abs_(col)
+    c = REGISTRY.counters("dispatch.")
+    assert (c["dispatch.compile.pad"], c["dispatch.hit.pad"]) == (1, 2)
+    assert c["dispatch.pad.jitted"] == 3
+
+
+def test_a_pad_that_fails_runs_the_op_inline(rng, monkeypatch):
+    """``call`` never raises on the pad's behalf and keeps no second pad:
+    the op answers un-jitted over the rows as they came."""
+    col = _int_col(rng, 600)
+    want = red.sum_(col)
+    real = dispatch.compiled
+
+    def broken(op, fn, *args, **kw):
+        if op == "pad":
+            raise RuntimeError("injected pad failure")
+        return real(op, fn, *args, **kw)
+
+    monkeypatch.setattr(dispatch, "compiled", broken)
+    got = red.sum_(col)
+    assert (int(got[0]), bool(got[1])) == (int(want[0]), bool(want[1]))
+    c = REGISTRY.counters("dispatch.")
+    assert (c["dispatch.pad_error"], c["dispatch.inline.pad_error"]) == (1, 1)
+    assert c["dispatch.pad.jitted"] == 1   # the first call's

@@ -198,22 +198,29 @@ def test_one_executable_per_region_per_bucket():
     assert st["regions"] == 4 and st["staged_regions"] == 0
     assert st["executables"] == 1, st
     assert st["executables_per_query"] == {"tpch_q1": 1}
-    assert REGISTRY.counter("dispatch.hit").value == 3
+    c = REGISTRY.counters("dispatch.")
+    assert c["dispatch.hit.fusion.tpch_q1"] == 3
+    # one pad an exact row count; 32 sits on its bucket (masks only)
+    assert c["dispatch.compile.pad"] == 4 and "dispatch.hit.pad" not in c
+    assert (c["dispatch.pad.jitted"], c["dispatch.pad.passthrough"]) == (3, 1)
 
 
 def test_fused_compiles_fewer_executables_than_staged():
     """The whole point: the staged q1 pays one executable per op
     (groupby machinery, sort, gather...); the fused region pays ONE."""
+    def compiles():   # (the ops' executables, the pads' beside them)
+        c = REGISTRY.counters("dispatch.compile.")
+        pads = c.pop("dispatch.compile.pad", 0)
+        return sum(c.values()), pads
+
     li = tpch.lineitem_table(40)
     tpch.tpch_q1(li)
-    fused_compiles = sum(
-        REGISTRY.counters("dispatch.compile.").values())
-    assert fused_compiles == 1
+    fused_compiles, pads = compiles()
+    assert (fused_compiles, pads) == (1, 1)
 
     REGISTRY.reset()
     _staged(lambda: tpch.tpch_q1(li))
-    staged_compiles = sum(
-        REGISTRY.counters("dispatch.compile.").values())
+    staged_compiles, _ = compiles()
     assert staged_compiles > fused_compiles, (
         f"staged path compiled {staged_compiles} executables; fusion "
         f"must beat it (got {fused_compiles})")

@@ -832,6 +832,69 @@ def test_padded_copy_bytes_counts_the_whole_copy(rows, padded):
         (1024 - rows) * row_bytes)
 
 
+def _dispatch_moved(before):
+    now = REGISTRY.counters("dispatch.")
+    return {k: v - before.get(k, 0) for k, v in now.items()
+            if v != before.get(k, 0)}
+
+
+def test_a_served_request_pads_through_one_cached_executable():
+    """The first request of a row count compiles its pad (beside its
+    region); every later one of that row count, over fresh rows, is one hit
+    of the pad and one of the region and compiles nothing. A second row
+    count in the bucket compiles a pad and no region."""
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        session = srv.session("s1")
+
+        def served(rows, seed):
+            before = REGISTRY.counters("dispatch.")
+            plan, bindings = _q1_bindings(rows, seed=seed)
+            session.submit(plan, bindings).result(timeout=60)
+            return _dispatch_moved(before)
+
+        first = served(600, 0)
+        assert first["dispatch.compile.pad"] == 1
+        assert first["dispatch.compile.fusion.tpch_q1"] == 1
+        assert first["dispatch.pad.jitted"] == 1
+        for seed in (1, 2, 3):
+            moved = served(600, seed)
+            assert moved["dispatch.hit.pad"] == 1
+            assert moved["dispatch.hit.fusion.tpch_q1"] == 1
+            assert moved["dispatch.pad.jitted"] == 1
+            assert "dispatch.compile" not in moved
+            assert not any(k.startswith("dispatch.inline") for k in moved)
+        other = served(700, 4)
+        assert other["dispatch.compile.pad"] == 1
+        assert other["dispatch.hit.fusion.tpch_q1"] == 1
+        assert other["dispatch.compile"] == 1
+
+
+def test_a_pad_that_fails_still_serves_the_right_answer(monkeypatch):
+    """``dispatch.call`` takes the inline path (``pad_error``) and the
+    region answers un-jitted over the rows as they came; the ops inside it
+    then dispatch one by one, and their pads fail alike."""
+    plan, bindings = _q1_bindings(600)
+    want = fusion.execute(plan, bindings)
+    real = dispatch.compiled
+
+    def broken(op, fn, *args, **kw):
+        if op == "pad":
+            raise RuntimeError("injected pad failure")
+        return real(op, fn, *args, **kw)
+
+    monkeypatch.setattr(dispatch, "compiled", broken)
+    before = REGISTRY.counters("dispatch.")
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        ticket = srv.session("s1").submit(
+            plan, {"lineitem": tpch.lineitem_table(600)})
+        got = ticket.result(timeout=60)
+    _assert_tables_identical(got.table, want.table, "q1 after a failed pad")
+    moved = _dispatch_moved(before)
+    assert moved["dispatch.inline.pad_error"] >= 1
+    assert moved["dispatch.inline"] == moved["dispatch.inline.pad_error"]
+    assert "dispatch.pad.jitted" not in moved
+
+
 def test_admission_queue_starts_at_enqueue_not_at_ticket_creation(
         monkeypatch):
     """A slow fingerprint sits between the ticket's creation and its
