@@ -29,16 +29,30 @@ Contracts, in order of importance:
   globally, and the whole execution runs inside
   ``telemetry.session_scope(sid)`` so fallback/spill/resilience events
   emitted by ANY inner layer carry ``session`` attribution. Every ticket
-  has a ``request`` id and two span trees joined by it
+  has a ``request`` id and three span trees joined by it
   (``telemetry/spans.py``): ``submit.<plan>`` on the client's thread
   (``cache.fingerprint`` with its per-buffer ``.copy`` / ``.hash``,
-  ``cache.lookup``, ``admission.enqueue``) and ``query.<plan>`` on the
+  ``cache.lookup``, ``admission.enqueue``), ``query.<plan>`` on the
   worker's (``admission.queue`` from the enqueue to the pickup,
   ``admission.wait``, ``server.stage_bindings``, the degrade rungs and
-  regions, ``server.record_actual``, ``cache.put``), so nothing between
-  ``submit`` and the ticket's resolve runs outside a named span.
+  regions, ``server.record_actual``, ``cache.put``, ``ticket.resolve``)
+  and ``query.result.<plan>`` on the thread that first calls
+  ``ticket.result()`` (``ticket.wait`` up to the worker's resolve,
+  ``ticket.wake`` from there to the return), so nothing between
+  ``submit`` and the client's return runs outside a named span.
   ``QueryTicket.queue_wait_s`` is the deadline's clock and starts at
-  submit, fingerprint included; the true wait is the spans'.
+  submit, fingerprint included; the true wait is the spans';
+  ``QueryTicket.wake_s`` is the wake-up.
+* **A request that ran long keeps its trees** — with telemetry on the
+  server holds, per plan signature, the latencies of the last 64 served
+  requests (submit to the return of ``result()``; to the resolve for a
+  ticket nobody awaits). One that took more than twice their median and
+  at least 0.010 s over it, with 16 known, is *slow*: counter
+  ``server.slow_requests``, one flight record ``slow`` (its three trees,
+  the ``gc`` records that overlap it, the server's state; kept apart
+  from the ring of completed trees) and one warning line naming the
+  largest self times. The host's collector pauses are on record meanwhile
+  (``telemetry/gcwatch.py``: ``host.gc_pauses``, ``host.gc_pause_ns``).
 * **A table may arrive as a file** — a scan bound to a
   ``parquet.split.ParquetSplit`` (one Spark scan task: path, byte range,
   read schema by name) is served like a bound ``Table``; the binding's type
@@ -96,6 +110,7 @@ from __future__ import annotations
 import collections
 import itertools
 import os
+import statistics
 import threading
 
 try:  # POSIX advisory locks for the shared learned-estimate file
@@ -122,6 +137,7 @@ from spark_rapids_jni_tpu.runtime.memory import (
     table_chip_nbytes,
 )
 from spark_rapids_jni_tpu.telemetry.events import (
+    enabled as _telemetry_enabled,
     events as _ring_events,
     record_degrade,
     record_integrity,
@@ -129,7 +145,7 @@ from spark_rapids_jni_tpu.telemetry.events import (
     session_scope,
 )
 from spark_rapids_jni_tpu.utils.atomic_io import atomic_write_json, load_json
-from spark_rapids_jni_tpu.telemetry import spans
+from spark_rapids_jni_tpu.telemetry import gcwatch, spans
 from spark_rapids_jni_tpu.telemetry.registry import REGISTRY
 from spark_rapids_jni_tpu.utils.config import cache_dir, get_option
 from spark_rapids_jni_tpu.utils.log import get_logger
@@ -152,6 +168,17 @@ _HOST_SIDE = (HostTableChunk, ParquetScan)
 # one id per submitted request, process-wide: what joins the client
 # thread's ``submit.<plan>`` span tree to the worker's ``query.<plan>``
 _REQUEST_IDS = itertools.count(1)
+
+# a served request is *slow* when, with _SLOW_MIN_KNOWN latencies of its plan
+# signature known (of the last _SLOW_KNOWN served), it took more than twice
+# their median and at least _SLOW_OVER_S over it
+_SLOW_KNOWN = 64
+_SLOW_MIN_KNOWN = 16
+_SLOW_OVER_S = 0.010
+
+# who awaits and who judges a ticket, and every server's known latencies: held
+# for a flag's flip or one median, never around anything that waits
+_TICKET_LOCK = threading.Lock()
 
 
 def live_servers() -> list:
@@ -236,7 +263,8 @@ class QueryTicket:
     def __init__(self, session_id: str, plan: fusion.Plan, bindings: dict,
                  estimate: int, donate_inputs: bool,
                  deadline_ms: int = 0,
-                 outofcore: Optional[Callable] = None):
+                 outofcore: Optional[Callable] = None,
+                 server: Optional["QueryServer"] = None):
         self.session = session_id
         self.request = next(_REQUEST_IDS)
         self.plan = plan
@@ -268,6 +296,20 @@ class QueryTicket:
         # which is where the worker's admission.queue span starts
         self._submit_span: Optional[int] = None
         self._enqueued_at: Optional[float] = None
+        # the client's end, all of it None / False with telemetry off: how
+        # long the first result() took to come back once the worker had
+        # resolved (0.0 for a ticket resolved before it was asked), the two
+        # stamps that part it, and the request's roots, kept for the record
+        # of a request that ran long
+        self.wake_s: Optional[float] = None
+        self._resolved_at: Optional[float] = None
+        self._returned_at: Optional[float] = None
+        self._submit_root: Optional[spans.Span] = None
+        self._query_root: Optional[spans.Span] = None
+        self._result_root: Optional[spans.Span] = None
+        self._awaited = False
+        self._judged = False
+        self._server = server
         self._value: Any = None
         self._exc: Optional[BaseException] = None
         self._done = threading.Event()
@@ -282,19 +324,75 @@ class QueryTicket:
         return self._done.is_set()
 
     def result(self, timeout: Optional[float] = None):
+        if not self._first_await():
+            self._wait(timeout)
+        else:
+            # the request's third root, on the CALLER's thread and in no
+            # tree of its own: query.result.<plan>, joined to the other
+            # two like the worker's. Its name starts with ``query.`` and
+            # its children carry no profiler annotation, so a reader that
+            # counts idle time under program spans counts none here
+            with spans.span(f"query.result.{self.plan.name}",
+                            parent=spans.NULL_SPAN, session=self.session,
+                            plan=self.plan.name, **self._joined()) as rsp:
+                self._result_root = rsp
+                try:
+                    self._wait(timeout)
+                finally:
+                    self._returned(rsp)
+            if self._server is not None:
+                self._server._judge(self, from_worker=False)
+        if self._exc is not None:
+            raise self._exc
+        return self._value
+
+    def _joined(self) -> dict:
+        """What joins a later root of the request to its first: the
+        request's id and the id of its ``submit.<plan>`` span."""
+        joined = {"request": self.request}
+        if self._submit_span is not None:
+            joined["caused_by"] = self._submit_span
+        return joined
+
+    def _wait(self, timeout: Optional[float]) -> None:
         if not self._done.wait(timeout):
             raise TimeoutError(
                 f"query {self.plan.name!r} (session {self.session}) not "
                 f"done within {timeout}s")
-        if self._exc is not None:
-            raise self._exc
-        return self._value
+
+    def _first_await(self) -> bool:
+        """True for the first ``result()`` of a ticket with telemetry on:
+        the one call that records the client's end."""
+        if not _telemetry_enabled():
+            return False
+        with _TICKET_LOCK:
+            first, self._awaited = not self._awaited, True
+        return first
+
+    def _returned(self, rsp) -> None:
+        """The two halves of the client's wait, recorded after the fact
+        under ``rsp``: ``ticket.wait`` up to the worker's stamp and
+        ``ticket.wake`` from it to now. A ticket resolved before it was
+        asked has neither, one that timed out has no resolve to part at."""
+        now = time.monotonic()
+        resolved = self._resolved_at
+        if resolved is not None and resolved > rsp.start:
+            spans.record_child("ticket.wait", rsp.start, resolved,
+                               session=self.session)
+            spans.record_child("ticket.wake", resolved, now,
+                               session=self.session)
+            self.wake_s = now - resolved
+        elif resolved is not None:
+            self.wake_s = 0.0
+        self._returned_at = now
 
     def _resolve(self, status: str, value: Any = None,
                  exc: Optional[BaseException] = None) -> None:
         self.status = status
         self._value = value
         self._exc = exc
+        if _telemetry_enabled():
+            self._resolved_at = time.monotonic()
         self._done.set()
 
 
@@ -395,6 +493,15 @@ class QueryServer:
         self._stop = threading.Event()
         self._closed = False
         self._draining = False
+        # plan signature -> latencies of its last served requests, what a
+        # slow one is told from (under _TICKET_LOCK); with telemetry on the
+        # first server of a process puts the collector's pauses on record
+        # and the slow count exists, so a window without one reads 0
+        self._latencies: dict[str, collections.deque] = {}
+        self._gc_watched = _telemetry_enabled()
+        if self._gc_watched:
+            gcwatch.acquire()
+            REGISTRY.counter("server.slow_requests")
         _LIVE_SERVERS.add(self)
         self._workers = [
             threading.Thread(target=self._worker, daemon=True,
@@ -480,7 +587,8 @@ class QueryServer:
         ddl = int(deadline_ms if deadline_ms is not None
                   else get_option("server.deadline_ms"))
         ticket = QueryTicket(sid, plan, bindings, estimate, donate_inputs,
-                             deadline_ms=ddl, outofcore=outofcore)
+                             deadline_ms=ddl, outofcore=outofcore,
+                             server=self)
         # the request's first root, on the CLIENT's thread: fingerprint,
         # cache lookup and enqueue are its children; the worker's root
         # query.<plan> carries the same request id and names this span
@@ -488,6 +596,7 @@ class QueryServer:
         with spans.span(f"submit.{plan.name}", session=sid, plan=plan.name,
                         request=ticket.request) as sspan:
             ticket._submit_span = sspan.id
+            ticket._submit_root = sspan or None
             if not splits or self._resolve_splits(
                     ticket, sspan, costed=estimate_bytes is not None):
                 self._submit(ticket, cache_fingerprint)
@@ -600,6 +709,8 @@ class QueryServer:
         # anyone inspects the limiter for leaks
         self.result_cache.close()
         self._save_learned()
+        if self._gc_watched:
+            gcwatch.release()
 
     def drain(self, timeout: Optional[float] = 30.0) -> dict:
         """Graceful drain: stop admitting (new submits reject with
@@ -1146,6 +1257,70 @@ class QueryServer:
                      ticket.plan.name, sid, kind)
         ticket._resolve("failed", exc=exc)
 
+    def _judge(self, ticket: QueryTicket, *, from_worker: bool) -> None:
+        """Once a served request has closed its trees: note its latency
+        under its plan signature and, where it ran long, keep its record.
+        Both ends call it and the one that is last judges: the worker after
+        its root has closed, unless a client is inside ``result()`` (whose
+        return is the latency's end), the client after its own, unless the
+        worker's root is still open. A ticket nobody awaits is judged by
+        the worker, to its resolve. A hit never ran and is not judged."""
+        qroot = ticket._query_root
+        if ticket.status != "served" or qroot is None:
+            return
+        with _TICKET_LOCK:
+            if ticket._judged:
+                return
+            if from_worker:
+                rroot = ticket._result_root
+                if ticket._awaited and (rroot is None or rroot.end is None):
+                    return
+            elif qroot.end is None:
+                return
+            ticket._judged = True
+            latency = max(ticket._resolved_at, ticket._returned_at or 0.0) \
+                - ticket._submitted_at
+            known = self._latencies.setdefault(
+                self._plan_signature(ticket.plan, ticket.bindings),
+                collections.deque(maxlen=_SLOW_KNOWN))
+            median = statistics.median(known) \
+                if len(known) >= _SLOW_MIN_KNOWN else None
+            known.append(latency)
+        if (median is not None and latency > 2.0 * median
+                and latency - median >= _SLOW_OVER_S):
+            self._slow(ticket, latency, median)
+
+    def _slow(self, ticket: QueryTicket, latency: float,
+              median: float) -> None:
+        """Count a slow request, keep its three trees with the collector's
+        records that overlap it and the server's state, and say in one line
+        where it spent the time."""
+        REGISTRY.counter("server.slow_requests").inc()
+        gcwatch.flush()
+        t0 = ticket._submitted_at
+        t1 = t0 + latency
+        pauses = [r for r in _ring_events() if r.get("kind") == "gc"
+                  and r["t1"] > t0 and r["t0"] < t1]
+        paused = sum(min(r["t1"], t1) - max(r["t0"], t0) for r in pauses)
+        roots = [r for r in (ticket._submit_root, ticket._query_root,
+                             ticket._result_root) if r is not None]
+        state = self._state_snapshot()
+        state.update(request=ticket.request, latency_s=latency,
+                     median_s=median, gc=pauses)
+        spans.dump_flight_record("slow", roots=roots, state=state)
+        own: dict = {}
+        for root in roots:
+            for name, seconds in spans.self_times(root).items():
+                own[name] = own.get(name, 0.0) + seconds
+        own.pop("ticket.wait", None)   # the worker's tree is what explains it
+        largest = sorted(own.items(), key=lambda kv: -kv[1])[:3]
+        _log.warning(
+            "slow request: %s (session %s, request %d) took %.6fs against "
+            "a median of %.6fs; largest self times %s; gc pause inside "
+            "%.6fs",
+            ticket.plan.name, ticket.session, ticket.request, latency,
+            median, ", ".join(f"{n} {v:.6f}s" for n, v in largest), paused)
+
     def _state_snapshot(self) -> dict:
         """Runtime state stamped into flight-recorder dumps: limiter
         watermarks, queue depths, in-flight count, spill-store totals."""
@@ -1185,14 +1360,12 @@ class QueryServer:
             # seam below (admission, degrade rungs, regions, pipeline
             # chunks, spills) attaches to this tree via the thread-local
             # stack; request/caused_by join it to submit's tree
-            joined = {"request": ticket.request}
-            if ticket._submit_span is not None:
-                joined["caused_by"] = ticket._submit_span
             with spans.span(f"query.{ticket.plan.name}", session=sid,
                             plan=ticket.plan.name,
                             estimate_bytes=ticket.estimate,
-                            **joined) as qspan:
+                            **ticket._joined()) as qspan:
                 info["span"] = qspan
+                ticket._query_root = qspan or None
                 try:
                     # the true queue wait: from the client's enqueue (after
                     # its fingerprint) to this pickup
@@ -1345,7 +1518,11 @@ class QueryServer:
                     record_server(ticket.plan.name, "served", session=sid,
                                   wall_ms=lat_ms,
                                   wait_ms=ticket.queue_wait_s * 1e3)
-                    ticket._resolve("served", value=result)
+                    # the moment of the resolve, on the profiler's clock
+                    # too: where the client's wait ends and its wake-up
+                    # starts
+                    with spans.child("ticket.resolve", session=sid):
+                        ticket._resolve("served", value=result)
                 except resilience.QueryCancelled as exc:
                     # a deliberate stop, not a failure: the reservation
                     # and the in-flight slot release in the SAME finally
@@ -1369,3 +1546,6 @@ class QueryServer:
                 self._inflight.pop(id(ticket), None)
             if held:
                 self.limiter.release(held)
+            if ticket._resolved_at is not None:   # telemetry is on
+                gcwatch.flush()
+                self._judge(ticket, from_worker=True)

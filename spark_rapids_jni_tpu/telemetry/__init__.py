@@ -30,7 +30,6 @@ from spark_rapids_jni_tpu.telemetry.events import (
     drain,
     enabled,
     events,
-    record_bench_stale,
     record_compile_cache,
     record_degrade,
     record_dispatch,
@@ -67,7 +66,6 @@ __all__ = [
     "enabled",
     "events",
     "flight_records",
-    "record_bench_stale",
     "record_compile_cache",
     "record_degrade",
     "record_dispatch",

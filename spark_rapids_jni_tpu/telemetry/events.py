@@ -1,7 +1,7 @@
 """Structured JSONL event log: dispatches, fallbacks, cache hits, staleness.
 
 Every record answers the question the round-5 bench could not: *where did
-this op actually run, and why?* Four event kinds:
+this op actually run, and why?* The event kinds:
 
 - ``dispatch``      — an op ran on its intended engine (``engine`` says which);
                       carries ``wall_ms`` when timed via ``trace_range(record=)``.
@@ -13,11 +13,13 @@ this op actually run, and why?* Four event kinds:
 - ``compile_cache`` — hit/miss on a pattern-compile cache (regex DFA / linear).
 - ``spill``         — device→host spill under memory pressure; carries
                       ``bytes_moved``.
-- ``bench_stale``   — bench served a last-known-good ledger value instead of a
-                      fresh measurement.
 - ``span``          — one closed node of a query's causal span tree
                       (telemetry/spans.py): id/parent/root, monotonic t0/t1,
                       status (ok/degraded/cancelled/failed).
+- ``gc``            — one collection of the host's garbage collector that was
+                      of generation 2 or paused the process for over 1 ms
+                      (telemetry/gcwatch.py): monotonic t0/t1, ``generation``,
+                      ``collected``.
 
 Each record is stamped with ``ts`` (epoch seconds), ``platform`` (jax backend
 if jax is already imported — telemetry itself never imports jax, keeping the
@@ -53,7 +55,6 @@ __all__ = [
     "record_compile_cache",
     "record_spill",
     "record_resilience",
-    "record_bench_stale",
     "record_server",
     "record_degrade",
     "record_integrity",
@@ -112,17 +113,25 @@ def enabled() -> bool:
     return bool(get_option("telemetry.enabled"))
 
 
+_backend: Optional[str] = None   # jax's backend, once it has told it
+
+
 def _platform() -> str:
     # Never import jax from here: telemetry is zero-dep and must not trigger
     # backend init (test_import_hygiene.py). If the workload already imported
-    # jax, report its backend; otherwise "none".
+    # jax, report its backend; otherwise "none". The backend of a process
+    # does not change: asked once, not once a record.
+    global _backend
+    if _backend is not None:
+        return _backend
     jax = sys.modules.get("jax")
     if jax is None:
         return "none"
     try:
-        return str(jax.default_backend())
+        _backend = str(jax.default_backend())
     except Exception:
         return "unknown"
+    return _backend
 
 
 def _replica() -> str:
@@ -586,26 +595,6 @@ def record_exchange(
     return True
 
 
-def record_bench_stale(
-    metric: str,
-    *,
-    stale_s: float,
-    reason: str,
-    **extra: Any,
-) -> bool:
-    """Bench served a last-known-good ledger value instead of measuring."""
-    if not reason or not str(reason).strip():
-        raise ValueError(f"record_bench_stale({metric!r}): reason must be non-empty")
-    if not enabled():
-        return False
-    rec = _base("bench_stale", metric, None, None, extra)
-    rec["reason"] = str(reason)
-    rec["stale_s"] = float(stale_s)
-    REGISTRY.counter("bench_stale_total").inc()
-    _emit(rec)
-    return True
-
-
 def events(n: Optional[int] = None) -> List[Dict[str, Any]]:
     """The last ``n`` (default: all buffered) records, oldest first."""
     with _ring_lock:
@@ -676,7 +665,6 @@ def summary(records: Optional[Iterable[Dict[str, Any]]] = None) -> Dict[str, Any
     cluster: Dict[str, int] = {}
     hosts: set = set()
     per_host: Dict[str, int] = {}
-    stale_reads = 0
     dispatches = 0
     spill_bytes = 0
     spans = 0
@@ -732,8 +720,6 @@ def summary(records: Optional[Iterable[Dict[str, Any]]] = None) -> Dict[str, Any
             spill_bytes += int(r.get("bytes_moved", 0))
         elif kind == "compile_cache":
             cache["hit" if r.get("hit") else "miss"] += 1
-        elif kind == "bench_stale":
-            stale_reads += 1
         elif kind == "dispatch":
             dispatches += 1
     return {
@@ -759,5 +745,4 @@ def summary(records: Optional[Iterable[Dict[str, Any]]] = None) -> Dict[str, Any
         "compress": compress,
         "spans": spans,
         "span_status": dict(sorted(span_status.items())),
-        "stale_reads": stale_reads,
     }

@@ -181,11 +181,10 @@ def report(path: str, *, session: Optional[str] = None,
     lines = [render_table(per_op), ""]
     lines.append(
         "events={events}  fallbacks={fallbacks_total}  "
-        "spill_bytes={sb}  cache_hit/miss={h}/{m}  stale_reads={stale}".format(
+        "spill_bytes={sb}  cache_hit/miss={h}/{m}".format(
             events=s["events"], fallbacks_total=s["fallbacks_total"],
             sb=_fmt_bytes(s["spill_bytes_total"]),
             h=s["compile_cache"]["hit"], m=s["compile_cache"]["miss"],
-            stale=s["stale_reads"],
         )
     )
     # serving-runtime sections render only when such events exist, so

@@ -86,6 +86,7 @@ __all__ = [
     "record_child",
     "current_span",
     "current_root",
+    "self_times",
     "validate",
     "chrome_trace",
     "write_chrome_trace",
@@ -280,11 +281,10 @@ class Span:
             _events._emit(rec)
             REGISTRY.counter("spans_total").inc()
             if self.parent is None:
-                _RECORDER.note({
-                    "trigger": "completed",
-                    "root": self.id,
-                    "tree": self.tree(),
-                })
+                # the closed tree itself: serialised when someone reads
+                # the ring, which is almost never
+                _RECORDER.note({"trigger": "completed", "root": self.id,
+                                "tree": self})
 
     # -- mutation ------------------------------------------------------------
 
@@ -314,11 +314,26 @@ class Span:
         """Serialize the whole tree this span roots (or belongs to).
         Open spans appear with ``t1: null`` / status ``open``."""
         root = self.root
+        if root is None:        # taken apart: what is left is the node
+            return self._node()
         with root._tree_lock:
             out = root._node()
         if root._dropped:
             out["dropped_spans"] = root._dropped
         return out
+
+    def _take_apart(self) -> None:
+        """Unlink the closed tree this root holds, once the flight recorder
+        has let go of it. A tree is a cycle (a root is its own ``root``,
+        every child points back at its parent): kept for a few requests it
+        grows old, and left whole it would wait for a collection of the
+        oldest generation to be freed. Apart, its spans go by count."""
+        todo = [self]
+        while todo:
+            node = todo.pop()
+            todo.extend(node.children)
+            node.children = []
+            node.parent = node.root = None
 
     def deepest_open(self) -> Optional["Span"]:
         """The deepest not-yet-closed span in this tree — 'where is this
@@ -342,7 +357,9 @@ class Span:
 def span(name: str, *, parent: Optional[Span] = None, **attrs: Any):
     """Open a span. With no ``parent`` and no thread-local current span
     this starts a new root (a new query tree) — seams that must never
-    create trees of their own use :func:`child` instead."""
+    create trees of their own use :func:`child` instead.
+    ``parent=NULL_SPAN`` starts a root whatever this thread has open (the
+    client's end of a request, which belongs to no tree of its caller)."""
     if not _events.enabled():
         return NULL_SPAN
     if parent is None:
@@ -366,21 +383,51 @@ def child(name: str, *, parent: Optional[Span] = None, **attrs: Any):
     return Span(name, p, attrs)
 
 
-def record_child(name: str, start: float, **attrs: Any) -> None:
+def record_child(name: str, start: float, end: Optional[float] = None,
+                 **attrs: Any) -> None:
     """Record a child of this thread's current span that began at ``start``
     (a ``time.monotonic`` reading, taken on whichever thread the wait
-    began on) and ends now. For an interval whose start no ``with`` on
-    this thread can see: a ticket's wait between the client's enqueue and
-    the worker's pickup. It lies before its parent's own start, and it
-    has no profiler annotation (the profiler takes no past start)."""
+    began on) and ends at ``end`` (another such reading; now when left
+    out). For an interval no ``with`` on this thread can see: a ticket's
+    wait between the client's enqueue and the worker's pickup, which lies
+    before its parent's own start, or the two halves of a client's wait
+    for its result, which part at a moment the worker stamped. It has no
+    profiler annotation (the profiler takes no past start)."""
     parent = current_span()
     if parent is None or not _events.enabled():
         return
     sp = Span(name, parent, attrs)
     sp._attach()
     sp.start = float(start)
-    sp.end = time.monotonic()
+    sp.end = time.monotonic() if end is None else float(end)
     sp._emit()
+
+
+def self_times(root: Span) -> dict:
+    """{span name: seconds} of self time over the tree of ``root``: a
+    node's duration less what its children cover of it (a child recorded
+    after the fact may lie outside its parent; children on a pool's
+    threads overlap one another). A node still open ends now."""
+    now = time.monotonic()
+    out: dict = {}
+    with root._tree_lock:
+        todo = [root]
+        while todo:
+            node = todo.pop()
+            t0 = node.start
+            t1 = node.end if node.end is not None else now
+            covered, at = 0.0, t0
+            for s, e in sorted(
+                    (max(c.start, t0),
+                     min(c.end if c.end is not None else now, t1))
+                    for c in node.children):
+                if e > at:
+                    covered += e - max(s, at)
+                    at = e
+            out[node.name] = out.get(node.name, 0.0) + max(
+                t1 - t0 - covered, 0.0)
+            todo.extend(node.children)
+    return out
 
 
 def current_span() -> Optional[Span]:
@@ -398,40 +445,60 @@ def current_root() -> Optional[Span]:
 # ---------------------------------------------------------------------------
 
 
+_SLOW_KEPT = 32   # dumps of slow requests kept, apart from the ring
+
+
 class _FlightRecorder:
     """Bounded ring of recent span trees (completed roots and explicit
     dumps). Depth re-reads ``telemetry.flight_recorder_depth`` on every
-    note so tests/operators can resize without rebuilding the ring."""
+    note so tests/operators can resize without rebuilding the ring. A
+    completed root is noted as the closed ``Span`` and serialised when the
+    ring is read; one that leaves the ring is taken apart. Dumps of slow requests (trigger ``slow``) are kept apart,
+    the newest ``_SLOW_KEPT``: the next few requests' completed trees must
+    not push out the one a reader will come for."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._ring: deque = deque()
+        self._slow: deque = deque(maxlen=_SLOW_KEPT)
         self._seq = 0
 
     def note(self, entry: dict) -> None:
         depth = max(1, int(get_option("telemetry.flight_recorder_depth")))
+        gone = []
         with self._lock:
             self._seq += 1
             entry = dict(entry)
             entry["seq"] = self._seq
+            if entry["trigger"] == "slow":
+                self._slow.append(entry)
+                return
             self._ring.append(entry)
             while len(self._ring) > depth:
-                self._ring.popleft()
+                gone.append(self._ring.popleft()["tree"])
+        for tree in gone:
+            if isinstance(tree, Span):
+                tree._take_apart()
 
     def records(self) -> list:
         with self._lock:
-            return list(self._ring)
+            kept = sorted([*self._slow, *self._ring],
+                          key=lambda e: e["seq"])
+        return [dict(e, tree=e["tree"].tree())
+                if isinstance(e["tree"], Span) else e for e in kept]
 
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+            self._slow.clear()
 
 
 _RECORDER = _FlightRecorder()
 
 
 def flight_records() -> list:
-    """The in-memory flight-recorder ring, oldest first."""
+    """The in-memory flight-recorder ring and the kept dumps of slow
+    requests, oldest first."""
     return _RECORDER.records()
 
 
@@ -440,18 +507,23 @@ def _safe_name(text: str) -> str:
 
 
 def dump_flight_record(trigger: str, *, root: Optional[Span] = None,
+                       roots: Iterable[Span] = (),
                        state: Optional[dict] = None) -> Optional[str]:
     """Snapshot one query's span tree plus the caller-supplied runtime
     state (limiter watermarks, queue depths) into a single structured
     artifact: appended to the in-memory ring always, written as JSON
-    under ``telemetry.flight_recorder_path`` when that is set. Returns
+    under ``telemetry.flight_recorder_path`` when that is set. ``roots``
+    are the roots of every tree of one request (client's, worker's,
+    client's end): the first is the artifact's ``tree``, all of them its
+    ``trees``. Returns
     the artifact path (referenced from QueryRejected / failure records)
     or None. Never raises — a failed write only bumps the
     ``dropped_writes`` counter, matching the JSONL sink's posture."""
     if not _events.enabled():
         return None
+    roots = [r for r in roots if isinstance(r, Span)]
     if root is None:
-        root = current_root()
+        root = roots[0] if roots else current_root()
     tree = root.tree() if isinstance(root, Span) else None
     artifact = {
         "kind": "flight_record",
@@ -462,6 +534,8 @@ def dump_flight_record(trigger: str, *, root: Optional[Span] = None,
         "tree": tree,
         "state": dict(state) if state else {},
     }
+    if roots:
+        artifact["trees"] = [r.tree() for r in roots]
     _RECORDER.note(artifact)
     out_dir = str(get_option("telemetry.flight_recorder_path") or "")
     if not out_dir:

@@ -393,7 +393,8 @@ def test_the_span_trees_of_a_request_hold_the_scan(lineitem):
     roots = {r["span"]: r for r in spans
              if r.get("parent") is None and r.get("request") == ticket.request}
     assert sorted(r["op"] for r in roots.values()) == [
-        "query.tpch_q1_planned", "submit.tpch_q1_planned"]
+        "query.result.tpch_q1_planned", "query.tpch_q1_planned",
+        "submit.tpch_q1_planned"]
     mine = [r for r in spans if r["root"] in roots]
     by_id = {r["span"]: r for r in mine}
 

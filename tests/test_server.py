@@ -533,9 +533,10 @@ def test_degrade_step_dumps_flight_record(tmp_path):
     assert "rung.fused" in rungs
     assert art["state"]["limiter"]["budget"] == 1 << 28
     # the query's own span tree records the degraded outcome
-    q_spans = [r for r in ring_events() if r.get("kind") == "span"
-               and r.get("op", "").startswith("query.")]
-    assert q_spans and q_spans[-1]["status"] == "degraded"
+    # (the client's root query.result.<plan> closes after it, status ok)
+    by_op = {r["op"]: r for r in ring_events() if r.get("kind") == "span"}
+    assert by_op["query.tpch_q1"]["status"] == "degraded"
+    assert by_op["query.result.tpch_q1"]["status"] == "ok"
 
 
 def test_rejection_carries_flight_record(tmp_path):
@@ -712,7 +713,7 @@ def _table_bytes(table):
                if buf is not None)
 
 
-def test_served_miss_is_two_trees_joined_by_request():
+def test_served_miss_is_three_trees_joined_by_request():
     from spark_rapids_jni_tpu.telemetry import spans
 
     set_option("telemetry.enabled", True)
@@ -720,13 +721,18 @@ def test_served_miss_is_two_trees_joined_by_request():
     with server.QueryServer(budget_bytes=1 << 28) as srv:
         ticket = srv.session("s1").submit(plan, bindings)
         ticket.result(timeout=60)
-        roots, tree = _request_spans(ticket, 2)
-    assert set(roots) == {"submit.tpch_q1", "query.tpch_q1"}
-    sub, qry = roots["submit.tpch_q1"], roots["query.tpch_q1"]
-    assert sub["request"] == qry["request"] == ticket.request
-    assert qry["caused_by"] == sub["span"] == ticket._submit_span
+        roots, tree = _request_spans(ticket, 3)
+    assert set(roots) == {"submit.tpch_q1", "query.tpch_q1",
+                          "query.result.tpch_q1"}
+    sub, qry, res = (roots["submit.tpch_q1"], roots["query.tpch_q1"],
+                     roots["query.result.tpch_q1"])
+    assert sub["request"] == qry["request"] == res["request"] == (
+        ticket.request)
+    assert qry["caused_by"] == res["caused_by"] == sub["span"] == (
+        ticket._submit_span)
     assert "caused_by" not in sub
-    assert sub["tid"] == threading.get_ident() != qry["tid"]
+    # two threads: the client's holds the first and the third tree
+    assert sub["tid"] == res["tid"] == threading.get_ident() != qry["tid"]
     assert spans.validate(tree) == []
 
     def names_under(root):
@@ -739,7 +745,9 @@ def test_served_miss_is_two_trees_joined_by_request():
         "query.tpch_q1", "admission.queue", "admission.wait",
         "server.stage_bindings", "rung.fused", "region.tpch_q1",
         "dispatch.pad", "dispatch.execute", "server.record_actual",
-        "cache.put"}
+        "cache.put", "ticket.resolve"}
+    assert names_under(res) == {"query.result.tpch_q1", "ticket.wait",
+                                "ticket.wake"}
     # every buffer of this table is under the digest's threshold: each
     # crossed to the host whole, so the two halves carry the same bytes
     for half in ("copy", "hash"):
@@ -753,6 +761,185 @@ def test_served_miss_is_two_trees_joined_by_request():
     # one request, counted once: exactly one root no other span caused
     assert [r["op"] for r in tree if r.get("parent") is None
             and "caused_by" not in r] == ["submit.tpch_q1"]
+    # four records more a request than before them
+    assert [sum(r["op"] == n for r in tree) for n in (
+        "query.result.tpch_q1", "ticket.wait", "ticket.wake",
+        "ticket.resolve")] == [1, 1, 1, 1]
+
+
+def test_the_clients_wait_parts_at_the_workers_stamp():
+    """``ticket.wait`` + ``ticket.wake`` cover ``query.result.<plan>``, and
+    they part at the moment the worker stamped inside ``ticket.resolve``."""
+    set_option("telemetry.enabled", True)
+    plan, bindings = _q1_bindings(600)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        ticket = srv.session("s1").submit(plan, bindings)
+        ticket.result(timeout=60)
+        roots, tree = _request_spans(ticket, 3)
+    by_op = {r["op"]: r for r in tree}
+    res, wait, wake, resolve = (by_op[n] for n in (
+        "query.result.tpch_q1", "ticket.wait", "ticket.wake",
+        "ticket.resolve"))
+    assert wait["parent"] == wake["parent"] == res["span"]
+    assert resolve["parent"] == roots["query.tpch_q1"]["span"]
+    assert wait["t0"] == res["t0"]
+    assert wait["t1"] == wake["t0"] == ticket._resolved_at
+    assert resolve["t0"] <= ticket._resolved_at <= resolve["t1"]
+    assert wake["t1"] == ticket._returned_at <= res["t1"]
+    assert ticket.wake_s == wake["t1"] - wake["t0"] > 0
+    # the root closes two records after its children end: microseconds
+    assert res["t1"] - wake["t1"] < 5e-3
+
+
+def test_result_called_twice_opens_one_root():
+    set_option("telemetry.enabled", True)
+    plan, bindings = _q1_bindings(600)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        ticket = srv.session("s1").submit(plan, bindings)
+        first = ticket.result(timeout=60)
+        wake = ticket.wake_s
+        assert ticket.result(timeout=60) is first
+        roots, tree = _request_spans(ticket, 3)
+    assert ticket.wake_s == wake
+    assert [r["op"] for r in tree].count("query.result.tpch_q1") == 1
+    assert [r["op"] for r in tree].count("ticket.wake") == 1
+
+
+def test_telemetry_off_records_no_client_end_and_installs_no_gc_hook():
+    from spark_rapids_jni_tpu.telemetry import gcwatch
+
+    assert not get_option("telemetry.enabled")
+    plan, bindings = _q1_bindings(600)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        assert not gcwatch.installed()
+        ticket = srv.session("s1").submit(plan, bindings)
+        ticket.result(timeout=60)
+        assert ticket.status == "served"
+        assert (ticket._resolved_at, ticket._returned_at, ticket.wake_s,
+                ticket._result_root) == (None, None, None, None)
+        assert not ticket._awaited and not ticket._judged
+        assert srv._latencies == {}
+    assert ring_events() == []
+    assert "server.slow_requests" not in REGISTRY.counters()
+    assert "host.gc_pauses" not in REGISTRY.counters()
+
+
+def test_a_slow_request_keeps_its_trees(monkeypatch):
+    """One request of 40 stalls at the seam ``server.execute``: it is
+    counted once, its record holds its three trees, the warning line names
+    the span that slept, and 40 later requests do not push the record out
+    of the flight recorder (whose ring holds 16 trees)."""
+    from spark_rapids_jni_tpu.telemetry import spans
+
+    set_option("telemetry.enabled", True)
+    spans.reset()
+    said = []
+    monkeypatch.setattr(server._log, "warning",
+                        lambda msg, *args: said.append(msg % args))
+    stall = {"at": 30, "seen": 0}
+
+    def probe(seam, seq, ctx):
+        if seam == "server.execute":
+            stall["seen"] += 1
+            # a floor under every request, so that a busy host's jitter is
+            # small against the median; the one stall on top of it
+            time.sleep(0.32 if stall["seen"] == stall["at"] else 0.02)
+
+    plan = tpch._q1_plan()
+    tickets = []
+    with faults.inject(probe), \
+            server.QueryServer(budget_bytes=1 << 28) as srv:
+        session = srv.session("s1")
+        for i in range(80):
+            ticket = session.submit(
+                plan, {"lineitem": tpch.lineitem_table(600, seed=i)})
+            ticket.result(timeout=60)
+            tickets.append(ticket)
+            if i == 39:
+                # the worker closes its root just after the client's return
+                _request_spans(ticket, 3)
+                slow_after_40 = REGISTRY.counters()["server.slow_requests"]
+        _request_spans(tickets[-1], 3)
+        (known,) = srv._latencies.values()
+    stalled = tickets[stall["at"] - 1]
+    assert all(t._judged for t in tickets)
+    assert len(known) == 64    # of 80
+    mine = [r for r in spans.flight_records() if r["trigger"] == "slow"
+            and r["state"]["request"] == stalled.request]
+    assert len(mine) == 1 and slow_after_40 >= 1
+    assert REGISTRY.counters()["server.slow_requests"] == sum(
+        r["trigger"] == "slow" for r in spans.flight_records())
+    (record,) = mine
+    assert [t["name"] for t in record["trees"]] == [
+        "submit.tpch_q1", "query.tpch_q1", "query.result.tpch_q1"]
+    assert record["tree"] == record["trees"][0]
+    assert all(t["status"] == "ok" and t["t1"] is not None
+               for t in record["trees"])
+    assert [c["name"] for c in record["trees"][2]["children"]] == [
+        "ticket.wait", "ticket.wake"]
+    assert record["state"]["latency_s"] > 0.3 > 2 * record["state"]["median_s"]
+    assert record["state"]["limiter"]["budget"] == 1 << 28
+    assert record["state"]["gc"] == [
+        r for r in ring_events() if r.get("kind") == "gc"
+        and r["t1"] > stalled._submitted_at
+        and r["t0"] < stalled._submitted_at + record["state"]["latency_s"]]
+    # the seam fires under the worker's root alone: the root slept
+    (line,) = [m for m in said if f"request {stalled.request})" in m]
+    assert line.startswith("slow request: tpch_q1 (session s1,")
+    assert "largest self times query.tpch_q1 0.3" in line
+    assert "gc pause inside 0." in line
+
+
+def test_a_ticket_nobody_awaits_is_judged_to_its_resolve():
+    set_option("telemetry.enabled", True)
+    plan, bindings = _q1_bindings(600)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        ticket = srv.session("s1").submit(plan, bindings)
+        deadline = time.monotonic() + 60
+        while not ticket._judged and time.monotonic() < deadline:
+            time.sleep(0.005)
+        (known,) = srv._latencies.values()
+        assert list(known) == [ticket._resolved_at - ticket._submitted_at]
+        ticket.result(timeout=60)      # too late to count: judged already
+        assert len(known) == 1 and ticket.wake_s == 0.0
+
+
+def test_a_collection_inside_a_request_is_on_record():
+    """A forced ``gc.collect()`` (generation 2) while a request is served
+    gives a ``gc`` record inside the request's interval and moves both
+    counters; the hook is there while a server with telemetry is."""
+    import gc
+
+    from spark_rapids_jni_tpu.telemetry import gcwatch
+
+    set_option("telemetry.enabled", True)
+
+    def probe(seam, seq, ctx):
+        if seam == "server.execute":
+            gc.collect()
+
+    plan, bindings = _q1_bindings(600)
+    with server.QueryServer(budget_bytes=1 << 28) as srv:
+        assert gcwatch.installed()
+        with server.QueryServer(budget_bytes=1 << 28):
+            pass
+        assert gcwatch.installed()     # the first server still holds it
+        before = REGISTRY.counters()
+        assert before["host.gc_pauses"] >= 0 <= before["host.gc_pause_ns"]
+        with faults.inject(probe):
+            ticket = srv.session("s1").submit(plan, bindings)
+            ticket.result(timeout=60)
+        roots, _ = _request_spans(ticket, 3)
+    assert not gcwatch.installed()
+    after = REGISTRY.counters()
+    assert after["host.gc_pauses"] > before["host.gc_pauses"]
+    assert after["host.gc_pause_ns"] > before["host.gc_pause_ns"]
+    qry = roots["query.tpch_q1"]
+    full = [r for r in ring_events() if r.get("kind") == "gc"
+            and r["generation"] == 2
+            and qry["t0"] <= r["t0"] <= r["t1"] <= qry["t1"]]
+    assert full and all(r["collected"] >= 0 and r["op"] == "gc"
+                        for r in full)
 
 
 def test_served_hit_is_one_tree():
@@ -764,6 +951,15 @@ def test_served_hit_is_one_tree():
         ticket = session.submit(plan, bindings)
         assert ticket.done() and ticket.status == "served"
         roots, tree = _request_spans(ticket, 1)
+        # a ticket resolved before it is asked: the client's root is empty
+        ticket.result(timeout=60)
+        assert ticket.wake_s == 0.0
+        (res,) = [r for r in ring_events() if r.get("kind") == "span"
+                  and r.get("request") == ticket.request
+                  and r["op"] == "query.result.tpch_q1"]
+        assert not [r for r in ring_events() if r.get("root") == res["span"]
+                    and r is not res]
+        assert res["t1"] - res["t0"] < 5e-3
     assert set(roots) == {"submit.tpch_q1"}
     by_op = {r["op"]: r for r in tree}
     assert set(by_op) == {"submit.tpch_q1", "cache.fingerprint",

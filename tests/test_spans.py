@@ -170,6 +170,42 @@ def test_record_child_with_a_given_start(enabled):
     assert spans.validate(_span_records()) == []
 
 
+def test_record_child_with_a_given_end(enabled):
+    """An interval that ended at a moment another thread stamped: the
+    child ends where it is told, not now."""
+    import time
+
+    began = time.monotonic() - 0.5
+    with spans.span("query.result.q") as root:
+        spans.record_child("ticket.wait", began, began + 0.2, session="s")
+        spans.record_child("ticket.wake", began + 0.2, began + 0.25)
+        assert [c["name"] for c in root.tree()["children"]] == [
+            "ticket.wait", "ticket.wake"]
+    by_op = {r["op"]: r for r in _span_records()}
+    wait, wake = by_op["ticket.wait"], by_op["ticket.wake"]
+    assert (wait["t0"], wait["t1"]) == (began, began + 0.2)
+    assert (wake["t0"], wake["t1"]) == (began + 0.2, began + 0.25)
+    assert wait["dur_ms"] == pytest.approx(200.0) and wait["session"] == "s"
+    assert wait["parent"] == wake["parent"] == by_op["query.result.q"]["span"]
+    assert spans.validate(_span_records()) == []
+
+
+def test_self_times_of_a_tree(enabled):
+    """A node's duration less what its children cover of it: overlapping
+    children count once, one that lies before its parent explains none."""
+    with spans.span("query.q") as root:
+        pass
+    root.start, root.end = 10.0, 20.0
+    for name, t0, t1 in (("admission.queue", 8.0, 10.0), ("scan", 11.0, 15.0),
+                         ("scan", 13.0, 17.0), ("cache.put", 18.0, 19.0)):
+        kid = spans.Span(name, root, {})
+        kid._attach()
+        kid.start, kid.end = t0, t1
+    assert spans.self_times(root) == {
+        "query.q": 3.0, "admission.queue": 2.0, "scan": 8.0,
+        "cache.put": 1.0}
+
+
 def test_spans_land_in_a_profiler_trace(enabled, tmp_path):
     """Under a profiler session every span is also a TraceAnnotation of
     its name in the host plane, carrying its id and the request's."""
@@ -318,6 +354,90 @@ def test_flight_ring_is_bounded(enabled):
             pass
     ring = spans.flight_records()
     assert [r["tree"]["name"] for r in ring] == ["query.q3", "query.q4"]
+
+
+def test_completed_trees_are_serialised_when_the_ring_is_read(enabled):
+    """A closed root hands the recorder the tree itself; a reader gets what
+    it always got."""
+    with spans.span("query.q", session="s") as q:
+        with spans.child("rung.fused", rows=7) as c:
+            c.set_status("degraded")
+    (held,) = spans._RECORDER._ring
+    assert held["tree"] is q
+    (entry,) = spans.flight_records()
+    assert entry == {
+        "trigger": "completed", "root": q.id, "seq": held["seq"],
+        "tree": {"span": q.id, "name": "query.q", "status": "ok",
+                 "t0": q.start, "t1": q.end, "attrs": {"session": "s"},
+                 "children": [{
+                     "span": c.id, "name": "rung.fused",
+                     "status": "degraded", "t0": c.start, "t1": c.end,
+                     "attrs": {"rows": 7}, "children": []}]}}
+    assert json.loads(json.dumps(spans.flight_records())) == [entry]
+    assert spans._RECORDER._ring[0]["tree"] is q   # reading keeps the tree
+
+
+def test_a_tree_that_leaves_the_ring_is_freed_by_count(enabled):
+    """A span tree is a reference cycle. One the recorder has held for a
+    few requests is old by the time it is let go: taken apart, it needs no
+    collection to be freed."""
+    import gc
+    import weakref
+
+    gc.collect()
+    gc.disable()
+    try:
+        class Tag:
+            pass
+
+        with spans.span("query.q") as q:
+            with spans.child("rung.fused") as c:
+                pass
+        tags = [Tag(), Tag()]        # what only the two spans hold on to
+        c.annotate(tag=tags[0])
+        q.annotate(tag=tags[1])
+        kid, root = (weakref.ref(t) for t in tags)
+        del c, tags
+        for i in range(16):      # the ring's depth: the first tree leaves
+            with spans.span(f"query.q{i}"):
+                pass
+        assert kid() is None and root() is not None
+        assert q.root is None and q.children == []
+        assert q.tree()["name"] == "query.q" and q.tree()["children"] == []
+        assert spans.self_times(q) == {"query.q": q.end - q.start}
+        del q
+        assert root() is None
+    finally:
+        gc.enable()
+    assert [r["tree"]["name"] for r in spans.flight_records()] == [
+        f"query.q{i}" for i in range(16)]
+
+
+def test_slow_dumps_are_kept_apart_from_the_ring(enabled):
+    """The trees of a request that ran long outlive the next sixteen
+    completed roots; the newest 32 such dumps are kept."""
+    with spans.span("submit.q", request=5) as a:
+        pass
+    with spans.span("query.q", request=5) as b:
+        pass
+    assert spans.dump_flight_record(
+        "slow", roots=[a, spans.NULL_SPAN, b], state={"request": 5}) is None
+    for i in range(40):
+        with spans.span(f"query.q{i}"):
+            pass
+    ring = spans.flight_records()
+    assert [r["trigger"] for r in ring] == ["slow"] + ["completed"] * 16
+    slow = ring[0]
+    assert slow["root"] == a.id and slow["tree"]["name"] == "submit.q"
+    assert [t["name"] for t in slow["trees"]] == ["submit.q", "query.q"]
+    assert slow["state"] == {"request": 5}
+    assert [r["seq"] for r in ring] == sorted(r["seq"] for r in ring)
+    for i in range(40):
+        spans.dump_flight_record("slow", roots=[a], state={"request": i})
+    kept = [r for r in spans.flight_records() if r["trigger"] == "slow"]
+    assert [r["state"]["request"] for r in kept] == list(range(8, 40))
+    spans.reset()
+    assert spans.flight_records() == []
 
 
 def test_dump_flight_record_writes_artifact(enabled, tmp_path):

@@ -135,18 +135,16 @@ def test_jsonl_schema(enabled):
     telemetry.record_compile_cache("regex_dfa", hit=False)
     telemetry.record_spill(
         "spill_store", "budget exceeded", bytes_moved=4096, rows=10)
-    telemetry.record_bench_stale(
-        "groupby", stale_s=12.5, reason="TPU probe failed")
     lines = enabled.read_text().splitlines()
-    assert len(lines) == 5
+    assert len(lines) == 4
     recs = [json.loads(ln) for ln in lines]  # every line parses
     for rec in recs:
         assert rec["kind"] in (
-            "dispatch", "fallback", "compile_cache", "spill", "bench_stale")
+            "dispatch", "fallback", "compile_cache", "spill")
         assert rec["op"]
         assert isinstance(rec["ts"], float)
         assert isinstance(rec["platform"], str)
-        if rec["kind"] in ("fallback", "spill", "bench_stale"):
+        if rec["kind"] in ("fallback", "spill"):
             assert rec["reason"].strip()
     by_kind = {r["kind"]: r for r in recs}
     assert by_kind["dispatch"]["rows"] == 128
@@ -154,12 +152,14 @@ def test_jsonl_schema(enabled):
     assert by_kind["dispatch"]["wall_ms"] == 1.5
     assert by_kind["fallback"]["engine"] == "host"
     assert by_kind["spill"]["bytes_moved"] == 4096
-    assert by_kind["bench_stale"]["stale_s"] == 12.5
     # the ring mirrors the file
     assert [r["kind"] for r in telemetry.events()] == [r["kind"] for r in recs]
     # registry counters track the event stream
     assert telemetry.REGISTRY.counter("fallbacks_total").value == 1
-    assert telemetry.REGISTRY.counter("events_total").value == 5
+    assert telemetry.REGISTRY.counter("events_total").value == 4
+    # the kind a bench.py that is gone wrote has gone with it
+    assert not hasattr(telemetry, "record_bench_stale")
+    assert "stale_reads" not in telemetry.summary()
 
 
 def test_env_round_trip(monkeypatch, tmp_path):
