@@ -842,12 +842,18 @@ def _q3_planned_plan(segment: int, cutoff: int) -> fusion.Plan:
     # groups, the same answer, one 64-bit sort key and not three keys);
     # and there are at most |orders| groups and the null group of the
     # unmatched rows, so the groupby's look-ups, its results and the
-    # result's sort run over that many rows, not over the lineitem bucket
+    # result's sort run over that many rows, not over the lineitem bucket;
+    # and the key is no 64-bit number: pk2 matched a row only where its
+    # l_orderkey lies in the dense key's [1, |orders|] (``in_range`` is
+    # part of ``matched``) and ``_q3_planned_keyed_fn`` nulls the key of
+    # every other row, so every non-null key has 21 bits at SF1 and sorts
+    # as one word, in one sort with its null rank and the row-valid bit
     g = fusion.GroupBy(fusion.Project(j2, _q3_planned_keyed_fn), (0,),
                        ((1, "first_include_nulls"),
                         (2, "first_include_nulls"), (3, "sum")),
                        max_groups=fusion.groups_of("orders"),
-                       label="groupby")
+                       label="groupby",
+                       key_ranges=((1, fusion.rows_of("orders")),))
     return fusion.Plan("tpch_q3_planned", fusion.Sort(
         g, (3, 1), ascending=(False, True), nulls_first=(False, False)))
 
@@ -862,16 +868,19 @@ def tpch_q3_planned(customer: Table, orders: Table, lineitem: Table,
     gather — the join phase compiles with ZERO sorts (HLO-pinned in
     tests), where the general q3 pays two build-side lexsorts + probe
     searchsorteds on the sort-based machinery. On a v5e at SF1 (PERF.md
-    section 5, traced run of PR 31) the two joins take 0.45 s of a 1.34 s
+    section 5, traced runs of PR 37) the two joins take 0.44 s of a 1.01 s
     request, nearly all of it pk2's gathers of the order's columns by
-    6,001,215 positions, the groupby 0.57 s (its key sort 0.27 s, its
-    look-ups at 1,500,001 rows 0.21 s, the key and the revenue brought
-    into key order as five packed words 0.07 s; date and priority are read
-    at the group's first row) and the result's sort 0.28 s. The
+    6,001,215 positions, the result's sort 0.31 s and the groupby 0.24 s:
+    its look-ups at 1,500,001 rows 0.16 s, the key and the revenue brought
+    into key order as four packed words 0.06 s, its key sort 0.02 s (0.27 s
+    while the key was sorted as the 64-bit number its type says: three
+    passes that each gathered a word); date and priority are read at the
+    group's first row. The
     orderkey groupby stays on the general (sort-based) path: its
     cardinality is data-dependent, which is exactly the boundary of
     what a planner can declare; what the declared keys do give it is
-    one key to sort on and a bound on its groups (``_q3_planned_plan``).
+    one key to sort on, a bound on its groups and the key's range
+    (``_q3_planned_plan``).
 
     Output rows are one per LINEITEM row (PK fanout <= 1): no join
     capacity estimate, no overflow retry — the static shape is the

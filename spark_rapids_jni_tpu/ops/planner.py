@@ -319,6 +319,95 @@ def dense_pk_join(
         jnp.sum(matched.astype(jnp.int64)), pk_violation)
 
 
+class NarrowedKeys(NamedTuple):
+    """``narrow_group_keys``' result: the table to group, the check of the
+    declaration, and what ``widen_group_keys`` needs on the way out."""
+
+    table: Table
+    # True when a real row's non-null key lies outside its declared range:
+    # the rebased key wrapped, rows of different keys may share a group.
+    # The caller refuses the result, as it does on ``pk_violation``.
+    out_of_range: jnp.ndarray
+    # (position among the keys, the key's own dtype, lo) of each rebased key
+    narrowed: tuple
+
+
+def _range_dtype(span: int):
+    """The narrowest unsigned storage type that holds ``0..span``; None
+    past 32 bits."""
+    for dt in (t.UINT8, t.UINT16, t.UINT32):
+        if span < 1 << (8 * dt.storage_dtype.itemsize):
+            return dt
+    return None
+
+
+def narrow_group_keys(table: Table, keys: Sequence[int], ranges: Sequence,
+                      row_valid: jnp.ndarray | None = None) -> NarrowedKeys:
+    """Rebase every groupby key with a DECLARED range to the width the
+    range takes. ``ranges`` has one entry a key: None, or ``(lo, hi)``,
+    the planner's fact that every non-null key of a real row lies in
+    ``[lo, hi]`` (a PK constraint, a Parquet footer's min/max, a dense key
+    a join two nodes earlier verified row by row). Such a key becomes
+    ``data - lo`` in the narrowest unsigned type that holds ``hi - lo``,
+    its validity kept: the same groups in the same order, and a sort key
+    of 8, 16 or 32 bits where the schema's ``bigint`` was two words and,
+    with its null rank and the row-valid bit, a third (``ops/sort.py``: 80
+    bits sort word by word in a loop, 48 in one variadic sort). A range
+    that narrows nothing (past 32 bits, or as wide as the key's own type)
+    leaves its key alone and is not checked: nothing rests on it.
+
+    Rowwise, so it is the same on one chip and on a chip's share of the
+    rows. The declaration is VERIFIED, not trusted: one pass over the rows
+    in the key's own type, before the narrowing cast."""
+    if len(ranges) != len(keys):
+        raise ValueError(
+            f"key_ranges has {len(ranges)} entries for {len(keys)} keys")
+    cols = list(table.columns)
+    out_of_range = jnp.bool_(False)
+    narrowed = []
+    for at, (k, rng) in enumerate(zip(keys, ranges)):
+        if rng is None:
+            continue
+        lo, hi = int(rng[0]), int(rng[1])
+        c = cols[k]
+        if (c.dtype.is_string or c.dtype.is_decimal128
+                or c.dtype.storage_dtype.kind not in "iu"):
+            raise ValueError(
+                f"a key range needs an integer key; key {k} is {c.dtype}")
+        info = np.iinfo(c.dtype.storage_dtype)
+        if not info.min <= lo <= hi <= info.max:
+            raise ValueError(
+                f"key range [{lo}, {hi}] is empty or leaves key {k}'s "
+                f"{c.dtype.storage_dtype.name}")
+        narrow = _range_dtype(hi - lo)
+        if (narrow is None or narrow.storage_dtype.itemsize
+                >= c.dtype.storage_dtype.itemsize):
+            continue
+        own = c.data.dtype.type
+        inside = (c.data >= own(lo)) & (c.data <= own(hi))
+        real = c.valid_mask() if row_valid is None \
+            else c.valid_mask() & row_valid
+        out_of_range = out_of_range | jnp.any(real & ~inside)
+        cols[k] = Column(narrow, (c.data - own(lo)).astype(narrow.jnp_dtype),
+                         c.validity)
+        narrowed.append((at, c.dtype, lo))
+    return NarrowedKeys(Table(cols), out_of_range, tuple(narrowed))
+
+
+def widen_group_keys(grouped: Table, narrowed: tuple) -> Table:
+    """A groupby's result (its keys first) with the keys
+    ``narrow_group_keys`` rebased given back their own type and values:
+    one pass over the m group rows. Validity as the groupby left it; the
+    bytes under a null key are what the rebase of the stored bytes gives
+    back (the stored bytes themselves where they lie in the range)."""
+    cols = list(grouped.columns)
+    for at, dtype, lo in narrowed:
+        c = cols[at]
+        data = c.data.astype(dtype.jnp_dtype) + dtype.jnp_dtype.type(lo)
+        cols[at] = Column(dtype, data, c.validity)
+    return Table(cols)
+
+
 def _dense_prologue(gid: jnp.ndarray, m: int, block: int,
                     values: jnp.ndarray | None):
     """Shared scaffolding of the dense-id reductions: range-check in

@@ -5,14 +5,17 @@ the capability surface; exercised by TPC-H q1's final ORDER BY).
 TPU-first design: no comparator kernels. Each key column is *encoded* into
 order-preserving unsigned integer words (floats via sign-magnitude flip,
 signed ints via sign-bit flip, a 64-bit integer as its two 32-bit halves,
-with a null indicator folded in). Keys that pack into two 32-bit words
-are one ``jnp.argsort`` / ``jnp.lexsort`` (XLA's variadic sort, a
-comparator over every operand); wider keys are sorted one word at a time
-(``_radix_order``), because the TPU compiler's time for a variadic sort
-grows with about the square of its operand words (PERF.md section 6, PR
-28). Encoded keys also give Spark-compatible total float order (NaN
-sorts greatest, -0.0 == 0.0 is NOT collapsed: -0.0 < 0.0 bitwise —
-documented deviation from Java's Double.compare only for -0.0).
+with a null indicator folded in). Keys that pack into one 32-bit word are
+one ``jnp.argsort``, into two one variadic sort of the words and a 32-bit
+iota (``_sort_words``: XLA's variadic sort, a comparator over every
+operand); wider keys are sorted one word at a time (``_radix_order``),
+because the TPU compiler's time for a variadic sort grows with about the
+square of its operand words (PERF.md section 6, PR 28). A 64-bit key
+whose values a plan declares to span 32 bits or fewer is narrowed before
+it gets here (``ops/planner.py narrow_group_keys``). Encoded keys also
+give Spark-compatible total float order (NaN sorts greatest, -0.0 == 0.0
+is NOT collapsed: -0.0 < 0.0 bitwise — documented deviation from Java's
+Double.compare only for -0.0).
 
 What follows the sort is a row permutation. ``gather`` takes any indices
 and moves every buffer and every byte-wide mask by an element gather of
@@ -207,6 +210,19 @@ def _pack_words(lex_keys: list[jnp.ndarray]) -> list[jnp.ndarray]:
     return words + [acc]
 
 
+def _sort_words(words: list[jnp.ndarray]) -> tuple:
+    """One stable variadic sort of one or two key words (minor -> major)
+    and a 32-bit iota: ``(order, the words in that order)``. What
+    ``jnp.argsort`` / ``jnp.lexsort`` run, but for the iota: theirs is
+    int64 under x64, two words more to compare and move (8,388,608 rows of
+    two key words on a v5e, PERF.md section 6, PR 33: 0.0366 s and 63 s of
+    cold compile with an int64 iota, 0.0240 s and 41 s with this one)."""
+    iota = jax.lax.iota(jnp.int32, words[0].shape[0])
+    *major_first, order = jax.lax.sort(
+        (*words[::-1], iota), num_keys=len(words))
+    return order, major_first[::-1]
+
+
 def _radix_order(words: list[jnp.ndarray]) -> jnp.ndarray:
     """The stable order by uint32 ``words`` (minor -> major) as one stable
     single-key sort a word, least significant first, each over the rows
@@ -216,7 +232,11 @@ def _radix_order(words: list[jnp.ndarray]) -> jnp.ndarray:
     compile time whatever the row count (chip readings: PERF.md section
     6, PR 28), so a variadic sort of eight operands does not compile in a
     time a cold start can pay; this compiles in the same time for any
-    number of words."""
+    number of words. Every pass gathers its word by the running order
+    (0.072 s a pass at 8,388,608 rows inside a region, beside 0.018 s for
+    the sort itself), so it is for keys that are truly wider than two
+    words: several key columns, a 64-bit key with no declared range, the
+    sort of planned q3's result (a 64-bit revenue and a date)."""
     stacked = jnp.stack(words)
 
     def one_pass(i, order):
@@ -257,7 +277,11 @@ def _sort_order_impl(row_args, aux, rvs, *, keys, ascending, nulls_first):
     lex_keys, packable = _lex_keys(table, keys, ascending, nulls_first, rv)
     if len(lex_keys) == 1:
         return jnp.argsort(lex_keys[0], stable=True).astype(jnp.int32)
-    if packable and len(lex_keys) > 2:
+    if packable and len(lex_keys) == 2:
+        # 33 to 64 bits of key, null ranks and row-valid bit: one variadic
+        # sort (a groupby key narrowed to its declared range lands here)
+        return _sort_words(lex_keys)[0]
+    if packable:
         # wider than the two words one variadic sort takes well
         return _radix_order(_pack_words(lex_keys))
     return jnp.lexsort(tuple(lex_keys)).astype(jnp.int32)
@@ -308,15 +332,8 @@ def sort_key_words(table: Table, keys: Sequence[int],
     if not packable:
         raise TypeError("sort_key_words: a key has no fixed-width sort word")
     if len(lex_keys) <= 2:
-        # jnp.argsort / jnp.lexsort's own sort with its keys kept, but for
-        # the iota: theirs is int64 under x64, two words more to compare
-        # and move (8,388,608 rows of two key words on a v5e, PERF.md
-        # section 6, PR 33: 0.0366 s and 63 s of cold compile with an
-        # int64 iota, 0.0240 s and 41 s with this one)
-        iota = jax.lax.iota(jnp.int32, lex_keys[0].shape[0])
-        *major_first, order = jax.lax.sort(
-            (*lex_keys[::-1], iota), num_keys=len(lex_keys))
-        return order, lex_keys, major_first[::-1]
+        order, sorted_words = _sort_words(lex_keys)
+        return order, lex_keys, sorted_words
     words = _pack_words(lex_keys)
     order = _radix_order(words)
     return order, words, _move_words(words, order)

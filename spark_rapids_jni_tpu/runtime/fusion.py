@@ -211,10 +211,22 @@ class Project(NamedTuple):
 class GroupBy(NamedTuple):
     """``groupby_aggregate`` (or ``plan_groupby`` when ``domains`` is
     given). ``max_groups`` may be an int, None, or a ``min_rows_of`` /
-    ``groups_of`` spec.
+    ``groups_of`` spec. ``key_ranges`` is a planner's fact like it: one
+    entry a key, None or ``(lo, hi)`` with ``hi`` an int or a ``rows_of``
+    spec, declaring that every non-null key of a real row lies in ``[lo,
+    hi]``. An integer key with a range is grouped as ``key - lo`` in the
+    narrowest unsigned type that holds ``hi - lo``
+    (``ops/planner.narrow_group_keys``) and the group keys are widened back
+    on the way out: the same table, from a sort key of 32 bits or fewer
+    where the schema's 64 were sorted word by word (planned q3's key sort:
+    one sort of two words and an iota, not three passes that each gather a
+    word). Not with ``domains`` (the bounded lowering has its own
+    dictionary).
     Side outputs land in the result meta under ``<label>.*``
-    (num_groups/overflowed/sum_overflow, or present/domain_miss/lowered
-    on the planned lowering)."""
+    (num_groups/overflowed/sum_overflow/in_place, with a range that
+    narrowed a key also key_narrowed and key_out_of_range, which the
+    served path refuses as it does ``pk_violation``; or
+    present/domain_miss/lowered on the planned lowering)."""
 
     child: Any
     keys: tuple
@@ -223,6 +235,7 @@ class GroupBy(NamedTuple):
     domains: Any = None
     budget: int = 4096
     label: str = "groupby"
+    key_ranges: Any = None
 
 
 class Join(NamedTuple):
@@ -451,6 +464,23 @@ def _fn_key(fn) -> tuple:
     return (mod, qual)
 
 
+def _declares_range(node: GroupBy) -> bool:
+    """Whether a groupby declares a range for any of its keys
+    (``key_ranges`` None, or None for every key, declares none and lowers
+    as a node without the field)."""
+    if node.key_ranges is None:
+        return False
+    if node.domains is not None:
+        raise ValueError(
+            f"groupby {node.label!r}: key_ranges with domains (the bounded "
+            f"lowering has its own dictionary)")
+    if len(node.key_ranges) != len(node.keys):
+        raise ValueError(
+            f"groupby {node.label!r}: {len(node.key_ranges)} key_ranges "
+            f"for {len(node.keys)} keys")
+    return any(r is not None for r in node.key_ranges)
+
+
 def _fingerprint(nodes, resolved: dict) -> tuple:
     """Structural digest of the plan DAG: node kinds, static params,
     resolved row specs, and child indices — the fused region's dispatch
@@ -472,7 +502,8 @@ def _fingerprint(nodes, resolved: dict) -> tuple:
                     (None if d is None else (tuple(d.values), d.kind))
                     for d in node.domains)
             entry = ("groupby", node.keys, node.aggs,
-                     resolved[id(node)], doms, node.budget)
+                     resolved[id(node)], doms, node.budget,
+                     resolved.get((id(node), "key_ranges")))
         elif isinstance(node, Join):
             entry = ("join", node.left_on, node.right_on,
                      resolved[id(node)], node.how)
@@ -506,6 +537,11 @@ def _resolve_statics(nodes, true_rows: dict) -> dict:
     for node in nodes:
         if isinstance(node, GroupBy):
             resolved[id(node)] = _resolve(node.max_groups, true_rows)
+            if _declares_range(node):
+                resolved[id(node), "key_ranges"] = tuple(
+                    None if r is None
+                    else (int(r[0]), _resolve(r[1], true_rows))
+                    for r in node.key_ranges)
         elif isinstance(node, Join):
             resolved[id(node)] = _resolve(node.out_rows, true_rows)
         elif isinstance(node, DensePkJoin):
@@ -572,6 +608,9 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
                 if placement and placement[id(node.child)] == SHARDED:
                     keys += [f"{node.label}.shuffle_rows",
                              f"{node.label}.shuffle_bytes"]
+                if _declares_range(node):
+                    keys += [f"{node.label}.key_narrowed",
+                             f"{node.label}.key_out_of_range"]
         elif isinstance(node, Join):
             keys.append(f"{node.label}.total")
         elif isinstance(node, DensePkJoin):
@@ -632,7 +671,8 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
     from spark_rapids_jni_tpu.ops import bloom_filter as _bloom
     from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate
     from spark_rapids_jni_tpu.ops.join import apply_join_maps, join
-    from spark_rapids_jni_tpu.ops.planner import dense_pk_join, plan_groupby
+    from spark_rapids_jni_tpu.ops.planner import (
+        dense_pk_join, narrow_group_keys, plan_groupby, widen_group_keys)
     from spark_rapids_jni_tpu.ops.sort import gather, sort_order
 
     env: dict = {}
@@ -652,40 +692,59 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                 out = (node.fn(tbl, *node.params), rv)
             else:
                 out = (node.fn(tbl, rv, *node.params), None)
-        elif isinstance(node, GroupBy) and placement is not None \
-                and placement[id(node.child)] == SHARDED:
-            tbl, rv = ev(node.child)
-            gtbl, gside = _mesh_groupby(node, tbl, rv, resolved[id(node)],
-                                        mesh_axis)
-            side.extend(gside)
-            out = (gtbl, None)
         elif isinstance(node, GroupBy):
             tbl, rv = ev(node.child)
-            if node.domains is not None:
+            # a declared key range: the key is grouped at the width the
+            # range takes and widened back on the way out, all under this
+            # node's scope (rowwise, so the same on a chip's share)
+            ranges = resolved.get((id(node), "key_ranges"))
+            if ranges is not None:
+                keyed = narrow_group_keys(tbl, node.keys, ranges, rv)
+                tbl = keyed.table
+            over_mesh = (placement is not None
+                         and placement[id(node.child)] == SHARDED)
+            rv_out = None
+            if over_mesh:
+                gtbl, gside = _mesh_groupby(
+                    node, tbl, rv, resolved[id(node)], mesh_axis)
+            elif node.domains is not None:
                 res = plan_groupby(
                     tbl, list(node.keys), list(node.aggs),
                     list(node.domains), budget=node.budget, row_valid=rv)
-                side.extend([
+                gtbl, gside = res.table, [
                     (f"{node.label}.present", res.present),
                     (f"{node.label}.domain_miss", res.domain_miss),
                     (f"{node.label}.overflowed",
                      jnp.asarray(res.overflowed)),
-                ])
-                out = (res.table, None)
+                ]
             else:
                 g = groupby_aggregate(
                     tbl, list(node.keys), list(node.aggs),
                     max_groups=resolved[id(node)], row_valid=rv)
-                side.extend([
+                gtbl, gside = g.table, [
                     (f"{node.label}.num_groups", g.num_groups),
                     (f"{node.label}.overflowed", jnp.asarray(g.overflowed)),
                     (f"{node.label}.sum_overflow",
                      jnp.asarray(g.sum_overflow)),
                     (f"{node.label}.in_place", jnp.asarray(g.in_place)),
+                ]
+                if resolved[id(node)] is None:
+                    rv_out = rv   # padded to the input rows: still positional
+            side.extend(gside)
+            if ranges is not None:
+                gtbl = widen_group_keys(gtbl, keyed.narrowed)
+                broke = keyed.out_of_range
+                if over_mesh:     # on any chip
+                    broke = jax.lax.psum(
+                        broke.astype(jnp.int32), mesh_axis) > 0
+                side.extend([
+                    # whether a key was narrowed is a fact of the lowering
+                    # (the key's type, the range's width), as in_place is
+                    (f"{node.label}.key_narrowed",
+                     jnp.asarray(bool(keyed.narrowed))),
+                    (f"{node.label}.key_out_of_range", broke),
                 ])
-                # a None budget pads to the input rows: still positional
-                rv_out = rv if resolved[id(node)] is None else None
-                out = (g.table, rv_out)
+            out = (gtbl, rv_out)
         elif isinstance(node, Join):
             ltbl, lrv = ev(node.left)
             rtbl, rrv = ev(node.right)
@@ -1465,16 +1524,20 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     shuffled (exchanges, the partial rows it sent and the bytes its
     ``all_to_all`` put between chips), and how many nodes broke what the plan
     declares: a dense primary key that is not one (``pk_violation``), a
-    group bound that was too small (``overflowed``); and how many groupbys
-    took their aggregates over the rows where they lie, no value word
-    brought into key order (``groupby.in_place``: a fact of the lowering,
-    ``ops/groupby.py``). A result with a broken declaration is a wrong
-    answer; the served path refuses it
+    group bound that was too small (``overflowed``), a key outside its
+    declared range (``key_out_of_range``); and how many groupbys took
+    their aggregates over the rows where they lie, no value word brought
+    into key order (``groupby.in_place``: a fact of the lowering,
+    ``ops/groupby.py``), and how many grouped a key at the width of its
+    declared range (``groupby.key_narrowed``: a fact of the lowering too,
+    ``ops/planner.narrow_group_keys``). A result with a broken declaration
+    is a wrong answer; the served path refuses it
     (``QueryServer._account_meta``). Converting a meta value waits for the
     device, so call it where the meta is wanted on the host anyway."""
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
              "join.pk_violation": 0, "groupby.groups": 0,
              "groupby.overflowed": 0, "groupby.in_place": 0,
+             "groupby.key_narrowed": 0, "groupby.key_out_of_range": 0,
              "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0}
     for node in _topo(plan.root):
         if isinstance(node, (Join, DensePkJoin)):
@@ -1491,8 +1554,9 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["groupby.groups"] += int(groups)
             facts["groupby.overflowed"] += bool(
                 meta.get(f"{node.label}.overflowed", False))
-            facts["groupby.in_place"] += bool(
-                meta.get(f"{node.label}.in_place", False))
+            for fact in ("in_place", "key_narrowed", "key_out_of_range"):
+                facts[f"groupby.{fact}"] += bool(
+                    meta.get(f"{node.label}.{fact}", False))
             sent = meta.get(f"{node.label}.shuffle_rows")
             if sent is not None:   # lowered over a mesh: one all_to_all
                 facts["shuffle.exchanges"] += 1

@@ -1071,8 +1071,9 @@ class QueryServer:
         """Count, once a request, what the plan's joins and groupbys report
         in the result's meta (its host copy; ``fusion.meta_facts``:
         counters ``join.probe_rows``, ``join.matched_rows``,
-        ``groupby.groups``, ``groupby.in_place``, ``join.pk_violation``,
-        ``groupby.overflowed``, and of a groupby lowered over a mesh
+        ``groupby.groups``, ``groupby.in_place``, ``groupby.key_narrowed``,
+        ``join.pk_violation``, ``groupby.overflowed``,
+        ``groupby.key_out_of_range``, and of a groupby lowered over a mesh
         ``shuffle.exchanges``, ``shuffle.rows`` and ``shuffle.bytes``: the
         served path's shuffle telemetry), and refuse a result that broke what its plan declares:
         rows were dropped or merged, so it must not resolve as a success."""
@@ -1086,10 +1087,15 @@ class QueryServer:
                 f"plan {plan.name!r}: a groupby found more groups than its "
                 f"bound; the result is not the query's answer",
                 groups=facts["groupby.groups"])
-        if facts["join.pk_violation"]:
-            raise resilience.FatalExecutionError(
-                f"plan {plan.name!r}: a declared dense primary key is not "
-                f"one (pk_violation); the result is not the query's answer")
+        for fact, broke in (
+                ("join.pk_violation", "a declared dense primary key is not "
+                 "one (pk_violation)"),
+                ("groupby.key_out_of_range", "a groupby key lies outside "
+                 "its declared range (key_out_of_range)")):
+            if facts[fact]:
+                raise resilience.FatalExecutionError(
+                    f"plan {plan.name!r}: {broke}; the result is not the "
+                    f"query's answer")
 
     def _default_estimate(self, plan: fusion.Plan, bindings: dict) -> int:
         """Headroom x the measured-truth EMA for this plan signature when

@@ -339,3 +339,192 @@ def test_row_specs_resolve_from_true_rows():
     assert fusion._resolve(fusion.min_rows_of("t", 7), {"t": 4}) == 4
     assert fusion._resolve(None, {}) is None
     assert fusion._resolve(12, {}) == 12
+
+
+# ---------------------------------------------------------------------------
+# declared key ranges: a ranged GroupBy gives the table an unranged one gives
+# ---------------------------------------------------------------------------
+
+
+def _ranged_table(np_dt, lo, hi, n, groups, seed=3):
+    """[key over ``groups`` values of [lo, hi] with both ends and nulls,
+    int32 payload with nulls, decimal payload]."""
+    rng = np.random.default_rng([seed, n, groups])
+    pool = np.unique(np.r_[lo, hi, rng.integers(lo, hi + 1, groups)])
+    key = pool[rng.integers(0, len(pool), n)].astype(np_dt)
+    return Table([
+        Column(t.DType.from_numpy(np.dtype(np_dt)), key, rng.random(n) > 0.1),
+        Column(t.INT32, rng.integers(-50, 50, n).astype(np.int32),
+               rng.random(n) > 0.2),
+        Column(t.decimal64(-2), rng.integers(-10**9, 10**9, n))])
+
+
+def _ranged_plan(key_ranges, aggs, max_groups, name="ranged_groupby"):
+    return fusion.Plan(name, fusion.GroupBy(
+        fusion.Scan("t"), (0,), aggs, max_groups=max_groups,
+        label="groupby", key_ranges=key_ranges))
+
+
+_WORD_MOVING = ((1, "first_include_nulls"), (2, "sum"), (1, "count"))
+_IN_PLACE = ((2, "sum"), (1, "count"), (1, "sum"))
+
+
+@pytest.mark.parametrize("np_dt, lo, hi", [
+    (np.int64, 1, 1_500_000), (np.int64, -40_000, 90_000),
+    (np.int64, 1, 200), (np.int32, -7, 60_000),
+], ids=["i64_from_1", "i64_lo_negative", "i64_8bit", "i32_lo_negative"])
+@pytest.mark.parametrize("aggs, max_groups, groups", [
+    (_WORD_MOVING, 3000, 2000),      # over _SMALL_M: permute's words
+    (_WORD_MOVING, None, 150),       # no bound: padded to the rows
+    (_IN_PLACE, 64, 40),             # under it: the sums where the rows lie
+], ids=["word_moving", "unbounded", "in_place"])
+@pytest.mark.parametrize("n", [4096, 5000], ids=["on_bucket", "phantom_rows"])
+def test_ranged_groupby_matches_unranged(np_dt, lo, hi, aggs, max_groups,
+                                         groups, n):
+    """Bit for bit: the key's dtype, validity and values (the null group
+    among them), every aggregate, the groups' order, the side outputs;
+    fused (a bucket's phantom rows where n is off it) and staged."""
+    from spark_rapids_jni_tpu.ops import groupby as gb
+
+    table = _ranged_table(np_dt, lo, hi, n, min(groups, hi - lo))
+    ranged = _ranged_plan(((lo, hi),), aggs, max_groups)
+    plain = _ranged_plan(None, aggs, max_groups)
+    got = fusion.execute(ranged, {"t": table})
+    want = fusion.execute(plain, {"t": table})
+    assert [c.dtype for c in got.table.columns] == [
+        c.dtype for c in want.table.columns]
+    _assert_tables_identical(got.table, want.table, "ranged vs unranged")
+    for fact in ("num_groups", "overflowed", "sum_overflow", "in_place"):
+        assert np.array_equal(got.meta[f"groupby.{fact}"],
+                              want.meta[f"groupby.{fact}"]), fact
+    assert not bool(got.meta["groupby.overflowed"])
+    assert bool(got.meta["groupby.in_place"]) == (
+        aggs is _IN_PLACE and max_groups <= gb._SMALL_M)
+    assert bool(got.meta["groupby.key_narrowed"])
+    assert not bool(got.meta["groupby.key_out_of_range"])
+    assert "groupby.key_narrowed" not in want.meta
+    # the null group is there, first
+    first = got.table.column(0)
+    assert not bool(np.asarray(first.valid_mask())[0])
+    staged = _staged(lambda: fusion.execute(ranged, {"t": table}))
+    _assert_tables_identical(staged.table, got.table, "staged vs fused")
+    assert bool(staged.meta["groupby.key_narrowed"])
+    facts = fusion.meta_facts(ranged, got.meta)
+    assert facts["groupby.key_narrowed"] == 1
+    assert facts["groupby.key_out_of_range"] == 0
+    assert fusion.meta_facts(plain, want.meta)["groupby.key_narrowed"] == 0
+
+
+def test_ranged_groupby_over_a_mesh_matches_one_chip():
+    """The rebase is rowwise, so a chip's share is rebased as the whole
+    is: the same groups with the same sums through partial, shuffle (of
+    the narrowed key), merge and collect. A mesh's groups come chip after
+    chip (the key's hash places them), so they are compared in key
+    order."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_rapids_jni_tpu.parallel.mesh import EXEC_AXIS, executor_mesh
+
+    lo, hi, n = -40_000, 90_000, 4096
+    table = _ranged_table(np.int64, lo, hi, n, 40)
+    aggs = ((2, "sum"), (1, "count"))
+    sharding = NamedSharding(executor_mesh(4), P(EXEC_AXIS))
+    sharded = Table([Column(c.dtype, jax.device_put(c.data, sharding),
+                            jax.device_put(c.valid_mask(), sharding))
+                     for c in table.columns])
+    ranged = _ranged_plan(((lo, hi),), aggs, 64, name="ranged_mesh")
+    got = fusion.execute(ranged, {"t": sharded})
+    assert "groupby.shuffle_rows" in got.meta      # it crossed the chips
+    assert bool(got.meta["groupby.key_narrowed"])
+    assert not bool(got.meta["groupby.key_out_of_range"])
+    want = fusion.execute(_ranged_plan(None, aggs, 64), {"t": table})
+    groups = int(want.meta["groupby.num_groups"])
+    assert int(got.meta["groupby.num_groups"]) == groups
+
+    def rows(res):
+        assert res.table.column(0).dtype == t.INT64
+        cols = [c.to_pylist()[:groups] for c in res.table.columns]
+        return sorted(zip(*cols), key=lambda r: (r[0] is not None, r[0] or 0))
+
+    assert rows(got) == rows(want)
+    # a key outside the range on ONE chip's rows is every chip's fact
+    narrow = _ranged_plan(((lo, hi - 70_000),), aggs, 64, name="ranged_mesh")
+    assert bool(fusion.execute(
+        narrow, {"t": sharded}).meta["groupby.key_out_of_range"])
+
+
+def test_key_outside_its_declared_range_is_reported():
+    table = _ranged_table(np.int64, 1, 1000, 600, 30)
+    held = _ranged_plan(((1, 1000),), _WORD_MOVING, 64)
+    broken = _ranged_plan(((2, 1000),), _WORD_MOVING, 64)   # key 1 exists
+    for run in (fusion.execute, lambda p, b: _staged(
+            lambda: fusion.execute(p, b))):
+        assert not bool(run(held, {"t": table}).meta[
+            "groupby.key_out_of_range"])
+        res = run(broken, {"t": table})
+        assert bool(res.meta["groupby.key_out_of_range"])
+        assert fusion.meta_facts(broken, res.meta)[
+            "groupby.key_out_of_range"] == 1
+
+
+def test_served_key_out_of_range_is_a_failed_request():
+    """The declaration is verified, not trusted: the server refuses the
+    result as it refuses a ``pk_violation``, and nothing of it stays in
+    the result cache (the same request fails again and is no hit)."""
+    from spark_rapids_jni_tpu.runtime import resilience
+    from spark_rapids_jni_tpu.runtime.server import QueryServer
+
+    table = _ranged_table(np.int64, 1, 1000, 600, 30)
+    held = _ranged_plan(((1, fusion.rows_of("t", 2)),), _IN_PLACE, 64)
+    broken = _ranged_plan(((2, fusion.rows_of("t", 2)),), _IN_PLACE, 64)
+    with QueryServer(budget_bytes=4 << 30) as srv:
+        ticket = srv.session("s").submit(held, {"t": table})
+        assert ticket.result() is not None and ticket.status == "served"
+        counters = REGISTRY.counters()
+        assert counters["groupby.key_narrowed"] == 1
+        assert counters.get("groupby.key_out_of_range", 0) == 0
+        entries = srv.result_cache.stats()["entries"]
+        for again in (1, 2):
+            ticket = srv.session("s").submit(broken, {"t": table})
+            with pytest.raises(resilience.FatalExecutionError,
+                               match="key_out_of_range.*not the query's"):
+                ticket.result()
+            assert ticket.status == "failed"
+            counters = REGISTRY.counters()
+            assert counters["groupby.key_out_of_range"] == again
+            assert counters.get("cache.hit", 0) == 0
+            assert srv.result_cache.stats()["entries"] == entries == 1
+        assert counters["server.served"] == 1
+
+
+def test_key_ranges_are_a_static_of_the_plan():
+    """They ride the plan's fingerprint (the dispatch key, the result
+    cache's plan half) with their row specs resolved; with ``domains``
+    they are refused, as is one entry too few."""
+    from spark_rapids_jni_tpu.ops.planner import scalar_domain
+
+    table = _ranged_table(np.int64, 1, 1000, 600, 30)
+    b = {"t": table}
+    prints = [fusion.plan_fingerprint(_ranged_plan(r, _IN_PLACE, 64), b)
+              for r in (None, ((1, 1000),), ((1, 1200),), ((0, 1000),),
+                        ((1, fusion.rows_of("t", 2)),))]
+    assert len(set(prints[:4])) == 4
+    assert prints[4] == prints[2]          # 2 x 600 rows
+    assert prints[0] == fusion.plan_fingerprint(
+        _ranged_plan((None,), _IN_PLACE, 64), b)
+    before = REGISTRY.counters().get("dispatch.compile.fusion.ranged_groupby", 0)
+    for r in (((1, 1000),), ((1, 1200),), ((1, 1000),)):
+        fusion.execute(_ranged_plan(r, _IN_PLACE, 64), b)
+    assert REGISTRY.counters()[
+        "dispatch.compile.fusion.ranged_groupby"] == before + 2
+    with_domains = fusion.Plan("p", fusion.GroupBy(
+        fusion.Scan("t"), (0,), ((2, "sum"),),
+        domains=(scalar_domain(range(1, 9)),), key_ranges=((1, 8),)))
+    with pytest.raises(ValueError, match="key_ranges with domains"):
+        fusion.execute(with_domains, b)
+    with pytest.raises(ValueError, match="key_ranges with domains"):
+        fusion.plan_fingerprint(with_domains, b)
+    two_keys = fusion.Plan("p", fusion.GroupBy(
+        fusion.Scan("t"), (0, 1), ((2, "sum"),), key_ranges=((1, 8),)))
+    with pytest.raises(ValueError, match="1 key_ranges for 2 keys"):
+        fusion.execute(two_keys, b)

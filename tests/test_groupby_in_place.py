@@ -433,16 +433,19 @@ def test_general_q1_region_sorts_its_keys_and_nothing_else():
 
 
 def test_planned_q3_region_keeps_its_sorts(monkeypatch):
-    """The bound of planned q3 (257 here, 1,500,001 in the cell) is a
-    groups-of-orders spec over the block path's gate in the cell; at the
-    tests' size it is held over it by hand: the region's sorts are the
-    parent's in number and kind, and ``permute``'s loop is still there."""
+    """The bound of planned q3 (70,001 here, 1,500,001 in the cell) is a
+    groups-of-orders spec over the block path's gate: the region keeps the
+    word-moving path, its sorts what they were in number, and ``permute``'s
+    loop is still there. The key sort's loop is not (PR 37): the key's
+    declared range makes it one sort of two words and an iota."""
     monkeypatch.setattr(so, "_SORT_MOVE_MIN_WORDS", 0)
-    monkeypatch.setattr(gb, "_SMALL_M", 64)
-    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204),
+                      _q3_tables(n_ord=70_000))
     sorts = _sorts(hlo)
     assert len(sorts) == 4 and sorts.count("u32[4000]{0}") == 1, sorts
-    assert len(_loops_that_sort(hlo)) == 3     # key sort, permute, ORDER BY
+    assert sorts.count(
+        "(u32[4000]{0}, u32[4000]{0}, s32[4000]{0})") == 1, sorts
+    assert len(_loops_that_sort(hlo)) == 2     # permute, ORDER BY
 
 
 # -- the served path's counter --------------------------------------------

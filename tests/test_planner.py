@@ -1015,3 +1015,115 @@ def test_plan_groupby_auto_budget_clamps():
     with pytest.raises(ValueError, match="max_budget"):
         plan_groupby_auto(tbl, [0], [(1, "sum")], [None],
                           budget=4096, max_budget=64)
+
+
+# ---------------------------------------------------------------------------
+# declared key ranges: a groupby key at the width its range takes
+# ---------------------------------------------------------------------------
+
+
+def _ranged_key(np_dt, lo, hi, n=500, seed=9):
+    """A key column over [lo, hi] with ties, both ends, and nulls."""
+    rng = np.random.default_rng(seed)
+    pool = np.r_[lo, hi, rng.integers(lo, hi + 1, 12)].astype(np_dt)
+    vals = pool[rng.integers(0, len(pool), n)]
+    return vals, rng.random(n) > 0.15
+
+
+@pytest.mark.parametrize("np_dt, lo, hi, narrow", [
+    (np.int64, 1, 1_500_000, t.UINT32),
+    (np.int64, 1, 65_536, t.UINT16),
+    (np.int64, -40_000, 25_535, t.UINT16),
+    (np.int64, -(1 << 40), -(1 << 40) + 255, t.UINT8),
+    (np.int64, 0, (1 << 32) - 1, t.UINT32),
+    (np.int32, -7, 200, t.UINT8),
+    (np.int32, 1, 65_536, t.UINT16),
+], ids=["i64_21bit", "i64_16bit", "i64_lo_negative", "i64_far_from_zero",
+        "i64_32bit", "i32_8bit", "i32_16bit"])
+def test_narrow_group_keys_rebases_to_the_ranges_width(np_dt, lo, hi, narrow):
+    from spark_rapids_jni_tpu.ops.planner import (narrow_group_keys,
+                                                  widen_group_keys)
+
+    vals, valid = _ranged_key(np_dt, lo, hi)
+    dt = t.DType.from_numpy(np.dtype(np_dt))
+    other = Column(t.INT64, jnp.arange(len(vals), dtype=jnp.int64))
+    table = Table([other, Column(dt, jnp.asarray(vals), jnp.asarray(valid))])
+    keyed = narrow_group_keys(table, (1,), ((lo, hi),))
+    assert not bool(keyed.out_of_range)
+    assert keyed.narrowed == ((0, dt, lo),)
+    key = keyed.table.column(1)
+    assert key.dtype == narrow
+    assert np.array_equal(np.asarray(key.valid_mask()), valid)
+    assert np.array_equal(np.asarray(key.data).astype(object),
+                          vals.astype(object) - lo)
+    assert keyed.table.column(0) is other      # what has no range stays
+    # order is kept: the rebased key sorts as the key does
+    assert np.array_equal(np.argsort(np.asarray(key.data), kind="stable"),
+                          np.argsort(vals, kind="stable"))
+    # a groupby's result has its keys first: the way back
+    back = widen_group_keys(Table([key, other]), keyed.narrowed).column(0)
+    assert back.dtype == dt
+    assert np.array_equal(np.asarray(back.data), vals)   # nulls' bytes too
+    assert np.array_equal(np.asarray(back.valid_mask()), valid)
+
+
+def test_narrow_group_keys_checks_the_declaration():
+    """A real row's non-null key outside the range breaks it; a null key
+    and a phantom row's key are no keys."""
+    from spark_rapids_jni_tpu.ops.planner import narrow_group_keys
+
+    vals = np.array([1, 5, 9, 10, 0, 11, -3], np.int64)
+
+    def broke(valid, row_valid=None, rng=(1, 10)):
+        table = Table([Column(t.INT64, jnp.asarray(vals),
+                              jnp.asarray(np.array(valid, bool)))])
+        rv = None if row_valid is None else jnp.asarray(
+            np.array(row_valid, bool))
+        return bool(narrow_group_keys(table, (0,), (rng,), rv).out_of_range)
+
+    assert not broke([1, 1, 1, 1, 0, 0, 0])
+    assert broke([1, 1, 1, 1, 1, 0, 0])            # 0 < lo
+    assert broke([1, 1, 1, 1, 0, 1, 0])            # 11 > hi
+    assert broke([1, 1, 1, 1, 0, 0, 1])            # -3 wraps when rebased
+    assert not broke([1] * 7, row_valid=[1, 1, 1, 1, 0, 0, 0])
+    assert broke([1] * 7, row_valid=[1, 1, 1, 1, 0, 1, 0])
+    assert not broke([1] * 7, rng=(-3, 11))
+
+
+def test_narrow_group_keys_leaves_what_a_range_cannot_narrow():
+    """A range past 32 bits, or one as wide as the key's own type, rebases
+    nothing (and is not checked: nothing rests on it); a key with no range
+    is not looked at."""
+    from spark_rapids_jni_tpu.ops.planner import narrow_group_keys
+
+    table = Table([
+        Column(t.INT64, jnp.asarray(np.array([5, 1 << 40], np.int64))),
+        Column(t.INT32, jnp.asarray(np.array([7, 70_000], np.int32))),
+        Column(t.INT8, jnp.asarray(np.array([1, 2], np.int8))),
+        Column.from_pylist(["a", "b"], t.STRING)])
+    keyed = narrow_group_keys(
+        table, (0, 1, 2, 3),
+        ((0, 1 << 32), (0, 65_536), (0, 3), None))
+    assert keyed.narrowed == () and not bool(keyed.out_of_range)
+    assert all(a is b for a, b in zip(keyed.table.columns, table.columns))
+
+
+@pytest.mark.parametrize("keys, ranges, match", [
+    ((3,), ((0, 9),), "integer key"),
+    ((4,), ((0, 9),), "integer key"),
+    ((0,), ((9, 0),), "empty"),
+    ((1,), ((0, 1 << 40),), "leaves key"),
+    ((0, 1), ((0, 9),), "1 entries for 2 keys"),
+], ids=["string", "float", "empty", "outside_the_type", "count"])
+def test_narrow_group_keys_refuses_a_range_that_cannot_hold(keys, ranges,
+                                                            match):
+    from spark_rapids_jni_tpu.ops.planner import narrow_group_keys
+
+    table = Table([
+        Column(t.INT64, jnp.zeros(2, jnp.int64)),
+        Column(t.INT32, jnp.zeros(2, jnp.int32)),
+        Column(t.INT8, jnp.zeros(2, jnp.int8)),
+        Column.from_pylist(["a", "b"], t.STRING),
+        Column(t.FLOAT64, jnp.zeros(2, jnp.float64))])
+    with pytest.raises(ValueError, match=match):
+        narrow_group_keys(table, keys, ranges)

@@ -168,22 +168,38 @@ def moved_by(request, monkeypatch):
     return request.param
 
 
+# orders enough that the key's declared range [1, |orders|] takes more
+# than 16 bits, as the cell's 1,500,000 do: the key is grouped as a uint32
+N_ORD = 70_000
+
+
 def test_planned_q3_region_sorts_one_word_at_a_time(moved_by):
     """XLA's TPU compiler takes about the square of a sort's operand words
-    in compile time: the region's sorts (the groupby's, the result's and,
-    where the groupby's words move by sort passes, that loop's one) are
-    each two operands of 32 bits, in a loop; the groupby's compaction of
+    in compile time: the result's sort and, where the groupby's words move
+    by sort passes, that loop's one are each two operands of 32 bits, in a
+    loop; the groupby's key sort is ONE sort of the key narrowed to its
+    declared range, the word of its null rank and row-valid bit, and a
+    32-bit iota, in no loop (it was a loop of three passes that each
+    gathered a word by the running order); the groupby's compaction of
     its group starts is one operand of 32 bits; none has a 64-bit
     operand."""
-    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
+    n = 4000
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204),
+                      _q3_tables(n_ord=N_ORD, n=n))
     sorts = _sorts(hlo)
     assert len(sorts) == (4 if moved_by == "sort_passes" else 3), sorts
-    assert sorts.count("u32[4000]{0}") == 1, sorts
+    assert sorts.count(f"u32[{n}]{{0}}") == 1, sorts
+    key_sort = f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s32[{n}]{{0}})"
+    assert sorts.count(key_sort) == 1, sorts
     for result in sorts:
-        assert re.fullmatch(
+        assert result == key_sort or re.fullmatch(
             r"\(u32\[\d+\]\{0\}, [us]32\[\d+\]\{0\}\)|u32\[\d+\]\{0\}",
             result), result
     assert not re.search(r"[us]64\[[^\]]*\][^=\n]* sort\(", hlo)
+    # the key sort and the compaction stand under the node, in no loop
+    flat = [name for name in _scoped(hlo, "sort", "groupby")
+            if "/while/" not in name]
+    assert len(flat) == 2, flat
 
 
 def _scoped(hlo: str, kind: str, node: str) -> list:
@@ -194,16 +210,18 @@ def _scoped(hlo: str, kind: str, node: str) -> list:
 
 
 def test_planned_q3_groupby_finds_its_bounds_by_one_compaction(moved_by):
-    """The bounds of the 257 groups come from one sort of the group-start
-    mask and a slice: under the node's scope no ``while`` steps a binary
-    search over per-row group ids (there were two, of 24 gathers of
-    1,500,001 each in the cell), the loops left are the key sort's and
-    ``permute``'s, and there is the one sort more."""
-    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
+    """The bounds of the 70,001 groups come from one sort of the
+    group-start mask and a slice: under the node's scope no ``while``
+    steps a binary search over per-row group ids (there were two, of 24
+    gathers of 1,500,001 each in the cell), the one loop left is
+    ``permute``'s (the key sort's went with the key's declared range),
+    and beside it stand the key sort and the one sort more."""
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204),
+                      _q3_tables(n_ord=N_ORD))
     loops = _scoped(hlo, "while", "groupby")
     assert not [name for name in loops if "searchsorted" in name], loops
-    assert len(loops) == (2 if moved_by == "sort_passes" else 1), loops
-    assert len(_scoped(hlo, "sort", "groupby")) == len(loops) + 1
+    assert len(loops) == (1 if moved_by == "sort_passes" else 0), loops
+    assert len(_scoped(hlo, "sort", "groupby")) == len(loops) + 2
 
 
 def _gathers(hlo: str, node: str | None = None) -> list:
@@ -219,18 +237,20 @@ def _gathers(hlo: str, node: str | None = None) -> list:
 
 
 def test_planned_q3_groupby_moves_no_column_it_reads_at_one_row(moved_by):
-    """The groupby brings into key order the key, the revenue and their
-    bits: five words. ``o_orderdate`` / ``o_shippriority`` are read at the
-    group's first row through ``order`` (m = |orders| + 1 rows), so under
-    the node's scope no gather outside the key sort's loop has n rows but
-    the one of the five packed words, and with sort passes none at all."""
-    n, m = 4000, 257
-    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables())
+    """The groupby brings into key order the key (one word since its
+    range is declared), the revenue and their bits: four words.
+    ``o_orderdate`` / ``o_shippriority`` are read at the group's first row
+    through ``order`` (m = |orders| + 1 rows), so under the node's scope
+    no gather has n rows, in a loop or out of one (the key sort's three
+    went with its loop), but the one of the four packed words, and with
+    sort passes none at all."""
+    n, m = 4000, N_ORD + 1
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204),
+                      _q3_tables(n_ord=N_ORD, n=n))
     found = _gathers(hlo, "groupby")
-    rows_n = [(t, dims) for t, dims, looped in found
-              if not looped and n in dims]
+    rows_n = [(t, dims) for t, dims, _ in found if n in dims]
     assert rows_n == ([] if moved_by == "sort_passes"
-                      else [("u32", [5, n])]), rows_n
+                      else [("u32", [4, n])]), rows_n
     # the first-row picks: int32 data and a mask, at m rows
     picks = [dims for t, dims, _ in found if t == "s32"]
     assert picks and all(dims == [m] for dims in picks), picks
@@ -238,9 +258,12 @@ def test_planned_q3_groupby_moves_no_column_it_reads_at_one_row(moved_by):
 
 def test_general_q1_sort_keeps_its_two_packed_words():
     """General q1's keys (two int8 flags, their null ranks, the row-valid
-    bit: 40 bits) pack into two uint32 words sorted by one variadic sort
-    with jnp.lexsort's int64 iota, as before 64-bit keys became words: the
-    accepted cell ``sf1_q1_general_fresh`` compiles the module it compiled."""
+    bit: 40 bits) pack into two uint32 words sorted by one variadic sort,
+    as before 64-bit keys became words. ``sort_order``'s two-word sort is
+    the one ``sort_key_words`` runs, a 32-bit iota its third operand and
+    not ``jnp.lexsort``'s int64 one (PR 37): no cell sorts through this
+    branch without a declared key range (general q1's groupby reads
+    ``sort_key_words``, its ORDER BY is one word)."""
     n = 4096
     rng = np.random.default_rng(3)
     flags = Table([Column(t.INT8, jnp.asarray(
@@ -254,7 +277,61 @@ def test_general_q1_sort_keeps_its_two_packed_words():
 
     rv = jnp.asarray(rng.random(n) > 0.2)
     hlo = jax.jit(order).lower(flags, rv).compile().as_text()
-    assert _sorts(hlo) == [f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s64[{n}]{{0}})"]
+    assert _sorts(hlo) == [f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s32[{n}]{{0}})"]
+    rows = np.asarray(order(flags, rv))
+    keys = [np.asarray(c.data) for c in flags.columns]
+    valid = [np.asarray(c.valid_mask()) for c in flags.columns]
+    want = _oracle_order(list(zip(keys, valid)), (True, True), (True, True),
+                         np.asarray(rv))
+    assert rows.tolist()[:int(np.asarray(rv).sum())] == want[:int(
+        np.asarray(rv).sum())]
+
+
+def _mesh_region_hlo(plan, table) -> str:
+    """The region as one chip of four runs it (``fusion.mesh_step`` under
+    ``shard_map``): the lowering ``sf10_q1_distributed_4chip`` compiles."""
+    from jax.sharding import PartitionSpec as P
+
+    from spark_rapids_jni_tpu.parallel.mesh import EXEC_AXIS, executor_mesh
+
+    def step(local):
+        res = fusion.mesh_step(plan, {"lineitem": local}, EXEC_AXIS)
+        return res.table, res.meta
+
+    region = jax.shard_map(step, mesh=executor_mesh(4), in_specs=P(EXEC_AXIS),
+                           out_specs=P(), check_vma=False)
+    return jax.jit(region).lower(table).compile().as_text()
+
+
+@pytest.mark.parametrize("cell", ["general", "distributed"])
+def test_q1_regions_lower_as_without_the_key_ranges_field(cell):
+    """The no-change side of the declared key range: the two q1 plans
+    whose groupby takes the sort path and declares no range (the cells
+    ``sf1_q1_general_fresh`` and, over a mesh of four,
+    ``sf10_q1_distributed_4chip``). A ``GroupBy`` that declares none,
+    None for every key, lowers to the text of the default, with the same
+    side outputs and the same fingerprint: the cells compile what they
+    compiled."""
+    table = tpch.lineitem_table(4096)
+    if cell == "general":
+        plan = tpch._q1_plan()
+        lower = lambda p: _region_hlo(p, {"lineitem": table})  # noqa: E731
+    else:
+        plan = tpch._q1_distributed_plan()
+        lower = lambda p: _mesh_region_hlo(p, table)  # noqa: E731
+    (g,) = [n for n in fusion._topo(plan.root)
+            if isinstance(n, fusion.GroupBy)]
+    explicit = fusion.Plan(plan.name, fusion.replace_node(
+        plan.root, g, g._replace(key_ranges=(None,) * len(g.keys))))
+    # lowered from one line: the text names the frames it was traced from
+    assert g.key_ranges is None
+    hlo, none_a_key = [lower(p) for p in (plan, explicit)]
+    assert hlo == none_a_key
+    bindings = {"lineitem": table}
+    res = fusion.execute(explicit, bindings)
+    assert not [k for k in res.meta if ".key_" in k], sorted(res.meta)
+    assert fusion.plan_fingerprint(plan, bindings) == \
+        fusion.plan_fingerprint(explicit, bindings)
 
 
 @pytest.mark.parametrize("bound", [tpch._Q1_GROUP_BUDGET, 2049])
@@ -263,8 +340,9 @@ def test_general_q1_groupby_keeps_its_key_sort(moved_by, bound):
     groups its one sort is the key sort, the two packed words and a 32-bit
     iota, and it moves nothing (PR 33: the aggregates are taken where the
     rows lie). Bounded over the small-bound gate (2,049 groups) the key
-    sort is the variadic ``(u32, u32, s64)`` it was (60 s of cold compile
-    on the chip) and it moves eleven words: two int8 keys with seven
+    sort is ``sort_order``'s variadic sort of the same two words and, since
+    PR 37, the same 32-bit iota (``(u32, u32, s64)`` with ``jnp.lexsort``'s:
+    60 s of cold compile on the chip) and it moves eleven words: two int8 keys with seven
     validities and the row-valid bit in one, five int64 columns in ten. No
     column and no mask has a gather of its own."""
     from spark_rapids_jni_tpu.ops import groupby as gb
@@ -285,7 +363,7 @@ def test_general_q1_groupby_keeps_its_key_sort(moved_by, bound):
         assert moved == [] and sorts == [
             f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s32[{n}]{{0}})"], (moved, sorts)
         return
-    assert f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s64[{n}]{{0}})" in sorts, sorts
+    assert f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s32[{n}]{{0}})" in sorts, sorts
     if moved_by == "sort_passes":
         assert moved == [] and f"(u32[{n}]{{0}}, u32[{n}]{{0}})" in sorts
     else:
@@ -312,7 +390,10 @@ def test_every_plan_node_names_its_heavy_operations():
         match = re.search(r"region\.tpch_q3_planned/([^/]+)/", name)
         where.setdefault(kind, set()).add(match.group(1) if match else None)
     assert where["sort"] == {"groupby", "sort"}
-    assert where["while"] >= {"groupby", "sort"} and None not in where["while"]
+    # the loop left is the result's sort's (the groupby's key sort has
+    # none since its key's range is declared, and ``permute`` moves a
+    # table this small by one gather)
+    assert where["while"] >= {"sort"} and None not in where["while"]
     assert where["gather"] >= {"pk1", "pk2", "groupby", "sort"}
     assert None not in where["gather"]
 
