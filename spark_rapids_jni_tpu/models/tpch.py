@@ -291,22 +291,32 @@ _Q6_DISC_HI = 7
 _Q6_QTY_HI = 2400
 
 
-def _q6_reduce(lineitem: Table, row_valid) -> Table:
-    """q6's masked multiply-accumulate as a fusion Project (rowwise=False
-    — the 1-row output is its own space). Region-padded phantom rows have
-    null validity everywhere, so ``sel`` already excludes them and
-    ``row_valid`` needs no explicit fold."""
+def _q6_where(lineitem: Table) -> jnp.ndarray:
+    """q6's WHERE as a fusion Filter's predicate: the four columns it
+    reads non-null, shipdate in the year, discount and quantity in their
+    bounds. Region-padded phantom rows have null validity everywhere, so
+    it is False on them."""
     qty = lineitem.column(L_QUANTITY)
     price = lineitem.column(L_EXTENDEDPRICE)
     disc = lineitem.column(L_DISCOUNT)
     ship = lineitem.column(L_SHIPDATE)
-    sel = (
+    return (
         qty.valid_mask() & price.valid_mask() & disc.valid_mask()
         & ship.valid_mask()
         & (ship.data >= _Q6_DATE_LO) & (ship.data < _Q6_DATE_HI)
         & (disc.data >= _Q6_DISC_LO) & (disc.data <= _Q6_DISC_HI)
         & (qty.data < _Q6_QTY_HI)
     )
+
+
+def _q6_reduce(lineitem: Table, row_valid) -> Table:
+    """q6's masked multiply-accumulate as a fusion Project (rowwise=False
+    — the 1-row output is its own space) over the rows ``_q6_where`` kept:
+    the Filter above nulled every other row in every column, padding
+    included, so ``row_valid`` needs no explicit fold."""
+    price = lineitem.column(L_EXTENDEDPRICE)
+    disc = lineitem.column(L_DISCOUNT)
+    sel = price.valid_mask() & disc.valid_mask()
     prod = jnp.where(sel, price.data * disc.data, jnp.int64(0))
     total = jnp.sum(prod).reshape(1)
     any_row = jnp.any(sel).reshape(1)
@@ -314,9 +324,13 @@ def _q6_reduce(lineitem: Table, row_valid) -> Table:
 
 
 def _q6_plan() -> fusion.Plan:
-    """q6 as a one-node fused region: the masked multiply-accumulate."""
+    """q6 as one fused region of two nodes: the WHERE (a ``Filter``, which
+    reports the rows it saw and kept) and the multiply-accumulate over the
+    rows it kept. One pass over the four columns: XLA fuses the predicate
+    into the reduction."""
     return fusion.Plan("tpch_q6", fusion.Project(
-        fusion.Scan("lineitem"), _q6_reduce, rowwise=False))
+        fusion.Filter(fusion.Scan("lineitem"), _q6_where, label="where"),
+        _q6_reduce, rowwise=False))
 
 
 @func_range("tpch_q6")
@@ -2671,6 +2685,130 @@ def tpch_q13_reference(orders: Table) -> Table:
     g = groupby_aggregate(orders, [O_CUSTKEY], [(O_ORDERKEY, "count")],
                           max_groups=None)
     return trim_table(g.table, int(np.asarray(g.num_groups)))
+
+
+# ---- TPC-H q13 whole (customer distribution) as one served Plan -----------
+#
+#   SELECT c_count, count(*) AS custdist
+#   FROM (SELECT c_custkey, count(o_orderkey)
+#         FROM customer LEFT OUTER JOIN orders
+#              ON c_custkey = o_custkey
+#             AND o_comment NOT LIKE '%:word1%:word2%'
+#         GROUP BY c_custkey) AS c_orders (c_custkey, c_count)
+#   GROUP BY c_count ORDER BY custdist DESC, c_count DESC
+#
+# The q13-shaped plans above leave the LIKE and the outer join out (they
+# are the cluster's exchange workload and stay as they are). This is the
+# query, over its own two tables.
+
+# q13 orders columns: the three the query reads; o_comment in the padded
+# layout (VARCHAR(79): chars uint8[n, 79])
+O13_ORDERKEY, O13_CUSTKEY, O13_COMMENT = 0, 1, 2
+# The bound of the outer groupby: the distinct values of c_count. No
+# planner's fact (a customer may hold any number of orders), so it is a
+# budget with ``overflowed`` as its guard, like q1's: a customer of dbgen's
+# holds at most 41 orders at any scale factor, and 1,024 is the largest
+# bound at which the groupby takes its counts over the rows where they lie
+# (``ops/groupby.py _SMALL_M``).
+_Q13_DIST_BUDGET = 1024
+
+
+def _q13_where(orders: Table, pattern: str) -> jnp.ndarray:
+    """``o_comment NOT LIKE pattern`` as the join's condition on orders:
+    three-valued, so an order whose comment is NULL is not kept either."""
+    from spark_rapids_jni_tpu.ops import strings as s
+
+    comment = orders.column(O13_COMMENT)
+    return comment.valid_mask() & (s.like(comment, pattern).data == 0)
+
+
+def _q13_cust_fn(customer: Table) -> Table:
+    """The preserved side of the outer join: the customer's key alone."""
+    return Table([customer.column(C_CUSTKEY)])
+
+
+def _q13_counts_fn(j: Table) -> Table:
+    """The outer join's output as ``c_orders(c_count, c_custkey)``: a
+    customer no order joined reads a NULL count there, and
+    ``count(o_orderkey)`` over such a group is 0."""
+    # j: [c_custkey, o_custkey (the group's key), count(o_orderkey)]
+    ckey, cnt = j.column(0), j.column(2)
+    return Table([
+        Column(cnt.dtype,
+               jnp.where(cnt.valid_mask(), cnt.data, jnp.int64(0)),
+               ckey.valid_mask()),
+        ckey,
+    ])
+
+
+def _q13_plan(word1: str = "special", word2: str = "requests") -> fusion.Plan:
+    """TPC-H q13, whole, as one fused region. What the planner declares
+    is what ``_q3_planned_plan`` does: ``c_custkey`` is customer's dense
+    primary key, clustered 1..|customer| in load order, and ``o_custkey``
+    is a foreign key into it.
+
+    * ``where``: the join's condition on orders alone, ``o_comment NOT
+      LIKE '%word1%word2%'`` (``ops/strings.py like``, in row blocks), as a
+      Filter: an order it drops keeps its row and loses its validity.
+    * ``c_orders``: ``count(o_orderkey)`` by ``o_custkey`` over the kept
+      orders, which is the aggregate of the outer join pushed below it (the
+      join's key is the preserved side's primary key, so a group of the
+      join is one customer's orders): the sort path under the key's
+      declared range, at most |customer| groups and the null group of the
+      dropped rows.
+    * ``outer``: customer LEFT OUTER JOIN those groups. The preserved side
+      is the table laid out by the key, so every group writes its row
+      number into its customer's slot and every customer reads its own
+      (``dense_pk_join`` ``probe_clustered``): no sort, no search. A
+      customer no group wrote to reads NULL, and its count is 0.
+    * ``custdist``: ``count(*)`` by ``c_count``, a small bound over an
+      integer key, then the ORDER BY.
+
+    A declaration that does not hold (an ``o_custkey`` outside 1..|customer|:
+    ``c_orders.key_out_of_range``; a customer row that is not at its key's
+    place: ``outer.pk_violation``; more distinct counts than the budget:
+    ``custdist.overflowed``) is in the result's meta, and the served path
+    refuses the result."""
+    pattern = f"%{word1}%{word2}%"
+    kept = fusion.Filter(fusion.Scan("orders"), _q13_where, (pattern,),
+                         label="where", like_columns=(O13_COMMENT,))
+    c_orders = fusion.GroupBy(
+        kept, (O13_CUSTKEY,), ((O13_ORDERKEY, "count"),),
+        max_groups=fusion.groups_of("customer"), label="c_orders",
+        key_ranges=((1, fusion.rows_of("customer")),))
+    cust = fusion.Project(fusion.Scan("customer", bucket=False),
+                          _q13_cust_fn)
+    outer = fusion.DensePkJoin(cust, c_orders, 0, 0, 1,
+                               fusion.rows_of("customer"),
+                               probe_clustered=True, label="outer")
+    dist = fusion.GroupBy(fusion.Project(outer, _q13_counts_fn), (0,),
+                          ((1, "count"),), max_groups=_Q13_DIST_BUDGET,
+                          label="custdist")
+    return fusion.Plan("tpch_q13", fusion.Sort(
+        dist, (1, 0), ascending=(False, False),
+        nulls_first=(False, False)))
+
+
+def tpch_q13_numpy(customer: Table, orders: Table,
+                   word1: str = "special", word2: str = "requests") -> list:
+    """Host oracle for q13: ``[(c_count, custdist)]`` in the query's order,
+    by Python's ``re`` and ``collections.Counter`` over the host copies."""
+    import collections
+    import re
+
+    pat = re.compile(re.escape(word1) + ".*" + re.escape(word2), re.S)
+    comments = orders.column(O13_COMMENT).to_pylist()
+    okeys = orders.column(O13_ORDERKEY).to_pylist()
+    ckeys = orders.column(O13_CUSTKEY).to_pylist()
+    per_customer = collections.Counter()
+    for okey, ckey, text in zip(okeys, ckeys, comments):
+        if text is None or pat.search(text) or ckey is None:
+            continue
+        per_customer[ckey] += okey is not None
+    dist = collections.Counter(
+        per_customer.get(k, 0)
+        for k in customer.column(C_CUSTKEY).to_pylist())
+    return sorted(dist.items(), key=lambda kv: (-kv[1], -kv[0]))
 
 
 # ---------------------------------------------------------------------------

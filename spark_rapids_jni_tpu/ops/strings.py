@@ -422,6 +422,44 @@ def like(col: Column, pattern: str, escape: str = "\\") -> Column:
         segs.pop()
 
     p = pad_strings(col)
+    plan = (tuple(segs), tuple(gaps), tail_gap)
+    n, block = p.size, _LIKE_BLOCK_ROWS
+    if n <= block:
+        return _bool8_result(_like_rows(p.chars, p.data, *plan), col)
+    # Row blocks: every step below makes (rows, w + 1) booleans, a byte
+    # each, and every '%' is a scan of seven levels along them, some twenty
+    # such arrays alive at a time. Over 16,777,216 rows of 79 bytes one is
+    # 1.3 GB; a block's are 5 MB each. The rows stand alone, so the blocks'
+    # answers side by side are the column's.
+    full = n // block
+    chars, lengths = p.chars, p.data
+    hit = jax.lax.map(
+        lambda blk: _like_rows(blk[0], blk[1], *plan),
+        (chars[:full * block].reshape(full, block, chars.shape[1]),
+         lengths[:full * block].reshape(full, block))).reshape(-1)
+    if n > full * block:
+        hit = jnp.concatenate([hit, _like_rows(
+            chars[full * block:], lengths[full * block:], *plan)])
+    return _bool8_result(hit, col)
+
+
+# Rows a step of ``like``'s loop over a long column: large enough that the
+# loop's 256 steps at 16,777,216 rows are no cost beside a step's work
+# (0.8 ms each on a v5e, PERF.md section 5, PR 38), small enough that a
+# step's twenty-odd temporaries (5 MB each at 79 bytes a row) leave the
+# chip's 16 GB to the tables. The chip holds uint8[n, 79] column-major, 80
+# bytes a row, and XLA copies it into the loop's [80, blocks, rows] order
+# once before the loop: that copy is the column's size whatever the block.
+_LIKE_BLOCK_ROWS = 1 << 16
+
+
+def _like_rows(chars: jnp.ndarray, lengths: jnp.ndarray, segs: tuple,
+               gaps: tuple, tail_gap: tuple) -> jnp.ndarray:
+    """bool[n]: which rows of a padded string block (``chars`` uint8[n, w],
+    ``lengths`` int32[n]) match ``like``'s compiled pattern: literal
+    ``segs``, each after its gap ``(single characters, saw %)``, and the
+    gap after the last."""
+    p = Column(STRING, lengths, None, chars=chars)
     n = p.size
     w = int(p.chars.shape[1])
     jdx = jnp.arange(w + 1, dtype=jnp.int32)
@@ -480,7 +518,7 @@ def like(col: Column, pattern: str, escape: str = "\\") -> Column:
     else:
         hit = jnp.take_along_axis(
             reach, jnp.clip(p.data, 0, w)[:, None], axis=1)[:, 0]
-    return _bool8_result(hit, col)
+    return hit
 
 
 # ---- transforms ------------------------------------------------------------

@@ -187,11 +187,18 @@ class Scan(NamedTuple):
 class Filter(NamedTuple):
     """WHERE via the masking idiom: ``pred(table, *params) -> bool[n]``;
     rows where the predicate is False get their validity nulled in every
-    column (never compacted — static shapes)."""
+    column (never compacted — static shapes). ``like_columns`` names the
+    padded string columns of the child that the predicate matches against
+    a pattern (``ops/strings.py like``): what it examines of them is their
+    ``chars``, every real row at the column's full width.
+    Meta: ``<label>.rows_in`` (real rows the predicate saw: not a
+    bucket's padding) / ``<label>.rows_kept`` / ``<label>.like_bytes``."""
 
     child: Any
     pred: Callable
     params: tuple = ()
+    label: str = "filter"
+    like_columns: tuple = ()
 
 
 class Project(NamedTuple):
@@ -257,7 +264,9 @@ class DensePkJoin(NamedTuple):
     """Planner-declared dense-PK lookup join (``ops/planner.dense_pk_join``):
     probe-aligned output, no capacity estimate. ``key_hi`` may be a
     ``rows_of`` spec. The build child should hang off an unbucketed Scan
-    when ``clustered=True`` (build rows must equal the declared range).
+    when ``clustered=True`` (build rows must equal the declared range), and
+    the probe child when ``probe_clustered=True`` (the preserved side of a
+    LEFT OUTER join is the table laid out by the key: q13's customer).
     Meta: ``<label>.total`` / ``<label>.pk_violation``."""
 
     probe: Any
@@ -268,6 +277,7 @@ class DensePkJoin(NamedTuple):
     key_hi: Any
     clustered: bool = False
     label: str = "pk_join"
+    probe_clustered: bool = False
 
 
 class BloomBuild(NamedTuple):
@@ -492,7 +502,8 @@ def _fingerprint(nodes, resolved: dict) -> tuple:
         if isinstance(node, Scan):
             entry = ("scan", node.name, node.bucket)
         elif isinstance(node, Filter):
-            entry = ("filter", _fn_key(node.pred), node.params)
+            entry = ("filter", _fn_key(node.pred), node.params,
+                     node.like_columns)
         elif isinstance(node, Project):
             entry = ("project", _fn_key(node.fn), node.params, node.rowwise)
         elif isinstance(node, GroupBy):
@@ -509,7 +520,8 @@ def _fingerprint(nodes, resolved: dict) -> tuple:
                      resolved[id(node)], node.how)
         elif isinstance(node, DensePkJoin):
             entry = ("pk_join", node.probe_key, node.build_key, node.key_lo,
-                     resolved[id(node)], node.clustered)
+                     resolved[id(node)], node.clustered,
+                     node.probe_clustered)
         elif isinstance(node, BloomBuild):
             entry = ("bloom_build", node.key, node.num_bits, node.num_hashes)
         elif isinstance(node, BloomProbe):
@@ -595,7 +607,10 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
     mesh reports of its shuffle."""
     keys: list = []
     for node in nodes:
-        if isinstance(node, GroupBy):
+        if isinstance(node, Filter):
+            keys += [f"{node.label}.rows_in", f"{node.label}.rows_kept",
+                     f"{node.label}.like_bytes"]
+        elif isinstance(node, GroupBy):
             if node.domains is not None:
                 keys += [f"{node.label}.present",
                          f"{node.label}.domain_miss",
@@ -685,7 +700,23 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
             out = (tables[node.name], rvs.get(node.name))
         elif isinstance(node, Filter):
             tbl, rv = ev(node.child)
-            out = (_null_all(tbl, node.pred(tbl, *node.params)), rv)
+            keep = node.pred(tbl, *node.params)
+            real = jnp.asarray(tbl.num_rows if rv is None else jnp.sum(
+                rv, dtype=jnp.int64), jnp.int64)
+            kept = jnp.sum(keep if rv is None else keep & rv,
+                           dtype=jnp.int64)
+            if placement is not None and placement[id(node)] == SHARDED:
+                # a chip saw its share of the rows: the counts of them all
+                real, kept = (jax.lax.psum(v, mesh_axis)
+                              for v in (real, kept))
+            width = sum(int(tbl.column(i).chars.shape[1])
+                        for i in node.like_columns)
+            side.extend([
+                (f"{node.label}.rows_in", real),
+                (f"{node.label}.rows_kept", kept),
+                (f"{node.label}.like_bytes", real * width),
+            ])
+            out = (_null_all(tbl, keep), rv)
         elif isinstance(node, Project):
             tbl, rv = ev(node.child)
             if node.rowwise:
@@ -762,7 +793,8 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                 btbl = _null_all(btbl, brv)
             r = dense_pk_join(ptbl, btbl, node.probe_key, node.build_key,
                               node.key_lo, resolved[id(node)],
-                              clustered=node.clustered)
+                              clustered=node.clustered,
+                              probe_clustered=node.probe_clustered)
             side.extend([
                 (f"{node.label}.total", r.total),
                 (f"{node.label}.pk_violation", r.pk_violation),
@@ -1518,8 +1550,11 @@ def execute(plan: Plan, bindings: dict, *,
 
 
 def meta_facts(plan: Plan, meta: dict) -> dict:
-    """What the joins and groupbys of ``plan`` report in a result's
-    ``meta``, summed over its nodes: rows that probed and rows that matched
+    """What the filters, joins and groupbys of ``plan`` report in a result's
+    ``meta``, summed over its nodes: real rows a predicate saw and rows it
+    kept, and the bytes of string ``chars`` it matched against a pattern
+    (``filter.rows_in``, ``filter.rows_kept``, ``strings.like_bytes``), rows
+    that probed and rows that matched
     (joins that say both), groups, what a groupby lowered over a mesh
     shuffled (exchanges, the partial rows it sent and the bytes its
     ``all_to_all`` put between chips), and how many nodes broke what the plan
@@ -1538,9 +1573,16 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
              "join.pk_violation": 0, "groupby.groups": 0,
              "groupby.overflowed": 0, "groupby.in_place": 0,
              "groupby.key_narrowed": 0, "groupby.key_out_of_range": 0,
-             "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0}
+             "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0,
+             "filter.rows_in": 0, "filter.rows_kept": 0,
+             "strings.like_bytes": 0}
     for node in _topo(plan.root):
-        if isinstance(node, (Join, DensePkJoin)):
+        if isinstance(node, Filter):
+            for fact, field in (("filter.rows_in", "rows_in"),
+                                ("filter.rows_kept", "rows_kept"),
+                                ("strings.like_bytes", "like_bytes")):
+                facts[fact] += int(meta.get(f"{node.label}.{field}", 0))
+        elif isinstance(node, (Join, DensePkJoin)):
             total = meta.get(f"{node.label}.total")
             rows = meta.get(f"{node.label}.probe_rows")
             if total is not None and rows is not None:

@@ -496,13 +496,17 @@ def cache_key(plan: fusion.Plan, bindings: dict,
 
 
 def _snap_meta(meta: dict) -> dict:
-    out = {}
-    for k, v in (meta or {}).items():
-        if hasattr(v, "dtype") and hasattr(v, "shape"):
-            out[k] = np.asarray(v)
-        else:
-            out[k] = v
-    return out
+    """A result's meta with every device value read back to the host.
+    Every value's copy is started before the first is waited for, so a
+    result pays one transfer's latency and not one a value (0.42 ms each on
+    a v5e, PERF.md section 5: planned q13 reports sixteen)."""
+    meta = meta or {}
+    for v in meta.values():
+        start = getattr(v, "copy_to_host_async", None)
+        if start is not None:
+            start()
+    return {k: np.asarray(v) if hasattr(v, "dtype") and hasattr(v, "shape")
+            else v for k, v in meta.items()}
 
 
 def _rehydrate_meta(meta: dict) -> dict:

@@ -1068,9 +1068,11 @@ class QueryServer:
             self._save_learned()
 
     def _account_meta(self, plan: fusion.Plan, meta: dict) -> None:
-        """Count, once a request, what the plan's joins and groupbys report
-        in the result's meta (its host copy; ``fusion.meta_facts``:
-        counters ``join.probe_rows``, ``join.matched_rows``,
+        """Count, once a request, what the plan's filters, joins and
+        groupbys report in the result's meta (its host copy;
+        ``fusion.meta_facts``: counters ``filter.rows_in``,
+        ``filter.rows_kept``, ``strings.like_bytes``,
+        ``join.probe_rows``, ``join.matched_rows``,
         ``groupby.groups``, ``groupby.in_place``, ``groupby.key_narrowed``,
         ``join.pk_violation``, ``groupby.overflowed``,
         ``groupby.key_out_of_range``, and of a groupby lowered over a mesh
