@@ -828,6 +828,20 @@ def _q3_planned_keyed_fn(jt: Table) -> Table:
     ])
 
 
+def _q3_planned_revenue_fn(jt: Table) -> Table:
+    """The keyed table's key and revenue alone. Inside a region the
+    join's gathers of o_orderdate and o_shippriority by the lineitem rows
+    then have no user, and XLA compiles none of them."""
+    keyed = _q3_planned_keyed_fn(jt)
+    return Table([keyed.column(0), keyed.column(3)])
+
+
+def _q3_planned_result_fn(lt: Table) -> Table:
+    """The late look-up's output in q3's column order."""
+    # lt: [l_orderkey, revenue, o_orderkey, o_orderdate, o_shippriority]
+    return Table([lt.column(0), lt.column(3), lt.column(4), lt.column(1)])
+
+
 def _q3_planned_plan(segment: int, cutoff: int) -> fusion.Plan:
     """q3 with planner-declared dense clustered PKs, as one fused region.
     The clustered build sides (customer, the orders-aligned lookup table)
@@ -845,15 +859,18 @@ def _q3_planned_plan(segment: int, cutoff: int) -> fusion.Plan:
                             label="pk1")
     build2 = fusion.Project(j1, _q3_build2_fn)
     # join 2: each LINEITEM row looks up its order (clustered orderkey,
-    # build2 rows still in orders load order = orderkey order)
+    # build2 rows still in orders load order = orderkey order) and needs
+    # one bit of it: did the order survive the date and segment filters
+    # (the Project over it drops the order's date and priority, so the
+    # region gathers neither by the lineitem rows)
     j2 = fusion.DensePkJoin(probe, build2, 0, 0, 1,
                             fusion.rows_of("orders"), clustered=True,
                             label="pk2")
     # the dense keys declared above make two more facts the planner's:
-    # o_orderdate and o_shippriority are gathered by the order's primary
-    # key, so they are functions of l_orderkey: the groupby keys on it
-    # alone and carries the other two as the group's first row (the same
-    # groups, the same answer, one 64-bit sort key and not three keys);
+    # o_orderdate and o_shippriority are functions of l_orderkey (the
+    # order's primary key), so the groupby keys on it alone (one 64-bit
+    # sort key and not three keys) and the two are wanted once a group,
+    # not once a lineitem row: ``late`` fetches them below;
     # and there are at most |orders| groups and the null group of the
     # unmatched rows, so the groupby's look-ups, its results and the
     # result's sort run over that many rows, not over the lineitem bucket;
@@ -862,14 +879,20 @@ def _q3_planned_plan(segment: int, cutoff: int) -> fusion.Plan:
     # part of ``matched``) and ``_q3_planned_keyed_fn`` nulls the key of
     # every other row, so every non-null key has 21 bits at SF1 and sorts
     # as one word, in one sort with its null rank and the row-valid bit
-    g = fusion.GroupBy(fusion.Project(j2, _q3_planned_keyed_fn), (0,),
-                       ((1, "first_include_nulls"),
-                        (2, "first_include_nulls"), (3, "sum")),
+    g = fusion.GroupBy(fusion.Project(j2, _q3_planned_revenue_fn), (0,),
+                       ((1, "sum"),),
                        max_groups=fusion.groups_of("orders"),
                        label="groupby",
                        key_ranges=((1, fusion.rows_of("orders")),))
+    # the group's key looks its order up once more, against the same
+    # build2 (still clustered by that very key): date and priority at
+    # |orders| + 1 rows. The null group's key is out of range, so its two
+    # are null, as the first row of the unmatched rows had them
+    late = fusion.DensePkJoin(g, build2, 0, 0, 1, fusion.rows_of("orders"),
+                              clustered=True, label="late")
     return fusion.Plan("tpch_q3_planned", fusion.Sort(
-        g, (3, 1), ascending=(False, True), nulls_first=(False, False)))
+        fusion.Project(late, _q3_planned_result_fn), (3, 1),
+        ascending=(False, True), nulls_first=(False, False)))
 
 
 @func_range("tpch_q3_planned")
@@ -882,14 +905,16 @@ def tpch_q3_planned(customer: Table, orders: Table, lineitem: Table,
     gather — the join phase compiles with ZERO sorts (HLO-pinned in
     tests), where the general q3 pays two build-side lexsorts + probe
     searchsorteds on the sort-based machinery. On a v5e at SF1 (PERF.md
-    section 5, traced runs of PR 37) the two joins take 0.44 s of a 1.01 s
-    request, nearly all of it pk2's gathers of the order's columns by
-    6,001,215 positions, the result's sort 0.31 s and the groupby 0.24 s:
-    its look-ups at 1,500,001 rows 0.16 s, the key and the revenue brought
-    into key order as four packed words 0.06 s, its key sort 0.02 s (0.27 s
-    while the key was sorted as the 64-bit number its type says: three
-    passes that each gathered a word); date and priority are read at the
-    group's first row. The
+    section 5, traced runs of PR 40) the joins take 0.15 s of a 0.68 s
+    request: pk2's one gather of the match bit by 6,001,215 positions
+    0.068 s (0.40 s while it also gathered the order's key, date and
+    priority there), the look-up ``late`` that fetches date and priority
+    at the 1,500,001 group rows 0.070 s, pk1 0.012 s; the result's sort
+    0.34 s; the groupby 0.16 s: its look-ups at 1,500,001 rows 0.07 s, the
+    key and the revenue brought into key order as four packed words
+    0.06 s, its key sort 0.02 s (0.27 s while the key was sorted as the
+    64-bit number its type says: three passes that each gathered a
+    word). The
     orderkey groupby stays on the general (sort-based) path: its
     cardinality is data-dependent, which is exactly the boundary of
     what a planner can declare; what the declared keys do give it is
@@ -906,7 +931,8 @@ def tpch_q3_planned(customer: Table, orders: Table, lineitem: Table,
     return Q3PlannedResult(
         GroupByResult(res.table, res.meta["groupby.num_groups"]),
         res.meta["pk2.total"],
-        res.meta["pk1.pk_violation"] | res.meta["pk2.pk_violation"])
+        res.meta["pk1.pk_violation"] | res.meta["pk2.pk_violation"]
+        | res.meta["late.pk_violation"])
 
 
 def tpch_q3_numpy(customer: Table, orders: Table, lineitem: Table,

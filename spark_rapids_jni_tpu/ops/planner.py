@@ -236,14 +236,27 @@ def dense_pk_join(
 
     * ``clustered=True``: build row i holds key ``key_lo + i`` (the
       layout of a loaded dimension or generated key column). The join
-      is then pure arithmetic + one row gather — ZERO sorts anywhere,
-      and the general join's build-side lexsort + probe searchsorted
-      vanish; what is left is the gather (planned q3 at SF1 on a v5e:
-      0.65 s for 6,001,215 probe rows against 1,500,000 build rows of
-      three columns, PERF.md section 5, PR 28). The
-      declaration is VERIFIED, not trusted:
-      each gathered build key is compared to the probe key, and a slot
-      holding a different valid key raises ``pk_violation``.
+      is then pure arithmetic and row gathers — ZERO sorts anywhere, and
+      the general join's build-side lexsort + probe searchsorted vanish.
+      The declaration is VERIFIED, not trusted, and where the build side
+      lives: one pass over its ``nb`` rows holds every non-null build key
+      to ``key_lo + i``, and any other raises ``pk_violation``. That is
+      STRICTER than comparing the gathered build key with the probe key
+      at every probe row, as this join did before PR 40: a misplaced key
+      that no probe row happens to hit is caught too, and nothing that
+      check stopped gets through. Once it holds, the build key at a
+      matched probe row IS the probe key, so the probe's rows gather one
+      bit, the build key's validity (did my key survive the build side's
+      filters), and the build key column of the output is the probe key's
+      data under ``matched``. What is left is the gathers, the bit's and
+      one of each other build column's data and mask; inside a jitted
+      region a column that nothing downstream reads costs none (a
+      ``Project`` that drops it leaves its gather without a user). Planned
+      q3 at SF1 on a v5e (PERF.md section 5, traced runs of PR 40) takes
+      0.068 s for 6,001,215 probe rows (a bucket of 8,388,608) against
+      1,500,000 build rows, all of it the one gather of the bit, where the
+      gathered key, its mask and the order's date and priority took 0.40 s
+      in five gathers.
     * ``clustered=False``: one lexsort of the (small) build side; the
       probe side is searchsorted + gather. Duplicate build keys raise
       ``pk_violation`` (PK uniqueness is part of the declaration).
@@ -286,13 +299,13 @@ def dense_pk_join(
                 f"clustered dense PK needs build rows == key range "
                 f"({nb} != {key_hi - key_lo + 1})")
         pos = jnp.clip(pk.data - key_lo, 0, nb - 1).astype(jnp.int32)
-        bkey_at = bk.data[pos]
-        bvalid_at = bk.valid_mask()[pos]
-        matched = in_range & bvalid_at & (bkey_at == pk.data)
+        bvalid = bk.valid_mask()
         # a slot holding a DIFFERENT valid key means the layout is not
-        # clustered after all
-        pk_violation = jnp.any(in_range & bvalid_at
-                               & (bkey_at != pk.data))
+        # clustered after all: checked over the build's rows, no gather
+        at_home = bk.data == (jnp.arange(nb, dtype=bk.data.dtype)
+                              + bk.data.dtype.type(key_lo))
+        pk_violation = jnp.any(bvalid & ~at_home)
+        matched = in_range & bvalid[pos]
     elif probe_clustered:
         if key_hi - key_lo + 1 != n:
             raise ValueError(
@@ -349,11 +362,18 @@ def dense_pk_join(
                                 | (bk.data > bk.data.dtype.type(key_hi))))
         pk_violation = dup | oor
 
-    out_cols = list(probe.columns)
-    gathered = gather(build, pos)
-    for c in gathered.columns:
-        out_cols.append(Column(
-            c.dtype, c.data, c.valid_mask() & matched, chars=c.chars))
+    cols = list(build.columns)
+    if clustered:
+        # the layout holds, so the build key at a matched row is the probe
+        # key itself: no gather reads it
+        del cols[build_key]
+    cols = list(gather(Table(cols), pos).columns)
+    if clustered:
+        cols.insert(build_key,
+                    Column(bk.dtype, pk.data.astype(bk.data.dtype)))
+    out_cols = list(probe.columns) + [
+        Column(c.dtype, c.data, c.valid_mask() & matched, chars=c.chars)
+        for c in cols]
     return DensePkJoinResult(
         Table(out_cols), matched,
         jnp.sum(matched.astype(jnp.int64)), pk_violation)

@@ -425,6 +425,100 @@ def test_dense_pk_join_clustered_matches_bruteforce(rng):
     assert int(res.total) == cnt
 
 
+@pytest.mark.parametrize("width, build_key", [
+    (1, 0), (3, 0), (3, 1), (3, 2)],
+    ids=["key_only", "key_first", "key_middle", "key_last"])
+def test_dense_pk_join_clustered_key_column_is_the_probe_key(
+        rng, width, build_key):
+    """The brute force of the test above wherever the build key stands:
+    its column of the output is the probe key under ``matched`` (no
+    gather reads it), in the build key's dtype and place, and every other
+    build column is gathered as before."""
+    from spark_rapids_jni_tpu.ops.planner import dense_pk_join
+
+    nb, n = 50, 300
+    bvalid = rng.random(nb) > 0.2  # filtered build rows (WHERE idiom)
+    payload = [rng.integers(0, 100, nb).astype(dt)
+               for dt in (np.int64, np.int32)][:width - 1]
+    cols = [Column.from_numpy(v) for v in payload]
+    cols.insert(build_key, Column.from_numpy(
+        np.arange(1, nb + 1, dtype=np.int32), validity=bvalid))
+    build = Table(cols)
+    pkeys = rng.integers(-3, nb + 4, n).astype(np.int64)  # some OOR
+    pvalid = rng.random(n) > 0.1                          # some null
+    probe = Table([Column.from_numpy(pkeys, validity=pvalid)])
+    res = dense_pk_join(probe, build, 0, build_key, 1, nb, clustered=True)
+    assert not bool(res.pk_violation)
+    assert res.table.num_columns == 1 + width
+    want = (pvalid & (pkeys >= 1) & (pkeys <= nb)
+            & bvalid[np.clip(pkeys - 1, 0, nb - 1)])
+    assert np.asarray(res.matched).tolist() == want.tolist()
+    assert int(res.total) == int(want.sum())
+    values = [v[np.clip(pkeys - 1, 0, nb - 1)] for v in payload]
+    values.insert(build_key, pkeys)
+    for at, vals in enumerate(values):
+        got = res.table.column(1 + at)
+        assert got.dtype == build.column(at).dtype
+        assert got.to_pylist() == [
+            int(v) if m else None for v, m in zip(vals, want)]
+
+
+@pytest.mark.parametrize("misplaced_valid, violation", [
+    (True, True), (False, False)], ids=["valid_key", "null_key"])
+def test_dense_pk_join_clustered_checks_the_build_side(misplaced_valid,
+                                                       violation):
+    """The layout is verified where the build lives: a misplaced key that
+    NO probe row touches raises ``pk_violation`` (the probe-side compare
+    this join made before PR 40 let it pass); a null build key is a
+    filtered row whatever lies under it."""
+    from spark_rapids_jni_tpu.ops.planner import dense_pk_join
+
+    build = Table([
+        Column.from_numpy(np.asarray([1, 99, 3], np.int64),
+                          validity=np.asarray([True, misplaced_valid, True])),
+        Column.from_numpy(np.asarray([7, 8, 9], np.int64)),
+    ])
+    probe = Table([Column.from_numpy(np.asarray([1, 3, 3], np.int64))])
+    res = dense_pk_join(probe, build, 0, 0, 1, 3, clustered=True)
+    assert bool(res.pk_violation) == violation
+    assert res.table.column(2).to_pylist() == [7, 9, 9]
+
+
+@pytest.mark.parametrize("keep, payload", [
+    (False, []), (True, [("pred", 4096), ("s32", 4096)])],
+    ids=["payload_dropped", "payload_kept"])
+def test_dense_pk_join_clustered_gathers_what_is_read(keep, payload):
+    """Jitted, the probe's rows gather ONE bit, the build key's validity,
+    and a build column's data and mask only where something reads the
+    column: never the key's words, and the layout check is a pass over
+    the build's rows. No knob says so: a caller that drops a column from
+    the join's table leaves its gathers without a user."""
+    from spark_rapids_jni_tpu.ops.planner import dense_pk_join
+
+    nb, n = 64, 4096
+    build = Table([
+        Column.from_numpy(np.arange(1, nb + 1, dtype=np.int64),
+                          validity=np.arange(nb) % 3 > 0),
+        Column.from_numpy(np.arange(nb, dtype=np.int32),
+                          validity=np.arange(nb) % 5 > 0),
+    ])
+    probe = Table([Column.from_numpy(
+        (np.arange(n, dtype=np.int64) * 7) % (nb + 2))])
+
+    def join(p, b):
+        r = dense_pk_join(p, b, 0, 0, 1, nb, clustered=True)
+        table = r.table if keep else Table(r.table.columns[:2])
+        return table, r.total, r.pk_violation
+
+    hlo = jax.jit(join).lower(probe, build).compile().as_text()
+    gathers = sorted(
+        (kind, *[int(d) for d in dims.split(",") if d != "1"])
+        for kind, dims in re.findall(
+            r"= (\w+)\[([\d,]*)\]\S* gather\(", hlo))
+    assert gathers == sorted([("pred", n)] + payload), gathers
+    assert not re.search(r"= \S+ sort\(", hlo) and " scatter(" not in hlo
+
+
 def test_dense_pk_join_sorted_mode_matches(rng):
     from spark_rapids_jni_tpu.ops.planner import dense_pk_join
 
@@ -519,6 +613,15 @@ def test_q3_planned_matches_general_and_oracle():
             continue
         got[keys[i]] = (revs[i], dates[i], prios[i])
     assert got == oracle
+    # date and priority are looked up by the group's key (PR 40): the null
+    # group's key names no order, so its two are null, as every pad row's
+    assert int(res.result.num_groups) == len(oracle) + 1
+    assert all(dates[i] is None and prios[i] is None
+               for i in range(tbl.num_rows) if keys[i] is None)
+    assert int(res.join_total) == sum(
+        int(k) in oracle for k, d in zip(
+            np.asarray(li.column(0).data), np.asarray(li.column(3).data))
+        if d > 9204)
     # ORDER BY revenue DESC: the live prefix is non-increasing, and
     # every null-key row strictly follows every real row
     first_null = next((i for i in range(tbl.num_rows)

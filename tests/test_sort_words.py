@@ -238,12 +238,13 @@ def _gathers(hlo: str, node: str | None = None) -> list:
 
 def test_planned_q3_groupby_moves_no_column_it_reads_at_one_row(moved_by):
     """The groupby brings into key order the key (one word since its
-    range is declared), the revenue and their bits: four words.
-    ``o_orderdate`` / ``o_shippriority`` are read at the group's first row
-    through ``order`` (m = |orders| + 1 rows), so under the node's scope
-    no gather has n rows, in a loop or out of one (the key sort's three
-    went with its loop), but the one of the four packed words, and with
-    sort passes none at all."""
+    range is declared), the revenue and their bits: four words. Under the
+    node's scope no gather has n rows, in a loop or out of one (the key
+    sort's three went with its loop), but the one of the four packed
+    words, and with sort passes none at all. ``o_orderdate`` /
+    ``o_shippriority`` are no aggregates of it (PR 40): the look-up
+    ``late`` reads them by the group's key at m = |orders| + 1 rows, and
+    ``pk2`` gathers at the n probe rows one bit and nothing else."""
     n, m = 4000, N_ORD + 1
     hlo = _region_hlo(tpch._q3_planned_plan(0, 9204),
                       _q3_tables(n_ord=N_ORD, n=n))
@@ -251,9 +252,12 @@ def test_planned_q3_groupby_moves_no_column_it_reads_at_one_row(moved_by):
     rows_n = [(t, dims) for t, dims, _ in found if n in dims]
     assert rows_n == ([] if moved_by == "sort_passes"
                       else [("u32", [4, n])]), rows_n
-    # the first-row picks: int32 data and a mask, at m rows
-    picks = [dims for t, dims, _ in found if t == "s32"]
-    assert picks and all(dims == [m] for dims in picks), picks
+    assert not [t for t, _, _ in found if t == "s32"], found
+    # the late look-up: the build key's validity and two int32 columns
+    assert sorted(_gathers(hlo, "late")) == [
+        ("pred", [m], False), ("s32", [m], False), ("s32", [m], False)]
+    assert _gathers(hlo, "pk2") == [("pred", [n], False)]
+    assert _gathers(hlo, "pk1") == [("pred", [N_ORD], False)]
 
 
 def test_general_q1_sort_keeps_its_two_packed_words():
@@ -379,7 +383,8 @@ def test_every_plan_node_names_its_heavy_operations():
     scopes = fusion.node_scopes(nodes)
     assert [scopes[id(n)] for n in nodes] == [
         "scan.0", "project.1", "scan.2", "project.3", "scan.4", "project.5",
-        "pk1", "project.7", "pk2", "project.9", "groupby", "sort"]
+        "pk1", "project.7", "pk2", "project.9", "groupby", "late",
+        "project.12", "sort"]
     # the staged walk of fusion.execute under an outer trace has the nodes'
     # scopes too; the served region adds its own ``region.<plan>`` above
     hlo = _region_hlo(plan, _q3_tables())
@@ -394,7 +399,7 @@ def test_every_plan_node_names_its_heavy_operations():
     # none since its key's range is declared, and ``permute`` moves a
     # table this small by one gather)
     assert where["while"] >= {"sort"} and None not in where["while"]
-    assert where["gather"] >= {"pk1", "pk2", "groupby", "sort"}
+    assert where["gather"] >= {"pk1", "pk2", "groupby", "late", "sort"}
     assert None not in where["gather"]
 
 
@@ -428,7 +433,30 @@ def test_served_pk_violation_is_a_failed_request():
     assert REGISTRY.counters().get("server.served", 0) == served
     assert isinstance(exc, resilience.FatalExecutionError)
     assert "pk_violation" in str(exc)
-    assert REGISTRY.counters()["join.pk_violation"] == before + 1
+    # the counter counts the nodes that said so: two look the broken build
+    # side up, pk2 and the late look-up of date and priority
+    assert REGISTRY.counters()["join.pk_violation"] == before + 2
+
+
+def test_planned_q3_late_look_up_is_no_join_of_the_query():
+    """``late`` fetches the order's date and priority at the groups' rows.
+    It probes with groups, not with a scan's rows, so it reports no
+    ``probe_rows`` and ``join.probe_rows`` / ``join.matched_rows`` stay the
+    rows of the query's two joins; every real group finds its order."""
+    tables = _q3_tables()
+    plan = tpch._q3_planned_plan(0, 9204)
+    probed = REGISTRY.counters().get("join.probe_rows", 0)
+    ticket, result, exc = _serve(plan, tables)
+    assert exc is None and ticket.status == "served"
+    meta = result.meta
+    assert "late.probe_rows" not in meta
+    assert not bool(meta["late.pk_violation"])
+    facts = fusion.meta_facts(plan, meta)
+    assert facts["join.probe_rows"] == 256 + 4000
+    assert facts["join.matched_rows"] == int(meta["pk1.total"]) + int(
+        meta["pk2.total"])
+    assert int(meta["late.total"]) == int(meta["groupby.num_groups"]) - 1
+    assert REGISTRY.counters()["join.probe_rows"] == probed + 256 + 4000
 
 
 @pytest.mark.parametrize("cache", [True, False])
