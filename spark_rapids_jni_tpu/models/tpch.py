@@ -2502,6 +2502,71 @@ def tpch_q4_planned_result(orders: Table, lineitem: Table,
                         domains=[string_domain(_Q12_PRIORITIES)])
 
 
+# ---- TPC-H q4 whole as one served Plan ------------------------------------
+#
+#   SELECT o_orderpriority, count(*) AS order_count
+#   FROM orders
+#   WHERE o_orderdate >= date ':1' AND o_orderdate < date ':1' + 3 months
+#     AND EXISTS (SELECT * FROM lineitem
+#                 WHERE l_orderkey = o_orderkey
+#                   AND l_commitdate < l_receiptdate)
+#   GROUP BY o_orderpriority ORDER BY o_orderpriority
+#
+# over its own two tables: orders (o_orderkey, o_orderdate, o_orderpriority
+# CHAR(15) in the padded layout) and lineitem (l_orderkey, l_commitdate,
+# l_receiptdate). ``tpch_q4`` / ``tpch_q4_planned_result`` above stay the
+# eager pipelines over the q12 tables (Arrow-layout strings, the maps-based
+# join): what this plan's semi join is held against.
+
+L4_ORDERKEY, L4_COMMITDATE, L4_RECEIPTDATE = 0, 1, 2
+
+
+def _q4_orders_where(orders: Table, qtr_start: int,
+                     qtr_end: int) -> jnp.ndarray:
+    od = orders.column(O4_ORDERDATE)
+    return (od.valid_mask() & (od.data >= jnp.int32(qtr_start))
+            & (od.data < jnp.int32(qtr_end)))
+
+
+def _q4_late_where(lineitem: Table) -> jnp.ndarray:
+    commit = lineitem.column(L4_COMMITDATE)
+    receipt = lineitem.column(L4_RECEIPTDATE)
+    return (commit.valid_mask() & receipt.valid_mask()
+            & (commit.data < receipt.data))
+
+
+def _q4_plan(qtr_start: int = _Q4_QTR_START,
+             qtr_end: int = _Q4_QTR_END) -> fusion.Plan:
+    """TPC-H q4, whole, as one fused region. The plan declares nothing
+    about ``o_orderkey`` or ``l_orderkey`` but their type: the ``EXISTS``
+    is the general join, ``how="left_semi"`` (an order counts once however
+    many of its lineitems are late; a NULL key on either side, a row a
+    ``WHERE`` dropped and a bucket's padding match nothing).
+
+    * ``quarter`` / ``late``: the two ``WHERE``s as Filters.
+    * ``exists``: orders LEFT SEMI JOIN lineitem on the order key; the
+      orders stay where they lie under a row mask.
+    * ``groupby``: ``count(*)`` by the string column ``o_orderpriority``
+      under the five values the DDL states (clause 4.2.2.13), so the
+      bounded lowering: a row the join dropped reads a NULL priority and
+      counts in the null slot, which is no group of the answer. A priority
+      outside the five sets ``groupby.domain_miss``.
+    * the ORDER BY, over the six slots."""
+    from spark_rapids_jni_tpu.ops.planner import string_domain
+
+    exists = fusion.Join(
+        fusion.Filter(fusion.Scan("orders"), _q4_orders_where,
+                      (int(qtr_start), int(qtr_end)), label="quarter"),
+        fusion.Filter(fusion.Scan("lineitem"), _q4_late_where, label="late"),
+        (O4_ORDERKEY,), (L4_ORDERKEY,), None, how="left_semi",
+        label="exists")
+    counts = fusion.GroupBy(
+        exists, (O4_ORDERPRIORITY,), ((O4_ORDERKEY, "count"),),
+        domains=(string_domain(_Q12_PRIORITIES),), label="groupby")
+    return fusion.Plan("tpch_q4", fusion.Sort(
+        counts, (0,), nulls_first=(False,)))
+
+
 # ---------------------------------------------------------------------------
 # q17 — small-quantity-order revenue (correlated AVG subquery ->
 # groupby mean + join + filtered exact sum)

@@ -19,6 +19,22 @@ uses), after which the join runs on a single collision-free int32 rank
 column. cuDF's hash join is exact on composite keys; rank encoding is the
 sort-based TPU equivalent (no collision-at-hash wrong answers, unlike the
 round-1 "pre-hash into one column" recipe this replaces).
+
+A semi or anti join asks one bit a probe row, so ``semi_join_mask`` gives
+that and no maps: both sides' keys in ONE sort (a key's valid build rows
+ahead of everybody else who holds it), a running maximum that tells every
+row whether its key's run opened with a build row, and a second sort that
+brings the bits back to the probe's row order. No search, no offsets, no
+gather: what a fused region lowers ``Join(how="left_semi" | "left_anti")``
+to (``runtime/fusion.py``). ``join(..., how="left_semi")`` keeps the maps.
+
+Scopes (``jax.named_scope``, under the plan node's own inside a region; a
+device trace splits the join's time by them): ``build`` is everything that
+orders or indexes the build side (``_sorted_valid_keys``; the merged sort
+of ``semi_join_mask``, which orders the probe's keys with it), ``probe``
+everything else of the join: the searches, the prefix sum and the maps,
+``apply_join_maps``' gathers; the runs' heads, the running maximum, the
+sort back and the mask.
 """
 
 from __future__ import annotations
@@ -32,7 +48,7 @@ import numpy as np
 
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.ops.hash import probe_sorted_lo_hi
-from spark_rapids_jni_tpu.ops.sort import gather, sort_order
+from spark_rapids_jni_tpu.ops.sort import _split64, gather, sort_order
 from spark_rapids_jni_tpu.utils.tracing import func_range
 
 
@@ -78,8 +94,6 @@ def _join_maps_impl(
     left_row_valid: jnp.ndarray | None = None,
     right_row_valid: jnp.ndarray | None = None,
 ) -> JoinMaps:
-    n_left = left_key.shape[0]
-    n_right = right_key.shape[0]
     # Rows that are not rows at all (padding/phantom shuffle slots) must
     # never match, regardless of what their key bytes and key validity
     # happen to hold — fold row existence into key validity up front.
@@ -87,9 +101,21 @@ def _join_maps_impl(
         left_valid = left_valid & left_row_valid
     if right_row_valid is not None:
         right_valid = right_valid & right_row_valid
-    sorted_key, n_valid_right, perm = _sorted_valid_keys(
-        right_key, right_valid)
+    with jax.named_scope("build"):
+        sorted_key, n_valid_right, perm = _sorted_valid_keys(
+            right_key, right_valid)
+    with jax.named_scope("probe"):
+        return _probe_maps(left_key, left_valid, right_key, right_valid,
+                           sorted_key, n_valid_right, perm, out_size, how,
+                           left_row_valid, right_row_valid)
 
+
+def _probe_maps(left_key, left_valid, right_key, right_valid, sorted_key,
+                n_valid_right, perm, out_size, how, left_row_valid,
+                right_row_valid) -> JoinMaps:
+    """Everything of the maps-based join after the build side's sort."""
+    n_left = left_key.shape[0]
+    n_right = right_key.shape[0]
     # Match runs per probe row (empty when the probe key is null).
     # probe_sorted_lo_hi is the kernel-tier seam: searchsorted pair on
     # the XLA tier, the streaming Pallas probe kernel otherwise.
@@ -242,8 +268,21 @@ def rank_encode_keys(
 _JOIN_TYPES = ("inner", "left", "left_semi", "left_anti", "right", "full")
 
 
-def _join_impl(row_args, aux_args, row_valids, *, lkeys, rkeys,
-               out_size, how) -> JoinMaps:
+def key_valid(table: Table, keys: Sequence[int],
+              row_valid: jnp.ndarray | None = None) -> jnp.ndarray:
+    """bool[n]: no key column of the row is NULL (SQL: such a row matches
+    nothing), and with ``row_valid`` the row exists at all."""
+    valid = table.column(keys[0]).valid_mask()
+    for k in keys[1:]:
+        valid = valid & table.column(k).valid_mask()
+    return valid if row_valid is None else valid & row_valid
+
+
+def _encoded_keys(row_args, row_valids, lkeys, rkeys) -> tuple:
+    """``(left key, left key valid, right key, right key valid, left row
+    valid, right row valid)`` of a join's two row groups: one exact
+    integral key a side (the column itself, or the key tuples' dense ranks
+    over both sides)."""
     ((left, left_row_valid), (right, right_row_valid)) = row_args
     if row_valids is not None:
         # Row-dim padding happened: a caller-supplied row_valid was padded
@@ -255,12 +294,7 @@ def _join_impl(row_args, aux_args, row_valids, *, lkeys, rkeys,
         if right_row_valid is None:
             right_row_valid = rrv
 
-    lvalid = left.column(lkeys[0]).valid_mask()
-    for k in lkeys[1:]:
-        lvalid = lvalid & left.column(k).valid_mask()
-    rvalid = right.column(rkeys[0]).valid_mask()
-    for k in rkeys[1:]:
-        rvalid = rvalid & right.column(k).valid_mask()
+    lvalid, rvalid = key_valid(left, lkeys), key_valid(right, rkeys)
 
     lc = left.column(lkeys[0])
     rc0 = right.column(rkeys[0])
@@ -276,10 +310,26 @@ def _join_impl(row_args, aux_args, row_valids, *, lkeys, rkeys,
         lkey, rkey = lc.data, rc0.data
     else:
         lkey, rkey = rank_encode_keys(left, right, list(lkeys), list(rkeys))
+    return lkey, lvalid, rkey, rvalid, left_row_valid, right_row_valid
+
+
+def _join_impl(row_args, aux_args, row_valids, *, lkeys, rkeys,
+               out_size, how) -> JoinMaps:
+    lkey, lvalid, rkey, rvalid, left_row_valid, right_row_valid = \
+        _encoded_keys(row_args, row_valids, lkeys, rkeys)
     return _join_maps_impl(
         lkey, lvalid, rkey, rvalid, out_size, how, left_row_valid,
         right_row_valid,
     )
+
+
+def _key_tuples(left_on, right_on) -> tuple:
+    left_keys = [left_on] if isinstance(left_on, int) else list(left_on)
+    right_keys = [right_on] if isinstance(right_on, int) else list(right_on)
+    if len(left_keys) != len(right_keys) or not left_keys:
+        raise ValueError("left_on and right_on must be equal-length, non-empty")
+    return (tuple(int(k) for k in left_keys),
+            tuple(int(k) for k in right_keys))
 
 
 @func_range("join")
@@ -319,12 +369,7 @@ def join(
     if how not in _JOIN_TYPES:
         raise ValueError(
             f"unsupported join type {how!r}; valid: {_JOIN_TYPES}")
-    left_keys = [left_on] if isinstance(left_on, int) else list(left_on)
-    right_keys = [right_on] if isinstance(right_on, int) else list(right_on)
-    if len(left_keys) != len(right_keys) or not left_keys:
-        raise ValueError("left_on and right_on must be equal-length, non-empty")
-    lkeys_t = tuple(int(k) for k in left_keys)
-    rkeys_t = tuple(int(k) for k in right_keys)
+    lkeys_t, rkeys_t = _key_tuples(left_on, right_on)
     out_size = int(out_size)
 
     from spark_rapids_jni_tpu.runtime import dispatch
@@ -336,6 +381,111 @@ def join(
         ((left, left_row_valid), (right, right_row_valid)),
         statics=(lkeys_t, rkeys_t, out_size, how),
         slice_rows=False,
+    )
+
+
+class SemiJoinMask(NamedTuple):
+    """A semi or anti join's answer where the probe's rows lie."""
+
+    keep: jnp.ndarray        # bool[n_left]: the probe row is in the result
+    total: jnp.ndarray       # scalar int64: how many are
+    build_rows: jnp.ndarray  # scalar int64: real build rows, non-null key
+
+
+def _key_words(key: jnp.ndarray) -> list:
+    """An integral key as uint32 words, major first: equal words exactly
+    for equal keys (their order is not the keys', which nobody needs)."""
+    if key.dtype.itemsize == 8:
+        return _split64(key)[::-1]
+    return [key.astype(jnp.uint32)]
+
+
+def _probe_matches(left_key: jnp.ndarray, right_key: jnp.ndarray,
+                   right_valid: jnp.ndarray) -> jnp.ndarray:
+    """bool[n_left]: the probe row's key bytes equal those of a build row
+    with ``right_valid`` (the caller folds the probe's own validity in).
+
+    One sort of both sides' keys with a last word that holds a row's place
+    in ``[probe rows, build rows]`` and, above it, a bit that is 0 only on
+    a valid build row: inside a key's run those come first. A run then
+    holds a match for its probe rows exactly when its head is one, which a
+    running maximum over ``2 * (head's place in the order) + (head is a
+    build row)`` hands to every row of the run. A second sort, of ``2 *
+    place + bit`` alone, brings the bits back: the probe's rows lead."""
+    n_left, n_right = left_key.shape[0], right_key.shape[0]
+    if n_left == 0 or n_right == 0:
+        return jnp.zeros((n_left,), jnp.bool_)
+    n = n_left + n_right
+    if n >= 1 << 31:
+        raise ValueError(f"semi join of {n} rows: a place takes 31 bits")
+    with jax.named_scope("build"):
+        words = [jnp.concatenate([lw, rw]) for lw, rw in zip(
+            _key_words(left_key), _key_words(right_key))]
+        other = jnp.concatenate([jnp.ones((n_left,), jnp.bool_), ~right_valid])
+        place = jax.lax.iota(jnp.uint32, n) | (other.astype(jnp.uint32) << 31)
+        # every operand a key: no two rows tie, so the sort need not be
+        # stable (a stable one gets an iota operand more from XLA)
+        *ordered, place = jax.lax.sort(
+            (*words, place), num_keys=len(words) + 1, is_stable=False)
+    with jax.named_scope("probe"):
+        differs = ordered[0][1:] != ordered[0][:-1]
+        for w in ordered[1:]:
+            differs = differs | (w[1:] != w[:-1])
+        head = jnp.concatenate([jnp.ones((1,), jnp.bool_), differs])
+        at = jax.lax.iota(jnp.uint32, n) << 1
+        opened_by_build = jax.lax.cummax(jnp.where(
+            head, at | (place >> 31 == 0).astype(jnp.uint32),
+            jnp.uint32(0))) & 1
+        back = jax.lax.sort(
+            ((place & jnp.uint32(0x7FFFFFFF)) << 1) | opened_by_build,
+            is_stable=False)
+        return (back[:n_left] & 1) == 1
+
+
+def _semi_join_impl(row_args, aux_args, row_valids, *, lkeys, rkeys,
+                    how) -> SemiJoinMask:
+    lkey, lvalid, rkey, rvalid, lrv, rrv = _encoded_keys(
+        row_args, row_valids, lkeys, rkeys)
+    if rrv is not None:    # a row that is none holds no key
+        rvalid = rvalid & rrv
+    matched = _probe_matches(lkey, rkey, rvalid) & lvalid
+    with jax.named_scope("probe"):
+        # a NULL probe key matches nothing: out of a semi join, in an anti
+        # join's result (Spark NOT EXISTS / cuDF left_anti), as the maps say
+        keep = matched if how == "left_semi" else ~matched
+        if lrv is not None:
+            keep = keep & lrv
+        return SemiJoinMask(keep, jnp.sum(keep, dtype=jnp.int64),
+                            jnp.sum(rvalid, dtype=jnp.int64))
+
+
+@func_range("semi_join_mask")
+def semi_join_mask(
+    left: Table,
+    right: Table,
+    left_on: int | Sequence[int],
+    right_on: int | Sequence[int],
+    how: str = "left_semi",
+    left_row_valid: jnp.ndarray | None = None,
+    right_row_valid: jnp.ndarray | None = None,
+) -> SemiJoinMask:
+    """``left_semi`` / ``left_anti`` as a mask over the probe's rows where
+    they lie: ``keep[i]`` exactly where ``join(..., how=how)`` has a
+    ``left_index`` of ``i`` among its real rows (its first ``total``, in
+    row order), for any key ``join`` takes, duplicates on both sides, NULL
+    keys and phantom rows. No ``out_size``: nothing is laid out. Runs
+    through the dispatch cache as ``join`` does, a bucket a side."""
+    if how not in ("left_semi", "left_anti"):
+        raise ValueError(f"semi_join_mask: {how!r} is no semi or anti join")
+    lkeys_t, rkeys_t = _key_tuples(left_on, right_on)
+
+    from spark_rapids_jni_tpu.runtime import dispatch
+
+    return dispatch.call(
+        "semi_join_mask",
+        partial(_semi_join_impl, lkeys=lkeys_t, rkeys=rkeys_t, how=how),
+        ((left, left_row_valid), (right, right_row_valid)),
+        statics=(lkeys_t, rkeys_t, how),
     )
 
 

@@ -246,10 +246,28 @@ class GroupBy(NamedTuple):
 
 
 class Join(NamedTuple):
-    """General equi-join + ``apply_join_maps`` materialization: left
-    columns then right columns, ``out_rows`` output rows (an int or a
-    ``rows_of`` spec — resolved from TRUE row counts, never buckets).
-    Meta: ``<label>.total``."""
+    """General equi-join on keys nobody declared anything about
+    (``ops/join.py``). What each ``how`` hands to the node above:
+
+    * ``inner`` / ``left`` / ``right`` / ``full``: ``apply_join_maps``'
+      materialization, left columns then right columns, ``out_rows`` output
+      rows (an int or a ``rows_of`` spec — resolved from TRUE row counts,
+      never buckets), a row space of its own; the side a row did not find
+      reads NULL.
+    * ``left_semi`` / ``left_anti``: the LEFT columns alone, every row where
+      it lay (``semi_join_mask``): a row the join drops keeps its place and
+      loses its validity in every column, as a ``Filter``'s does, so the
+      output is the left child's row space and ``out_rows`` is not read
+      (give None). An anti join keeps a row with a NULL key, and cannot
+      tell one from a row a ``Filter`` below it dropped: both read NULL in
+      every column above it.
+
+    Lowers under its label's scope with the sub-scopes ``build`` and
+    ``probe`` (``ops/join.py`` says which stage lies under which).
+    Meta: ``<label>.total`` (output rows; of a semi or anti join the left
+    rows kept), ``<label>.build_rows`` (real right rows with a non-null
+    key: what entered the join of the build side), and where the left side
+    holds a scan's rows ``<label>.probe_rows`` (a static: that scan's)."""
 
     left: Any
     right: Any
@@ -382,6 +400,8 @@ class Plan(NamedTuple):
     name: str
     root: Any
 
+
+_MASK_JOINS = ("left_semi", "left_anti")   # ``Join.how`` that keep row space
 
 _NODE_TYPES = (Scan, Filter, Project, GroupBy, Join, DensePkJoin,
                BloomBuild, BloomProbe, Sort, Limit, Exchange)
@@ -596,6 +616,8 @@ def _spaces(nodes) -> dict:
             spaces[id(node)] = spaces[id(node.child)]
         elif isinstance(node, Sort):
             spaces[id(node)] = spaces[id(node.child)]
+        elif isinstance(node, Join) and node.how in _MASK_JOINS:
+            spaces[id(node)] = spaces[id(node.left)]   # rows stay in place
         elif isinstance(node, (Join, Limit, Exchange)):
             spaces[id(node)] = None
     return spaces
@@ -627,7 +649,7 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
                     keys += [f"{node.label}.key_narrowed",
                              f"{node.label}.key_out_of_range"]
         elif isinstance(node, Join):
-            keys.append(f"{node.label}.total")
+            keys += [f"{node.label}.total", f"{node.label}.build_rows"]
         elif isinstance(node, DensePkJoin):
             keys += [f"{node.label}.total", f"{node.label}.pk_violation"]
         elif isinstance(node, BloomProbe):
@@ -649,6 +671,8 @@ def _scanned_rows(node, true_rows: dict) -> Optional[int]:
             node = node.child
         elif isinstance(node, DensePkJoin):
             node = node.probe
+        elif isinstance(node, Join) and node.how in _MASK_JOINS:
+            node = node.left
         else:
             return None
     return int(true_rows[node.name])
@@ -685,7 +709,12 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
     from spark_rapids_jni_tpu import types as _t
     from spark_rapids_jni_tpu.ops import bloom_filter as _bloom
     from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate
-    from spark_rapids_jni_tpu.ops.join import apply_join_maps, join
+    from spark_rapids_jni_tpu.ops.join import (
+        apply_join_maps,
+        join,
+        key_valid,
+        semi_join_mask,
+    )
     from spark_rapids_jni_tpu.ops.planner import (
         dense_pk_join, narrow_group_keys, plan_groupby, widen_group_keys)
     from spark_rapids_jni_tpu.ops.sort import gather, sort_order
@@ -779,11 +808,25 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
         elif isinstance(node, Join):
             ltbl, lrv = ev(node.left)
             rtbl, rrv = ev(node.right)
-            maps = join(ltbl, rtbl, list(node.left_on), list(node.right_on),
-                        out_size=resolved[id(node)], how=node.how,
-                        left_row_valid=lrv, right_row_valid=rrv)
-            side.append((f"{node.label}.total", maps.total))
-            out = (apply_join_maps(ltbl, rtbl, maps), None)
+            if node.how in _MASK_JOINS:
+                # one bit a left row: no maps, nothing moves
+                semi = semi_join_mask(
+                    ltbl, rtbl, list(node.left_on), list(node.right_on),
+                    node.how, left_row_valid=lrv, right_row_valid=rrv)
+                total, build_rows = semi.total, semi.build_rows
+                out = (_null_all(ltbl, semi.keep), lrv)
+            else:
+                maps = join(
+                    ltbl, rtbl, list(node.left_on), list(node.right_on),
+                    out_size=resolved[id(node)], how=node.how,
+                    left_row_valid=lrv, right_row_valid=rrv)
+                total = maps.total
+                with jax.named_scope("probe"):
+                    build_rows = jnp.sum(
+                        key_valid(rtbl, node.right_on, rrv), dtype=jnp.int64)
+                    out = (apply_join_maps(ltbl, rtbl, maps), None)
+            side.extend([(f"{node.label}.total", total),
+                         (f"{node.label}.build_rows", build_rows)])
         elif isinstance(node, DensePkJoin):
             ptbl, prv = ev(node.probe)
             btbl, brv = ev(node.build)
@@ -1570,7 +1613,7 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     (``QueryServer._account_meta``). Converting a meta value waits for the
     device, so call it where the meta is wanted on the host anyway."""
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
-             "join.pk_violation": 0, "groupby.groups": 0,
+             "join.build_rows": 0, "join.pk_violation": 0, "groupby.groups": 0,
              "groupby.overflowed": 0, "groupby.in_place": 0,
              "groupby.key_narrowed": 0, "groupby.key_out_of_range": 0,
              "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0,
@@ -1588,6 +1631,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
             if total is not None and rows is not None:
                 facts["join.probe_rows"] += int(rows)
                 facts["join.matched_rows"] += int(total)
+            facts["join.build_rows"] += int(
+                meta.get(f"{node.label}.build_rows", 0))
             facts["join.pk_violation"] += bool(
                 meta.get(f"{node.label}.pk_violation", False))
         elif isinstance(node, GroupBy):

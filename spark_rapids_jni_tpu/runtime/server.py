@@ -1072,7 +1072,7 @@ class QueryServer:
         groupbys report in the result's meta (its host copy;
         ``fusion.meta_facts``: counters ``filter.rows_in``,
         ``filter.rows_kept``, ``strings.like_bytes``,
-        ``join.probe_rows``, ``join.matched_rows``,
+        ``join.probe_rows``, ``join.matched_rows``, ``join.build_rows``,
         ``groupby.groups``, ``groupby.in_place``, ``groupby.key_narrowed``,
         ``join.pk_violation``, ``groupby.overflowed``,
         ``groupby.key_out_of_range``, and of a groupby lowered over a mesh

@@ -528,3 +528,114 @@ def test_key_ranges_are_a_static_of_the_plan():
         fusion.Scan("t"), (0, 1), ((2, "sum"),), key_ranges=((1, 8),)))
     with pytest.raises(ValueError, match="1 key_ranges for 2 keys"):
         fusion.execute(two_keys, b)
+
+
+# ---------------------------------------------------------------------------
+# semi and anti joins inside a region: one bit a left row, rows where they lay
+# ---------------------------------------------------------------------------
+
+
+def _semi_tables(nl: int, nr: int):
+    rng = np.random.default_rng(nl * 131 + nr)
+    left = Table([
+        Column.from_numpy(rng.integers(0, 12, nl).astype(np.int64)
+                          * (2 ** 35 + 3)),
+        Column.from_numpy(rng.integers(0, 4, nl).astype(np.int32)),
+        Column.from_numpy(np.arange(nl, dtype=np.int64))])
+    right = Table([Column.from_numpy(
+        rng.integers(3, 20, nr).astype(np.int64) * (2 ** 35 + 3))])
+    return (_with_null_tail(left, cols=(0,)),
+            _with_null_tail(right, cols=(0,)))
+
+
+def _semi_plan(how: str, grouped: bool = True) -> fusion.Plan:
+    joined = fusion.Join(fusion.Scan("l"), fusion.Scan("r"), (0,), (0,),
+                         None, how=how, label="semi")
+    if not grouped:
+        return fusion.Plan("semi_rows", joined)
+    return fusion.Plan("semi_counts", fusion.GroupBy(
+        joined, (1,), ((2, "count"), (2, "sum")), max_groups=16,
+        label="groupby"))
+
+
+@pytest.mark.parametrize("how", ["left_semi", "left_anti"])
+@pytest.mark.parametrize("n", EDGE_COUNTS)
+def test_semi_join_hands_a_row_mask_to_the_node_above(how, n):
+    """The left columns alone, every row where it lay: a dropped row
+    loses its validity in every column, so the GroupBy above counts the
+    kept rows and the root slices back to the left side's true rows; the
+    fused region is the staged walk bit for bit, and both are the
+    maps-based join's rows."""
+    from spark_rapids_jni_tpu.ops.join import join
+
+    left, right = _semi_tables(n, 2 * n + 1)
+    b = {"l": left, "r": right}
+    rows = fusion.execute(_semi_plan(how, grouped=False), b)
+    staged = _staged(lambda: fusion.execute(_semi_plan(how, False), b))
+    _assert_tables_identical(rows.table, staged.table, f"{how} n={n}")
+    assert rows.table.num_rows == n and rows.table.num_columns == 3
+    maps = join(left, right, 0, 0, out_size=n, how=how)
+    total = int(maps.total)
+    kept = np.asarray(maps.left_index)[:total]
+    assert int(rows.meta["semi.total"]) == total
+    assert int(rows.meta["semi.probe_rows"]) == n
+    assert int(rows.meta["semi.build_rows"]) == int(
+        np.asarray(right.column(0).valid_mask()).sum())
+    for i in range(3):
+        want = np.zeros(n, bool)
+        want[kept] = np.asarray(left.column(i).valid_mask())[kept]
+        assert np.array_equal(
+            np.asarray(rows.table.column(i).valid_mask()), want)
+        assert np.array_equal(np.asarray(rows.table.column(i).data),
+                              np.asarray(left.column(i).data))
+    counts = fusion.execute(_semi_plan(how), b)
+    staged = _staged(lambda: fusion.execute(_semi_plan(how), b))
+    _assert_tables_identical(counts.table, staged.table, f"{how} n={n}")
+    groups = int(counts.meta["groupby.num_groups"])
+    got = {int(k): int(v) for k, v, ok in zip(
+        np.asarray(counts.table.column(0).data)[:groups],
+        np.asarray(counts.table.column(1).data)[:groups],
+        np.asarray(counts.table.column(0).valid_mask())[:groups]) if ok}
+    flags = np.asarray(left.column(1).data)[kept]
+    assert got == {int(f): int((flags == f).sum()) for f in set(flags)}
+
+
+def test_runtime_filters_leave_semi_joins_alone():
+    """The planner pass builds a bloom filter for single-key INNER joins
+    only: with it on, a semi or anti join's plan is the plan."""
+    left, right = _semi_tables(33, 70)
+    b = {"l": left, "r": right}
+    set_option("rtfilter.enabled", True)
+    try:
+        for how in ("left_semi", "left_anti"):
+            plan = _semi_plan(how)
+            assert fusion.inject_runtime_filters(plan, b) is plan
+            on = fusion.execute(plan, b)
+            assert not any(k.startswith("rtf_") for k in on.meta)
+        inner = fusion.Plan("inner", fusion.Join(
+            fusion.Scan("l"), fusion.Scan("r"), (0,), (0,),
+            fusion.rows_of("l", 8), label="semi"))
+        assert fusion.execute(inner, b).meta["semi.build_rows"] is not None
+    finally:
+        reset_option("rtfilter.enabled")
+
+
+def test_estimate_hbm_bytes_of_a_semi_join_plan():
+    """A semi join lays out no rows of its own: the estimate is the
+    inputs and the groupby's slots above it (the join's sort is for the
+    server's headroom to hold: PERF.md says how far that is)."""
+    from spark_rapids_jni_tpu.runtime.memory import _table_nbytes
+
+    left, right = _semi_tables(1000, 4000)
+    b = {"l": left, "r": right}
+    inputs = _table_nbytes(left) + _table_nbytes(right)
+    width = max(1, inputs // 5000)
+    assert fusion.estimate_hbm_bytes(_semi_plan("left_semi", False), b) \
+        == inputs
+    assert fusion.estimate_hbm_bytes(_semi_plan("left_semi"), b) \
+        == inputs + 16 * width
+    q4 = tpch._q4_plan()
+    nodes = fusion._topo(q4.root)
+    assert fusion._spaces(nodes)[id(nodes[-1])] is None     # six slots
+    semi = next(n for n in nodes if isinstance(n, fusion.Join))
+    assert fusion._spaces(nodes)[id(semi)] == "orders"
