@@ -10,10 +10,13 @@ packed 32-bit words moved once; what is read at one row a group stays where
 it is), mark segment boundaries, and reduce between them with prefix sums
 and segmented scans, no scatter — all static-shape, all fused by XLA.
 Under a small group bound whose aggregates are sums, counts and extrema
-(``_aggregates_in_place``) only the keys are sorted: the rows stay where
-they lie and are summed by slot (``_KeySlots``), the integer lanes on the
-MXU. Output is padded to the input row count with ``num_groups`` reported
-alongside (static shapes are the price of jit; callers slice on host).
+(``_aggregates_in_place``) no value word moves: the rows stay where they
+lie and are summed by slot (``_KeySlots``), the integer lanes on the MXU;
+the groups come from a sort of the key words alone or, bounded at
+``_MIN_LOOP_M`` or fewer, from no sort at all (``_least_groups``: repeated
+minimum over the words, a step a group the data holds). Output is padded
+to the input row count with ``num_groups`` reported alongside (static
+shapes are the price of jit; callers slice on host).
 
 Null semantics are Spark's: null keys form their own group; aggregates skip
 null values; COUNT counts non-null; an all-null group's SUM/MIN/MAX/MEAN is
@@ -31,8 +34,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_jni_tpu.columnar import Column, Table
-from spark_rapids_jni_tpu.ops.sort import (permute, sort_key_words,
-                                            sort_order)
+from spark_rapids_jni_tpu.ops.sort import (key_words, permute,
+                                            sort_key_words, sort_order,
+                                            words_in_order)
 from spark_rapids_jni_tpu.types import DType, TypeId, decimal128
 from spark_rapids_jni_tpu.utils.tracing import func_range
 
@@ -59,6 +63,11 @@ class GroupByResult(NamedTuple):
     # small group bound: ``_KeySlots``), False when the value words were
     # brought into key order first. A fact of the lowering, not of the data.
     in_place: jnp.ndarray | bool = False
+    # True when the rows' key words were sorted to count the groups: under
+    # a bound of ``_MIN_LOOP_M`` or fewer the groups are found by repeated
+    # minimum and only a broken bound pays that sort, for the true count.
+    # A fact of the data.
+    key_sorted: jnp.ndarray | bool = False
 
     def compact(self) -> Table:
         """Host-side trim to the real group count."""
@@ -749,6 +758,80 @@ class _KeySlots:
                                  jnp.zeros((), per_slot.dtype)), axis=0)
 
 
+# Largest bound at which the in-place path finds its groups by repeated
+# minimum (``_least_groups``) and not from a sort of the rows' key words.
+# The loop runs one step a group the DATA holds and one more, at most
+# m + 1; a step is one pass over the words.
+_MIN_LOOP_M = 64
+
+
+def _least_groups(words, rv, m: int):
+    """The first ``m`` groups of rows with key ``words`` (k x uint32[n],
+    minor -> major: ``ops/sort.py key_words``) in key order, by repeated
+    minimum over the rows where they lie: ``(group_words k x uint32[m],
+    first_row int32[m], found int32)``. Step g of a ``while_loop`` is ONE
+    variadic reduction over the rows: the least (not a candidate, major
+    word, ..., minor word, row index), the candidates being the real rows
+    whose words lie above group g - 1's. That is group g's words and the
+    least row that holds them, the row a stable sort puts first in its
+    group. It stops when no candidate is left or group m + 1 has been
+    found: ``found`` is the number of groups up to m + 1, ``first_row`` is
+    n and ``group_words`` is arbitrary past it. A pass with fused reads a
+    step: no sort, no gather, no scatter."""
+    n = words[0].shape[0]
+    iota = jax.lax.iota(jnp.int32, n)
+    # the carry starts from a word, so that under shard_map it varies over
+    # the same mesh axes going in as out
+    zero = words[0][0] & jnp.uint32(0)
+
+    def lesser(x, y):
+        less, tie = False, True
+        for a, b in zip(x, y):
+            less = less | (tie & (a < b))
+            tie = tie & (a == b)
+        return tuple(jnp.where(less, a, b) for a, b in zip(x, y))
+
+    def step(carry):
+        g, _, prev, group_words, first_row = carry
+        above = jnp.zeros((n,), jnp.bool_)
+        for w, p in zip(words, prev):
+            above = (w > p) | ((w == p) & above)
+        cand = above | (g == 0)
+        if rv is not None:
+            cand = cand & rv
+        out_of, *least, row = jax.lax.reduce(
+            ((~cand).astype(jnp.uint32), *words[::-1], iota),
+            (jnp.uint32(1), *[jnp.uint32(0xFFFFFFFF)] * len(words),
+             jnp.int32(n)), lesser, (0,))
+        more = out_of == 0
+        least = least[::-1]
+        return (g + more.astype(jnp.int32), more, least,
+                [gw.at[g].set(lw) for gw, lw in zip(group_words, least)],
+                first_row.at[g].set(jnp.where(more, row, n)))
+
+    found, _, _, group_words, first_row = jax.lax.while_loop(
+        lambda carry: (carry[0] <= m) & carry[1], step,
+        (zero.astype(jnp.int32), zero == 0, [zero] * len(words),
+         [jnp.zeros((m + 1,), jnp.uint32) + zero] * len(words),
+         jnp.full((m + 1,), n, jnp.int32) + zero.astype(jnp.int32)))
+    return [gw[:m] for gw in group_words], first_row[:m], found
+
+
+def _words_equal_prev(in_order, rv) -> jnp.ndarray:
+    """bool[n]: row i of the key words ``in_order`` (in key order) has the
+    words of row i - 1, or is a phantom row: they sort last and start no
+    group."""
+    n = in_order[0].shape[0]
+    eq_prev = in_order[0][1:] == in_order[0][:-1]
+    for w in in_order[1:]:
+        eq_prev = eq_prev & (w[1:] == w[:-1])
+    same = jnp.concatenate([jnp.zeros((1,), jnp.bool_), eq_prev])
+    if rv is not None:
+        same = same | (jax.lax.iota(jnp.int32, n)
+                       >= jnp.sum(rv, dtype=jnp.int32))
+    return same
+
+
 def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                             max_groups) -> GroupByResult:
     ((table, row_valid),) = row_args
@@ -766,25 +849,26 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
     data_at, mask_at = _row_reads(keys, aggs)
     mask_at = [i for i in mask_at
                if i not in data_at and table.column(i).validity is not None]
+    # The sum of a group does not depend on the order of its rows: in
+    # place the rows stay where they lie and are matched to the m groups
+    # by their key words (``_KeySlots``). No value word moves: at
+    # 8,388,608 rows and eleven value words that was twelve sort passes,
+    # 0.15 of the 0.19 s this function took; it takes 0.039 s (PERF.md
+    # section 6, PR 33). What is left to find is the groups' words, in key
+    # order, and a row of each group: under a bound of ``_MIN_LOOP_M`` or
+    # fewer by repeated minimum over the words (``_least_groups``: as many
+    # steps as the data holds groups, and one), over it from the key sort,
+    # whose cost does not go with the bound.
+    by_loop = in_place and m <= _MIN_LOOP_M
+    key_sorted = False
     if in_place:
-        # The sum of a group does not depend on the order of its rows. The
-        # key sort hands back the keys' words in key order, which give the
-        # group starts; the rows stay where they lie and are matched to
-        # the m groups by their words (``_KeySlots``). No value word
-        # moves: at 8,388,608 rows and eleven value words that was twelve
-        # sort passes, 0.15 of the 0.19 s this function took; it takes
-        # 0.039 s (PERF.md section 6, PR 33).
-        order, words, sorted_words = sort_key_words(table, keys, rv)
         read_col = {i: table.column(i) for i in data_at}
         read_mask = {i: table.column(i).validity for i in mask_at}
-        eq_prev = sorted_words[0][1:] == sorted_words[0][:-1]
-        for w in sorted_words[1:]:
-            eq_prev = eq_prev & (w[1:] == w[:-1])
-        same = jnp.concatenate([jnp.zeros((1,), jnp.bool_), eq_prev])
-        if rv is not None:
-            # phantom rows sort last and start no group
-            same = same | (jax.lax.iota(jnp.int32, n)
-                           >= jnp.sum(rv, dtype=jnp.int32))
+    if by_loop:
+        words = key_words(table, keys, rv)
+    elif in_place:
+        order, words, sorted_words = sort_key_words(table, keys, rv)
+        same = _words_equal_prev(sorted_words, rv)
     else:
         order = sort_order(table, keys, row_valid=rv)
         # Only what is read at every row comes into key order, as packed
@@ -829,23 +913,37 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
     # 0.513 s; PERF.md section 6, PR 31).
     block = _pick_block(n, m) if small else 0
     garange = jnp.arange(m, dtype=jnp.int32)
-    if small:
-        starts, num_groups = _group_starts(same, m + 1, block)
-        g_lo, g_hi = starts[:m], starts[1:]
+    if by_loop:
+        group_words, first_row, found = _least_groups(words, rv, m)
+        overflowed = key_sorted = found > m
+        # the loop knows the first m groups and that there are more: the
+        # true count past the bound is the only thing a sort is still for
+        # (of the words alone, each compared with the row before it), and
+        # a request whose bound holds never runs it
+        num_groups = jax.lax.cond(
+            overflowed, lambda: jnp.sum(
+                ~_words_equal_prev(words_in_order(words), rv),
+                dtype=jnp.int32),
+            lambda: found)
     else:
-        num_groups, g_lo, g_hi = _group_bounds(same, m)
-    overflowed = num_groups > m
-    # first row of each group (n = absent, matching the old scatter-min)
-    first_idx = jnp.where(g_hi > g_lo, g_lo, n)
+        if small:
+            starts, num_groups = _group_starts(same, m + 1, block)
+            g_lo, g_hi = starts[:m], starts[1:]
+        else:
+            num_groups, g_lo, g_hi = _group_bounds(same, m)
+        overflowed = num_groups > m
+        # first row of each group (n = absent, matching the old scatter-min)
+        first_idx = jnp.where(g_hi > g_lo, g_lo, n)
+        if in_place:
+            # a group's first row where it lies
+            first_row = jnp.where(
+                first_idx < n, order[jnp.clip(first_idx, 0, n - 1)], n)
+            group_words = [w[jnp.clip(first_row, 0, n - 1)] for w in words]
     if in_place:
-        # a group's first row where it lies: its keys are read there, and
-        # its words are what the rows are matched against
-        first_row = jnp.where(
-            first_idx < n, order[jnp.clip(first_idx, 0, n - 1)], n)
+        # a group's keys are read at its first row, and its words are what
+        # the rows are matched against
         out_cols = _gather_group_keys(table, keys, first_row, m, n)
-        slots = _KeySlots(
-            words, [w[jnp.clip(first_row, 0, n - 1)] for w in words],
-            first_idx < n)
+        slots = _KeySlots(words, group_words, first_row < n)
     else:
         out_cols = _gather_group_keys(
             sorted_keys, range(len(keys)), first_idx, m, n)
@@ -1525,7 +1623,7 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         out_cols.append(Column(c.dtype, red, vcount > 0))
 
     return GroupByResult(Table(out_cols), num_groups, overflowed,
-                         sum128_overflow, in_place)
+                         sum128_overflow, in_place, key_sorted)
 
 
 @func_range("groupby_aggregate")

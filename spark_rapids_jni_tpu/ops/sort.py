@@ -312,31 +312,51 @@ def sort_order(
         statics=(tuple(keys), tuple(ascending), tuple(nulls_first)))
 
 
+def key_words(table: Table, keys: Sequence[int],
+              row_valid: jnp.ndarray | None = None) -> list:
+    """The keys of every row (null ranks and the row-valid bit folded in)
+    as uint32 words, minor -> major, where the rows lie: what
+    ``sort_order(table, keys, row_valid=row_valid)`` sorts by. uint order
+    on the words, the last the most significant, is the keys' order. Two
+    rows have the same words exactly when they have the same key tuple,
+    null-ness included; a phantom row's words equal no real row's. For keys
+    of fixed-width fields only (``_key_bits``): float64 and decimal128 have
+    no such words and raise."""
+    ones = [True] * len(keys)
+    lex_keys, packable = _lex_keys(table, keys, ones, ones, row_valid)
+    if not packable:
+        raise TypeError("key_words: a key has no fixed-width sort word")
+    return lex_keys if len(lex_keys) <= 2 else _pack_words(lex_keys)
+
+
 def sort_key_words(table: Table, keys: Sequence[int],
                    row_valid: jnp.ndarray | None = None) -> tuple:
     """``sort_order(table, keys, row_valid=row_valid)`` with what it sorted
-    by: ``(order, words, sorted_words)``. ``words`` are the keys of every
-    row (null ranks and the row-valid bit folded in) as uint32 words, minor
-    -> major, where the rows lie; ``sorted_words`` the same in the order
-    ``order``. Two rows have the same words exactly when they have the same
-    key tuple, null-ness included; a phantom row's words equal no real
-    row's. For keys of fixed-width fields only (``_key_bits``): float64
-    and decimal128 have no such words and raise.
+    by: ``(order, words, sorted_words)``. ``words`` are ``key_words`` of
+    the rows where they lie; ``sorted_words`` the same in the order
+    ``order``.
 
     Keys of one or two words are the operands of the one variadic sort
     ``sort_order`` runs, which brings them into order whether or not
     anybody reads them: here they are read. Wider keys are sorted word by
     word (``_radix_order``) and moved after it (``_move_words``)."""
-    ones = [True] * len(keys)
-    lex_keys, packable = _lex_keys(table, keys, ones, ones, row_valid)
-    if not packable:
-        raise TypeError("sort_key_words: a key has no fixed-width sort word")
-    if len(lex_keys) <= 2:
-        order, sorted_words = _sort_words(lex_keys)
-        return order, lex_keys, sorted_words
-    words = _pack_words(lex_keys)
+    words = key_words(table, keys, row_valid)
+    if len(words) <= 2:
+        order, sorted_words = _sort_words(words)
+        return order, words, sorted_words
     order = _radix_order(words)
     return order, words, _move_words(words, order)
+
+
+def words_in_order(words: list) -> list:
+    """``words`` (uint32, minor -> major) in their own order and nothing
+    else: no row index comes along, so equal rows need no order among
+    themselves and the sort is unstable (a stable one gets an iota operand
+    from XLA). For who counts or compares neighbours and reads no row."""
+    if len(words) <= 2:
+        return list(jax.lax.sort(tuple(words[::-1]), num_keys=len(words),
+                                 is_stable=False))[::-1]
+    return _move_words(words, _radix_order(words))
 
 
 def gather(table: Table, indices: jnp.ndarray) -> Table:

@@ -230,7 +230,7 @@ class GroupBy(NamedTuple):
     word). Not with ``domains`` (the bounded lowering has its own
     dictionary).
     Side outputs land in the result meta under ``<label>.*``
-    (num_groups/overflowed/sum_overflow/in_place, with a range that
+    (num_groups/overflowed/sum_overflow/in_place/key_sorted, with a range that
     narrowed a key also key_narrowed and key_out_of_range, which the
     served path refuses as it does ``pk_violation``; or
     present/domain_miss/lowered on the planned lowering)."""
@@ -641,7 +641,8 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
                 keys += [f"{node.label}.num_groups",
                          f"{node.label}.overflowed",
                          f"{node.label}.sum_overflow",
-                         f"{node.label}.in_place"]
+                         f"{node.label}.in_place",
+                         f"{node.label}.key_sorted"]
                 if placement and placement[id(node.child)] == SHARDED:
                     keys += [f"{node.label}.shuffle_rows",
                              f"{node.label}.shuffle_bytes"]
@@ -787,6 +788,7 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                     (f"{node.label}.sum_overflow",
                      jnp.asarray(g.sum_overflow)),
                     (f"{node.label}.in_place", jnp.asarray(g.in_place)),
+                    (f"{node.label}.key_sorted", jnp.asarray(g.key_sorted)),
                 ]
                 if resolved[id(node)] is None:
                     rv_out = rv   # padded to the input rows: still positional
@@ -1065,6 +1067,9 @@ def _mesh_groupby(node: GroupBy, tbl: Table, rv, bound, axis: str):
                 # how the partial was lowered (ops/groupby.py): a fact of
                 # the trace, the same on every chip
                 (f"{label}.in_place", jnp.asarray(part.in_place)),
+                # whether a chip's partial sorted its key words to count
+                # the groups past its bound: a fact of the data
+                (f"{label}.key_sorted", anywhere(part.key_sorted)),
                 (f"{label}.shuffle_rows", jax.lax.psum(sent, axis)),
                 # what the all_to_all carries between chips (a chip keeps
                 # its own share): a fact of the partial's schema and the
@@ -1606,7 +1611,10 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     declared range (``key_out_of_range``); and how many groupbys took
     their aggregates over the rows where they lie, no value word brought
     into key order (``groupby.in_place``: a fact of the lowering,
-    ``ops/groupby.py``), and how many grouped a key at the width of its
+    ``ops/groupby.py``), how many of those sorted their key words to count
+    the groups past a broken bound (``groupby.key_sorted``: a fact of the
+    data; a bound of 64 or fewer that holds finds its groups with no
+    sort), and how many grouped a key at the width of its
     declared range (``groupby.key_narrowed``: a fact of the lowering too,
     ``ops/planner.narrow_group_keys``). A result with a broken declaration
     is a wrong answer; the served path refuses it
@@ -1615,6 +1623,7 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
              "join.build_rows": 0, "join.pk_violation": 0, "groupby.groups": 0,
              "groupby.overflowed": 0, "groupby.in_place": 0,
+             "groupby.key_sorted": 0,
              "groupby.key_narrowed": 0, "groupby.key_out_of_range": 0,
              "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0,
              "filter.rows_in": 0, "filter.rows_kept": 0,
@@ -1641,7 +1650,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["groupby.groups"] += int(groups)
             facts["groupby.overflowed"] += bool(
                 meta.get(f"{node.label}.overflowed", False))
-            for fact in ("in_place", "key_narrowed", "key_out_of_range"):
+            for fact in ("in_place", "key_sorted", "key_narrowed",
+                         "key_out_of_range"):
                 facts[f"groupby.{fact}"] += bool(
                     meta.get(f"{node.label}.{fact}", False))
             sent = meta.get(f"{node.label}.shuffle_rows")

@@ -1073,7 +1073,8 @@ class QueryServer:
         ``fusion.meta_facts``: counters ``filter.rows_in``,
         ``filter.rows_kept``, ``strings.like_bytes``,
         ``join.probe_rows``, ``join.matched_rows``, ``join.build_rows``,
-        ``groupby.groups``, ``groupby.in_place``, ``groupby.key_narrowed``,
+        ``groupby.groups``, ``groupby.in_place``, ``groupby.key_sorted``,
+        ``groupby.key_narrowed``,
         ``join.pk_violation``, ``groupby.overflowed``,
         ``groupby.key_out_of_range``, and of a groupby lowered over a mesh
         ``shuffle.exchanges``, ``shuffle.rows`` and ``shuffle.bytes``: the

@@ -3,8 +3,11 @@
 rows where they lie, matched to the first m groups by their key words, no
 value word brought into key order. Against the same call without a bound
 (the word-moving path) trimmed to m rows and against a plain dict oracle,
-bit for bit on integer lanes; the shape of the lowered regions; the
-``groupby.in_place`` counter of the served path."""
+bit for bit on integer lanes; how the groups are found (under a bound of
+``_MIN_LOOP_M`` or fewer by repeated minimum over the key words,
+``_least_groups``, the words sorted only to count past a broken bound);
+the shape of the lowered regions; the ``groupby.in_place`` and
+``groupby.key_sorted`` counters of the served path."""
 
 import re
 
@@ -114,6 +117,28 @@ def _case(name):
                        _col(t.INT64, val, vvalid)]),
                 [0, 1, 2], ((3, "sum"), (3, "count"), (3, "min")),
                 rng.random(N) > 0.2, 16, None, True)
+    if name == "all_keys_null":
+        return (one_key(_keys(rng, 5), np.zeros(N, bool)), [0], sums,
+                rng.random(N) > 0.3, M, 1, True)
+    if name == "one_wide_key":     # 64 bits, a null rank, the row-valid bit
+        return (Table([_col(t.INT64, rng.integers(-3, 3, N) * 2**33,
+                            rng.random(N) > 0.1),
+                       _col(t.INT64, val, vvalid)]),
+                [0], ((1, "sum"), (1, "count"), (1, "max")),
+                rng.random(N) > 0.2, M, 7, True)
+    if name.startswith("bound_"):   # bound_<m>_groups_<g>: the loop's steps
+        m, groups = (int(x) for x in name.split("_")[1::2])
+        n = max(N, 2 * m * gb._MIN_BLOCK)
+        g = rng.permutation(np.concatenate(
+            [np.arange(groups), rng.integers(0, groups, n - groups)]))
+        # two words; groups 0 and 1 are (null, 0) and (null, 1)
+        return (Table([_col(t.INT16, (g // 2 - 100).astype(np.int16),
+                            g // 2 != 0),
+                       _col(t.INT8, (g % 2).astype(np.int8)),
+                       _col(t.INT64, rng.integers(-10**9, 10**9, n),
+                            rng.random(n) > 0.2)]),
+                [0, 1], ((2, "sum"), (2, "count"), (2, "min")),
+                rng.random(n) > 0.1, m, groups, True)
     if name in ("first_last", "nunique", "float_key", "string_minmax"):
         k = _keys(rng, 4)
         if name == "float_key":     # -0.0 and 0.0: one group, two words
@@ -138,7 +163,12 @@ CASES = ("null_keys", "phantom_rows", "all_phantom", "empty", "exactly_m",
          "m_plus_1", "ten_m", "wrapping_sums", "decimal_sums",
          "minmax_all_null_groups", "float_lanes", "two_keys",
          "three_keys_wide", "first_last", "nunique", "float_key",
-         "string_minmax")
+         "string_minmax", "all_keys_null", "one_wide_key",
+         # fewer groups than the bound, exactly m, m + 1, many more
+         "bound_1_groups_1", "bound_1_groups_2", "bound_1_groups_40",
+         "bound_5_groups_2", "bound_5_groups_5", "bound_5_groups_6",
+         "bound_5_groups_90", "bound_64_groups_9", "bound_64_groups_64",
+         "bound_64_groups_65", "bound_64_groups_300")
 
 
 def _same_columns(got: Table, want: Table, rows: int):
@@ -233,6 +263,10 @@ def test_small_bound_against_unbounded_and_a_dict(case, chunks):
     # the true count even past the bound; the first m groups in key order
     assert int(got.num_groups) == true_groups
     assert bool(got.overflowed) == (true_groups > m)
+    # the words are sorted only to count past a broken bound of the loop
+    assert bool(got.key_sorted) == (
+        in_place and m <= gb._MIN_LOOP_M and true_groups > m)
+    assert not bool(want.key_sorted)
     assert got.table.num_rows == m
     _same_columns(got.table, want.table, min(m, true_groups))
     for c in got.table.columns:     # nothing past the last group
@@ -309,26 +343,71 @@ def test_sort_key_words_is_sort_order_with_its_words(keys):
 
 
 @pytest.mark.parametrize("m", [1, 64, 65, 1024, 1025])
-def test_the_gate_is_the_small_bound_and_its_aggregates(m):
+def test_the_gate_is_the_small_bound_and_its_aggregates(m, monkeypatch):
     """In place up to ``_SMALL_M`` groups where the rows pay for the block
     path; with a minimum or a float sum beside the integer sums only up to
-    ``_SLOT_REDUCE_M``; never without a bound."""
+    ``_SLOT_REDUCE_M``; never without a bound. In place the groups come
+    from the loop up to ``_MIN_LOOP_M`` (64) and from the key sort over
+    it (65): the loop sorts no key."""
     n = 2 * 1024 * 32
     rng = np.random.default_rng(m)
     table = Table([_col(t.INT32, rng.integers(0, 50, n).astype(np.int32)),
                    _col(t.INT64, rng.integers(0, 9, n)),
                    _col(t.FLOAT64, rng.random(n))])
+    key_sorts = []
+    monkeypatch.setattr(gb, "sort_key_words", lambda *a: (
+        key_sorts.append(1), so.sort_key_words(*a))[1])
+    dispatch.clear()
     run = lambda aggs, bound: bool(gb.groupby_aggregate(  # noqa: E731
         table, [0], aggs, max_groups=bound).in_place)
     assert run(((1, "sum"), (1, "count")), m) == (m <= gb._SMALL_M)
+    assert len(key_sorts) == (gb._MIN_LOOP_M < m <= gb._SMALL_M)
     assert run(((1, "sum"), (1, "min")), m) == (m <= gb._SLOT_REDUCE_M)
     assert run(((2, "sum"),), m) == (m <= gb._SLOT_REDUCE_M)
+    assert len(key_sorts) == (gb._MIN_LOOP_M < m <= gb._SMALL_M)
     if m == 1:
         assert not run(((1, "sum"),), None)
         few = Table([_col(c.dtype, np.asarray(c.data)[:20])    # a bucket of 32
                      for c in table.columns])
         assert not bool(gb.groupby_aggregate(
             few, [0], ((1, "sum"),), max_groups=1).in_place)
+    dispatch.clear()
+
+
+@pytest.mark.parametrize("m", [1, 5, 64, 65])
+def test_a_groups_key_cells_are_its_first_rows(m):
+    """The loop hands on, a group, the least row that holds its words: the
+    row a stable sort puts first. A null key's stored bytes differ from
+    row to row, and the group's key cell is the first row's, as on the
+    sort's side of the gate (65) and without a bound."""
+    n = 2 * 65 * gb._MIN_BLOCK
+    rng = np.random.default_rng(m)
+    g = rng.integers(0, 4, n)
+    stored = rng.integers(-2**31, 2**31 - 1, n).astype(np.int32)
+    key = np.where(g == 0, stored, g).astype(np.int32)     # group 0: null
+    rv = rng.random(n) > 0.3
+    table = Table([_col(t.INT32, key, g != 0),
+                   _col(t.INT64, rng.integers(0, 9, n))])
+    got = gb.groupby_aggregate(table, [0], ((1, "sum"),), max_groups=m,
+                               row_valid=jnp.asarray(rv))
+    want = gb.groupby_aggregate(table, [0], ((1, "sum"),),
+                                row_valid=jnp.asarray(rv))
+    rows = min(m, 4)
+    first = [int(np.flatnonzero(rv & (g == i))[0]) for i in range(rows)]
+    cells = np.asarray(got.table.column(0).data)[:rows]
+    assert cells.tolist() == key[first].tolist()
+    assert cells.tolist() == np.asarray(want.table.column(0).data)[
+        :rows].tolist()
+    assert not np.asarray(got.table.column(0).valid_mask())[0]
+
+    words = so.key_words(table, [0], jnp.asarray(rv))
+    group_words, first_row, found = jax.jit(
+        lambda w, v: gb._least_groups(w, v, m))(words, jnp.asarray(rv))
+    assert int(found) == min(4, m + 1)       # it stops at group m + 1
+    assert np.asarray(first_row)[:rows].tolist() == first
+    assert (np.asarray(first_row)[rows:] == n).all()
+    for w, gw in zip(words, group_words):
+        assert np.asarray(gw)[:rows].tolist() == np.asarray(w)[first].tolist()
 
 
 def test_auto_grows_past_the_gate_and_says_so():
@@ -418,17 +497,52 @@ def _loops_that_sort(hlo: str) -> list:
     return found
 
 
+def _computations(hlo: str) -> dict:
+    """The text of every computation of an HLO module by its name."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}", hlo, re.S | re.M)}
+
+
+def _under_a_conditional(hlo: str) -> set:
+    """Names of the computations that run only inside a branch of a
+    ``conditional``: the branches and whatever they call."""
+    comps = _computations(hlo)
+    todo = []
+    for text in comps.values():
+        for line in text.splitlines():
+            if " conditional(" in line:
+                todo += re.findall(
+                    r"(?:branch_computations=\{|true_computation=|"
+                    r"false_computation=|, )%?([\w.\-]+)",
+                    line[line.index(" conditional("):].split(
+                        "metadata=")[0])
+    inside = set()
+    while todo:
+        name = todo.pop()
+        if name in comps and name not in inside:
+            inside.add(name)
+            todo += re.findall(r"%([\w.\-]+)", comps[name])
+    return inside
+
+
 def test_general_q1_region_sorts_its_keys_and_nothing_else():
-    """One bucket of the served general q1: the n-row sorts are the key
-    sort and at most one more, no ``while`` sorts (the parent's ``permute``
-    ran twelve passes in one), and no n-row gather."""
+    """One bucket of the served general q1, whose bound (64) is the
+    loop's: the groupby's only n-row sort is the words-only one that
+    counts the groups past a broken bound, and it lies inside the
+    ``conditional``'s branch, not on the taken path (the parent sorted the
+    words and an iota of every row there); no ``while`` sorts, the loop
+    that finds the groups least of all; no n-row gather."""
     n = 5000      # under an outer trace the region is walked unpadded
     hlo = _region_hlo(tpch._q1_plan(), {"lineitem": tpch.lineitem_table(n)})
-    rows_n = [s for s in _sorts(hlo) if f"[{n}]" in s]
-    # two int8 flags and their null ranks: one word beside a 32-bit iota
-    assert f"(u32[{n}]{{0}}, s32[{n}]{{0}})" in rows_n
-    assert len(rows_n) <= 2, rows_n
-    assert _loops_that_sort(hlo) == []
+    comps, inside = _computations(hlo), _under_a_conditional(hlo)
+    where = {name: [s for s in _sorts(text) if f"[{n}]" in s]
+             for name, text in comps.items()}
+    # two int8 flags and their null ranks: one word, and no iota with it
+    assert sorted(s for found in where.values() for s in found) == [
+        f"u32[{n}]{{0}}"]
+    assert [name for name, found in where.items()
+            if found and name not in inside] == []
+    assert inside and _loops_that_sort(hlo) == []
     assert [g for g in _gathers(hlo, "groupby") if n in g[1]] == []
 
 
@@ -451,15 +565,17 @@ def test_planned_q3_region_keeps_its_sorts(monkeypatch):
 # -- the served path's counter --------------------------------------------
 
 def _served(plan, bindings):
-    before = REGISTRY.counters().get("groupby.in_place", 0)
+    names = ("groupby.in_place", "groupby.key_sorted")
+    before = [REGISTRY.counters().get(name, 0) for name in names]
     with QueryServer(budget_bytes=4 << 30) as srv:
         ticket = srv.session("t").submit(plan, bindings)
         try:
             result, exc = ticket.result(), None
         except Exception as caught:
             result, exc = None, caught
-    return (ticket, result, exc,
-            REGISTRY.counters().get("groupby.in_place", 0) - before)
+    return (ticket, result, exc, *(
+        REGISTRY.counters().get(name, 0) - was
+        for name, was in zip(names, before)))
 
 
 @pytest.mark.parametrize("plan,moves", [
@@ -467,7 +583,9 @@ def _served(plan, bindings):
 def test_served_requests_count_groupby_in_place(plan, moves):
     """Once a request, like ``groupby.groups``: 1 for general q1, 0 for
     the declared-domain plan and for q3's bound over the gate; a bound
-    that overflowed in place is still a refused request."""
+    that overflowed in place is still a refused request. Only that one
+    sorted its key words (``groupby.key_sorted``), for the true count its
+    ``CapacityOverflow`` gives."""
     if plan == "overflow":
         rng = np.random.default_rng(4)
         table = Table([_col(t.INT64, rng.integers(0, 50, N)),
@@ -475,9 +593,11 @@ def test_served_requests_count_groupby_in_place(plan, moves):
         probe = fusion.Plan("in_place_overflow", fusion.GroupBy(
             fusion.Scan("t"), (0,), ((1, "sum"),), max_groups=M,
             label="groupby"))
-        ticket, result, exc, moved = _served(probe, {"t": table})
+        ticket, result, exc, moved, sorted_keys = _served(probe, {"t": table})
         assert isinstance(exc, resilience.CapacityOverflow)
+        assert exc.context["groups"] == 50
         assert ticket.status == "failed" and moved == moves
+        assert sorted_keys == 1
         return
     made = {"general_q1": (tpch._q1_plan(),
                            {"lineitem": tpch.lineitem_table(5000, seed=1)}),
@@ -485,9 +605,10 @@ def test_served_requests_count_groupby_in_place(plan, moves):
                            {"lineitem": tpch.lineitem_table(5000, seed=2)}),
             "planned_q3": (tpch._q3_planned_plan(0, 9204),
                            _q3_tables(n_ord=2100, n=70000))}[plan]
-    ticket, result, exc, moved = _served(*made)
+    ticket, result, exc, moved, sorted_keys = _served(*made)
     assert exc is None and ticket.status == "served"
     assert (ticket.tier, ticket.rung, ticket.steps) == ("fused", 0, 0)
-    assert moved == moves
+    assert moved == moves and sorted_keys == 0
     facts = fusion.meta_facts(made[0], result.meta)
     assert facts["groupby.in_place"] == moves
+    assert facts["groupby.key_sorted"] == 0
