@@ -27,6 +27,11 @@ row whether its key's run opened with a build row, and a second sort that
 brings the bits back to the probe's row order. No search, no offsets, no
 gather: what a fused region lowers ``Join(how="left_semi" | "left_anti")``
 to (``runtime/fusion.py``). ``join(..., how="left_semi")`` keeps the maps.
+The merged sort carries the key words the keys it was handed need: where
+the rows with a key hold ONE high word between them and low words less
+than 2**31 apart (dbgen's order keys, any 64-bit surrogate under 2**31) a
+64-bit key sorts as one word, decided inside the region from the data
+(``_probe_matches``).
 
 Scopes (``jax.named_scope``, under the plan node's own inside a region; a
 device trace splits the join's time by them): ``build`` is everything that
@@ -390,6 +395,8 @@ class SemiJoinMask(NamedTuple):
     keep: jnp.ndarray        # bool[n_left]: the probe row is in the result
     total: jnp.ndarray       # scalar int64: how many are
     build_rows: jnp.ndarray  # scalar int64: real build rows, non-null key
+    # scalar bool: a 64-bit key was sorted as one word
+    key_narrowed: jnp.ndarray
 
 
 def _key_words(key: jnp.ndarray) -> list:
@@ -400,10 +407,29 @@ def _key_words(key: jnp.ndarray) -> list:
     return [key.astype(jnp.uint32)]
 
 
-def _probe_matches(left_key: jnp.ndarray, right_key: jnp.ndarray,
-                   right_valid: jnp.ndarray) -> jnp.ndarray:
-    """bool[n_left]: the probe row's key bytes equal those of a build row
-    with ``right_valid`` (the caller folds the probe's own validity in).
+def _sorted_narrow(hi, lo, place, lo_least) -> tuple:
+    """The merged sort where the high word says nothing and the low words
+    span less than 2**31: ONE key word, ``(low - least low) << 1 | not a
+    valid build row``, the place word its payload. ``(the high word
+    changes at this row: never, rebased low words, places)`` in that
+    order."""
+    key = ((lo - lo_least) << 1) | (place >> 31)
+    key, place = jax.lax.sort((key, place), num_keys=1, is_stable=False)
+    return jnp.zeros((hi.shape[0] - 1,), jnp.bool_), key >> 1, place
+
+
+def _sorted_wide(hi, lo, place, lo_least) -> tuple:
+    """The same in (high, low, place) order: three key words."""
+    hi, lo, place = jax.lax.sort((hi, lo, place), num_keys=3, is_stable=False)
+    return hi[1:] != hi[:-1], lo, place
+
+
+def _probe_matches(left_key: jnp.ndarray, left_valid: jnp.ndarray,
+                   right_key: jnp.ndarray,
+                   right_valid: jnp.ndarray) -> tuple:
+    """``(bool[n_left], scalar bool)``: the probe row has ``left_valid``
+    and its key equals that of a build row with ``right_valid``; and
+    whether a 64-bit key was sorted as one word.
 
     One sort of both sides' keys with a last word that holds a row's place
     in ``[probe rows, build rows]`` and, above it, a bit that is 0 only on
@@ -411,26 +437,54 @@ def _probe_matches(left_key: jnp.ndarray, right_key: jnp.ndarray,
     holds a match for its probe rows exactly when its head is one, which a
     running maximum over ``2 * (head's place in the order) + (head is a
     build row)`` hands to every row of the run. A second sort, of ``2 *
-    place + bit`` alone, brings the bits back: the probe's rows lead."""
+    place + bit`` alone, brings the bits back: the probe's rows lead.
+
+    The merged sort's operands are the key's uint32 words and the place
+    word, every one a key: no two rows tie, so it need not be stable (a
+    stable one gets an iota operand more from XLA). A 4-byte key is one
+    word. A 64-bit key is two, and where the rows with a key (``left_valid``
+    / ``right_valid``) hold ONE high word between them and low words less
+    than 2**31 apart (a minimum and a maximum of each word, over words the
+    sort reads anyway) a ``lax.cond`` sorts one key word, the rebased low
+    word with the place word's top bit under it, and the place word as its
+    payload: equal low words are then equal keys among the rows that decide
+    anything, and rows that tie in the key are a run's valid build rows or
+    its others, whose order nobody reads. A row without a key may land in
+    any run: it never opens one as a build row and its own bit is masked
+    here. Keys that straddle a high word, or lie further apart, sort all
+    three words."""
     n_left, n_right = left_key.shape[0], right_key.shape[0]
+    narrowed = jnp.zeros((), jnp.bool_)
     if n_left == 0 or n_right == 0:
-        return jnp.zeros((n_left,), jnp.bool_)
+        return jnp.zeros((n_left,), jnp.bool_), narrowed
     n = n_left + n_right
     if n >= 1 << 31:
         raise ValueError(f"semi join of {n} rows: a place takes 31 bits")
     with jax.named_scope("build"):
-        words = [jnp.concatenate([lw, rw]) for lw, rw in zip(
+        *major, minor = [jnp.concatenate([lw, rw]) for lw, rw in zip(
             _key_words(left_key), _key_words(right_key))]
         other = jnp.concatenate([jnp.ones((n_left,), jnp.bool_), ~right_valid])
         place = jax.lax.iota(jnp.uint32, n) | (other.astype(jnp.uint32) << 31)
-        # every operand a key: no two rows tie, so the sort need not be
-        # stable (a stable one gets an iota operand more from XLA)
-        *ordered, place = jax.lax.sort(
-            (*words, place), num_keys=len(words) + 1, is_stable=False)
+        if major:
+            (hi,) = major
+            keyed = jnp.concatenate([left_valid, right_valid])
+            least = [jnp.min(jnp.where(keyed, w, jnp.uint32(0xFFFFFFFF)))
+                     for w in (hi, minor)]
+            most = [jnp.max(jnp.where(keyed, w, jnp.uint32(0)))
+                    for w in (hi, minor)]
+            # (with no keyed row at all every least lies above its most:
+            # the wide sort runs and decides nothing)
+            narrowed = (least[0] == most[0]) & (most[1] - least[1] < 1 << 31)
+            hi_changes, minor, place = jax.lax.cond(
+                narrowed, _sorted_narrow, _sorted_wide,
+                hi, minor, place, least[1])
+        else:
+            minor, place = jax.lax.sort(
+                (minor, place), num_keys=2, is_stable=False)
     with jax.named_scope("probe"):
-        differs = ordered[0][1:] != ordered[0][:-1]
-        for w in ordered[1:]:
-            differs = differs | (w[1:] != w[:-1])
+        differs = minor[1:] != minor[:-1]
+        if major:
+            differs = differs | hi_changes
         head = jnp.concatenate([jnp.ones((1,), jnp.bool_), differs])
         at = jax.lax.iota(jnp.uint32, n) << 1
         opened_by_build = jax.lax.cummax(jnp.where(
@@ -439,7 +493,7 @@ def _probe_matches(left_key: jnp.ndarray, right_key: jnp.ndarray,
         back = jax.lax.sort(
             ((place & jnp.uint32(0x7FFFFFFF)) << 1) | opened_by_build,
             is_stable=False)
-        return (back[:n_left] & 1) == 1
+        return ((back[:n_left] & 1) == 1) & left_valid, narrowed
 
 
 def _semi_join_impl(row_args, aux_args, row_valids, *, lkeys, rkeys,
@@ -448,7 +502,8 @@ def _semi_join_impl(row_args, aux_args, row_valids, *, lkeys, rkeys,
         row_args, row_valids, lkeys, rkeys)
     if rrv is not None:    # a row that is none holds no key
         rvalid = rvalid & rrv
-    matched = _probe_matches(lkey, rkey, rvalid) & lvalid
+    matched, narrowed = _probe_matches(
+        lkey, lvalid if lrv is None else lvalid & lrv, rkey, rvalid)
     with jax.named_scope("probe"):
         # a NULL probe key matches nothing: out of a semi join, in an anti
         # join's result (Spark NOT EXISTS / cuDF left_anti), as the maps say
@@ -456,7 +511,7 @@ def _semi_join_impl(row_args, aux_args, row_valids, *, lkeys, rkeys,
         if lrv is not None:
             keep = keep & lrv
         return SemiJoinMask(keep, jnp.sum(keep, dtype=jnp.int64),
-                            jnp.sum(rvalid, dtype=jnp.int64))
+                            jnp.sum(rvalid, dtype=jnp.int64), narrowed)
 
 
 @func_range("semi_join_mask")
@@ -473,8 +528,12 @@ def semi_join_mask(
     they lie: ``keep[i]`` exactly where ``join(..., how=how)`` has a
     ``left_index`` of ``i`` among its real rows (its first ``total``, in
     row order), for any key ``join`` takes, duplicates on both sides, NULL
-    keys and phantom rows. No ``out_size``: nothing is laid out. Runs
-    through the dispatch cache as ``join`` does, a bucket a side."""
+    keys and phantom rows. No ``out_size``: nothing is laid out.
+    ``key_narrowed`` says whether a 64-bit key was sorted as one word
+    (``_probe_matches``: the rows with a key held one high word between
+    them and low words less than 2**31 apart; never for a 4-byte key or
+    the dense ranks of a composite one, which are one word as they come).
+    Runs through the dispatch cache as ``join`` does, a bucket a side."""
     if how not in ("left_semi", "left_anti"):
         raise ValueError(f"semi_join_mask: {how!r} is no semi or anti join")
     lkeys_t, rkeys_t = _key_tuples(left_on, right_on)

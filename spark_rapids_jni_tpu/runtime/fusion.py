@@ -255,19 +255,25 @@ class Join(NamedTuple):
       never buckets), a row space of its own; the side a row did not find
       reads NULL.
     * ``left_semi`` / ``left_anti``: the LEFT columns alone, every row where
-      it lay (``semi_join_mask``): a row the join drops keeps its place and
-      loses its validity in every column, as a ``Filter``'s does, so the
-      output is the left child's row space and ``out_rows`` is not read
-      (give None). An anti join keeps a row with a NULL key, and cannot
-      tell one from a row a ``Filter`` below it dropped: both read NULL in
-      every column above it.
+      it lay (``semi_join_mask``: one sort of both sides' keys as the
+      uint32 words they need and a place word, a running maximum, a sort
+      back; a 64-bit key whose rows hold one high word between them, and
+      low words under 2**31 apart, sorts as one word with the place word
+      its payload, chosen inside the region from the data): a
+      row the join drops keeps its place and loses its validity in every
+      column, as a ``Filter``'s does, so the output is the left child's
+      row space and ``out_rows`` is not read (give None). An anti join
+      keeps a row with a NULL key, and cannot tell one from a row a
+      ``Filter`` below it dropped: both read NULL in every column above it.
 
     Lowers under its label's scope with the sub-scopes ``build`` and
     ``probe`` (``ops/join.py`` says which stage lies under which).
     Meta: ``<label>.total`` (output rows; of a semi or anti join the left
     rows kept), ``<label>.build_rows`` (real right rows with a non-null
-    key: what entered the join of the build side), and where the left side
-    holds a scan's rows ``<label>.probe_rows`` (a static: that scan's)."""
+    key: what entered the join of the build side), of a semi or anti join
+    ``<label>.key_narrowed`` (its merged sort took the narrow form: a fact
+    of the data), and where the left side holds a scan's rows
+    ``<label>.probe_rows`` (a static: that scan's)."""
 
     left: Any
     right: Any
@@ -651,6 +657,8 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
                              f"{node.label}.key_out_of_range"]
         elif isinstance(node, Join):
             keys += [f"{node.label}.total", f"{node.label}.build_rows"]
+            if node.how in _MASK_JOINS:
+                keys += [f"{node.label}.key_narrowed"]
         elif isinstance(node, DensePkJoin):
             keys += [f"{node.label}.total", f"{node.label}.pk_violation"]
         elif isinstance(node, BloomProbe):
@@ -815,20 +823,22 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                 semi = semi_join_mask(
                     ltbl, rtbl, list(node.left_on), list(node.right_on),
                     node.how, left_row_valid=lrv, right_row_valid=rrv)
-                total, build_rows = semi.total, semi.build_rows
+                facts = [("total", semi.total),
+                         ("build_rows", semi.build_rows),
+                         ("key_narrowed", semi.key_narrowed)]
                 out = (_null_all(ltbl, semi.keep), lrv)
             else:
                 maps = join(
                     ltbl, rtbl, list(node.left_on), list(node.right_on),
                     out_size=resolved[id(node)], how=node.how,
                     left_row_valid=lrv, right_row_valid=rrv)
-                total = maps.total
                 with jax.named_scope("probe"):
                     build_rows = jnp.sum(
                         key_valid(rtbl, node.right_on, rrv), dtype=jnp.int64)
                     out = (apply_join_maps(ltbl, rtbl, maps), None)
-            side.extend([(f"{node.label}.total", total),
-                         (f"{node.label}.build_rows", build_rows)])
+                facts = [("total", maps.total), ("build_rows", build_rows)]
+            side.extend((f"{node.label}.{fact}", value)
+                        for fact, value in facts)
         elif isinstance(node, DensePkJoin):
             ptbl, prv = ev(node.probe)
             btbl, brv = ev(node.build)
@@ -1603,7 +1613,9 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     kept, and the bytes of string ``chars`` it matched against a pattern
     (``filter.rows_in``, ``filter.rows_kept``, ``strings.like_bytes``), rows
     that probed and rows that matched
-    (joins that say both), groups, what a groupby lowered over a mesh
+    (joins that say both), semi and anti joins whose merged sort carried a
+    64-bit key as one word (``join.key_narrowed``: a fact of the data,
+    ``ops/join.py``), groups, what a groupby lowered over a mesh
     shuffled (exchanges, the partial rows it sent and the bytes its
     ``all_to_all`` put between chips), and how many nodes broke what the plan
     declares: a dense primary key that is not one (``pk_violation``), a
@@ -1621,7 +1633,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     (``QueryServer._account_meta``). Converting a meta value waits for the
     device, so call it where the meta is wanted on the host anyway."""
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
-             "join.build_rows": 0, "join.pk_violation": 0, "groupby.groups": 0,
+             "join.build_rows": 0, "join.key_narrowed": 0,
+             "join.pk_violation": 0, "groupby.groups": 0,
              "groupby.overflowed": 0, "groupby.in_place": 0,
              "groupby.key_sorted": 0,
              "groupby.key_narrowed": 0, "groupby.key_out_of_range": 0,
@@ -1642,6 +1655,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["join.matched_rows"] += int(total)
             facts["join.build_rows"] += int(
                 meta.get(f"{node.label}.build_rows", 0))
+            facts["join.key_narrowed"] += bool(
+                meta.get(f"{node.label}.key_narrowed", False))
             facts["join.pk_violation"] += bool(
                 meta.get(f"{node.label}.pk_violation", False))
         elif isinstance(node, GroupBy):

@@ -1073,6 +1073,7 @@ class QueryServer:
         ``fusion.meta_facts``: counters ``filter.rows_in``,
         ``filter.rows_kept``, ``strings.like_bytes``,
         ``join.probe_rows``, ``join.matched_rows``, ``join.build_rows``,
+        ``join.key_narrowed``,
         ``groupby.groups``, ``groupby.in_place``, ``groupby.key_sorted``,
         ``groupby.key_narrowed``,
         ``join.pk_violation``, ``groupby.overflowed``,

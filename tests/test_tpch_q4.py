@@ -19,6 +19,7 @@ from spark_rapids_jni_tpu import types as t
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.models import tpch
 from spark_rapids_jni_tpu.ops.join import (
+    _probe_matches,
     apply_join_maps,
     join,
     semi_join_mask,
@@ -166,6 +167,9 @@ def test_served_q4_equals_both_references(server, case):
     assert moved["filter.rows_in"] == ORDERS + ITEMS
     assert moved["filter.rows_kept"] == int(in_quarter.sum() + late.sum())
     assert moved.get("join.build_rows", 0) == int(entered.sum())
+    # dbgen's keys lie under 2**31: the merged sort carried one key word
+    assert moved.get("join.key_narrowed", 0) == 1
+    assert bool(served.meta["exists.key_narrowed"])
     assert moved["join.probe_rows"] == ORDERS
     assert moved.get("join.matched_rows", 0) == sum(want["groups"].values())
     assert moved.get("fusion.staged_regions", 0) == 0
@@ -284,6 +288,123 @@ def test_mask_is_the_maps_on_duplicate_laden_keys(how, wide, masks):
         assert int(semi.build_rows) == int(real.sum())
 
 
+def _high_word_cases(rng) -> dict:
+    """case -> (left keys, left key valid, left row valid, right keys,
+    right key valid, right row valid, dtype, whether the merged sort
+    carries one key word for a 64-bit key): a few hundred rows standing for
+    the millions, duplicates on both sides in every case."""
+    nl, nr = 300, 700
+    # from 2**30 to 3.2e9: under 2**32, over 2**31 among them, less than
+    # 2**31 apart
+    low_l = rng.integers(0, 60, nl).astype(np.int64) * 35_791_394 + 2**30
+    low_r = rng.integers(0, 45, nr).astype(np.int64) * 35_791_394 + 2**30
+    some = lambda n, p: rng.random(n) > p        # noqa: E731
+    cases = {}
+    # (a) every key under 2**32: high word 0
+    cases["under_2_32"] = (low_l, some(nl, .1), None,
+                           low_r, some(nr, .1), None, t.INT64, True)
+    # and the same further apart than 2**31: the place's top bit has no
+    # room under the low word
+    cases["under_2_32_spanning_2_31"] = (
+        (low_l - 2**30) * 2, some(nl, .1), None,
+        (low_r - 2**30) * 2, some(nr, .1), None, t.INT64, False)
+    # (b) every key negative, over -2**32: high word 0xFFFFFFFF
+    cases["all_negative"] = (low_l - 2**32, some(nl, .1), None,
+                             low_r - 2**32, some(nr, .1), None, t.INT64,
+                             True)
+    # (c) equal low words under different high words must not match: the
+    # right side holds every left key once more, 2**32 and 2**33 higher
+    straddle_r = np.concatenate([low_r[:300], low_l[:200] + 2**32,
+                                 low_l[:200] + 2**33])
+    cases["straddling_2_32"] = (low_l, some(nl, .1), None,
+                                straddle_r, some(nr, .1), None, t.INT64,
+                                False)
+    # (d) ONE valid row with another high word among equal ones, and its
+    # low word is a key the other side holds
+    one_r = low_r.copy()
+    one_r[123] = low_l[5] + 2**32
+    valid_r = some(nr, .1)
+    valid_r[123] = True
+    cases["one_row_with_another_high_word"] = (
+        low_l, some(nl, .1), None, one_r, valid_r, None, t.INT64, False)
+    # (e) NULL keys and phantom rows under another high word than every
+    # row with a key; their low words are keys the other side holds, or
+    # (the phantoms of the right) 2**31 off one: the same rebased word
+    lkv, lrv = some(nl, .2), some(nl, .2)
+    rkv, rrv = some(nr, .2), some(nr, .2)
+    odd_l = np.where(lkv & lrv, low_l, low_l + 3 * 2**32)
+    odd_r = np.where(rkv & rrv, low_r,
+                     low_r + np.where(rkv, 5 * 2**32 + 2**31, -2**32))
+    cases["keyless_rows_hold_another_high_word"] = (
+        odd_l, lkv, lrv, odd_r, rkv, rrv, t.INT64, True)
+    # (f) a 4-byte key is one word as it comes
+    cases["int32_keys"] = ((low_l - 2**30).astype(np.int32), some(nl, .1),
+                           some(nl, .1), (low_r - 2**30).astype(np.int32),
+                           some(nr, .1), None, t.INT32, False)
+    return cases
+
+
+HIGH_WORD_CASES = list(_high_word_cases(np.random.default_rng(0)))
+
+
+@pytest.mark.parametrize("how", ["left_semi", "left_anti"])
+@pytest.mark.parametrize("case", HIGH_WORD_CASES)
+def test_mask_by_the_key_words_the_keys_need(case, how):
+    """``semi_join_mask`` against ``join(how=...)``'s ``left_index`` set
+    where the merged sort carries one key word and where it carries all,
+    and the branch it took."""
+    lk, lkv, lrv, rk, rkv, rrv, dtype, narrowed = _high_word_cases(
+        np.random.default_rng(43 + HIGH_WORD_CASES.index(case)))[case]
+    mask = lambda m: None if m is None else jnp.asarray(m)   # noqa: E731
+    left = Table([Column(dtype, jnp.asarray(lk), jnp.asarray(lkv))])
+    right = Table([Column(dtype, jnp.asarray(rk), jnp.asarray(rkv))])
+    maps = join(left, right, 0, 0, out_size=len(lk), how=how,
+                left_row_valid=mask(lrv), right_row_valid=mask(rrv))
+    semi = semi_join_mask(left, right, 0, 0, how, mask(lrv), mask(rrv))
+    total = int(maps.total)
+    assert int(semi.total) == total and 0 < total < len(lk)
+    assert np.array_equal(np.asarray(maps.left_index)[:total],
+                          np.flatnonzero(np.asarray(semi.keep)))
+    assert bool(semi.key_narrowed) == narrowed
+    if how == "left_semi":     # numpy's word on it
+        real_r = rkv if rrv is None else rkv & rrv
+        real_l = lkv if lrv is None else lkv & lrv
+        assert np.array_equal(np.asarray(semi.keep),
+                              real_l & np.isin(lk, rk[real_r]))
+    # one word as it comes takes neither the reductions nor the conditional
+    jaxpr = str(jax.make_jaxpr(_probe_matches)(
+        jnp.asarray(lk), jnp.asarray(lkv), jnp.asarray(rk),
+        jnp.asarray(rkv)))
+    assert ("cond" in jaxpr) == (dtype == t.INT64)
+    assert ("reduce_min" in jaxpr) == (dtype == t.INT64)
+
+
+@pytest.mark.parametrize("shift, narrowed", [(0, 1), (2**32 - 1500, 0)],
+                         ids=["dbgen_keys", "keys_straddling_2_32"])
+def test_key_narrowed_is_a_fact_of_the_keys_and_counted_once(
+        server, shift, narrowed):
+    """``exists.key_narrowed`` in the result's meta and the server's
+    ``join.key_narrowed``: one a request over dbgen's keys, none over the
+    same keys moved to straddle 2**32, the answer the same."""
+    host = _host(4300)
+    want = reference_q4.q4(host)
+    host["orders"]["o_orderkey"] = host["orders"]["o_orderkey"] + shift
+    host["lineitem"]["l_orderkey"] = host["lineitem"]["l_orderkey"] + shift
+    keys = host["orders"]["o_orderkey"]
+    assert (keys.min() < 2**32 <= keys.max()) == (not narrowed)
+    before = REGISTRY.counters()
+    served = _serve(server, tpch._q4_plan(), _device(host))
+    moved = {k: v - before.get(k, 0) for k, v in REGISTRY.counters().items()}
+    assert reference_q4.read_answer(served.table)["rows"] == want["rows"]
+    assert len(want["rows"]) == 5
+    assert bool(served.meta["exists.key_narrowed"]) == bool(narrowed)
+    assert moved.get("join.key_narrowed", 0) == narrowed
+    assert moved["join.probe_rows"] == ORDERS
+    # the staged walk reports it as the region does
+    staged = fusion.execute(tpch._q4_plan(), _device(host), force_staged=True)
+    assert bool(staged.meta["exists.key_narrowed"]) == bool(narrowed)
+
+
 def test_mask_on_string_and_composite_keys():
     """Any key ``join`` takes: the dense ranks over both sides."""
     rng = np.random.default_rng(3)
@@ -301,6 +422,7 @@ def test_mask_on_string_and_composite_keys():
         assert int(semi.total) == total and 0 < total < 90
         assert np.array_equal(np.asarray(maps.left_index)[:total],
                               np.flatnonzero(np.asarray(semi.keep)))
+        assert not bool(semi.key_narrowed)    # dense ranks: one word
     with pytest.raises(ValueError, match="no semi or anti join"):
         semi_join_mask(left, right, 0, 0, "inner")
 
@@ -341,8 +463,10 @@ def test_build_and_probe_scopes_are_in_the_regions_hlo():
     hlo = jax.jit(region).lower(bindings).as_text(debug_info=True)
     under = set(re.findall(r'"jit\(region\)/region\.tpch_q4/exists/([^"]*)"',
                            hlo))
-    assert {"build/sort", "build/concatenate", "probe/sort",
-            "probe/slice"} <= under
+    # the merged sort twice, a form a branch of one conditional: three key
+    # words (branch 0) or one with the place word as its payload (branch 1)
+    assert {"build/cond/branch_0_fun/sort", "build/cond/branch_1_fun/sort",
+            "build/concatenate", "probe/sort", "probe/slice"} <= under
     # every operation of the join lies under one of the two but the fold
     # of the row masks into the keys' validity
     assert {n.split("/")[0] for n in under} <= {"build", "probe", "and"}
