@@ -7,7 +7,7 @@ Drives the main path once through its normal entry points at TPC-H scale
 -> device, result back through ``ticket.result()``), checks every result
 against the numpy oracle beside its plan, and fails unless the recovery
 rungs between a query and the chip (inline dispatch, the staged evaluator,
-the degrade ladder, the Pallas interpreter/XLA twin) all stayed unused.
+the degrade ladder) all stayed unused.
 
 Process model: this parent never imports JAX. Each phase is a child
 process, one after another, each gone before the next starts; all share
@@ -47,7 +47,7 @@ FOUR_CHIP_S = 1200.0
 F64_RTOL = 1e-9              # float64 averages; integers and decimals exact
 
 # TPC-H table cardinalities (specification clause 4.2.5): SF10 lineitem for
-# the scans, SF1 for the joins, the fleet and the kernels. Nothing of the
+# the scans, SF1 for the joins and the fleet. Nothing of the
 # schema is cut, only the scale, to what one chip holds.
 FULL = {
     "sf10_rows": 59_986_052,
@@ -150,7 +150,7 @@ def _compile_s(plan=None) -> float:
     return float(REGISTRY.histogram(name).sum) / 1e3
 
 
-def check_counters(*, tickets=(), native_kernels: bool = True) -> None:
+def check_counters(*, tickets=()) -> None:
     """Section 2 of the issue: no way off the chip that the smoke cannot
     see. Raises with the counter's name unless every recovery rung between
     a query and the device stayed unused."""
@@ -163,12 +163,9 @@ def check_counters(*, tickets=(), native_kernels: bool = True) -> None:
                  "resilience.rung.staged_fallback", "degrade.step"):
         if c.get(name, 0) != 0:
             raise SmokeFailure(f"{name} = {c[name]}, required 0")
-    if native_kernels and c.get("kernels.interpret", 0) != 0:
-        raise SmokeFailure(
-            f"kernels.interpret = {c['kernels.interpret']}, required 0")
     for name, value in c.items():
         if value and name.startswith(
-                ("fallback.fusion.", "kernels.fallback.", "degrade.tier.")):
+                ("fallback.fusion.", "degrade.tier.")):
             raise SmokeFailure(f"{name} = {value}, required absent")
     for label, where in tickets:
         if where != ("fused", 0, 0):
@@ -588,10 +585,9 @@ def serve_phase(sizes: dict, platform: str, seed: int = 0,
         ctx.say(f"oracle: numpy q3 has {len(q3_ref('a'))} groups")
 
         # timed honestly: if four enqueued runs cost less than twice one
-        # run, block_until_ready does not wait for the device. One run is
-        # the quickest of three: a host busy with other work (the tests'
-        # six workers) can only lengthen a run, and one lengthened sample
-        # of "one" failed the check at a ratio of 1.97
+        # run, block_until_ready does not wait for the device. Judged on
+        # the chip only: on a CPU shared with other work the ratio of two
+        # wall-clock readings says nothing. One run is the quickest of three
         plan = tpch._q1_plan()
         one = math.inf
         for _ in range(3):
@@ -605,7 +601,7 @@ def serve_phase(sizes: dict, platform: str, seed: int = 0,
         four = time.perf_counter() - t0
         ctx.say(f"sync: general q1 at {n1} rows warm, one run {one:.4f}s, "
                 f"four enqueued runs {four:.4f}s (ratio {four / one:.2f})")
-        if four < 2 * one:
+        if platform == "tpu" and four < 2 * one:
             raise SmokeFailure(
                 f"block_until_ready is not a sync: four runs took "
                 f"{four:.4f}s, one took {one:.4f}s")
@@ -663,111 +659,6 @@ def recompile_phase(sizes: dict, platform: str, seed: int = 0) -> dict:
     check_counters()
     return {"compile_s": _compile_s(), "cache_hits": hits,
             "cache_misses": misses}
-
-
-# ---------------------------------------------------------------------------
-# phase: kernels — every registered Pallas kernel, native, against its twin
-# ---------------------------------------------------------------------------
-
-
-def _kernel_cases(rows: int, seed: int) -> tuple:
-    """({tier-driven kernel name: run the op under the configured tier,
-    return its bytes}, the lineitem table). The row side is ``rows``; the
-    rest is the largest shape the kernel's own eligibility check admits."""
-    import numpy as np
-
-    from spark_rapids_jni_tpu.columnar import Column, Table
-    from spark_rapids_jni_tpu.models import tpch
-    from spark_rapids_jni_tpu.ops.join import join
-    from spark_rapids_jni_tpu.ops.pallas import hash_probe
-    from spark_rapids_jni_tpu.ops.row_conversion import convert_to_rows
-
-    li = tpch.lineitem_table(rows, seed)
-    rng = np.random.default_rng(seed)
-    # int32 keys (the probe kernel's lane width), a MAX_BUILD-key build side
-    probe = Table([Column.from_numpy(
-        rng.integers(0, 2 * hash_probe.MAX_BUILD, rows).astype(np.int32))])
-    build = Table([Column.from_numpy(
-        rng.integers(0, 2 * hash_probe.MAX_BUILD,
-                     hash_probe.MAX_BUILD).astype(np.int32))])
-
-    def q1_bounded():
-        return _table_bytes(tpch.tpch_q1_planned_result(li).table)
-
-    def probe_join():
-        maps = join(probe, build, 0, 0, 2 * rows, how="inner")
-        return [np.asarray(f).tobytes() for f in maps]
-
-    def to_rows():
-        # the seven q1/q6 columns: a 48-byte row, under the 256-byte cap
-        return [(b.num_rows, b.row_size, np.asarray(b.data).tobytes())
-                for b in convert_to_rows(li)]
-
-    return {
-        "groupby.bounded_accumulate": q1_bounded,
-        "join.hash_probe": probe_join,
-        "row_conversion.to_rows": to_rows,
-    }, li
-
-
-def kernels_phase(sizes: dict, platform: str, seed: int = 0) -> dict:
-    ctx = _Ctx(platform)
-    import numpy as np
-
-    import spark_rapids_jni_tpu.ops.pallas.q1 as pallas_q1
-    from spark_rapids_jni_tpu.models import tpch
-    from spark_rapids_jni_tpu.ops import pallas as ptier
-    from spark_rapids_jni_tpu.utils.config import reset_option, set_option
-
-    native = platform == "tpu"
-    mode = "native" if native else "interpret"
-    rows = sizes["sf1_rows"]
-    cases, li = _kernel_cases(rows, seed)
-    report = {}
-    registered = sorted(ptier.registered())
-    for name in registered:
-        t0 = time.perf_counter()
-        if name == "tpch_q1.fused":
-            # a whole-query kernel called directly: its twin is the
-            # bounded-domain plan's six real groups on the xla tier
-            got = pallas_q1.tpch_q1_pallas(li, interpret=not native)
-            want = tpch.tpch_q1_planned(li)
-            same = all(
-                np.asarray(g.data).tobytes()
-                == np.asarray(w.data)[:g.size].tobytes()
-                for g, w in zip(got.columns, want.columns)) and bool(
-                    np.asarray(got.column(0).valid_mask()).all())
-        else:
-            decided = f"kernels.{name}.pallas"
-            before = _counters().get(decided, 0)
-            set_option("kernels.tier", "pallas")
-            try:
-                got = cases[name]()
-            finally:
-                reset_option("kernels.tier")
-            if _counters().get(decided, 0) <= before:
-                raise SmokeFailure(
-                    f"kernel {name}: tier=pallas but decide() never chose "
-                    f"pallas ({decided} unchanged)")
-            set_option("kernels.tier", "xla")
-            try:
-                same = got == cases[name]()
-            finally:
-                reset_option("kernels.tier")
-        wall = time.perf_counter() - t0
-        ctx.say(f"kernel {name}: mode {mode}, {rows} rows, byte-identical "
-                f"to the xla tier: {same} ({wall:.1f}s with both compiles)")
-        if not same:
-            raise SmokeFailure(
-                f"kernel {name}: pallas ({mode}) differs from the xla tier")
-        report[name] = {"mode": mode, "wall_s": wall}
-    check_counters(native_kernels=native)
-    if not native and _counters().get("kernels.interpret", 0) < 1:
-        raise SmokeFailure("no kernel ran in the Pallas interpreter")
-    ctx.say(f"kernels: {len(registered)} registered, all {mode}, no "
-            f"kernels.fallback.* key, kernels.interpret "
-            f"{_counters().get('kernels.interpret', 0)}")
-    return report
 
 
 # ---------------------------------------------------------------------------
@@ -1072,7 +963,6 @@ def _run_phase(phase: str, scratch: str = SCRATCH) -> None:
         "probe": lambda: probe_phase("tpu"),
         "serve": lambda: serve_phase(FULL, "tpu"),
         "recompile": lambda: recompile_phase(FULL, "tpu"),
-        "kernels": lambda: kernels_phase(FULL, "tpu"),
         "fleet": lambda: fleet_phase(FULL, "tpu", info()),
         "mesh": lambda: mesh_phase(FULL, "tpu"),
         "cluster": lambda: cluster_phase(FULL, "tpu", info()),
@@ -1095,7 +985,6 @@ def main() -> int:
     print(f"{tag} compile cache: general q1 at SF1 compiled in {cold:.3f}s "
           f"in the serve phase and in {warm['compile_s']:.3f}s in a second "
           f"process ({warm['cache_hits']} persistent-cache hits)", flush=True)
-    _child("kernels", deadline, SCRATCH)
     _child("fleet", deadline, SCRATCH)
     if device["count"] >= 4:
         deadline += FOUR_CHIP_S

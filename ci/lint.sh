@@ -1,15 +1,15 @@
 #!/bin/bash
 # Static-analysis gate — the Python-side stand-in for the compile-time
 # enforcement the reference gets from C++ types and JNI signature checks:
-# tpulint (tools/tpulint) runs its twenty-six invariant rules —
-# twenty-three per-file AST rules (host/device
+# tpulint (tools/tpulint) runs its twenty-five invariant rules —
+# twenty-two per-file AST rules (host/device
 # boundary, traced branches, sentinel safety, regex padding byte, dtype
 # width, validity-mask derivation, fallback accounting, jit-via-dispatch,
 # pipeline-stage host-transfer, fusion-region host-sync,
 # error-must-classify, server-telemetry-session-id,
 # reservation-release-in-finally, span-must-scope, payload-must-verify,
 # cache-key-must-fingerprint, compress-inside-seal,
-# worker-exit-must-classify, pallas-kernel-must-have-oracle,
+# worker-exit-must-classify,
 # placement-must-record, rtfilter-decision-must-record,
 # exchange-overflow-must-classify, peer-flight-must-verify-manifest)
 # plus three whole-program concurrency rules built on the
@@ -751,54 +751,6 @@ print("cluster smoke OK: 2-host partitioned q1 == single-host, hot-shard "
       "SIGKILL failed over bit-identical via re-home, host death "
       "classified, 0 leaked bytes")
 EOF
-
-# kernel-tier smoke: rule 19 only proves Pallas kernels DECLARE an
-# oracle — this proves the tier itself still honors its contract: the
-# same bounded groupby under kernels.tier=pallas (interpret on CPU) is
-# byte-for-byte the kernels.tier=xla oracle, and every tier decision,
-# interpret-mode run and fallback is visible in the kernels.* counters.
-JAX_PLATFORMS=cpu python - <<'EOF2'
-import numpy as np
-import jax.numpy as jnp
-
-from spark_rapids_jni_tpu.columnar import Column, Table
-from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate_bounded
-from spark_rapids_jni_tpu.telemetry import REGISTRY
-from spark_rapids_jni_tpu.types import DType, TypeId
-from spark_rapids_jni_tpu.utils.config import reset_option, set_option
-
-rng = np.random.default_rng(0)
-n = 2049
-tbl = Table([
-    Column(DType(TypeId.INT32),
-           jnp.asarray(rng.choice([10, 20, 30], n).astype(np.int32)),
-           jnp.asarray(rng.random(n) > 0.1)),
-    Column(DType(TypeId.INT64),
-           jnp.asarray(rng.integers(-2**62, 2**62, n, dtype=np.int64)),
-           jnp.asarray(rng.random(n) > 0.2)),
-])
-aggs = [(1, "sum"), (1, "count"), (1, "mean")]
-
-
-def run(tier):
-    set_option("kernels.tier", tier)
-    try:
-        return groupby_aggregate_bounded(tbl, [0], aggs, [[10, 20, 30]])
-    finally:
-        reset_option("kernels.tier")
-
-
-rx, rp = run("xla"), run("pallas")
-for cx, cp in zip(rx.table.columns, rp.table.columns):
-    assert np.asarray(cx.data).tobytes() == np.asarray(cp.data).tobytes(), \
-        "pallas tier diverged from the xla oracle"
-c = REGISTRY.counters("kernels.")
-assert c.get("kernels.tier.pallas", 0) >= 1, c
-assert c.get("kernels.tier.xla", 0) >= 1, c
-assert c.get("kernels.interpret", 0) >= 1, c  # CPU runs are marked
-print("kernel-tier smoke OK: pallas == xla byte-for-byte, "
-      "decisions + interpret mode counted")
-EOF2
 
 # rtfilter smoke: a selective q72-style chunked aggregate with the
 # runtime bloom filter ON must stage strictly fewer probe rows than the

@@ -30,14 +30,12 @@ re-plans instead of corrupting. Distributed, the bounded plans merge
 with m-row collectives instead of row shuffles (zero-shuffle q72,
 one-exchange broadcast q3).
 
-Pallas posture: the shipped hot paths are XLA-emitted (the measured hot
-spots are layout transforms, scans, sorts, and gathers the compiler
-already fuses; scatter-heavy forms were redesigned scatter-free after a
-v5e reading in 2026-07 put scatters 1.6-4x behind the scan forms). The
-maintained kernel tier (ops/pallas/, ``kernels.tier``, default ``xla``)
-holds the Pallas kernels registered in ``ops.pallas.registered()``, each
-with its XLA bit-identity oracle; ``chip_smoke.py`` compiles every one
-natively and compares it with its oracle on the chip.
+Kernels: every operator has one implementation, emitted by XLA (the
+measured hot spots are layout transforms, scans, sorts, and gathers the
+compiler already fuses; scatter-heavy forms were redesigned scatter-free
+after a v5e reading in 2026-07 put scatters 1.6-4x behind the scan
+forms). A hand-written kernel replaces the XLA code for the inputs it
+takes, chosen by the code, once a chip reading in a cell shows it pays.
 
 Layer map (TPU equivalent of reference SURVEY.md section 1):
   L4' Java API parity sources  -> java/ (build-gated; no JVM in this image)

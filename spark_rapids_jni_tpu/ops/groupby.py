@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import numbers
 from functools import partial
-from typing import Any, NamedTuple, Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -1811,13 +1811,9 @@ def bounded_group_layout(domain_lens: Sequence[int]):
 
 
 class _XlaBoundedAccumulator:
-    """The bit-identity ORACLE accumulate for bounded groupby: one
-    masked whole-column reduction per (group, lane) — byte-for-byte the
-    pre-kernel-tier path (XLA fuses the m masked reductions into a
-    single pass over the rows). The Pallas twin
-    (``groupby.bounded_accumulate``, ops/pallas/groupby_accumulate.py)
-    must reproduce every method bit-for-bit; tpulint rule 19 keeps this
-    path reachable via ``kernels.tier=xla``."""
+    """The accumulate of the bounded groupby: one masked whole-column
+    reduction per (group, lane), which XLA fuses into a single pass
+    over the rows."""
 
     def __init__(self, table: Table, gid: jnp.ndarray, n: int, m: int):
         self._table = table
@@ -1863,113 +1859,6 @@ class _XlaBoundedAccumulator:
             c.valid_mask(), c.data, jnp.asarray(sentinel, c.data.dtype))
         return self._per_group(vv, jnp.min if op == "min" else jnp.max,
                                jnp.asarray(sentinel, c.data.dtype))
-
-
-class _PallasBoundedAccumulator:
-    """Pallas-tier accumulate: every (group, lane) partial from ONE
-    streaming kernel launch (ops/pallas/groupby_accumulate.py). Integer
-    sums ride 16-bit limb lanes recombined in wrapping int64 — exact
-    mod 2^64, which is bit-identical to the oracle's int64 sums; min/max
-    lanes carry the oracle's own sentinel so empty groups match too.
-    Built only after :func:`_pallas_bounded_plan` proved every aggregate
-    eligible."""
-
-    def __init__(self, table: Table, aggs, gid: jnp.ndarray, n: int,
-                 m: int, *, interpret: bool):
-        from spark_rapids_jni_tpu.ops.pallas import groupby_accumulate as pga
-
-        lanes: list[jnp.ndarray] = []
-        meta: list[tuple[str, int]] = []
-
-        def add(arr, op, neutral):
-            lanes.append(arr)
-            meta.append((op, int(neutral)))
-            return len(lanes) - 1
-
-        self._rows_lane = add(jnp.ones((n,), jnp.int32), "sum", 0)
-        self._vcount_lane: dict[int, int] = {}
-        self._sum_lanes: dict[int, list[int]] = {}
-        self._minmax_lane: dict[tuple[int, str], int] = {}
-        self._storage: dict[int, Any] = {}
-        for col_idx, op in aggs:
-            c = table.column(col_idx)
-            valid = c.valid_mask()
-            self._storage[col_idx] = c.data.dtype
-            if col_idx not in self._vcount_lane:
-                self._vcount_lane[col_idx] = add(
-                    valid.astype(jnp.int32), "sum", 0)
-            if op in ("sum", "mean") and col_idx not in self._sum_lanes:
-                vv_zero = jnp.where(valid, c.data, jnp.zeros_like(c.data))
-                limbs = pga.split_limbs(
-                    vv_zero, np.dtype(c.data.dtype).itemsize)
-                self._sum_lanes[col_idx] = [
-                    add(limb, "sum", 0) for limb in limbs]
-            if op in ("min", "max") and (col_idx, op) not in self._minmax_lane:
-                sentinel = int(minmax_sentinel(c.dtype, op))
-                vv = jnp.where(
-                    valid, c.data, jnp.asarray(sentinel, c.data.dtype))
-                self._minmax_lane[(col_idx, op)] = add(
-                    vv.astype(jnp.int32), op, sentinel)
-        self._sums, self._mins, self._maxs = pga.accumulate(
-            gid, lanes, tuple(meta), m, interpret=interpret)
-        self._combine = pga.combine_limbs
-
-    def rows_per_group(self) -> jnp.ndarray:
-        return self._sums[:, self._rows_lane]
-
-    def vcount(self, col_idx: int) -> jnp.ndarray:
-        return self._sums[:, self._vcount_lane[col_idx]]
-
-    def sum_int(self, col_idx: int) -> jnp.ndarray:
-        return self._combine(
-            [self._sums[:, li] for li in self._sum_lanes[col_idx]])
-
-    def sum_float(self, col_idx: int) -> jnp.ndarray:
-        raise AssertionError(
-            "float aggregates never kernelize (summation order would "
-            "break bit-identity) — _pallas_bounded_plan must have "
-            "routed this op to the oracle")
-
-    def minmax(self, col_idx: int, op: str) -> jnp.ndarray:
-        source = self._mins if op == "min" else self._maxs
-        red = source[:, self._minmax_lane[(col_idx, op)]]
-        return red.astype(self._storage[col_idx])
-
-
-def _pallas_bounded_plan(table: Table, aggs, n: int, m: int):
-    """Trace-time eligibility of one bounded groupby for the Pallas
-    accumulate tier. Returns a fallback reason (recorded by the caller)
-    or None when every aggregate kernelizes bit-identically."""
-    from spark_rapids_jni_tpu.ops.pallas import groupby_accumulate as pga
-
-    lane_count = 1  # the row-count lane
-    seen_vcount: set[int] = set()
-    seen_sum: set[int] = set()
-    for col_idx, op in aggs:
-        c = table.column(col_idx)
-        st = np.dtype(c.data.dtype)
-        if col_idx not in seen_vcount:
-            seen_vcount.add(col_idx)
-            lane_count += 1
-        if op in ("sum", "mean"):
-            acc_dt = _sum_dtype(c.dtype)
-            if acc_dt.storage_dtype.kind not in ("i", "u"):
-                # float sums are order-sensitive: kernelizing them would
-                # trade bit-identity for speed — never silently
-                return "float_agg"
-            if st.kind not in ("i", "u", "b"):
-                return "float_agg"
-            if col_idx not in seen_sum:
-                seen_sum.add(col_idx)
-                lane_count += pga.limb_count(st.itemsize)
-        elif op in ("min", "max"):
-            # the in-kernel lanes are int32: the cast must preserve
-            # order and value
-            if not (st.kind == "i" and st.itemsize <= 4
-                    or st.kind == "u" and st.itemsize <= 2):
-                return "minmax_width"
-            lane_count += 1
-    return pga.unsupported_reason(n, m, lane_count)
 
 
 class BoundedGroupByResult(NamedTuple):
@@ -2063,22 +1952,7 @@ def groupby_aggregate_bounded(
 
     out_cols: list[Column] = []
 
-    # kernel tier pick happens at TRACE time: the dispatch cache key
-    # carries the kernels digest, so a tier flip never reuses a stale
-    # executable and fused plans inherit the same decision
-    from spark_rapids_jni_tpu.ops import pallas as pallas_tier
-
-    decision = pallas_tier.decide("groupby.bounded_accumulate")
-    acc = None
-    if decision.use_pallas:
-        reason = _pallas_bounded_plan(table, aggs, n, m)
-        if reason is None:
-            acc = _PallasBoundedAccumulator(
-                table, aggs, gid, n, m, interpret=decision.interpret)
-        else:
-            pallas_tier.fall_back("groupby.bounded_accumulate", reason)
-    if acc is None:
-        acc = _XlaBoundedAccumulator(table, gid, n, m)
+    acc = _XlaBoundedAccumulator(table, gid, n, m)
 
     rows_per_group = acc.rows_per_group()
     present = rows_per_group > 0

@@ -181,34 +181,8 @@ def partition_hash(table: Table, columns: Sequence[int], num_partitions: int) ->
 def probe_sorted_lo_hi(
     sorted_key: jnp.ndarray, probe_key: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Kernel-tier seam for the hash-join/groupby probe loop: per probe
-    key, the [lo, hi) match-run bounds in the sentinel-padded sorted
-    build keys.
-
-    Tier pick happens at TRACE time (the dispatch cache key carries the
-    kernels digest, so join executables re-specialize when the tier
-    flips). The Pallas twin (ops/pallas/hash_probe.py) streams the
-    SMEM-resident build keys past each probe tile and is bit-identical
-    to the searchsorted pair by construction; anything it cannot take —
-    empty sides, > MAX_BUILD build rows, non-int32 keys — falls back to
-    the XLA oracle below with the reason recorded, never silently.
-    """
-    from spark_rapids_jni_tpu.ops import pallas as pallas_tier
-
-    op = "join.hash_probe"
-    decision = pallas_tier.decide(op)
-    if decision.use_pallas:
-        from spark_rapids_jni_tpu.ops.pallas import hash_probe as hp
-
-        if sorted_key.shape[0] == 0 or probe_key.shape[0] == 0:
-            reason = "empty_input"
-        else:
-            reason = hp.unsupported_reason(
-                sorted_key.shape[0], sorted_key.dtype)
-        if reason is None:
-            return hp.probe_lo_hi(
-                sorted_key, probe_key, interpret=decision.interpret)
-        pallas_tier.fall_back(op, reason)
+    """Per probe key, the [lo, hi) match-run bounds in the
+    sentinel-padded sorted build keys."""
     lo = jnp.searchsorted(sorted_key, probe_key, side="left")
     hi = jnp.searchsorted(sorted_key, probe_key, side="right")
     return lo, hi

@@ -122,8 +122,6 @@ def _probe_maps(left_key, left_valid, right_key, right_valid, sorted_key,
     n_left = left_key.shape[0]
     n_right = right_key.shape[0]
     # Match runs per probe row (empty when the probe key is null).
-    # probe_sorted_lo_hi is the kernel-tier seam: searchsorted pair on
-    # the XLA tier, the streaming Pallas probe kernel otherwise.
     lo, hi = probe_sorted_lo_hi(sorted_key, left_key)
     hi = jnp.minimum(hi, n_valid_right)  # the sentinel tail never matches
     lo = jnp.minimum(lo, hi)

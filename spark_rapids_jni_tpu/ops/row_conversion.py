@@ -129,24 +129,8 @@ def _to_rows_impl(
     starts.append(cursor)
     pieces.append(_pack_validity_bytes(jnp.stack(valids, axis=1)))
 
-    # kernel-tier seam: the XLA oracle interleaves by lane concatenation
-    # (alignment gaps / trailing row pad as explicit zero pieces); the
-    # Pallas twin assembles the same bytes by where-selects with gaps
-    # falling out of its zero-initialized tile. Tier pick is trace-time,
-    # keyed into the dispatch cache via the kernels digest.
-    from spark_rapids_jni_tpu.ops import pallas as pallas_tier
-
-    decision = pallas_tier.decide("row_conversion.to_rows")
-    if decision.use_pallas:
-        from spark_rapids_jni_tpu.ops.pallas import row_transpose as prt
-
-        reason = prt.unsupported_reason(n, size_per_row)
-        if reason is None:
-            return prt.assemble_rows(
-                pieces, starts, size_per_row,
-                interpret=decision.interpret)
-        pallas_tier.fall_back("row_conversion.to_rows", reason)
-
+    # interleave by lane concatenation: alignment gaps and the trailing
+    # row pad are explicit zero pieces
     padded: list[jnp.ndarray] = []
     cursor = 0
     for start, piece in zip(starts, pieces):

@@ -384,20 +384,6 @@ def _cache_store(key, compiled, ev: threading.Event) -> None:
     ev.set()
 
 
-def _kernels_digest() -> tuple:
-    """The Pallas kernel-tier configuration (ops/pallas) as a cache-key
-    component. Tier selection happens at TRACE time inside ``fn``, so
-    every executable must be keyed by the tier that traced it — flipping
-    ``kernels.tier`` (or a per-op override) can never replay an
-    executable traced under the other tier. Fused regions inherit this
-    through their own dispatch.call, which is exactly how a Pallas
-    kernel picks up shape bucketing, caching and donation like its XLA
-    twin."""
-    from spark_rapids_jni_tpu.ops import pallas as pallas_tier
-
-    return pallas_tier.kernels_digest()
-
-
 def _inline(op: str, reason: str, fn: Callable, row_args: tuple,
             aux_args: tuple) -> Any:
     REGISTRY.counter("dispatch.inline").inc()
@@ -517,7 +503,7 @@ def call(
         REGISTRY.counter("dispatch.pad_error").inc()
         return _inline(op, "pad_error", fn, row_args, aux_args)
 
-    key = (op, statics, donate_rows, _kernels_digest(),
+    key = (op, statics, donate_rows,
            _signature((padded, aux_args, row_valids)),
            jax.default_backend())
     compiled, lead_ev = _cache_lookup(key)
@@ -720,7 +706,7 @@ def sharded_call(
         REGISTRY.counter("dispatch.inline").inc()
         REGISTRY.counter("dispatch.inline.tracer").inc()
         return build()(*args)
-    key = (op, ("sharded", cfg) + tuple(statics), _kernels_digest(),
+    key = (op, ("sharded", cfg) + tuple(statics),
            _signature(args), jax.default_backend())
     compiled, lead_ev = _cache_lookup(key)
     if compiled is None:

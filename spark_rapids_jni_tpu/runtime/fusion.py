@@ -41,19 +41,6 @@ fused query is bit-identical to its op-by-op reference at any row count
 (tests/test_fusion.py pins this at 1, 2^k-1, 2^k, 2^k+1 rows with null
 tails).
 
-Kernel tier
------------
-The Pallas kernel tier (ops/pallas/, ``kernels.tier``) composes with
-fusion for free: tier selection happens at TRACE time inside each per-op
-implementation (``groupby_aggregate_bounded``, ``probe_sorted_lo_hi``,
-``_to_rows_impl``), so when a fused region inlines those ops the chosen
-kernels are baked into the single fused executable — Pallas kernels
-inherit the region's shape bucketing, executable cache, and donation
-exactly like their XLA twins. Every ``dispatch.call`` key (fused or
-staged) carries the kernels digest, so flipping ``kernels.tier`` or a
-per-op override re-specializes fused executables instead of reusing a
-stale tier's cache entry.
-
 Donation
 --------
 ``execute(..., donate_inputs=True)`` is the caller's declaration that the

@@ -92,9 +92,8 @@ _ENV = "SPARK_RAPIDS_TPU_INTEGRITY"
 
 def enabled() -> bool:
     """Is integrity verification on? The short env var
-    SPARK_RAPIDS_TPU_INTEGRITY is checked first (same precedence pattern
-    as SPARK_RAPIDS_TPU_KERNEL_TIER), then the ``integrity.enabled``
-    option."""
+    SPARK_RAPIDS_TPU_INTEGRITY is checked first, then the
+    ``integrity.enabled`` option."""
     env = os.environ.get(_ENV)
     if env is not None:
         return env.strip().lower() in ("1", "true", "yes", "on")

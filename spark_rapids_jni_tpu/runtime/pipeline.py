@@ -82,8 +82,7 @@ def pipeline_enabled() -> bool:
 
 def configured_prefetch_depth() -> int:
     """Prefetch depth: the short env var SPARK_RAPIDS_TPU_PIPELINE_PREFETCH
-    wins over the ``pipeline.prefetch_depth`` option (same pattern as
-    SPARK_RAPIDS_TPU_KERNEL_TIER for the kernel tier)."""
+    wins over the ``pipeline.prefetch_depth`` option."""
     env = os.environ.get("SPARK_RAPIDS_TPU_PIPELINE_PREFETCH")
     if env is not None and env.strip():
         return max(int(env), 1)

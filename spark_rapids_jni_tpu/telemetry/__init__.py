@@ -37,7 +37,6 @@ from spark_rapids_jni_tpu.telemetry.events import (
     record_fallback,
     record_fleet,
     record_integrity,
-    record_kernel_tier,
     record_resilience,
     record_rtfilter,
     record_server,
@@ -73,7 +72,6 @@ __all__ = [
     "record_fallback",
     "record_fleet",
     "record_integrity",
-    "record_kernel_tier",
     "record_resilience",
     "record_rtfilter",
     "record_server",

@@ -60,7 +60,6 @@ __all__ = [
     "record_integrity",
     "record_cache",
     "record_fleet",
-    "record_kernel_tier",
     "session_scope",
     "current_session",
     "events",
@@ -240,42 +239,6 @@ def record_fallback(
     rec["engine"] = "host"
     REGISTRY.counter(f"fallback.{op}").inc()
     REGISTRY.counter("fallbacks_total").inc()
-    _emit(rec)
-    return True
-
-
-def record_kernel_tier(
-    op: str,
-    *,
-    tier: str,
-    mode: str,
-    reason: str,
-    **extra: Any,
-) -> bool:
-    """The Pallas kernel tier (ops/pallas/) decided how op ``op`` traces:
-    ``tier`` ("pallas" | "xla") via ``mode`` ("native" | "interpret" |
-    "oracle"), because ``reason``. Every decision — including the xla
-    default and every fallback — is recorded, so a tier flip can never be
-    a silent behavior change. Decisions happen at trace time: a cached
-    executable replays its recorded decision without re-deciding."""
-    if not reason or not str(reason).strip():
-        # validated even when disabled: an unaccountable tier pick is a bug
-        raise ValueError(f"record_kernel_tier({op!r}): reason must be non-empty")
-    # Counters bump unconditionally (like dispatch.compile): the tier ledger
-    # must exist even when event emission is off, or a fallback is silent.
-    REGISTRY.counter(f"kernels.{op}.{tier}").inc()
-    REGISTRY.counter(f"kernels.tier.{tier}").inc()
-    if mode == "interpret":
-        REGISTRY.counter("kernels.interpret").inc()
-    if tier == "xla" and reason != "config":
-        # a non-config xla decision is a fallback: count it by reason
-        REGISTRY.counter(f"kernels.fallback.{reason}").inc()
-    if not enabled():
-        return False
-    rec = _base("kernel_tier", op, None, None, extra)
-    rec["tier"] = str(tier)
-    rec["mode"] = str(mode)
-    rec["reason"] = str(reason)
     _emit(rec)
     return True
 

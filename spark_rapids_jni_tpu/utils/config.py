@@ -248,19 +248,6 @@ _OPTIONS: dict[str, tuple[Any, type]] = {
     # How long a submit waits for a healthy replica (all dead/quarantined
     # or still booting) before failing classified.
     "fleet.dispatch_timeout_s": (30.0, float),
-    # Pallas kernel tier (ops/pallas/): which device implementation the
-    # hot inner loops (bounded-groupby accumulate, join hash probe,
-    # row-image transpose) trace into. "xla" = the legacy XLA primitives
-    # (byte-for-byte the pre-tier path, and always the bit-identity
-    # oracle), "pallas" = the hand-written kernels (interpret-mode on
-    # backends without Mosaic, e.g. CPU tier-1), "auto" = pallas on TPU,
-    # xla elsewhere. The short env var SPARK_RAPIDS_TPU_KERNEL_TIER is
-    # also honored (checked first by ops/pallas).
-    "kernels.tier": ("xla", str),
-    # Per-op tier overrides: "op=tier,op=tier" (e.g.
-    # "groupby.bounded_accumulate=pallas,join.hash_probe=xla"); an op
-    # absent here follows kernels.tier.
-    "kernels.tier_overrides": ("", str),
     # AOT warmup (QueryServer.warmup): how many of the costliest plan
     # signatures from the learned-estimate file a fresh replica
     # precompiles at boot (fleet _worker_main calls this before

@@ -75,7 +75,6 @@ _MEDIUM_TIER = {
     "tests/test_tpcds.py::test_q72_year_filter_changes_result",
     "tests/test_tpch.py::test_q1_groups_sorted_first",
     "tests/test_tpch.py::test_q1_matches_numpy_oracle",
-    "tests/test_tpch.py::test_q1_pallas_kernel_matches_oracle_interpret",
     "tests/test_tpch.py::test_q1_planned_checked_replans_on_domain_miss",
     "tests/test_tpch.py::test_q1_planned_matches_oracle_and_is_sort_free",
     "tests/test_tpch.py::test_tpch_q12_vs_numpy",

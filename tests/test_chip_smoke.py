@@ -54,20 +54,15 @@ def test_serve_phase_passes_tiny_on_cpu(tmp_path):
     assert {k.split("@")[0] for k in report} == {
         "q1_planned", "q6", "q1_general", "q1_parquet", "q3_general",
         "q3_planned", "sync", "fingerprint"}
-    assert report["sync"]["four_s"] >= 2 * report["sync"]["one_s"]
+    # both readings are there; their ratio is judged on the chip only
+    assert all(isinstance(report["sync"][k], float) and report["sync"][k] > 0
+               for k in ("one_s", "four_s"))
     # the table where it lives against numpy over its host copy (tiny: all
     # of it under the digest's threshold, so none digested on the device)
     assert report["fingerprint"]["moved"] == {
         "cache.fingerprint_bytes": 5000 * 38,
         "cache.fingerprint_device_bytes": 0}
     assert not list(tmp_path.iterdir())  # the Parquet files are removed
-
-
-def test_kernels_phase_runs_every_registered_kernel_interpreted():
-    report = chip_smoke.kernels_phase(TINY, "cpu")
-    assert set(report) == {"groupby.bounded_accumulate", "join.hash_probe",
-                           "row_conversion.to_rows", "tpch_q1.fused"}
-    assert all(r["mode"] == "interpret" for r in report.values())
 
 
 def test_fleet_phase_each_replica_serves_a_checked_query():
@@ -121,13 +116,13 @@ def test_counter_check_fails_when_compile_fell_back_inline():
         chip_smoke.check_counters()
 
 
-def test_counter_check_reads_tickets_and_kernel_fallbacks():
+def test_counter_check_reads_tickets_and_fusion_fallbacks():
     chip_smoke.check_counters(tickets=[("q", ("fused", 0, 0))])
     with pytest.raises(chip_smoke.SmokeFailure, match="ticket q"):
         chip_smoke.check_counters(tickets=[("q", ("staged", 1, 1))])
-    REGISTRY.counter("kernels.fallback.no_pallas_backend").inc()
+    REGISTRY.counter("fallback.fusion.tpch_q6").inc()
     with pytest.raises(chip_smoke.SmokeFailure,
-                       match="kernels.fallback.no_pallas_backend"):
+                       match="fallback.fusion.tpch_q6"):
         chip_smoke.check_counters()
 
 

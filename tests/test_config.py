@@ -32,11 +32,12 @@ def test_set_option_coerces_like_env():
     assert config.get_option("telemetry.enabled") is True
 
 
-def test_unknown_option_rejected():
+@pytest.mark.parametrize("name", ["no.such.option", "kernels.tier"])
+def test_unknown_option_rejected(name):
     with pytest.raises(KeyError):
-        config.get_option("no.such.option")
+        config.get_option(name)
     with pytest.raises(KeyError):
-        config.set_option("no.such.option", 1)
+        config.set_option(name, 1)
 
 
 def test_row_limit_option_wired():

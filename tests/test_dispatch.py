@@ -245,6 +245,20 @@ def test_statics_change_recompiles(rng):
     assert REGISTRY.counter("dispatch.hit.pad").value == 3
 
 
+def test_the_environment_is_no_part_of_an_executables_key(rng, monkeypatch):
+    """A key is the call's own arguments, the bucket configuration and the
+    backend: a variable that once chose a kernel per op, set between two
+    calls of the same op and shapes, compiles nothing."""
+    tbl = Table([Column.from_numpy(
+        rng.integers(0, 100, 20).astype(np.int64))])
+    sort_order(tbl, [0], ascending=[True])
+    before = REGISTRY.counter("dispatch.compile").value
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_KERNEL_TIER", "auto")
+    sort_order(tbl, [0], ascending=[True])
+    assert REGISTRY.counter("dispatch.compile").value == before
+    assert REGISTRY.counter("dispatch.hit.sort_order").value == 1
+
+
 def test_disabled_dispatch_never_compiles(rng):
     set_option("dispatch.enabled", False)
     col = _int_col(rng, 20)

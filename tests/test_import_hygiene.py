@@ -10,6 +10,8 @@ chip, which belongs to one process) would initialize first and the pin
 would silently stop working. This test pins that invariant mechanically.
 """
 
+import ast
+import pathlib
 import subprocess
 import sys
 
@@ -34,3 +36,26 @@ def test_package_import_initializes_no_backend():
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "IMPORT_CLEAN" in out.stdout
+
+
+def test_no_operator_imports_a_query_or_the_server():
+    """``ops/`` is what ``models/`` and ``runtime/server.py`` are built
+    from: an operator that imports either has a query's or the served
+    path's name in it. Every import statement counts, a function's own
+    too."""
+    package = pathlib.Path(__file__).parent.parent / "spark_rapids_jni_tpu"
+    upward = ("spark_rapids_jni_tpu.models",
+              "spark_rapids_jni_tpu.runtime.server")
+    found = []
+    for path in sorted((package / "ops").rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                names = [node.module] + [
+                    f"{node.module}.{a.name}" for a in node.names]
+            else:
+                continue
+            found += [f"{path.name}:{node.lineno} {name}" for name in names
+                      if name.startswith(upward)]
+    assert not found

@@ -175,7 +175,7 @@ def test_tpch_q3_distributed_matches_oracle():
     assert np.all(np.diff(revs.astype(np.int64)) <= 0)
 
 
-# ---- bounded-domain / planned / Pallas q1 (VERDICT r3 item 2) --------------
+# ---- bounded-domain / planned q1 (VERDICT r3 item 2) -----------------------
 
 
 def _q1_groups(out):
@@ -260,14 +260,6 @@ def test_q1_planned_checked_replans_on_domain_miss():
     assert _q1_groups(out).keys() == oracle.keys()
 
 
-def test_q1_pallas_kernel_matches_oracle_interpret():
-    from spark_rapids_jni_tpu.ops.pallas_q1 import tpch_q1_pallas
-
-    li = lineitem_table(10000, seed=5)  # non-multiple of block: padding
-    out = tpch_q1_pallas(li, interpret=True)
-    _assert_q1_matches_oracle(out, tpch_q1_numpy(li))
-
-
 def test_bounded_groupby_oracle_and_miss_flag(rng):
     from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate_bounded
 
@@ -305,19 +297,6 @@ def test_bounded_groupby_oracle_and_miss_flag(rng):
     res2 = groupby_aggregate_bounded(
         tbl2, [0], [(1, "sum")], key_domains=[(0, 5, 10)])
     assert bool(res2.domain_miss)
-
-
-def test_q1_pallas_rejects_nullable_inputs():
-    """The fused kernel's planner contract: nullable inputs raise at
-    trace time (zero-filling would silently break null-skipping)."""
-    from spark_rapids_jni_tpu.ops.pallas_q1 import tpch_q1_pallas
-
-    li = lineitem_table(256)
-    cols = list(li.columns)
-    cols[2] = Column(cols[2].dtype, cols[2].data,
-                     jnp.ones(256, dtype=bool))
-    with pytest.raises(NotImplementedError, match="non-nullable"):
-        tpch_q1_pallas(Table(cols), interpret=True)
 
 
 def test_bounded_groupby_float32_sum_dtype():

@@ -475,40 +475,6 @@ def test_rule_worker_exit_join_barrier_clean(tmp_path):
     assert not _by_rule(_lint_file(mod), "worker-exit-must-classify")
 
 
-def test_rule_pallas_oracle_seeded():
-    got = _by_rule(_lint_file(FIXTURES / "seeded_pallas_kernel.py"),
-                   "pallas-kernel-must-have-oracle")
-    # both launch sites fire: the module's only register_kernel has an
-    # EMPTY oracle, which does not count as a declaration
-    assert len(got) == 2, [f.source_line for f in got]
-    assert all("pallas_call" in f.source_line for f in got)
-
-
-def test_rule_pallas_oracle_clean_when_declared():
-    src = (FIXTURES / "seeded_pallas_kernel.py").read_text()
-    fixed = src.replace(
-        'register_kernel("rogue.kernel", oracle="", doc="no oracle '
-        'declared")',
-        'register_kernel("rogue.kernel", oracle="pkg.ops.mod.twin", '
-        'doc="declared")')
-    assert fixed != src
-    assert not _by_rule(lint_source(fixed, "ops/pallas/kern.py"),
-                        "pallas-kernel-must-have-oracle")
-
-
-def test_rule_pallas_oracle_scope(tmp_path):
-    # the same launches outside a pallas home are out of scope; a file
-    # inside an ops/pallas/ package is in scope under any basename
-    src = (FIXTURES / "seeded_pallas_kernel.py").read_text()
-    assert not _by_rule(lint_source(src, tmp_path / "plain_kernels.py"),
-                        "pallas-kernel-must-have-oracle")
-    pk = tmp_path / "ops" / "pallas"
-    pk.mkdir(parents=True)
-    target = pk / "kern.py"
-    target.write_text(src)
-    assert _by_rule(_lint_file(target), "pallas-kernel-must-have-oracle")
-
-
 def test_rule_placement_recorded_seeded():
     got = _by_rule(_lint_file(FIXTURES / "seeded_cluster_placement.py"),
                    "placement-must-record")
@@ -708,8 +674,6 @@ def test_every_rule_has_a_seeded_fixture():
     for f in _lint_file(FIXTURES / "seeded_resultcache_key.py"):
         seen.add(f.rule)
     for f in _lint_file(FIXTURES / "seeded_compress_memory.py"):
-        seen.add(f.rule)
-    for f in _lint_file(FIXTURES / "seeded_pallas_kernel.py"):
         seen.add(f.rule)
     for f in _lint_file(FIXTURES / "seeded_cluster_placement.py"):
         seen.add(f.rule)

@@ -3,7 +3,7 @@ a direct ``@jax.jit`` (one trace + compile per distinct row count) instead
 of routing through the shape-bucketed executable cache in
 ``runtime/dispatch.py`` — the per-shape compile storm ISSUE 3 exists to
 absorb. The pragma'd twin shows the blessed escape hatch for deliberate
-jits (Pallas kernel wrappers with their own shape quantization)."""
+jits (wrappers with their own shape quantization)."""
 
 import jax
 import jax.numpy as jnp

@@ -8,7 +8,7 @@ dtype width, validity-mask derivation — are enforced here mechanically
 over the stdlib ``ast``. No third-party dependencies, files are parsed
 and never imported.
 
-Two tiers of rules share one CLI and one suppression model: twenty-three
+Two tiers of rules share one CLI and one suppression model: twenty-two
 per-file AST rules (``tools/tpulint/rules.py``) and three whole-program
 concurrency rules (``tools/tpulint/concurrency.py`` — lock-order-cycle,
 blocking-call-under-lock, unguarded-shared-write) that run on the

@@ -1,4 +1,4 @@
-"""The twenty-three per-file tpulint rules.
+"""The twenty-two per-file tpulint rules.
 
 Each rule encodes an invariant the stack already relies on implicitly;
 the docstring of each ``check_*`` names the bug class that motivated it
@@ -609,7 +609,7 @@ def check_jit_via_dispatch(ctx: FileContext) -> List[RawFinding]:
     telemetry never sees the op. Scope: ops/*.py and any *_device.py
     (host-side drivers like chip_smoke.py measure whole pipelines and stay out
     of scope; runtime/dispatch.py itself owns the one legitimate jit).
-    A deliberate jit — e.g. a Pallas kernel wrapper whose shapes are
+    A deliberate jit — e.g. a wrapper whose shapes are
     block-quantized already — carries a
     ``# tpulint: disable=jit-via-dispatch`` pragma."""
     if not (_is_device_file(ctx.name) or "/ops/" in ("/" + ctx.path)):
@@ -1416,60 +1416,6 @@ def check_worker_exit_classified(ctx: FileContext) -> List[RawFinding]:
 
 
 # ---------------------------------------------------------------------------
-# rule 19: pallas-kernel-must-have-oracle
-# ---------------------------------------------------------------------------
-
-
-def _is_pallas_scope_file(ctx: FileContext) -> bool:
-    """Kernel-tier homes: any file inside a ``pallas`` package directory
-    or whose basename carries ``pallas``."""
-    return "pallas" in ctx.path.split("/")[:-1] or "pallas" in ctx.name
-
-
-def check_pallas_oracle(ctx: FileContext) -> List[RawFinding]:
-    """PR-15 bug class: a hand-written Pallas kernel with no declared
-    XLA bit-identity oracle. The kernel tier's whole contract is that
-    every kernel stays byte-for-byte checkable against the legacy XLA
-    implementation (``kernels.tier=xla``); a kernel module that launches
-    ``pl.pallas_call`` without a ``register_kernel(..., oracle=...)``
-    declaration naming its oracle (a non-empty string literal — the
-    dotted path of the XLA twin) has silently left the maintained tier:
-    nothing ties it to a reference, no tier decision is recorded for it,
-    and bit-identity tests cannot find its twin. Scope: pallas kernel
-    homes (a ``pallas`` package directory or a pallas-named file)."""
-    if not _is_pallas_scope_file(ctx):
-        return []
-    launches = [
-        node for node in ast.walk(ctx.tree)
-        if isinstance(node, ast.Call)
-        and _unparse(node.func).split(".")[-1] == "pallas_call"
-    ]
-    if not launches:
-        return []
-    for node in ast.walk(ctx.tree):
-        if not isinstance(node, ast.Call):
-            continue
-        if _unparse(node.func).split(".")[-1] != "register_kernel":
-            continue
-        for kw in node.keywords:
-            if (kw.arg == "oracle"
-                    and isinstance(kw.value, ast.Constant)
-                    and isinstance(kw.value.value, str)
-                    and kw.value.value.strip()):
-                return []
-    return [
-        RawFinding(
-            node.lineno, node.col_offset,
-            "pl.pallas_call in a kernel-tier module with no "
-            "register_kernel(..., oracle=\"<dotted path of the XLA "
-            "twin>\") declaration: every maintained Pallas kernel must "
-            "name its bit-identity oracle so the xla tier stays "
-            "reachable and the parity tests can find the twin")
-        for node in launches
-    ]
-
-
-# ---------------------------------------------------------------------------
 # rule 23: placement-must-record
 # ---------------------------------------------------------------------------
 
@@ -1841,11 +1787,6 @@ RULES = [
          "route the shape through resilience.classify_worker_exit / a "
          "classify call, raise, or visibly account for the read",
          check_worker_exit_classified),
-    Rule("pallas-kernel-must-have-oracle",
-         "a module launching pl.pallas_call in a pallas kernel home "
-         "must register_kernel(..., oracle=<non-empty literal>) naming "
-         "its XLA bit-identity twin",
-         check_pallas_oracle),
     Rule("placement-must-record",
          "a placement-named function in a fleet/cluster file that "
          "selects among candidates (min/max/sorted/random.*) must "
