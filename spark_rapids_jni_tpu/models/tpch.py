@@ -181,14 +181,19 @@ def _q1_work_table(lineitem: Table) -> Table:
     )
 
 
-def _q1_plan() -> fusion.Plan:
+def _q1_plan(max_groups=_Q1_GROUP_BUDGET) -> fusion.Plan:
     """q1 as ONE fusible region: filter+derive -> groupby -> sort. The
     filtered-out pseudo-group has null keys; q1's ORDER BY puts real
-    groups first (nulls last) so the compacted head is the answer."""
+    groups first (nulls last) so the compacted head is the answer.
+    ``max_groups`` is the bound the planner states for the groupby (q1's
+    own 64 by default): a bound over ``ops/groupby.py``'s ``_SMALL_M``
+    (1,024), or None for no bound at all, is what a planner that could not
+    bound the cardinality sends, and takes the groupby that sorts its rows
+    and moves every value word into key order (``groupby.in_place`` 0)."""
     return fusion.Plan("tpch_q1", fusion.Sort(
         fusion.GroupBy(
             fusion.Project(fusion.Scan("lineitem"), _q1_work_table),
-            (0, 1), tuple(_Q1_AGGS), max_groups=_Q1_GROUP_BUDGET,
+            (0, 1), tuple(_Q1_AGGS), max_groups=max_groups,
             label="groupby"),
         (0, 1), nulls_first=(False, False)))
 
@@ -1892,6 +1897,10 @@ def tpch_q12_planned_result(orders: Table, lineitem: Table,
 
 P_PARTKEY, P_TYPE, P_BRAND, P_CONTAINER, P_SIZE = 0, 1, 2, 3, 4
 
+# Six of clause 4.2.3's 150 three-syllable types: a toy for the eager q14 /
+# q19 functions and the tests. The served q14 is ``_q14_plan`` over the
+# benchmark's makers (``benchmark/tables/part_q14.py``: all 6 x 5 x 5 types
+# in the padded layout, 2,000,000 rows in a seeded permutation).
 _P_TYPES = ("PROMO BURNISHED COPPER", "PROMO PLATED BRASS",
             "STANDARD POLISHED TIN", "MEDIUM BRUSHED NICKEL",
             "ECONOMY ANODIZED STEEL", "SMALL PLATED COPPER")
@@ -1945,6 +1954,13 @@ _Q14_MONTH_START = 9374  # 1995-09-01
 _Q14_MONTH_END = 9404    # 1995-10-01
 
 
+def _q14_month_where(lineitem: Table, month_start: int,
+                     month_end: int) -> jnp.ndarray:
+    ship = lineitem.column(L14_SHIPDATE)
+    return (ship.valid_mask() & (ship.data >= jnp.int32(month_start))
+            & (ship.data < jnp.int32(month_end)))
+
+
 class Q14Result(NamedTuple):
     promo_revenue: jnp.ndarray   # int64 unscaled decimal(-4)
     total_revenue: jnp.ndarray   # int64 unscaled decimal(-4)
@@ -1963,15 +1979,16 @@ def tpch_q14(part: Table, lineitem: Table,
     """q14: shipdate-month lineitem joined to part; promo share of
     revenue. The CASE WHEN p_type LIKE 'PROMO%' lane runs the device
     LIKE engine on the join-gathered strings; revenue stays exact
-    int64 decimal(-4) to the end (the q6 posture)."""
+    int64 decimal(-4) to the end (the q6 posture).
+
+    An eager function a caller picks, op by op. The served q14 is
+    ``_q14_plan``: the same query as one ``fusion.Plan`` through
+    ``Session.submit``, its join a ``fusion.Join(how="inner")`` with a
+    stated capacity (cell ``q14_broadcast_join_fresh``)."""
     from spark_rapids_jni_tpu.ops import strings as s
     from spark_rapids_jni_tpu.ops.join import apply_join_maps, join
 
-    ship_c = lineitem.column(L14_SHIPDATE)
-    ship = ship_c.data
-    keep = (ship_c.valid_mask()
-            & (ship >= jnp.int32(month_start))
-            & (ship < jnp.int32(month_end)))
+    keep = _q14_month_where(lineitem, month_start, month_end)
     price = lineitem.column(L14_EXTENDEDPRICE)
     disc = lineitem.column(L14_DISCOUNT)
     revenue = price.data * (100 - disc.data)   # decimal(-4), exact
@@ -2019,11 +2036,7 @@ def tpch_q14_planned(part: Table, lineitem: Table,
     from spark_rapids_jni_tpu.ops import strings as s
     from spark_rapids_jni_tpu.ops.planner import dense_pk_join
 
-    ship_c = lineitem.column(L14_SHIPDATE)
-    ship = ship_c.data
-    keep = (ship_c.valid_mask()
-            & (ship >= jnp.int32(month_start))
-            & (ship < jnp.int32(month_end)))
+    keep = _q14_month_where(lineitem, month_start, month_end)
     price = lineitem.column(L14_EXTENDEDPRICE)
     disc = lineitem.column(L14_DISCOUNT)
     revenue = price.data * (100 - disc.data)   # decimal(-4), exact
@@ -2069,6 +2082,81 @@ def tpch_q14_numpy(part: Table, lineitem: Table,
         if tp.startswith("PROMO"):
             promo += rev
     return promo, total
+
+
+def _q14_part_fn(part: Table) -> Table:
+    """What q14 reads of ``part``: the key and ``p_type``."""
+    return Table([part.column(P_PARTKEY), part.column(P_TYPE)])
+
+
+# the joined table: the four lineitem columns, then p_partkey and p_type
+_J14_TYPE = L14_SHIPDATE + 1 + P_TYPE
+
+
+def _q14_lanes_fn(j: Table) -> Table:
+    """Above the join, as the query text has it: ``l_extendedprice * (1 -
+    l_discount)`` (decimal(-4), exact) over every joined row, and the same
+    under ``CASE WHEN p_type LIKE 'PROMO%'`` over the ``p_type`` the join
+    brought from ``part``. A row the join did not lay out reads NULL."""
+    from spark_rapids_jni_tpu.ops import strings as s
+
+    price = j.column(L14_EXTENDEDPRICE)
+    disc = j.column(L14_DISCOUNT)
+    ptype = j.column(_J14_TYPE)
+    ok = price.valid_mask() & disc.valid_mask()
+    revenue = price.data * (100 - disc.data)
+    promo = ptype.valid_mask() & (s.like(ptype, "PROMO%").data != 0)
+    return Table([
+        Column(t.decimal64(-4), jnp.where(promo, revenue, jnp.int64(0)), ok),
+        Column(t.decimal64(-4), revenue, ok),
+    ])
+
+
+def _q14_sum_fn(lanes: Table, row_valid) -> Table:
+    """The two sums, one row: NULL iff no row was joined (the q6
+    posture). The join's output is a row space of its own whose padding
+    reads NULL, so ``row_valid`` needs no fold."""
+    ok = lanes.column(1).valid_mask()
+    any_row = jnp.any(ok).reshape(1)
+    return Table([
+        Column(t.decimal64(-4),
+               jnp.sum(jnp.where(ok, c.data, jnp.int64(0))).reshape(1),
+               any_row)
+        for c in lanes.columns])
+
+
+def _q14_plan(month_start: int = _Q14_MONTH_START,
+              month_end: int = _Q14_MONTH_END,
+              out_rows=None) -> fusion.Plan:
+    """TPC-H q14, whole, as one fused region. The plan declares nothing
+    about ``l_partkey`` or ``p_partkey`` but their type: the join is the
+    general one, ``fusion.Join(how="inner")`` (a lineitem counts once for
+    every ``part`` row that holds its key; a NULL key on either side, a
+    row the ``WHERE`` dropped and a bucket's padding match nothing), and
+    ``tpch_q14_planned``'s dense clustered look-up is what it is not.
+
+    * ``month``: the ``WHERE`` on ``l_shipdate`` as a Filter.
+    * ``part_join``: lineitem INNER JOIN part on the part key, laid out
+      in ``out_rows`` rows: a capacity the planner states (an int, or a
+      ``fusion.rows_of`` spec; by default the lineitem's rows, which a
+      unique ``p_partkey`` cannot pass). An inner join with nothing
+      declared has no static bound on its output: the node reports
+      ``part_join.capacity`` and ``part_join.overflowed``, and the served
+      path refuses a result that outgrew it (``CapacityOverflow``).
+    * ``p_type`` travels through the join and the ``CASE ... LIKE
+      'PROMO%'`` is evaluated above it, over the gathered strings.
+    * the two sums: one row, ``promo_revenue`` and ``total_revenue`` as
+      exact unscaled int64 of scale -4; the ratio is the caller's."""
+    if out_rows is None:
+        out_rows = fusion.rows_of("lineitem")
+    joined = fusion.Join(
+        fusion.Filter(fusion.Scan("lineitem"), _q14_month_where,
+                      (int(month_start), int(month_end)), label="month"),
+        fusion.Project(fusion.Scan("part"), _q14_part_fn),
+        (L14_PARTKEY,), (P_PARTKEY,), out_rows, how="inner",
+        label="part_join")
+    return fusion.Plan("tpch_q14", fusion.Project(
+        fusion.Project(joined, _q14_lanes_fn), _q14_sum_fn, rowwise=False))
 
 
 # ---------------------------------------------------------------------------

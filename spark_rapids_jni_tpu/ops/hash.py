@@ -176,13 +176,3 @@ def partition_hash(table: Table, columns: Sequence[int], num_partitions: int) ->
     which IS pmod."""
     h = table_xxhash64(table, columns)
     return (h % jnp.int64(num_partitions)).astype(jnp.int32)
-
-
-def probe_sorted_lo_hi(
-    sorted_key: jnp.ndarray, probe_key: jnp.ndarray
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Per probe key, the [lo, hi) match-run bounds in the
-    sentinel-padded sorted build keys."""
-    lo = jnp.searchsorted(sorted_key, probe_key, side="left")
-    hi = jnp.searchsorted(sorted_key, probe_key, side="right")
-    return lo, hi

@@ -253,13 +253,25 @@ class Join(NamedTuple):
       keeps a row with a NULL key, and cannot tell one from a row a
       ``Filter`` below it dropped: both read NULL in every column above it.
 
+    ``out_rows`` is a capacity and a guarantee: a join with nothing
+    declared has no static bound on its output, so one that lays rows out
+    reports ``<label>.capacity`` (the resolved ``out_rows``) and
+    ``<label>.overflowed`` (``total`` passed it: rows were dropped), and
+    the served path refuses such a result (``QueryServer._account_meta``:
+    ``CapacityOverflow`` with the true total).
+
     Lowers under its label's scope with the sub-scopes ``build`` and
-    ``probe`` (``ops/join.py`` says which stage lies under which).
+    ``probe`` (``ops/join.py`` says which stage lies under which) and,
+    where it lays rows out, ``gather_rows`` (``apply_join_maps``: the
+    columns of both sides fetched by the maps; not ``gather``, the
+    primitive's own name, which ends the op name of every gather under
+    ``probe``: a reader by scope would count those too).
     Meta: ``<label>.total`` (output rows; of a semi or anti join the left
     rows kept), ``<label>.build_rows`` (real right rows with a non-null
     key: what entered the join of the build side), of a semi or anti join
     ``<label>.key_narrowed`` (its merged sort took the narrow form: a fact
-    of the data), and where the left side holds a scan's rows
+    of the data), of the others ``<label>.capacity`` and
+    ``<label>.overflowed``, and where the left side holds a scan's rows
     ``<label>.probe_rows`` (a static: that scan's)."""
 
     left: Any
@@ -643,9 +655,9 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
                     keys += [f"{node.label}.key_narrowed",
                              f"{node.label}.key_out_of_range"]
         elif isinstance(node, Join):
-            keys += [f"{node.label}.total", f"{node.label}.build_rows"]
-            if node.how in _MASK_JOINS:
-                keys += [f"{node.label}.key_narrowed"]
+            keys += [f"{node.label}.total", f"{node.label}.build_rows",
+                     f"{node.label}.key_narrowed" if node.how in _MASK_JOINS
+                     else f"{node.label}.overflowed"]
         elif isinstance(node, DensePkJoin):
             keys += [f"{node.label}.total", f"{node.label}.pk_violation"]
         elif isinstance(node, BloomProbe):
@@ -815,15 +827,18 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                          ("key_narrowed", semi.key_narrowed)]
                 out = (_null_all(ltbl, semi.keep), lrv)
             else:
+                capacity = resolved[id(node)]
                 maps = join(
                     ltbl, rtbl, list(node.left_on), list(node.right_on),
-                    out_size=resolved[id(node)], how=node.how,
+                    out_size=capacity, how=node.how,
                     left_row_valid=lrv, right_row_valid=rrv)
                 with jax.named_scope("probe"):
                     build_rows = jnp.sum(
                         key_valid(rtbl, node.right_on, rrv), dtype=jnp.int64)
+                with jax.named_scope("gather_rows"):
                     out = (apply_join_maps(ltbl, rtbl, maps), None)
-                facts = [("total", maps.total), ("build_rows", build_rows)]
+                facts = [("total", maps.total), ("build_rows", build_rows),
+                         ("overflowed", maps.total > capacity)]
             side.extend((f"{node.label}.{fact}", value)
                         for fact, value in facts)
         elif isinstance(node, DensePkJoin):
@@ -1448,13 +1463,16 @@ def execute(plan: Plan, bindings: dict, *,
         if isinstance(n, GroupBy) and n.domains is not None
     }
     # a join whose probe side holds a scan's rows says how many probed it:
-    # the denominator of its ``<label>.total``
+    # the denominator of its ``<label>.total``; one that lays rows out
+    # says how many it had room for
     for n in nodes:
         if isinstance(n, (Join, DensePkJoin)):
             rows = _scanned_rows(
                 n.left if isinstance(n, Join) else n.probe, true_rows)
             if rows is not None:
                 static_meta[f"{n.label}.probe_rows"] = rows
+        if isinstance(n, Join) and n.how not in _MASK_JOINS:
+            static_meta[f"{n.label}.capacity"] = resolved[id(n)]
     # rows sharded over a mesh axis are the signal, and the only one, that
     # the region runs across chips (see "lowering over a mesh" above)
     over = _bindings_mesh(bindings, bucketed)
@@ -1602,12 +1620,16 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     that probed and rows that matched
     (joins that say both), semi and anti joins whose merged sort carried a
     64-bit key as one word (``join.key_narrowed``: a fact of the data,
-    ``ops/join.py``), groups, what a groupby lowered over a mesh
+    ``ops/join.py``), the rows the joins that lay rows out had room for
+    (``join.capacity_rows``), how many of them outgrew it
+    (``join.overflowed``) and the true totals of those
+    (``join.overflow_rows``), groups, what a groupby lowered over a mesh
     shuffled (exchanges, the partial rows it sent and the bytes its
     ``all_to_all`` put between chips), and how many nodes broke what the plan
     declares: a dense primary key that is not one (``pk_violation``), a
-    group bound that was too small (``overflowed``), a key outside its
-    declared range (``key_out_of_range``); and how many groupbys took
+    group bound or a join's capacity that was too small (``overflowed``),
+    a key outside its declared range (``key_out_of_range``); and how many
+    groupbys took
     their aggregates over the rows where they lie, no value word brought
     into key order (``groupby.in_place``: a fact of the lowering,
     ``ops/groupby.py``), how many of those sorted their key words to count
@@ -1621,6 +1643,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     device, so call it where the meta is wanted on the host anyway."""
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
              "join.build_rows": 0, "join.key_narrowed": 0,
+             "join.capacity_rows": 0, "join.overflowed": 0,
+             "join.overflow_rows": 0,
              "join.pk_violation": 0, "groupby.groups": 0,
              "groupby.overflowed": 0, "groupby.in_place": 0,
              "groupby.key_sorted": 0,
@@ -1644,6 +1668,11 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 meta.get(f"{node.label}.build_rows", 0))
             facts["join.key_narrowed"] += bool(
                 meta.get(f"{node.label}.key_narrowed", False))
+            facts["join.capacity_rows"] += int(
+                meta.get(f"{node.label}.capacity", 0))
+            if bool(meta.get(f"{node.label}.overflowed", False)):
+                facts["join.overflowed"] += 1
+                facts["join.overflow_rows"] += int(total)
             facts["join.pk_violation"] += bool(
                 meta.get(f"{node.label}.pk_violation", False))
         elif isinstance(node, GroupBy):

@@ -1073,7 +1073,8 @@ class QueryServer:
         ``fusion.meta_facts``: counters ``filter.rows_in``,
         ``filter.rows_kept``, ``strings.like_bytes``,
         ``join.probe_rows``, ``join.matched_rows``, ``join.build_rows``,
-        ``join.key_narrowed``,
+        ``join.key_narrowed``, ``join.capacity_rows``, ``join.overflowed``,
+        ``join.overflow_rows``,
         ``groupby.groups``, ``groupby.in_place``, ``groupby.key_sorted``,
         ``groupby.key_narrowed``,
         ``join.pk_violation``, ``groupby.overflowed``,
@@ -1086,6 +1087,11 @@ class QueryServer:
         facts = fusion.meta_facts(plan, meta)
         for name, value in facts.items():
             REGISTRY.counter(name).inc(value)
+        if facts["join.overflowed"]:
+            raise resilience.CapacityOverflow(
+                f"plan {plan.name!r}: a join found more rows than its "
+                f"out_rows has room for; the result is not the query's "
+                f"answer", rows=facts["join.overflow_rows"])
         if facts["groupby.overflowed"]:
             raise resilience.CapacityOverflow(
                 f"plan {plan.name!r}: a groupby found more groups than its "
