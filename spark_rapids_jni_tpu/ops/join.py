@@ -44,15 +44,31 @@ between them and low words less than 2**31 apart (dbgen's keys, any 64-bit
 surrogate under 2**31) a 64-bit key sorts as one word, decided inside the
 region from the data.
 
+The joins that keep maps run at the probe rows that can emit, not at the
+padded batch, where that is far fewer (``_join_maps_impl``): an
+``out_size`` ``_COMPACT_FACTOR`` times or more under the probe side's rows
+(a static gate: every other join lowers with no conditional) and, counted
+inside the region, no more probe rows that can emit than ``out_size`` has
+slots (a row that is real and holds a key; under ``left`` / ``full`` /
+``left_anti`` a real row). Their positions are compacted into ``out_size``
+slots (``ops/sort.py positions_of``: positions only, no column moves), the
+key is fetched there, everything above runs on ``out_size + n_right`` rows
+and ``left_index`` is read back through the positions, which ascend, so
+the promised order needs no sort more. ``JoinMaps.probe_compacted`` says
+that this ran: a fact of the data, as ``key_narrowed`` is. A probe side
+with more such rows than slots (keys that mostly miss) takes the other
+branch, the whole join over all rows.
+
 Scopes (``jax.named_scope``, under the plan node's own inside a region; a
 device trace splits the join's time by them): ``build`` is everything that
 orders or indexes the build side (``_build_order``, and the merged sort,
 which orders the probe's keys with it), ``probe`` everything else that
-makes the maps or the mask: the runs' heads, the running passes, the
-offsets, the emitting rows' sort, the sort into output order; the running
-maximum, the sort back and the mask. ``apply_join_maps``' gathers lie
-under ``gather_rows`` where a region calls it (``fusion.Join``; not
-``gather``, which is also the primitive's name and ends the op name of
+makes the maps or the mask: the count and the positions of the probe rows
+that can emit and the key's fetch there, the runs' heads, the running
+passes, the offsets, the emitting rows' sort, the sort into output order;
+the running maximum, the sort back and the mask. ``apply_join_maps``'
+gathers lie under ``gather_rows`` where a region calls it (``fusion.Join``;
+not ``gather``, which is also the primitive's name and ends the op name of
 every gather under ``probe``).
 """
 
@@ -66,7 +82,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_jni_tpu.columnar import Column, Table
-from spark_rapids_jni_tpu.ops.sort import _split64, gather, sort_order
+from spark_rapids_jni_tpu.ops.sort import (
+    _split64, gather, positions_of, sort_order)
 from spark_rapids_jni_tpu.utils.tracing import func_range
 
 
@@ -80,6 +97,8 @@ class JoinMaps(NamedTuple):
     total: jnp.ndarray        # scalar int64: true number of output rows
     # bool: False on right/full-join rows with no left match (null left)
     left_valid: jnp.ndarray
+    # scalar bool: the join ran on the probe rows that can emit alone
+    probe_compacted: jnp.ndarray
 
 
 def _key_words(key: jnp.ndarray) -> list:
@@ -219,6 +238,24 @@ def _build_order(key: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
     return perm
 
 
+# A join whose ``out_size`` lies this many times or more under its probe
+# side's rows also compiles the form that runs on the probe rows that can
+# emit alone (``_join_maps_impl``); every other join lowers with no
+# conditional. On a v5e (PERF.md section 6, PR 46: an inner join of
+# 67,108,864 probe rows and 2,097,152 build rows alone in a jit, 0.9 of
+# ``out_size`` emitting; all rows / the emitting rows, seconds) the
+# emitting rows' form loses while the capacity's rows are many, because
+# what it adds are gathers at ``out_size`` positions (20 ns a position,
+# whatever they read from) and what it spares are two n-row sorts: 2.221 /
+# 3.197 at a factor of 4, 1.303 / 1.501 at 8, 0.893 / 0.717 at 16, 0.692 /
+# 0.359 at 32. Where the count passes ``out_size`` the conditional costs
+# 0.0025 s (0.7097 / 0.7122 at 32).
+_COMPACT_FACTOR = 16
+# the join types whose probe row emits only where it holds a key; under
+# the others a real row without one emits a row too
+_EMITS_BY_KEY = ("inner", "right", "left_semi")
+
+
 def _join_maps_impl(
     left_key: jnp.ndarray,
     left_valid: jnp.ndarray,
@@ -244,7 +281,58 @@ def _join_maps_impl(
     if n == 0:
         none = jnp.zeros((out_size,), jnp.bool_)
         zero = jnp.zeros((out_size,), jnp.int32)
-        return JoinMaps(zero, zero, none, none, jnp.int64(0), none)
+        return JoinMaps(zero, zero, none, none, jnp.int64(0), none,
+                        jnp.zeros((), jnp.bool_))
+    sides = (left_key, left_valid, left_row_valid,
+             right_key, right_valid, right_row_valid)
+    can_emit = left_valid if how in _EMITS_BY_KEY else left_row_valid
+    if can_emit is None or not 0 < out_size * _COMPACT_FACTOR <= n_left:
+        return _maps_of_rows(*sides, out_size=out_size, how=how)
+    # The capacity lies far under the probe's rows (a WHERE below the join
+    # dropped most of them, and static shapes carried them here): where no
+    # more rows can emit than the capacity has slots, the join runs on
+    # those rows alone; a probe side with more of them (keys that mostly
+    # miss) takes today's path whole.
+    with jax.named_scope("probe"):
+        fits = jnp.sum(can_emit, dtype=jnp.int32) <= out_size
+    return jax.lax.cond(
+        fits, partial(_maps_of_emitting_rows, out_size=out_size, how=how),
+        partial(_maps_of_rows, out_size=out_size, how=how), *sides)
+
+
+def _maps_of_emitting_rows(left_key, left_valid, left_row_valid,
+                           right_key, right_valid, right_row_valid, *,
+                           out_size: int, how: str) -> JoinMaps:
+    """The join over the probe rows that can emit, ``out_size`` of them at
+    the most, and nothing else of the probe side: their positions in
+    ``out_size`` slots, ascending (so the promised order holds with no
+    sort more), the key and its bits fetched there, ``_maps_of_rows`` over
+    ``out_size + n_right`` rows, and its ``left_index`` read through the
+    positions."""
+    n_left = left_key.shape[0]
+    with jax.named_scope("probe"):
+        by_key = how in _EMITS_BY_KEY
+        pos, count = positions_of(
+            left_valid if by_key else left_row_valid, out_size)
+        pos = jnp.minimum(pos, n_left - 1)
+        held = jax.lax.iota(jnp.int32, out_size) < count
+        keyed = held if by_key else left_valid[pos] & held
+        key = left_key[pos]
+    maps = _maps_of_rows(key, keyed, held,
+                         right_key, right_valid, right_row_valid,
+                         out_size=out_size, how=how)
+    with jax.named_scope("probe"):
+        return maps._replace(left_index=pos[maps.left_index],
+                             probe_compacted=jnp.ones((), jnp.bool_))
+
+
+def _maps_of_rows(left_key, left_valid, left_row_valid,
+                  right_key, right_valid, right_row_valid, *,
+                  out_size: int, how: str) -> JoinMaps:
+    """The maps over the rows as they come (row existence already folded
+    into ``left_valid`` / ``right_valid``)."""
+    n_left, n_right = left_key.shape[0], right_key.shape[0]
+    n = n_left + n_right
     with jax.named_scope("build"):
         perm = _build_order(right_key, right_valid)
         # a phantom probe row emits nothing; a real one without a key
@@ -342,6 +430,7 @@ def _probe_maps(hi_changes, minor, place, perm, n_left: int, n_right: int,
         row_valid=row_valid,
         total=probe_total,
         left_valid=row_valid,
+        probe_compacted=jnp.zeros((), jnp.bool_),
     )
 
 
@@ -377,6 +466,7 @@ def _with_unmatched_build_rows(maps: JoinMaps, left_key, left_valid,
             row_valid=row_valid,
             total=total,
             left_valid=row_valid & ~is_extra,
+            probe_compacted=maps.probe_compacted,
         )
 
 

@@ -374,6 +374,76 @@ def gather(table: Table, indices: jnp.ndarray) -> Table:
     return Table(cols)
 
 
+def _kth_set_bit(word: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
+    """int32: the place of the ``k``-th (from 0) set bit of a uint32
+    ``word`` that holds more than ``k``: five halvings, each a
+    ``population_count`` of the lower half of what is left."""
+    at = jnp.zeros_like(k)
+    for width in (16, 8, 4, 2, 1):
+        below = jax.lax.population_count(
+            (word >> at.astype(jnp.uint32)) & jnp.uint32((1 << width) - 1)
+        ).astype(jnp.int32)
+        above = k >= below
+        k = jnp.where(above, k - below, k)
+        at = jnp.where(above, at + width, at)
+    return at
+
+
+# ``positions_of`` packs its mask into words of one lane of a (32, 128)
+# tile: 32 rows 128 apart, so that a word is a reduction over sublanes of
+# the mask where it lies (no relayout, no padded minor axis).
+_TILE = 32 * 128
+
+
+def positions_of(mask: jnp.ndarray, k: int) -> tuple:
+    """``(int32[k], int32 scalar)``: the positions where ``mask`` (bool[n])
+    is set, ascending, in ``k`` slots with ``n`` in the slots past them,
+    and how many are set. The count is true whatever ``k``; where it
+    passes ``k`` the slots hold the first ``k`` positions.
+
+    A compaction of positions only, with no pass over the n rows but the
+    one that reads them: the mask is packed into 32-bit words (a row a
+    bit), a prefix sum over the words' bit counts gives every word its
+    first output slot, each word that holds a bit writes its number there
+    (distinct slots: the scatter need not be ordered) and a running
+    maximum hands it to the word's other slots; a slot's position is then
+    the ``slot - first slot``-th set bit of its word (``_kth_set_bit``).
+    A word's rows lie 128 apart inside a tile of 4,096 (``_TILE``), so the
+    slots come out in the rows' order tile by tile only: one sort of the
+    slots puts them right, and 4,096 slots more than ``k`` hold the whole
+    of the tile in which the ``k``-th set row lies, so that the first
+    ``k`` are exact. What an n-row sort or an n-row scatter of the
+    positions would do (PERF.md section 6, PR 46, has the forms timed
+    alone in a jit)."""
+    n = mask.shape[0]
+    if n == 0 or k == 0:
+        return jnp.full((k,), n, jnp.int32), jnp.zeros((), jnp.int32)
+    tiles = -(-n // _TILE)
+    if n + _TILE >= 1 << 31:
+        raise ValueError(f"positions of {n} rows: a position takes 31 bits")
+    if tiles * _TILE != n:
+        mask = jnp.pad(mask, (0, tiles * _TILE - n))
+    word = jnp.sum(mask.reshape(tiles, 32, 128).astype(jnp.uint32)
+                   << jax.lax.iota(jnp.uint32, 32)[None, :, None],
+                   axis=1, dtype=jnp.uint32).reshape(tiles * 128)
+    held = jax.lax.population_count(word).astype(jnp.int32)
+    ends = jnp.cumsum(held)
+    first, count = ends - held, ends[-1]
+    number = jax.lax.iota(jnp.int32, tiles * 128)
+    slots = k + _TILE
+    # (a word with no bit, or one whose slots lie past the last, writes
+    # nowhere: distinct places out of range, as the scatter was promised)
+    word_of = jax.lax.cummax(jnp.zeros((slots,), jnp.int32).at[jnp.where(
+        (held > 0) & (first < slots), first,
+        jnp.int32(2**31 - 1) - number)].set(
+            number, mode="drop", unique_indices=True))
+    slot = jax.lax.iota(jnp.int32, slots)
+    bit = _kth_set_bit(word[word_of], slot - first[word_of])
+    row = (word_of >> 7) * _TILE + bit * 128 + (word_of & 127)
+    return jax.lax.sort(jnp.where(slot < count, row, n),
+                        is_stable=False)[:k], count
+
+
 # ``_move_words`` brings uint32 words into a permutation's order by one
 # gather of k-word rows, or, from this many words in all (rows times words
 # a row, 64 MiB of them) by sort passes. On a v5e (PERF.md section 6, PR

@@ -270,8 +270,11 @@ class Join(NamedTuple):
     rows kept), ``<label>.build_rows`` (real right rows with a non-null
     key: what entered the join of the build side), of a semi or anti join
     ``<label>.key_narrowed`` (its merged sort took the narrow form: a fact
-    of the data), of the others ``<label>.capacity`` and
-    ``<label>.overflowed``, and where the left side holds a scan's rows
+    of the data), of the others ``<label>.capacity``,
+    ``<label>.overflowed`` and ``<label>.probe_compacted`` (the join ran on
+    the probe rows that can emit alone: a capacity far under the probe's
+    rows and no more such rows than it has slots, ``ops/join.py``; a fact
+    of the data too), and where the left side holds a scan's rows
     ``<label>.probe_rows`` (a static: that scan's)."""
 
     left: Any
@@ -655,9 +658,12 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
                     keys += [f"{node.label}.key_narrowed",
                              f"{node.label}.key_out_of_range"]
         elif isinstance(node, Join):
-            keys += [f"{node.label}.total", f"{node.label}.build_rows",
-                     f"{node.label}.key_narrowed" if node.how in _MASK_JOINS
-                     else f"{node.label}.overflowed"]
+            keys += [f"{node.label}.total", f"{node.label}.build_rows"]
+            if node.how in _MASK_JOINS:
+                keys += [f"{node.label}.key_narrowed"]
+            else:
+                keys += [f"{node.label}.overflowed",
+                         f"{node.label}.probe_compacted"]
         elif isinstance(node, DensePkJoin):
             keys += [f"{node.label}.total", f"{node.label}.pk_violation"]
         elif isinstance(node, BloomProbe):
@@ -838,7 +844,8 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                 with jax.named_scope("gather_rows"):
                     out = (apply_join_maps(ltbl, rtbl, maps), None)
                 facts = [("total", maps.total), ("build_rows", build_rows),
-                         ("overflowed", maps.total > capacity)]
+                         ("overflowed", maps.total > capacity),
+                         ("probe_compacted", maps.probe_compacted)]
             side.extend((f"{node.label}.{fact}", value)
                         for fact, value in facts)
         elif isinstance(node, DensePkJoin):
@@ -1621,7 +1628,9 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     (joins that say both), semi and anti joins whose merged sort carried a
     64-bit key as one word (``join.key_narrowed``: a fact of the data,
     ``ops/join.py``), the rows the joins that lay rows out had room for
-    (``join.capacity_rows``), how many of them outgrew it
+    (``join.capacity_rows``), how many of them ran on the probe rows that
+    can emit alone (``join.probe_compacted``: a fact of the data as
+    well), how many outgrew their room
     (``join.overflowed``) and the true totals of those
     (``join.overflow_rows``), groups, what a groupby lowered over a mesh
     shuffled (exchanges, the partial rows it sent and the bytes its
@@ -1643,6 +1652,7 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     device, so call it where the meta is wanted on the host anyway."""
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
              "join.build_rows": 0, "join.key_narrowed": 0,
+             "join.probe_compacted": 0,
              "join.capacity_rows": 0, "join.overflowed": 0,
              "join.overflow_rows": 0,
              "join.pk_violation": 0, "groupby.groups": 0,
@@ -1666,8 +1676,9 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["join.matched_rows"] += int(total)
             facts["join.build_rows"] += int(
                 meta.get(f"{node.label}.build_rows", 0))
-            facts["join.key_narrowed"] += bool(
-                meta.get(f"{node.label}.key_narrowed", False))
+            for fact in ("key_narrowed", "probe_compacted"):
+                facts[f"join.{fact}"] += bool(
+                    meta.get(f"{node.label}.{fact}", False))
             facts["join.capacity_rows"] += int(
                 meta.get(f"{node.label}.capacity", 0))
             if bool(meta.get(f"{node.label}.overflowed", False)):

@@ -1073,7 +1073,8 @@ class QueryServer:
         ``fusion.meta_facts``: counters ``filter.rows_in``,
         ``filter.rows_kept``, ``strings.like_bytes``,
         ``join.probe_rows``, ``join.matched_rows``, ``join.build_rows``,
-        ``join.key_narrowed``, ``join.capacity_rows``, ``join.overflowed``,
+        ``join.key_narrowed``, ``join.probe_compacted``,
+        ``join.capacity_rows``, ``join.overflowed``,
         ``join.overflow_rows``,
         ``groupby.groups``, ``groupby.in_place``, ``groupby.key_sorted``,
         ``groupby.key_narrowed``,

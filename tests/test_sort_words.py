@@ -105,6 +105,48 @@ def test_pack_words_is_the_keys_bit_string():
             (whole >> s) & 0xFFFFFFFF for s in (0, 32, 64)]
 
 
+def _mask_of(n: int, rows) -> np.ndarray:
+    mask = np.zeros(n, bool)
+    mask[np.asarray(rows, dtype=np.int64)] = True
+    return mask
+
+
+_RNG = np.random.default_rng(46)
+# case -> (mask, slots)
+POSITIONS = {
+    "an_empty_mask": (np.zeros(300, bool), 40),
+    "a_full_mask": (np.ones(128, bool), 128),
+    "as_many_as_slots": (_mask_of(1000, _RNG.choice(1000, 50, False)), 50),
+    "one_more_than_slots": (_mask_of(1000, _RNG.choice(1000, 51, False)),
+                            50),
+    "a_run_of_32_rows": (_mask_of(400, range(64, 96)), 40),
+    # (a word is one lane of a tile of 4,096 rows: 32 rows 128 apart)
+    "every_kept_row_in_one_word": (_mask_of(4500, range(5, 4096, 128)), 40),
+    "rows_no_multiple_of_the_word": (_RNG.random(1237) < 0.1, 200),
+    "the_last_row_alone": (_mask_of(1237, [1236]), 3),
+    "more_slots_than_rows": (_RNG.random(50) < 0.5, 80),
+    "dense_and_far_over_the_slots": (_RNG.random(4096) < 0.7, 64),
+    "the_last_slot_in_the_middle_of_a_tile": (_RNG.random(20000) < 0.5,
+                                              5000),
+    "sparse_over_many_tiles": (_RNG.random(70001) < 0.01, 1024),
+}
+
+
+@pytest.mark.parametrize("case", list(POSITIONS))
+def test_positions_of_against_flatnonzero(case):
+    """``positions_of``: the set rows' positions, ascending, padded with
+    n; the count true past the slots, the first ``k`` positions right."""
+    mask, k = POSITIONS[case]
+    want = np.flatnonzero(mask)
+    pos, count = jax.jit(so.positions_of, static_argnums=1)(
+        jnp.asarray(mask), k)
+    assert pos.dtype == jnp.int32 and pos.shape == (k,)
+    assert int(count) == len(want)
+    padded = np.full(k, len(mask))
+    padded[:min(k, len(want))] = want[:k]
+    assert np.asarray(pos).tolist() == padded.tolist()
+
+
 @pytest.mark.parametrize("max_groups", [None, 64])
 def test_groupby_three_keys_int64_int32_int32_matches_a_dict(max_groups):
     rng = np.random.default_rng(7)

@@ -9,7 +9,9 @@ against a numpy oracle of the pairs for every ``how`` and key width."""
 
 import os
 import sys
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -17,6 +19,7 @@ import pytest
 from spark_rapids_jni_tpu import types as t
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.models import tpch
+from spark_rapids_jni_tpu.ops import join as join_ops
 from spark_rapids_jni_tpu.ops.join import join
 from spark_rapids_jni_tpu.runtime import fusion, resilience
 from spark_rapids_jni_tpu.telemetry import REGISTRY
@@ -164,12 +167,16 @@ def test_served_q14_equals_both_references(server, case):
             if k.startswith("part_join.")}
     assert set(meta) == {"part_join.total", "part_join.build_rows",
                          "part_join.probe_rows", "part_join.capacity",
-                         "part_join.overflowed"}
+                         "part_join.overflowed",
+                         "part_join.probe_compacted"}
     assert meta["part_join.build_rows"] == int(
         np.sum(p.get("p_partkey_valid", np.ones(PARTS, bool))))
     assert meta["part_join.probe_rows"] == ITEMS
     assert meta["part_join.capacity"] == ITEMS     # the plan's default
     assert meta["part_join.overflowed"] == 0
+    # (a capacity at the probe's rows: the join runs on all of them)
+    assert meta["part_join.probe_compacted"] == 0
+    assert moved.get("join.probe_compacted", 0) == 0
     assert moved["join.capacity_rows"] == ITEMS
     assert moved.get("join.overflowed", 0) == 0
     assert moved.get("join.matched_rows", 0) == meta["part_join.total"]
@@ -267,10 +274,19 @@ def test_a_capacity_one_row_too_small_fails_the_request(server, how):
     total = int(fusion.execute(make(1 << 16), bindings).meta[
         f"{label}.total"])
     assert total > 100
+    before = REGISTRY.counters()
     ok = _serve(server, make(total), bindings)
     assert int(ok.meta[f"{label}.total"]) == total
     assert int(ok.meta[f"{label}.capacity"]) == total
     assert not bool(ok.meta[f"{label}.overflowed"])
+    # q14's capacity lies far under the batch's 16,384 padded rows and
+    # every keyed row of the month finds its part, so the join ran on
+    # those rows alone; the left join's capacity is over its probe's rows
+    compacted = how == "inner"
+    assert total * join_ops._COMPACT_FACTOR <= 16384 or not compacted
+    assert bool(ok.meta[f"{label}.probe_compacted"]) == compacted
+    assert REGISTRY.counters().get("join.probe_compacted", 0) - before.get(
+        "join.probe_compacted", 0) == compacted
     before = REGISTRY.counters()
     ticket = server.session("q14").submit(make(total - 1), bindings)
     with pytest.raises(resilience.CapacityOverflow) as refused:
@@ -279,6 +295,8 @@ def test_a_capacity_one_row_too_small_fails_the_request(server, how):
     moved = {k: v - before.get(k, 0) for k, v in REGISTRY.counters().items()}
     assert moved["join.overflowed"] == 1
     assert moved["join.capacity_rows"] == total - 1
+    # one keyed row more than slots: today's path, whole
+    assert moved.get("join.probe_compacted", 0) == 0
     # and fusion.execute, which raises nothing, says so in the meta
     direct = fusion.execute(make(total - 1), bindings)
     assert bool(direct.meta[f"{label}.overflowed"])
@@ -416,3 +434,114 @@ def test_join_maps_against_the_pairs(how, kind):
                          else None)
             assert int(short.total) == total
             assert np.asarray(short.row_valid).all()
+
+
+# ---------------------------------------------------------------------------
+# the join over the probe rows that can emit alone (a capacity far under the
+# probe's rows) against today's path and the oracle
+# ---------------------------------------------------------------------------
+
+_SLOTS = 48
+_PROBE_ROWS = 2 * _SLOTS * join_ops._COMPACT_FACTOR
+# build rows: duplicates, a NULL key and a phantom row among them
+_BUILD = {"key": [1, 1, 2, 3, 3, 3, 4, 5, 6, 7, 8, 9],
+          "keyed": [1, 1, 1, 1, 1, 1, 1, 1, 0, 1, 1, 1],
+          "real": [1, 1, 1, 1, 1, 1, 1, 1, 1, 0, 1, 1]}
+
+
+def _emitting_sides(how: str, emitting: int, rng):
+    """``(probe keys, keyed, real)`` with exactly ``emitting`` rows that
+    can emit under ``how``; the others are phantom rows or, where only a
+    key lets a row emit, also real rows with a NULL key. Most keys miss
+    the build side, some repeat, some hit a key two or three build rows
+    hold (never under ``left`` / ``full`` / ``left_anti``, whose every
+    real row emits: a second match would pass the slots)."""
+    by_key = how in join_ops._EMITS_BY_KEY
+    chosen = np.zeros(_PROBE_ROWS, bool)
+    chosen[rng.choice(_PROBE_ROWS, emitting, replace=False)] = True
+    hits = [1, 3, 4, 5, 8] if by_key else [2, 4, 5, 8, 9]
+    keys = np.where(rng.random(_PROBE_ROWS) < 0.3,
+                    rng.choice(hits, _PROBE_ROWS),
+                    rng.integers(100, 110, _PROBE_ROWS)).astype(np.int64)
+    if by_key:
+        keyed = chosen | (rng.random(_PROBE_ROWS) < 0.1)
+        real = chosen | (~keyed & (rng.random(_PROBE_ROWS) < 0.5))
+    else:
+        keyed = rng.random(_PROBE_ROWS) < 0.8
+        real = chosen
+    return keys, keyed, real
+
+
+def _maps_at(sides, out_size: int, how: str):
+    lkeys, lkeyed, lreal = sides
+    return jax.jit(partial(join_ops._join_maps_impl, out_size=out_size,
+                           how=how))(
+        jnp.asarray(lkeys), jnp.asarray(lkeyed),
+        jnp.asarray(np.array(_BUILD["key"], np.int64)),
+        jnp.asarray(np.array(_BUILD["keyed"], bool)),
+        left_row_valid=jnp.asarray(lreal),
+        right_row_valid=jnp.asarray(np.array(_BUILD["real"], bool)))
+
+
+@pytest.mark.parametrize("emitting", [_SLOTS // 2, _SLOTS - 1, _SLOTS,
+                                      _SLOTS + 1])
+@pytest.mark.parametrize("how", _HOWS)
+def test_join_maps_at_the_emitting_rows(how, emitting, monkeypatch):
+    """A capacity ``_COMPACT_FACTOR`` times under the probe's rows: with
+    no more rows that can emit than slots the join runs on those alone
+    (``probe_compacted``), with one more on today's path, and either way
+    its maps are today's (the same call with the gate shut), row for row;
+    where the total fits the slots they are the oracle's pairs."""
+    sides = _emitting_sides(how, emitting, np.random.default_rng(
+        _HOWS.index(how) * 100 + emitting))
+    got = _maps_at(sides, _SLOTS, how)
+    assert bool(got.probe_compacted) == (emitting <= _SLOTS)
+    monkeypatch.setattr(join_ops, "_COMPACT_FACTOR", 1 << 40)
+    today = _maps_at(sides, _SLOTS, how)
+    assert not bool(today.probe_compacted)
+    total = int(got.total)
+    for name, a, b in zip(got._fields[:-1], got, today):
+        a, b = np.asarray(a), np.asarray(b)
+        if name.endswith("_index"):   # read only where its side is valid
+            valid = np.asarray(getattr(today, name[:-5] + "valid"))
+            a, b = a[valid], b[valid]
+        assert np.array_equal(a, b), name
+    want = _oracle_pairs(
+        [(int(k),) for k in sides[0]], sides[1], sides[2],
+        [(k,) for k in _BUILD["key"]], _BUILD["keyed"], _BUILD["real"], how)
+    assert total == len(want)
+    if emitting == _SLOTS // 2:
+        assert total <= _SLOTS
+        if how in ("inner", "right"):   # a key that two or three rows hold
+            assert total > emitting / 4
+    if total <= _SLOTS:
+        li, ri = np.asarray(got.left_index), np.asarray(got.right_index)
+        lv, rv = np.asarray(got.left_valid), np.asarray(got.right_valid)
+        pairs = [(int(li[j]) if lv[j] else -1, int(ri[j]) if rv[j] else -1)
+                 for j in range(total)]
+        if how == "left_semi":
+            assert [g[0] for g in pairs] == [w[0] for w in want]
+        else:
+            assert pairs == want
+
+
+def test_a_capacity_at_half_the_probe_rows_lowers_as_before():
+    """The gate is static: a join whose ``out_size`` is half its probe
+    side's rows or more (every caller that sizes it by the probe's true
+    rows) holds no conditional and packs no mask."""
+    def lowered(out_size):
+        shapes = [jax.ShapeDtypeStruct((n,), dt) for n, dt in (
+            (1024, jnp.int64), (1024, jnp.bool_), (64, jnp.int64),
+            (64, jnp.bool_), (1024, jnp.bool_))]
+        return str(jax.make_jaxpr(
+            lambda lk, lv, rk, rv, lrv: join_ops._join_maps_impl(
+                lk, lv, rk, rv, out_size, "inner", lrv))(*shapes))
+
+    near = 1024 // join_ops._COMPACT_FACTOR
+    assert join_ops._COMPACT_FACTOR > 2
+    for out_size in (512, 1024, near + 1):
+        # (a 64-bit key's merged sort is a conditional of its own)
+        assert lowered(out_size).count("cond[") == 1
+        assert "population_count" not in lowered(out_size)
+    assert lowered(near).count("cond[") >= 2
+    assert "population_count" in lowered(near)
