@@ -910,12 +910,14 @@ def tpch_q3_planned(customer: Table, orders: Table, lineitem: Table,
     gather — the join phase compiles with ZERO sorts (HLO-pinned in
     tests), where the general q3 pays two build-side lexsorts + probe
     searchsorteds on the sort-based machinery. On a v5e at SF1 (PERF.md
-    section 5, traced runs of PR 40) the joins take 0.15 s of a 0.68 s
+    section 5, traced runs of PR 47) the joins take 0.15 s of a 0.355 s
     request: pk2's one gather of the match bit by 6,001,215 positions
     0.068 s (0.40 s while it also gathered the order's key, date and
     priority there), the look-up ``late`` that fetches date and priority
-    at the 1,500,001 group rows 0.070 s, pk1 0.012 s; the result's sort
-    0.34 s; the groupby 0.16 s: its look-ups at 1,500,001 rows 0.07 s, the
+    at the 1,500,001 group rows 0.068 s, pk1 0.012 s; the result's sort
+    0.014 s (0.34 s while it ordered all 1,500,001 group rows: it orders
+    the 131,072 that hold the 88,500 groups, ``fusion.Sort``); the
+    groupby 0.16 s: its look-ups at 1,500,001 rows 0.07 s, the
     key and the revenue brought into key order as four packed words
     0.06 s, its key sort 0.02 s (0.27 s while the key was sorted as the
     64-bit number its type says: three passes that each gathered a

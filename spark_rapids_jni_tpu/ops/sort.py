@@ -602,6 +602,84 @@ def permute(columns: Sequence[Column], order: jnp.ndarray,
     return out_cols, moved[mask_at:]
 
 
+# ``sort_before_padding`` sorts a table whose rows are padding from some row
+# on (a bounded groupby's result: the groups, then null rows up to the
+# bound) at a rung of about a sixteenth of its rows. The smallest rung worth
+# a conditional, from a chip reading (v5e, PERF.md section 6, PR 47: q3's
+# four masked columns by two keys alone in a jit, rows: all of them sorted |
+# the conditional's taken branch, s): 1,025: 0.00144 | 0.00128 (a tie);
+# 16,384: 0.00314 | 0.00139; 65,536: 0.00890 | 0.00157; 1,500,001: 0.3037 |
+# 0.0171 (0.0336 at an eighth). From a rung of 1,024 on the conditional is
+# over twice as quick and 0.0005 s ahead, the rule set before the reading;
+# q13's ORDER BY over 1,024 slots and every smaller sort stay under it. The
+# other branch is NOT free: 131,073 real rows of 1,500,001 read 0.3992 s
+# under the conditional and 0.3065 with none.
+_MIN_RUNG = 1024
+
+
+def padding_rung(table: Table, keys: Sequence[int],
+                 nulls_first: Sequence[bool] | None) -> int | None:
+    """The rows ``sort_before_padding`` would sort of ``table``: the power
+    of two at or over a sixteenth of its rows; None where the table may
+    not be sorted that way, all of it decided on what a trace knows. Every
+    key sorts its nulls last and has a validity mask (a row null in every
+    key then ranks after every row that has one, and a stable sort leaves
+    such rows in the order they stood in), every column is one of row
+    buffers (``gather``'s columns: fixed-width, or a padded string), and
+    the rung is ``_MIN_RUNG`` rows at least."""
+    if nulls_first is None or any(nulls_first):
+        return None
+    if any(table.column(k).validity is None for k in keys):
+        return None
+    if any(c.children is not None
+           or (c.dtype.is_string and not c.is_padded_string)
+           for c in table.columns):
+        return None
+    rung = 1 << (-(-table.num_rows // 16) - 1).bit_length()
+    return rung if _MIN_RUNG <= rung < table.num_rows else None
+
+
+def _sort_before_padding_impl(row_args, aux, rvs, *, keys, ascending,
+                              nulls_first, rung):
+    ((table,),) = row_args
+
+    def in_order(tbl):
+        return gather(tbl, sort_order(tbl, keys, ascending, nulls_first))
+
+    def head_in_order(tbl):
+        head = in_order(jax.tree.map(lambda buf: buf[:rung], tbl))
+        return jax.tree.map(
+            lambda ordered, buf: jnp.concatenate([ordered, buf[rung:]]),
+            head, tbl)
+
+    valid_past = [table.column(k).validity[rung:] for k in keys]
+    padding = ~jnp.any(jnp.stack(valid_past))
+    return jax.lax.cond(padding, head_in_order, in_order, table), padding
+
+
+def sort_before_padding(table: Table, keys: Sequence[int],
+                        ascending: Sequence[bool] | None,
+                        nulls_first: Sequence[bool], rung: int) -> tuple:
+    """``(sort_table(table, keys, ascending, nulls_first), whether the
+    first ``rung`` rows alone were sorted)``, for a ``rung`` that
+    ``padding_rung`` gave. Where no row from ``rung`` on is valid in any
+    key (one reduction over the keys' masks), those rows tie on every key
+    and rank last: the stable sort of the whole table is the stable sort
+    of the rows before them with the rest left where it lies, value for
+    value, and a ``lax.cond`` runs that; otherwise it runs the whole
+    sort."""
+    from spark_rapids_jni_tpu.runtime import dispatch
+
+    keys, nulls_first = tuple(keys), tuple(nulls_first)
+    ascending = (True,) * len(keys) if ascending is None else tuple(ascending)
+    return dispatch.call(
+        "sort_before_padding",
+        partial(_sort_before_padding_impl, keys=keys, ascending=ascending,
+                nulls_first=nulls_first, rung=rung),
+        ((table,),), statics=(keys, ascending, nulls_first, rung),
+        slice_rows=False, bucket_rows=False)
+
+
 @func_range("sort_table")
 def sort_table(
     table: Table,

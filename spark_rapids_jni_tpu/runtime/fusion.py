@@ -348,7 +348,17 @@ class BloomProbe(NamedTuple):
 class Sort(NamedTuple):
     """``sort_table``; when the input still carries a region row_valid the
     phantom rows rank strictly last (``sort_order``'s row_valid contract),
-    so the real prefix is exactly the staged sort."""
+    so the real prefix is exactly the staged sort.
+
+    An input with no row_valid whose keys all sort their nulls last and
+    have validity masks, of enough rows (``ops/sort.py padding_rung``: a
+    bounded groupby's result, the groups and then null rows up to the
+    bound), is sorted at a sixteenth of its rows where the data says that
+    every row past them is null in every key, the rest left where it
+    lies: the same table, value for value (``sort_before_padding``). Meta
+    of such a node, under its scope's name (``node_scopes``):
+    ``sort.prefix_sorted``, whether that ran; any other ``Sort`` lowers
+    as it always has and reports nothing."""
 
     child: Any
     keys: tuple
@@ -636,6 +646,7 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
     ``placement`` (``_mesh_placement``) adds what a groupby lowered over a
     mesh reports of its shuffle."""
     keys: list = []
+    scopes = node_scopes(nodes)    # a Sort has no label: its scope's name
     for node in nodes:
         if isinstance(node, Filter):
             keys += [f"{node.label}.rows_in", f"{node.label}.rows_kept",
@@ -668,7 +679,17 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
             keys += [f"{node.label}.total", f"{node.label}.pk_violation"]
         elif isinstance(node, BloomProbe):
             keys += [f"{node.label}.rows_in", f"{node.label}.rows_pass"]
+        elif isinstance(node, Sort):
+            keys += [f"{scopes[id(node)]}.prefix_sorted"]
     return keys
+
+
+def _side_meta(side) -> dict:
+    """The meta of ``(key, value)`` side outputs. A key that ``_side_keys``
+    lists for every node of a kind, while whether a node has the fact is
+    known only once its input is traced (a ``Sort``'s ``prefix_sorted``),
+    comes with None from a node that has none: it is left out."""
+    return {key: value for key, value in side if value is not None}
 
 
 # ---------------------------------------------------------------------------
@@ -731,10 +752,14 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
     )
     from spark_rapids_jni_tpu.ops.planner import (
         dense_pk_join, narrow_group_keys, plan_groupby, widen_group_keys)
-    from spark_rapids_jni_tpu.ops.sort import gather, sort_order
+    from spark_rapids_jni_tpu.ops.sort import (
+        gather, padding_rung, sort_before_padding, sort_order)
 
     env: dict = {}
     side: list = []
+    # children first, each node under its own scope (not its parents')
+    nodes = _topo(root)
+    scopes = node_scopes(nodes)
 
     def ev(node):
         if id(node) in env:
@@ -908,8 +933,15 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
             tbl, rv = ev(node.child)
             asc = None if node.ascending is None else list(node.ascending)
             nf = None if node.nulls_first is None else list(node.nulls_first)
-            order = sort_order(tbl, list(node.keys), asc, nf, row_valid=rv)
-            srt = gather(tbl, order)
+            rung = padding_rung(tbl, node.keys, nf) if rv is None else None
+            if rung is None:
+                srt = gather(tbl, sort_order(tbl, list(node.keys), asc, nf,
+                                             row_valid=rv))
+                took = None     # no fact of this node (``_side_meta``)
+            else:
+                srt, took = sort_before_padding(
+                    tbl, list(node.keys), asc, nf, rung)
+            side.append((f"{scopes[id(node)]}.prefix_sorted", took))
             if rv is None:
                 out = (srt, None)
             else:
@@ -931,10 +963,7 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
         env[id(node)] = out
         return out
 
-    # children first, each node under its own scope (not its parents')
-    order = _topo(root)
-    scopes = node_scopes(order)
-    for node in order:
+    for node in nodes:
         with jax.named_scope(scopes[id(node)]):
             value, _ = ev(node)
     return value, side
@@ -1131,7 +1160,7 @@ def mesh_step(plan: Plan, local: dict, axis: str) -> FusedResult:
     with jax.named_scope(f"region.{plan.name}"):
         value, side = _eval_plan(plan.root, local, {}, resolved, true_rows,
                                  mesh_axis=axis, placement=placement)
-    return FusedResult(value, dict(side))
+    return FusedResult(value, _side_meta(side))
 
 
 def _limit_bound(nodes, resolved: dict, spaces: dict,
@@ -1500,7 +1529,7 @@ def execute(plan: Plan, bindings: dict, *,
             rvs = {name: None for name in tables}
             value, side = _eval_plan(plan.root, tables, rvs, resolved,
                                      true_rows)
-        meta = dict(side)
+        meta = _side_meta(side)
         meta.update(static_meta)
         res = FusedResult(value, meta)
         _harvest_rtfilter(plan, nodes, res.meta)
@@ -1613,7 +1642,7 @@ def execute(plan: Plan, bindings: dict, *,
     root_space = spaces[id(plan.root)]
     if root_space is not None:
         value = _slice_to(value, int(true_rows[root_space]))
-    meta = dict(zip(side_keys, side_vals))
+    meta = _side_meta(zip(side_keys, side_vals))
     meta.update(static_meta)
     _harvest_rtfilter(plan, nodes, meta)
     return FusedResult(value, meta)
@@ -1646,8 +1675,10 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     data; a bound of 64 or fewer that holds finds its groups with no
     sort), and how many grouped a key at the width of its
     declared range (``groupby.key_narrowed``: a fact of the lowering too,
-    ``ops/planner.narrow_group_keys``). A result with a broken declaration
-    is a wrong answer; the served path refuses it
+    ``ops/planner.narrow_group_keys``), and how many sorts ordered the rows
+    before their input's padding alone (``sort.prefix_sorted``: a fact of
+    the data, ``ops/sort.py sort_before_padding``). A result with a broken
+    declaration is a wrong answer; the served path refuses it
     (``QueryServer._account_meta``). Converting a meta value waits for the
     device, so call it where the meta is wanted on the host anyway."""
     facts = {"join.probe_rows": 0, "join.matched_rows": 0,
@@ -1661,8 +1692,10 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
              "groupby.key_narrowed": 0, "groupby.key_out_of_range": 0,
              "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0,
              "filter.rows_in": 0, "filter.rows_kept": 0,
-             "strings.like_bytes": 0}
-    for node in _topo(plan.root):
+             "strings.like_bytes": 0, "sort.prefix_sorted": 0}
+    nodes = _topo(plan.root)
+    scopes = node_scopes(nodes)
+    for node in nodes:
         if isinstance(node, Filter):
             for fact, field in (("filter.rows_in", "rows_in"),
                                 ("filter.rows_kept", "rows_kept"),
@@ -1702,6 +1735,9 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["shuffle.rows"] += int(sent)
                 facts["shuffle.bytes"] += int(
                     meta[f"{node.label}.shuffle_bytes"])
+        elif isinstance(node, Sort):
+            facts["sort.prefix_sorted"] += bool(
+                meta.get(f"{scopes[id(node)]}.prefix_sorted", False))
     return facts
 
 

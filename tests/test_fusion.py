@@ -31,7 +31,9 @@ import pytest
 from spark_rapids_jni_tpu import types as t
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.models import tpch
+from spark_rapids_jni_tpu.ops import sort as so
 from spark_rapids_jni_tpu.runtime import dispatch, fusion
+from spark_rapids_jni_tpu.runtime.server import QueryServer
 from spark_rapids_jni_tpu.telemetry import REGISTRY
 from spark_rapids_jni_tpu.utils.config import reset_option, set_option
 
@@ -128,7 +130,9 @@ def test_q3_fused_matches_staged(n):
     assert fused.out_cap == staged.out_cap
 
 
-@pytest.mark.parametrize("n", (1, 16, 17))
+# (the last size puts the groupby's bound over ``ops/sort.py``'s floor: the
+# result's sort takes the rows before the padding alone, fused and staged)
+@pytest.mark.parametrize("n", (1, 16, 17, 16 * so._MIN_RUNG))
 def test_q3_planned_fused_matches_staged(n):
     cust = tpch.customer_table(max(n // 2, 1))
     orders = tpch.orders_table(n, cust.num_rows)
@@ -141,6 +145,8 @@ def test_q3_planned_fused_matches_staged(n):
     assert int(fused.join_total) == int(staged.join_total)
     assert bool(fused.pk_violation) == bool(staged.pk_violation)
     assert not bool(fused.pk_violation)
+    if n > 17:
+        assert REGISTRY.counters()["dispatch.compile.sort_before_padding"] == 1
 
 
 def test_q1_planned_domain_miss_replans_identically():
@@ -339,6 +345,72 @@ def test_row_specs_resolve_from_true_rows():
     assert fusion._resolve(fusion.min_rows_of("t", 7), {"t": 4}) == 4
     assert fusion._resolve(None, {}) is None
     assert fusion._resolve(12, {}) == 12
+
+
+# ---------------------------------------------------------------------------
+# a Sort over a bounded groupby's padded result: the rows before the padding
+# ---------------------------------------------------------------------------
+
+_BOUND = 256     # the rung of so many rows is 16, and the floor dropped to it
+_SORT_KEYS, _SORT_ASC, _SORT_NF = (1, 2), (False, True), (False, False)
+
+
+def _padded_groups_plan(sort: bool = True) -> fusion.Plan:
+    """[key, sum, max] of at most ``_BOUND`` groups, ORDER BY sum DESC, max."""
+    groups = fusion.GroupBy(fusion.Scan("t"), (0,), ((1, "sum"), (2, "max")),
+                            max_groups=_BOUND, label="groupby")
+    return fusion.Plan("sorted_groups", fusion.Sort(
+        groups, _SORT_KEYS, _SORT_ASC, _SORT_NF) if sort else groups)
+
+
+def _rows_of_groups(groups: int, null_group: int | None = None) -> Table:
+    """Three rows a group in a seeded order; every value of ``null_group``
+    is null, so that group's sum and max, the two sort keys, are null."""
+    rng = np.random.default_rng(groups)
+    key = rng.permutation(np.repeat(np.arange(groups, dtype=np.int64), 3))
+    real = key != (-1 if null_group is None else null_group)
+    return Table([
+        Column(t.INT64, key),
+        Column(t.decimal64(-2), rng.integers(-10**6, 10**6, len(key)), real),
+        Column(t.INT32, rng.integers(0, 4, len(key)).astype(np.int32), real)])
+
+
+# case -> (the groupby's input, whether the head alone is sorted)
+PADDED_GROUPS = {
+    "well_under_the_rung": (_rows_of_groups(5), 1),
+    "the_rung_exactly": (_rows_of_groups(16), 1),
+    "one_row_over_the_rung": (_rows_of_groups(17), 0),
+    "no_row": (_rows_of_groups(0), 1),
+    "a_group_null_in_every_sort_key": (_rows_of_groups(9, null_group=3), 1),
+}
+
+
+@pytest.mark.parametrize("case", list(PADDED_GROUPS))
+def test_sort_over_padded_groups_is_the_stable_whole_sort(case, monkeypatch):
+    """Value for value ``sort_table`` of the groupby's result, fused, staged
+    and served; ``sort.prefix_sorted`` says which branch ran. A real group
+    whose sort keys are null stands after the others and before the
+    padding, where the stable sort of all rows puts it."""
+    monkeypatch.setattr(so, "_MIN_RUNG", _BOUND // 16)
+    table, took = PADDED_GROUPS[case]
+    plan, bindings = _padded_groups_plan(), {"t": table}
+    groups = fusion.execute(_padded_groups_plan(sort=False), bindings)
+    want = so.sort_table(groups.table, _SORT_KEYS, _SORT_ASC, _SORT_NF)
+    fused = fusion.execute(plan, bindings)
+    staged = _staged(lambda: fusion.execute(plan, bindings))
+    for got, label in ((fused, "fused"), (staged, "staged")):
+        assert got.table.num_rows == _BOUND
+        _assert_tables_identical(got.table, want, f"{case} {label}")
+        assert int(got.meta["sort.prefix_sorted"]) == took, label
+        assert fusion.meta_facts(plan, got.meta)["sort.prefix_sorted"] == took
+    if case == "a_group_null_in_every_sort_key":
+        key, total = fused.table.column(0), fused.table.column(1)
+        assert key.to_pylist()[8] == 3 and total.to_pylist()[8] is None
+        assert key.to_pylist()[9] is None
+    with QueryServer(budget_bytes=4 << 30) as srv:
+        served = srv.session("t").submit(plan, bindings).result()
+    _assert_tables_identical(served.table, want, f"{case} served")
+    assert REGISTRY.counters()["sort.prefix_sorted"] == took
 
 
 # ---------------------------------------------------------------------------

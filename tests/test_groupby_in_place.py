@@ -551,15 +551,19 @@ def test_planned_q3_region_keeps_its_sorts(monkeypatch):
     groups-of-orders spec over the block path's gate: the region keeps the
     word-moving path, its sorts what they were in number, and ``permute``'s
     loop is still there. The key sort's loop is not (PR 37): the key's
-    declared range makes it one sort of two words and an iota."""
+    declared range makes it one sort of two words and an iota. The ORDER
+    BY's loop stands once a branch of its conditional (PR 47): over the
+    rung of the 70,001 group rows, and over all of them."""
     monkeypatch.setattr(so, "_SORT_MOVE_MIN_WORDS", 0)
     hlo = _region_hlo(tpch._q3_planned_plan(0, 9204),
                       _q3_tables(n_ord=70_000))
     sorts = _sorts(hlo)
-    assert len(sorts) == 4 and sorts.count("u32[4000]{0}") == 1, sorts
+    assert len(sorts) == 5 and sorts.count("u32[4000]{0}") == 1, sorts
     assert sorts.count(
         "(u32[4000]{0}, u32[4000]{0}, s32[4000]{0})") == 1, sorts
-    assert len(_loops_that_sort(hlo)) == 2     # permute, ORDER BY
+    for rows in (8192, 70_001):
+        assert sorts.count(f"(u32[{rows}]{{0}}, s32[{rows}]{{0}})") == 1, sorts
+    assert len(_loops_that_sort(hlo)) == 3     # permute, ORDER BY's two
 
 
 # -- the served path's counter --------------------------------------------

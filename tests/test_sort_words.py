@@ -217,9 +217,10 @@ N_ORD = 70_000
 
 def test_planned_q3_region_sorts_one_word_at_a_time(moved_by):
     """XLA's TPU compiler takes about the square of a sort's operand words
-    in compile time: the result's sort and, where the groupby's words move
-    by sort passes, that loop's one are each two operands of 32 bits, in a
-    loop; the groupby's key sort is ONE sort of the key narrowed to its
+    in compile time: the result's sort (in either branch of its
+    conditional) and, where the groupby's words move by sort passes, that
+    loop's one are each two operands of 32 bits, in a loop; the groupby's
+    key sort is ONE sort of the key narrowed to its
     declared range, the word of its null rank and row-valid bit, and a
     32-bit iota, in no loop (it was a loop of three passes that each
     gathered a word by the running order); the groupby's compaction of
@@ -229,7 +230,9 @@ def test_planned_q3_region_sorts_one_word_at_a_time(moved_by):
     hlo = _region_hlo(tpch._q3_planned_plan(0, 9204),
                       _q3_tables(n_ord=N_ORD, n=n))
     sorts = _sorts(hlo)
-    assert len(sorts) == (4 if moved_by == "sort_passes" else 3), sorts
+    # (the result's sort stands twice, once a branch of its conditional:
+    # ``test_planned_q3_sort_takes_the_rows_before_the_padding``)
+    assert len(sorts) == (5 if moved_by == "sort_passes" else 4), sorts
     assert sorts.count(f"u32[{n}]{{0}}") == 1, sorts
     key_sort = f"(u32[{n}]{{0}}, u32[{n}]{{0}}, s32[{n}]{{0}})"
     assert sorts.count(key_sort) == 1, sorts
@@ -238,6 +241,8 @@ def test_planned_q3_region_sorts_one_word_at_a_time(moved_by):
             r"\(u32\[\d+\]\{0\}, [us]32\[\d+\]\{0\}\)|u32\[\d+\]\{0\}",
             result), result
     assert not re.search(r"[us]64\[[^\]]*\][^=\n]* sort\(", hlo)
+    for rows in (N_ORD + 1, 8192):      # all the group rows | the rung
+        assert sorts.count(f"(u32[{rows}]{{0}}, s32[{rows}]{{0}})") == 1, sorts
     # the key sort and the compaction stand under the node, in no loop
     flat = [name for name in _scoped(hlo, "sort", "groupby")
             if "/while/" not in name]
@@ -249,6 +254,92 @@ def _scoped(hlo: str, kind: str, node: str) -> list:
     return [name for name in re.findall(
         rf'= [^\n]*? {kind}\([^\n]*?op_name="([^"]*)"', hlo)
         if re.search(rf"region\.[^/]+/{node}/", name)]
+
+
+def _sort_rows(hlo: str, node: str = "sort") -> dict:
+    """branch of the node's conditional -> the rows of the sorts in it."""
+    rows: dict = {}
+    for dims, name in re.findall(
+            r'= \([us]32\[(\d+)\][^\n]*? sort\([^\n]*?op_name="([^"]*)"', hlo):
+        if re.search(rf"region\.[^/]+/{node}/", name):
+            branch = re.search(r"/cond/(branch_\d)_fun/", name)
+            rows.setdefault(branch and branch.group(1), []).append(int(dims))
+    return rows
+
+
+def test_planned_q3_sort_takes_the_rows_before_the_padding():
+    """70,001 group rows are over the floor: the node lowers with ONE
+    conditional, whose taken branch sorts and gathers at the rung (the
+    power of two at or over a sixteenth: 8,192) and whose other branch is
+    the sort of all rows; each a loop of one two-operand sort."""
+    m = N_ORD + 1
+    rung = so.padding_rung(
+        Table([Column(t.INT32, jnp.zeros(m, jnp.int32), jnp.ones(m, bool))]),
+        (0,), (False,))
+    assert rung == 8192 >= so._MIN_RUNG
+    hlo = _region_hlo(tpch._q3_planned_plan(0, 9204), _q3_tables(n_ord=N_ORD))
+    assert len(_scoped(hlo, "conditional", "sort")) == 1
+    assert _sort_rows(hlo) == {"branch_1": [rung], "branch_0": [m]}
+    # a pass's word by the running order in the loop; then the four
+    # columns' data and their masks (the late look-up's two share one) by
+    # the sort's order: the same in either branch, at its rows
+    moved = sorted([("pred", False)] * 3 + [("s32", False)] * 2
+                   + [("s64", False)] * 2 + [("u32", True)])
+    found = _gathers(hlo, "sort")
+    for rows in (rung, m):
+        assert sorted((kind, inside) for kind, dims, inside in found
+                      if dims == [rows]) == moved, (rows, found)
+    assert len(found) == 2 * len(moved), found
+
+
+def _unmask_total(tbl: Table) -> Table:
+    """The groups' sum with no validity mask: a key that is never null."""
+    total = tbl.column(1)
+    return Table([tbl.column(0), Column(total.dtype, jnp.where(
+        total.valid_mask(), total.data, 0))])
+
+
+def _padded_groups_plan(bound: int, nulls_first=(False, False),
+                        fn=None) -> fusion.Plan:
+    groups = fusion.GroupBy(fusion.Scan("t"), (0,), ((1, "sum"),),
+                            max_groups=bound, label="groupby")
+    return fusion.Plan("sorted_groups", fusion.Sort(
+        groups if fn is None else fusion.Project(groups, fn), (1, 0),
+        ascending=(False, True), nulls_first=nulls_first))
+
+
+# what keeps a Sort out of ``padding_rung``'s gate -> the plan
+_OVER = 16 * so._MIN_RUNG
+OUTSIDE_THE_GATE = {
+    "a_key_sorts_its_nulls_first": _padded_groups_plan(_OVER, (False, True)),
+    "nulls_first_left_to_its_default": _padded_groups_plan(_OVER, None),
+    "a_key_column_without_a_mask": _padded_groups_plan(
+        _OVER, fn=_unmask_total),
+    "a_rung_under_the_floor": _padded_groups_plan(_OVER // 2),
+}
+
+
+@pytest.mark.parametrize("case", list(OUTSIDE_THE_GATE))
+def test_a_sort_outside_the_gate_lowers_as_it_always_has(case):
+    """No ``conditional`` under the node's scope, no ``sort.prefix_sorted``
+    in the result's meta; the same groups under the floor's bound and
+    nulls last are inside the gate (the control)."""
+    rng = np.random.default_rng(8)
+    bindings = {"t": Table([
+        Column(t.INT64, jnp.asarray(rng.integers(0, 300, 2000))),
+        Column(t.decimal64(-2), jnp.asarray(rng.integers(-99, 99, 2000)),
+               jnp.asarray(rng.random(2000) > 0.1))])}
+    plan = OUTSIDE_THE_GATE[case]
+    assert not _scoped(_region_hlo(plan, bindings), "conditional", "sort")
+    res = fusion.execute(plan, bindings)
+    assert "sort.prefix_sorted" not in res.meta
+    assert fusion.meta_facts(plan, res.meta)["sort.prefix_sorted"] == 0
+    if case == "a_rung_under_the_floor":
+        inside = _padded_groups_plan(_OVER)
+        assert len(_scoped(_region_hlo(inside, bindings),
+                           "conditional", "sort")) == 1
+        assert bool(fusion.execute(inside, bindings).meta[
+            "sort.prefix_sorted"])
 
 
 def test_planned_q3_groupby_finds_its_bounds_by_one_compaction(moved_by):

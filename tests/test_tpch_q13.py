@@ -277,6 +277,9 @@ def test_q13_plan_is_one_region_with_its_scopes():
     bindings = _device(customer, _with_text(orders))
     assert fusion.plan_fingerprint(plan, bindings) != fusion.plan_fingerprint(
         tpch._q13_plan("special", "deposits"), bindings)
+    # custdist's 1,024 slots are under ``ops/sort.py``'s floor: the ORDER BY
+    # lowers as it always has and says nothing of a sorted head
+    assert "sort.prefix_sorted" not in fusion.execute(plan, bindings).meta
 
 
 def test_a_customer_out_of_place_is_a_pk_violation(server):
