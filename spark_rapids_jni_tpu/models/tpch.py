@@ -2992,6 +2992,159 @@ def tpch_q13_numpy(customer: Table, orders: Table,
     return sorted(dist.items(), key=lambda kv: (-kv[1], -kv[0]))
 
 
+# ---- TPC-H q18 whole (large volume customer) as one served Plan -----------
+#
+#   SELECT c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
+#          sum(l_quantity)
+#   FROM customer, orders, lineitem
+#   WHERE o_orderkey IN (SELECT l_orderkey FROM lineitem
+#                        GROUP BY l_orderkey HAVING sum(l_quantity) > 300)
+#     AND c_custkey = o_custkey AND o_orderkey = l_orderkey
+#   GROUP BY c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+#   ORDER BY o_totalprice DESC, o_orderdate LIMIT 100
+#
+# over its own three tables: lineitem (l_orderkey, l_quantity DECIMAL(15,2)),
+# orders (o_orderkey, o_custkey, o_orderdate, o_totalprice DECIMAL(15,2))
+# and customer (c_custkey, c_name VARCHAR(25) in the padded layout).
+
+L18_ORDERKEY, L18_QUANTITY = 0, 1
+O18_ORDERKEY, O18_CUSTKEY, O18_ORDERDATE, O18_TOTALPRICE = 0, 1, 2, 3
+C18_CUSTKEY, C18_NAME = 0, 1
+# item_join's output: the lineitem's two columns, then cust_join's six
+# (the orders' four, then the customer's two)
+_J18_QUANTITY = L18_QUANTITY
+_J18_ORDERKEY, _J18_ORDERDATE, _J18_TOTALPRICE = 2, 4, 5
+_J18_CUSTKEY, _J18_NAME = 6, 7
+# the answer's columns, as the SELECT lists them
+Q18_NAME, Q18_CUSTKEY, Q18_ORDERKEY, Q18_ORDERDATE = 0, 1, 2, 3
+Q18_TOTALPRICE, Q18_QUANTITY = 4, 5
+
+_Q18_QUANTITY = 300
+_Q18_LIMIT = 100
+_Q18_OUT_ROWS = 1 << 16
+
+
+def _q18_having(groups: Table, threshold: int) -> jnp.ndarray:
+    """``HAVING sum(l_quantity) > threshold`` over ``order_qty``'s output
+    (the key, then the sum): strictly greater, and a NULL sum (a group of
+    NULL quantities, a row past the groups) is not kept."""
+    total = groups.column(1)
+    return total.valid_mask() & (total.data > jnp.int64(threshold))
+
+
+def _q18_plan(quantity: int = _Q18_QUANTITY, out_rows: int = _Q18_OUT_ROWS,
+              limit: int = _Q18_LIMIT) -> fusion.Plan:
+    """TPC-H q18, whole, as one fused region, as the query text has it.
+    The plan declares ONE thing about any key: ``l_orderkey`` is a foreign
+    key into orders (clause 1.4.2), so ``order_qty`` holds at most
+    |orders| groups and the null group (``fusion.groups_of``); a lineitem
+    batch that breaks that sets ``order_qty.overflowed`` and the served
+    path refuses the result. No range, density, clustering or uniqueness
+    of ``l_orderkey``, ``o_orderkey``, ``o_custkey`` or ``c_custkey``.
+
+    * ``order_qty``: ``sum(l_quantity)`` by ``l_orderkey`` over every
+      lineitem: the general sort path with a 64-bit key nobody declared a
+      range for, a row in four a group.
+    * ``having``: ``sum > quantity`` (DECIMAL(15,2) as unscaled int64) as a
+      ``Filter`` over the groups; ``having.rows_in`` counts the groups.
+    * ``in_heavy``: ``o_orderkey IN (...)`` as orders LEFT SEMI JOIN the
+      kept groups (a NULL key, the null group's among them, matches
+      nothing).
+    * ``cust_join``: those orders INNER JOIN customer on the customer key
+      (``c_name`` travels through the join); ``item_join``: lineitem INNER
+      JOIN that on the order key, over the SAME lineitem scan
+      ``order_qty`` reads. Each lays its rows out in ``out_rows`` rows, a
+      capacity the planner states: the node reports ``.capacity`` and
+      ``.overflowed`` and the served path refuses a result that outgrew
+      it (``CapacityOverflow`` with the true total).
+    * ``groupby``: the outer GROUP BY on the five keys, one of them the
+      padded string, with ``sum(l_quantity)`` taken again from the joined
+      lineitems; it cannot hold more groups than the join has rows.
+    * the ORDER BY ``o_totalprice`` descending then ``o_orderdate``
+      ascending, NULLs last in both; ``o_orderkey`` breaks what ties are
+      left, which SQL leaves open, so that the rows the joins did not
+      fill (NULL in every column) rank strictly last; then the LIMIT.
+
+    The answer: ``limit`` rows of ``c_name``, ``c_custkey``,
+    ``o_orderkey``, ``o_orderdate``, ``o_totalprice``, ``sum(l_quantity)``;
+    a row whose ``o_orderkey`` reads NULL is no row of the answer (fewer
+    heavy orders than the limit)."""
+    out_rows = int(out_rows)
+    items = fusion.Scan("lineitem")
+    order_qty = fusion.GroupBy(
+        items, (L18_ORDERKEY,), ((L18_QUANTITY, "sum"),),
+        max_groups=fusion.groups_of("orders"), label="order_qty")
+    having = fusion.Filter(order_qty, _q18_having, (int(quantity) * 100,),
+                           label="having")
+    in_heavy = fusion.Join(
+        fusion.Scan("orders"), having, (O18_ORDERKEY,), (0,), None,
+        how="left_semi", label="in_heavy")
+    cust_join = fusion.Join(
+        in_heavy, fusion.Scan("customer"), (O18_CUSTKEY,), (C18_CUSTKEY,),
+        out_rows, how="inner", label="cust_join")
+    item_join = fusion.Join(
+        items, cust_join, (L18_ORDERKEY,), (O18_ORDERKEY,), out_rows,
+        how="inner", label="item_join")
+    grouped = fusion.GroupBy(
+        item_join, (_J18_NAME, _J18_CUSTKEY, _J18_ORDERKEY, _J18_ORDERDATE,
+                    _J18_TOTALPRICE), ((_J18_QUANTITY, "sum"),),
+        max_groups=out_rows, label="groupby")
+    ordered = fusion.Sort(
+        grouped, (Q18_TOTALPRICE, Q18_ORDERDATE, Q18_ORDERKEY),
+        ascending=(False, True, True), nulls_first=(False, False, False))
+    return fusion.Plan("tpch_q18", fusion.Limit(ordered, int(limit)))
+
+
+def tpch_q18_numpy(customer: Table, orders: Table, lineitem: Table,
+                   quantity: int = _Q18_QUANTITY,
+                   limit: int = _Q18_LIMIT) -> list:
+    """Host oracle for q18, the query evaluated literally in Python:
+    ``[(c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
+    sum(l_quantity))]`` in the plan's order (``_q18_plan``: NULLs last, the
+    order key breaking ties), the decimals unscaled, ``c_name`` as text."""
+    import collections
+
+    def cells(col):
+        return col.to_pylist() if col.dtype.is_string else [
+            None if not ok else int(v) for v, ok in zip(
+                np.asarray(col.data).tolist(),
+                np.asarray(col.valid_mask()).tolist())]
+
+    lkey, lqty = (cells(lineitem.column(i))
+                  for i in (L18_ORDERKEY, L18_QUANTITY))
+    sums: dict = {}
+    for k, q in zip(lkey, lqty):
+        if k is not None and q is not None:
+            sums[k] = sums.get(k, 0) + q
+    heavy = {k for k, v in sums.items() if v > int(quantity) * 100}
+    items = collections.defaultdict(list)
+    for k, q in zip(lkey, lqty):
+        if k in heavy:
+            items[k].append(q)
+    names = collections.defaultdict(list)
+    for ck, name in zip(cells(customer.column(C18_CUSTKEY)),
+                        cells(customer.column(C18_NAME))):
+        if ck is not None:
+            names[ck].append(name)
+    groups: dict = {}
+    for ok, ck, date, price in zip(*(cells(orders.column(i)) for i in (
+            O18_ORDERKEY, O18_CUSTKEY, O18_ORDERDATE, O18_TOTALPRICE))):
+        if ok not in heavy:
+            continue
+        for name in names.get(ck, ()):
+            for q in items[ok]:
+                key = (name, ck, ok, date, price)
+                had = groups.get(key)
+                groups[key] = had if q is None else (had or 0) + q
+    big = float("inf")
+    # what ties after the order key stays in the groupby's key order
+    rows = sorted(groups.items(), key=lambda kv: (
+        big if kv[0][4] is None else -kv[0][4],
+        big if kv[0][3] is None else kv[0][3], kv[0][2],
+        kv[0][0] is not None, kv[0][0] or "", kv[0][1]))
+    return [key + (total,) for key, total in rows[:int(limit)]]
+
+
 # ---------------------------------------------------------------------------
 # AOT warmup registration (runtime/server.QueryServer.warmup)
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ null.
 
 from __future__ import annotations
 
+import contextlib
 import numbers
 from functools import partial
 from typing import NamedTuple, Sequence
@@ -834,6 +835,15 @@ def _words_equal_prev(in_order, rv) -> jnp.ndarray:
 
 def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
                             max_groups) -> GroupByResult:
+    # the word-moving branch names its three stages for a device trace
+    # (``key_sort``, ``move``, ``reduce``): the last is open to the end
+    with contextlib.ExitStack() as scopes:
+        return _aggregate(row_args, rvs, scopes, keys=keys, aggs=aggs,
+                          max_groups=max_groups)
+
+
+def _aggregate(row_args, rvs, scopes, *, keys, aggs,
+               max_groups) -> GroupByResult:
     ((table, row_valid),) = row_args
     rv = row_valid
     if rv is None and rvs is not None:
@@ -870,7 +880,8 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         order, words, sorted_words = sort_key_words(table, keys, rv)
         same = _words_equal_prev(sorted_words, rv)
     else:
-        order = sort_order(table, keys, row_valid=rv)
+        with jax.named_scope("key_sort"):
+            order = sort_order(table, keys, row_valid=rv)
         # Only what is read at every row comes into key order, as packed
         # words moved once (ops/sort.py ``permute``): the keys, the
         # operands of the aggregates that run over the rows, and the bare
@@ -879,10 +890,13 @@ def _groupby_aggregate_impl(row_args, aux, rvs, *, keys, aggs,
         # cells first / last pick) is fetched from the unsorted table
         # through ``order`` at m rows; a column no key and no aggregate
         # names does not move at all.
-        moved, moved_masks = permute(
-            [table.column(i) for i in data_at], order,
-            [table.column(i).validity for i in mask_at]
-            + ([] if rv is None else [rv]))
+        with jax.named_scope("move"):
+            moved, moved_masks = permute(
+                [table.column(i) for i in data_at], order,
+                [table.column(i).validity for i in mask_at]
+                + ([] if rv is None else [rv]))
+        # boundaries, segmented sums, the gathers at the bound's rows
+        scopes.enter_context(jax.named_scope("reduce"))
         read_col = dict(zip(data_at, moved))
         read_mask = dict(zip(mask_at, moved_masks))
         sorted_keys = Table([read_col[k] for k in keys])

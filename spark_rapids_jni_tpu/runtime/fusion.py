@@ -179,7 +179,9 @@ class Filter(NamedTuple):
     a pattern (``ops/strings.py like``): what it examines of them is their
     ``chars``, every real row at the column's full width.
     Meta: ``<label>.rows_in`` (real rows the predicate saw: not a
-    bucket's padding) / ``<label>.rows_kept`` / ``<label>.like_bytes``."""
+    bucket's padding, and over a bounded ``GroupBy`` (a HAVING) its
+    groups, not the rows of its bound) / ``<label>.rows_kept`` /
+    ``<label>.like_bytes``."""
 
     child: Any
     pred: Callable
@@ -220,7 +222,14 @@ class GroupBy(NamedTuple):
     (num_groups/overflowed/sum_overflow/in_place/key_sorted, with a range that
     narrowed a key also key_narrowed and key_out_of_range, which the
     served path refuses as it does ``pk_violation``; or
-    present/domain_miss/lowered on the planned lowering)."""
+    present/domain_miss/lowered on the planned lowering). A sort-path
+    node also says what entered it and what it had room for:
+    ``<label>.rows_in`` (the real rows of its input: a scan's true rows
+    where its input holds a scan's rows one for one, not a bucket's
+    padding), ``<label>.read_bytes`` (those rows times the bytes of its
+    key columns and of the columns it aggregates, each with one byte of
+    validity) and, with a bound, ``<label>.capacity`` (the resolved
+    ``max_groups``)."""
 
     child: Any
     keys: tuple
@@ -665,6 +674,8 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
                 if placement and placement[id(node.child)] == SHARDED:
                     keys += [f"{node.label}.shuffle_rows",
                              f"{node.label}.shuffle_bytes"]
+                keys += [f"{node.label}.rows_in",
+                         f"{node.label}.read_bytes"]
                 if _declares_range(node):
                     keys += [f"{node.label}.key_narrowed",
                              f"{node.label}.key_out_of_range"]
@@ -713,6 +724,13 @@ def _scanned_rows(node, true_rows: dict) -> Optional[int]:
     return int(true_rows[node.name])
 
 
+def _column_row_bytes(c: Column) -> int:
+    """Bytes a row of ``c``'s buffers but its validity: the data's, and a
+    padded string's width."""
+    return sum(int(np.prod(buf.shape[1:])) * buf.dtype.itemsize
+               for buf in (c.data, c.chars) if buf is not None)
+
+
 def _null_all(table: Table, keep: jnp.ndarray) -> Table:
     return Table([
         Column(c.dtype, c.data, c.valid_mask() & keep,
@@ -757,6 +775,9 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
 
     env: dict = {}
     side: list = []
+    # a bounded groupby's node id -> the groups its output holds (int32):
+    # the rows past them are padding, which no row mask says
+    groups_of_node: dict = {}
     # children first, each node under its own scope (not its parents')
     nodes = _topo(root)
     scopes = node_scopes(nodes)
@@ -769,9 +790,14 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
         elif isinstance(node, Filter):
             tbl, rv = ev(node.child)
             keep = node.pred(tbl, *node.params)
-            real = jnp.asarray(tbl.num_rows if rv is None else jnp.sum(
-                rv, dtype=jnp.int64), jnp.int64)
-            kept = jnp.sum(keep if rv is None else keep & rv,
+            seen = rv
+            if rv is None and id(node.child) in groups_of_node:
+                # a HAVING: the bound's rows past the groups are no rows
+                seen = (jax.lax.iota(jnp.int32, tbl.num_rows)
+                        < groups_of_node[id(node.child)])
+            real = jnp.asarray(tbl.num_rows if seen is None else jnp.sum(
+                seen, dtype=jnp.int64), jnp.int64)
+            kept = jnp.sum(keep if seen is None else keep & seen,
                            dtype=jnp.int64)
             if placement is not None and placement[id(node)] == SHARDED:
                 # a chip saw its share of the rows: the counts of them all
@@ -830,7 +856,27 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                 ]
                 if resolved[id(node)] is None:
                     rv_out = rv   # padded to the input rows: still positional
+                else:
+                    groups_of_node[id(node)] = jnp.minimum(
+                        g.num_groups, resolved[id(node)])
             side.extend(gside)
+            if node.domains is None:
+                # what entered it: a scan's true rows where the input holds
+                # them one for one (known while tracing), else its rows
+                rows_in = _scanned_rows(node.child, true_rows)
+                if rows_in is None:
+                    rows_in = tbl.num_rows if rv is None else jnp.sum(
+                        rv, dtype=jnp.int64)
+                read = dict.fromkeys(
+                    list(node.keys) + [c for c, _ in node.aggs]
+                    + [op[1] for _, op in node.aggs if isinstance(op, tuple)])
+                width = sum(_column_row_bytes(tbl.column(i)) + 1
+                            for i in read)
+                side.extend([
+                    (f"{node.label}.rows_in",
+                     jnp.asarray(rows_in, jnp.int64)),
+                    (f"{node.label}.read_bytes",
+                     jnp.asarray(rows_in, jnp.int64) * width)])
             if ranges is not None:
                 gtbl = widen_group_keys(gtbl, keyed.narrowed)
                 broke = keyed.out_of_range
@@ -1509,6 +1555,9 @@ def execute(plan: Plan, bindings: dict, *,
                 static_meta[f"{n.label}.probe_rows"] = rows
         if isinstance(n, Join) and n.how not in _MASK_JOINS:
             static_meta[f"{n.label}.capacity"] = resolved[id(n)]
+        if (isinstance(n, GroupBy) and n.domains is None
+                and resolved[id(n)] is not None):
+            static_meta[f"{n.label}.capacity"] = resolved[id(n)]
     # rows sharded over a mesh axis are the signal, and the only one, that
     # the region runs across chips (see "lowering over a mesh" above)
     over = _bindings_mesh(bindings, bucketed)
@@ -1666,7 +1715,11 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     ``all_to_all`` put between chips), and how many nodes broke what the plan
     declares: a dense primary key that is not one (``pk_violation``), a
     group bound or a join's capacity that was too small (``overflowed``),
-    a key outside its declared range (``key_out_of_range``); and how many
+    a key outside its declared range (``key_out_of_range``); the real rows
+    that entered the sort-path groupbys, the bytes of the key and
+    aggregated columns those rows hold, and the groups their bounds have
+    room for (``groupby.rows_in``, ``groupby.read_bytes``,
+    ``groupby.capacity_groups``); and how many
     groupbys took
     their aggregates over the rows where they lie, no value word brought
     into key order (``groupby.in_place``: a fact of the lowering,
@@ -1690,6 +1743,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
              "groupby.overflowed": 0, "groupby.in_place": 0,
              "groupby.key_sorted": 0,
              "groupby.key_narrowed": 0, "groupby.key_out_of_range": 0,
+             "groupby.rows_in": 0, "groupby.read_bytes": 0,
+             "groupby.capacity_groups": 0,
              "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0,
              "filter.rows_in": 0, "filter.rows_kept": 0,
              "strings.like_bytes": 0, "sort.prefix_sorted": 0}
@@ -1729,6 +1784,11 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                          "key_out_of_range"):
                 facts[f"groupby.{fact}"] += bool(
                     meta.get(f"{node.label}.{fact}", False))
+            for fact, field in (("rows_in", "rows_in"),
+                                ("read_bytes", "read_bytes"),
+                                ("capacity_groups", "capacity")):
+                facts[f"groupby.{fact}"] += int(
+                    meta.get(f"{node.label}.{field}", 0))
             sent = meta.get(f"{node.label}.shuffle_rows")
             if sent is not None:   # lowered over a mesh: one all_to_all
                 facts["shuffle.exchanges"] += 1

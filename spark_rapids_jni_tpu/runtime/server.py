@@ -1077,7 +1077,9 @@ class QueryServer:
         ``join.capacity_rows``, ``join.overflowed``,
         ``join.overflow_rows``,
         ``groupby.groups``, ``groupby.in_place``, ``groupby.key_sorted``,
-        ``groupby.key_narrowed``, ``sort.prefix_sorted``,
+        ``groupby.key_narrowed``, ``groupby.rows_in``,
+        ``groupby.read_bytes``, ``groupby.capacity_groups``,
+        ``sort.prefix_sorted``,
         ``join.pk_violation``, ``groupby.overflowed``,
         ``groupby.key_out_of_range``, and of a groupby lowered over a mesh
         ``shuffle.exchanges``, ``shuffle.rows`` and ``shuffle.bytes``: the

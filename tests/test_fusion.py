@@ -25,6 +25,7 @@ Four invariant families:
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -711,3 +712,107 @@ def test_estimate_hbm_bytes_of_a_semi_join_plan():
     assert fusion._spaces(nodes)[id(nodes[-1])] is None     # six slots
     semi = next(n for n in nodes if isinstance(n, fusion.Join))
     assert fusion._spaces(nodes)[id(semi)] == "orders"
+
+
+# ---------------------------------------------------------------------------
+# a Filter over a GroupBy's output (HAVING), a Join whose build side is that
+# filter, and what a sort-path groupby says entered it
+# ---------------------------------------------------------------------------
+
+
+def _over_ten(groups: Table, least: int) -> jnp.ndarray:
+    total = groups.column(1)
+    return total.valid_mask() & (total.data > jnp.int64(least))
+
+
+def _having_tables(n: int, keys: int, seed: int = 3):
+    rng = np.random.default_rng(seed)
+    facts = Table([
+        Column.from_numpy(rng.integers(0, keys, n).astype(np.int64)),
+        Column.from_numpy(rng.integers(1, 9, n).astype(np.int64))])
+    dims = Table([
+        Column.from_numpy(rng.permutation(keys + 5).astype(np.int64)),
+        Column.from_numpy(rng.integers(0, 99, keys + 5).astype(np.int32))])
+    return facts, dims
+
+
+def _having_plan(bound, least: int = 10) -> fusion.Plan:
+    sums = fusion.GroupBy(fusion.Scan("facts"), (0,), ((1, "sum"),),
+                          max_groups=bound, label="sums")
+    having = fusion.Filter(sums, _over_ten, (least,), label="having")
+    return fusion.Plan("having_in", fusion.Join(
+        fusion.Scan("dims"), having, (0,), (0,), None, how="left_semi",
+        label="in_list"))
+
+
+@pytest.mark.parametrize("n, keys", [(1, 1), (17, 3), (700, 40), (5000, 900)])
+def test_filter_over_a_groupby_and_a_join_that_builds_on_it(n, keys):
+    """``WHERE k IN (SELECT k ... GROUP BY k HAVING sum(v) > 10)`` against
+    numpy, fused and staged: the HAVING counts the groups it saw (not the
+    rows of the groupby's bound), the semi join's build rows are the
+    groups it kept."""
+    facts, dims = _having_tables(n, keys)
+    k, v = (np.asarray(facts.column(i).data) for i in (0, 1))
+    sums = np.bincount(k, weights=v, minlength=keys).astype(np.int64)
+    seen = int((np.bincount(k, minlength=keys) > 0).sum())
+    kept = np.flatnonzero(sums > 10)
+    want = np.isin(np.asarray(dims.column(0).data), kept)
+    plan = _having_plan(fusion.groups_of("dims"))
+    b = {"facts": facts, "dims": dims}
+    fused = fusion.execute(plan, b)
+    staged = _staged(lambda: fusion.execute(plan, b))
+    for got in (fused, staged):
+        assert np.array_equal(
+            np.asarray(got.table.column(0).valid_mask()), want)
+        assert got.table.num_rows == keys + 5
+        meta = {key: int(val) for key, val in got.meta.items()}
+        assert meta["sums.num_groups"] == seen
+        assert meta["sums.capacity"] == keys + 6
+        assert meta["having.rows_in"] == seen          # not keys + 6
+        assert meta["having.rows_kept"] == kept.size
+        assert meta["in_list.build_rows"] == kept.size
+        assert meta["in_list.total"] == int(want.sum())
+        # what entered the groupby: a key and a value, a validity byte each
+        assert meta["sums.rows_in"] == n
+        assert meta["sums.read_bytes"] == n * 18
+    _assert_tables_identical(fused.table, staged.table)
+
+
+def test_groupby_facts_are_summed_and_counted_once_a_request():
+    """``meta_facts`` sums what the sort-path groupbys say entered them
+    and what their bounds have room for (a groupby with no bound states no
+    capacity), and the server counts each once a request."""
+    facts, dims = _having_tables(700, 40)
+    b = {"facts": facts, "dims": dims}
+    plan = _having_plan(64)
+    got = fusion.execute(plan, b)
+    found = fusion.meta_facts(plan, got.meta)
+    assert (found["groupby.rows_in"], found["groupby.read_bytes"],
+            found["groupby.capacity_groups"]) == (700, 700 * 18, 64)
+    unbounded = _having_plan(None)
+    loose = fusion.execute(unbounded, b)
+    assert "sums.capacity" not in loose.meta
+    assert fusion.meta_facts(unbounded, loose.meta)[
+        "groupby.capacity_groups"] == 0
+    # with no bound the groups' rows are the input's, still positional
+    assert int(loose.meta["having.rows_in"]) == 700
+    two = fusion.Plan("two_groupbys", fusion.GroupBy(
+        fusion.GroupBy(fusion.Scan("facts"), (0,), ((1, "sum"),),
+                       max_groups=2048, label="first"),
+        (1,), ((0, "count"),), max_groups=128, label="second"))
+    both = fusion.execute(two, b)
+    found = fusion.meta_facts(two, both.meta)
+    assert found["groupby.rows_in"] == 700 + 2048
+    assert found["groupby.read_bytes"] == (700 + 2048) * 18
+    assert found["groupby.capacity_groups"] == 2048 + 128
+    with QueryServer(budget_bytes=4 << 30) as srv:
+        for served in (1, 2):
+            ticket = srv.session("s").submit(
+                plan, {"facts": _having_tables(700, 40, seed=served)[0],
+                       "dims": dims})
+            assert ticket.result() is not None
+            counters = REGISTRY.counters()
+            assert counters["groupby.rows_in"] == 700 * served
+            assert counters["groupby.read_bytes"] == 700 * 18 * served
+            assert counters["groupby.capacity_groups"] == 64 * served
+            assert counters["filter.rows_in"] == 40 * served
