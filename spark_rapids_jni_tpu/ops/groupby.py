@@ -37,6 +37,7 @@ import numpy as np
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.ops.sort import (key_words, permute,
                                             sort_key_words, sort_order,
+                                            sort_order_and_form,
                                             words_in_order)
 from spark_rapids_jni_tpu.types import DType, TypeId, decimal128
 from spark_rapids_jni_tpu.utils.tracing import func_range
@@ -69,6 +70,11 @@ class GroupByResult(NamedTuple):
     # minimum and only a broken bound pays that sort, for the true count.
     # A fact of the data.
     key_sorted: jnp.ndarray | bool = False
+    # True when the word-moving path ordered a lone 64-bit key as ONE
+    # uint32 (``ops/sort.py _lone_key_order``: the keyed rows hold one high
+    # word and low words under 2**30 apart). A fact of the data; False for
+    # every other key and in place (``sort_key_words`` keeps its words).
+    key_one_word: jnp.ndarray | bool = False
 
     def compact(self) -> Table:
         """Host-side trim to the real group count."""
@@ -870,7 +876,7 @@ def _aggregate(row_args, rvs, scopes, *, keys, aggs,
     # steps as the data holds groups, and one), over it from the key sort,
     # whose cost does not go with the bound.
     by_loop = in_place and m <= _MIN_LOOP_M
-    key_sorted = False
+    key_sorted = key_one_word = False
     if in_place:
         read_col = {i: table.column(i) for i in data_at}
         read_mask = {i: table.column(i).validity for i in mask_at}
@@ -881,7 +887,8 @@ def _aggregate(row_args, rvs, scopes, *, keys, aggs,
         same = _words_equal_prev(sorted_words, rv)
     else:
         with jax.named_scope("key_sort"):
-            order = sort_order(table, keys, row_valid=rv)
+            order, key_one_word = sort_order_and_form(
+                table, keys, row_valid=rv)
         # Only what is read at every row comes into key order, as packed
         # words moved once (ops/sort.py ``permute``): the keys, the
         # operands of the aggregates that run over the rows, and the bare
@@ -1637,7 +1644,7 @@ def _aggregate(row_args, rvs, scopes, *, keys, aggs,
         out_cols.append(Column(c.dtype, red, vcount > 0))
 
     return GroupByResult(Table(out_cols), num_groups, overflowed,
-                         sum128_overflow, in_place, key_sorted)
+                         sum128_overflow, in_place, key_sorted, key_one_word)
 
 
 @func_range("groupby_aggregate")

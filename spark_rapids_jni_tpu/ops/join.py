@@ -83,7 +83,7 @@ import numpy as np
 
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.ops.sort import (
-    _split64, gather, positions_of, sort_order)
+    _split64, gather, one_word_span, positions_of, sort_order)
 from spark_rapids_jni_tpu.utils.tracing import func_range
 
 
@@ -161,16 +161,11 @@ def _merged_sort(left_key, left_valid, right_key, right_valid,
         return None, minor, place, narrowed
     (hi,) = major
     keyed = jnp.concatenate([left_valid, right_valid])
-    least = [jnp.min(jnp.where(keyed, w, jnp.uint32(0xFFFFFFFF)))
-             for w in (hi, minor)]
-    most = [jnp.max(jnp.where(keyed, w, jnp.uint32(0)))
-            for w in (hi, minor)]
-    # (with no keyed row at all every least lies above its most:
-    # the wide sort runs and decides nothing)
-    narrowed = (least[0] == most[0]) & (most[1] - least[1] < 1 << 31)
+    # (with no keyed row at all the wide sort runs and decides nothing)
+    narrowed, lo_least = one_word_span(hi, minor, keyed, 31)
     hi_changes, minor, place = jax.lax.cond(
         narrowed, _sorted_narrow, _sorted_wide,
-        hi, minor, place, least[1])
+        hi, minor, place, lo_least)
     return hi_changes, minor, place, narrowed
 
 

@@ -12,7 +12,10 @@ operand); wider keys are sorted one word at a time (``_radix_order``),
 because the TPU compiler's time for a variadic sort grows with about the
 square of its operand words (PERF.md section 6, PR 28). A 64-bit key
 whose values a plan declares to span 32 bits or fewer is narrowed before
-it gets here (``ops/planner.py narrow_group_keys``). Encoded keys also
+it gets here (``ops/planner.py narrow_group_keys``); a lone 64-bit key
+nobody declared anything about is sorted as ONE word where its values
+allow, a fact of the data decided inside the trace (``_lone_key_order``).
+Encoded keys also
 give Spark-compatible total float order (NaN sorts greatest, -0.0 == 0.0
 is NOT collapsed: -0.0 < 0.0 bitwise — documented deviation from Java's
 Double.compare only for -0.0).
@@ -60,6 +63,15 @@ def _as_unsigned_key(col_data: jnp.ndarray, dtype: DType) -> jnp.ndarray:
     # float64 never reaches here: _key_arrays routes it to the value-level
     # two-key encoding (no 64-bit bitcast on TPU).
     raise TypeError(f"unsupported sort key type {dtype}")
+
+
+def _is_int64(dtype: DType) -> bool:
+    """The type's storage is an 8-byte integer (``_key_arrays`` cuts it
+    into two words: int64, uint64, decimal64, the 64-bit timestamps)."""
+    if dtype.is_decimal128 or dtype.is_string:
+        return False
+    np_dt = dtype.storage_dtype
+    return np_dt.kind in "iu" and np_dt.itemsize == 8
 
 
 def _key_arrays(col: Column, ascending: bool, nulls_first: bool):
@@ -112,7 +124,7 @@ def _key_arrays(col: Column, ascending: bool, nulls_first: bool):
         # -(+inf) = -inf sorts first, matching Spark's NaN-greatest order.
         nan_rank = jnp.isnan(v)
         value_keys = [key, (~nan_rank if not ascending else nan_rank)]
-    elif np_dt.kind in "iu" and np_dt.itemsize == 8:
+    elif _is_int64(dtype):
         # a 64-bit integer key as its low and high 32-bit words (sign flip
         # on the high word): uint order on the pair is the 64-bit order,
         # with no emulated 64-bit compare, and _pack_lex_keys can fold
@@ -235,8 +247,10 @@ def _radix_order(words: list[jnp.ndarray]) -> jnp.ndarray:
     number of words. Every pass gathers its word by the running order
     (0.072 s a pass at 8,388,608 rows inside a region, beside 0.018 s for
     the sort itself), so it is for keys that are truly wider than two
-    words: several key columns, a 64-bit key with no declared range, the
-    sort of planned q3's result (a 64-bit revenue and a date)."""
+    words: several key columns, the sort of planned q3's result (a 64-bit
+    revenue and a date), and a lone 64-bit key with no declared range
+    only where its values straddle a high word or lie 2**30 or further
+    apart (``_lone_key_order``'s other branch)."""
     stacked = jnp.stack(words)
 
     def one_pass(i, order):
@@ -269,25 +283,97 @@ def _lex_keys(table: Table, keys, ascending, nulls_first, rv) -> tuple:
     return _pack_lex_keys(lex_keys), packable
 
 
+def one_word_span(hi: jnp.ndarray, lo: jnp.ndarray, keyed: jnp.ndarray,
+                  bits: int) -> tuple:
+    """Whether the 64-bit keys whose uint32 words are ``(hi, lo)`` order
+    as their low words alone, rebased, in ``bits`` bits: ``(scalar bool,
+    the least low word)`` over the rows ``keyed`` marks, a minimum and a
+    maximum of each word. True where those rows hold ONE high word
+    between them and low words less than ``2**bits`` apart; with no such
+    row every least lies above its most and it is False."""
+    least = [jnp.min(jnp.where(keyed, w, jnp.uint32(0xFFFFFFFF)))
+             for w in (hi, lo)]
+    most = [jnp.max(jnp.where(keyed, w, jnp.uint32(0)))
+            for w in (hi, lo)]
+    return ((least[0] == most[0]) & (most[1] - least[1] < 1 << bits),
+            least[1])
+
+
+# the bits of a lone 64-bit key's one-word form that hold its rebased low
+# word; the null rank and the row-valid rank stand above them
+_ONE_WORD_BITS = 30
+
+
+def _lone_key_order(lex_keys: list, keyed: jnp.ndarray) -> tuple:
+    """``(the stable order by a lone 64-bit integer key's lex keys, scalar
+    bool: it was sorted as ONE word)``. ``lex_keys`` are ``_key_arrays``'
+    encoded low word, high word and null rank and, with a row-valid mask,
+    its rank: 72 or 80 bits, three words for ``_radix_order``, each pass a
+    gather of a word by the running order. ``keyed`` marks the rows that
+    hold a key: valid, and real where a row-valid mask is given.
+
+    A fact of the data decides, inside the trace, by a ``lax.cond``
+    (``one_word_span``: two reductions over words the sort reads anyway).
+    Where the keyed rows hold one high word and low words less than 2**30
+    apart, the order is that of ONE uint32: the low word less the least
+    one in bits 0-29 (0 on a row without a key, as ``_key_arrays`` makes a
+    null's value), the null rank in bit 30, the row-valid rank in bit 31.
+    It is sorted with a 32-bit iota as its second key, so no two rows tie
+    and the sort need not be stable (a stable one gets a third operand
+    from XLA): two operands, no gather. Descending and unsigned keys are
+    the encoded words' business and need no case here; negative keys (one
+    high word too) take it as well. Otherwise (keys that straddle a high
+    word, keys further apart, no keyed row) ``_radix_order`` runs as ever.
+
+    On the real rows both forms give the same permutation, bit for bit:
+    stable, nulls where their rank puts them, ties in row order. Rows a
+    row-valid mask calls no rows rank after every real row in both; among
+    themselves the one-word form leaves them by null rank and row, not by
+    their stored bytes. Both callers that pass a mask merge or mask them
+    (``ops/groupby.py _aggregate``: they start no group; ``fusion.Sort``:
+    its output's mask is positional)."""
+    lo, hi, null_rank, *rv_rank = lex_keys
+    fits, lo_least = one_word_span(hi, lo, keyed, _ONE_WORD_BITS)
+
+    def one_word():
+        word = (jnp.where(keyed, lo - lo_least, jnp.uint32(0))
+                | (null_rank.astype(jnp.uint32) << _ONE_WORD_BITS))
+        for rank in rv_rank:
+            word = word | (rank.astype(jnp.uint32) << (_ONE_WORD_BITS + 1))
+        iota = jax.lax.iota(jnp.int32, word.shape[0])
+        return jax.lax.sort((word, iota), num_keys=2, is_stable=False)[1]
+
+    return jax.lax.cond(
+        fits, one_word, lambda: _radix_order(_pack_words(lex_keys))), fits
+
+
 def _sort_order_impl(row_args, aux, rvs, *, keys, ascending, nulls_first):
+    """``(order, scalar bool: a lone 64-bit key was sorted as one word)``;
+    the flag is False, a constant, for every key outside that gate."""
     ((table, row_valid),) = row_args
     rv = row_valid
     if rv is None and rvs is not None:
         rv = rvs[0]
     lex_keys, packable = _lex_keys(table, keys, ascending, nulls_first, rv)
+    one_word = jnp.zeros((), jnp.bool_)
     if len(lex_keys) == 1:
-        return jnp.argsort(lex_keys[0], stable=True).astype(jnp.int32)
+        return jnp.argsort(lex_keys[0], stable=True).astype(jnp.int32), one_word
     if packable and len(lex_keys) == 2:
         # 33 to 64 bits of key, null ranks and row-valid bit: one variadic
         # sort (a groupby key narrowed to its declared range lands here)
-        return _sort_words(lex_keys)[0]
+        return _sort_words(lex_keys)[0], one_word
+    if packable and len(keys) == 1 and table.num_rows and _is_int64(
+            table.column(keys[0]).dtype):
+        # known while tracing: one key column of 64-bit integers (low
+        # word, high word, null rank and row-valid bit never pack)
+        keyed = table.column(keys[0]).valid_mask()
+        return _lone_key_order(lex_keys, keyed if rv is None else keyed & rv)
     if packable:
         # wider than the two words one variadic sort takes well
-        return _radix_order(_pack_words(lex_keys))
-    return jnp.lexsort(tuple(lex_keys)).astype(jnp.int32)
+        return _radix_order(_pack_words(lex_keys)), one_word
+    return jnp.lexsort(tuple(lex_keys)).astype(jnp.int32), one_word
 
 
-@func_range("sort_order")
 def sort_order(
     table: Table,
     keys: Sequence[int],
@@ -297,7 +383,23 @@ def sort_order(
 ) -> jnp.ndarray:
     """Stable sort permutation (int32) ordering rows by the key columns.
     Rows where ``row_valid`` is False sort after every real row (used by
-    callers that carry phantom rows, e.g. bounded shuffles)."""
+    callers that carry phantom rows, e.g. bounded shuffles), in no
+    promised order among themselves."""
+    return sort_order_and_form(
+        table, keys, ascending, nulls_first, row_valid)[0]
+
+
+@func_range("sort_order")
+def sort_order_and_form(
+    table: Table,
+    keys: Sequence[int],
+    ascending: Sequence[bool] | None = None,
+    nulls_first: Sequence[bool] | None = None,
+    row_valid: jnp.ndarray | None = None,
+) -> tuple:
+    """``(sort_order(...), scalar bool)``: the permutation, and whether a
+    lone 64-bit key was sorted as one word (``_lone_key_order``: a fact of
+    the data; False for every other key)."""
     if ascending is None:
         ascending = [True] * len(keys)
     if nulls_first is None:

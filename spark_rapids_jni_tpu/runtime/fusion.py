@@ -219,7 +219,9 @@ class GroupBy(NamedTuple):
     word). Not with ``domains`` (the bounded lowering has its own
     dictionary).
     Side outputs land in the result meta under ``<label>.*``
-    (num_groups/overflowed/sum_overflow/in_place/key_sorted, with a range that
+    (num_groups/overflowed/sum_overflow/in_place/key_sorted/key_one_word,
+    the last whether a lone 64-bit key with no declared range was ordered
+    as one word, a fact of the data; with a range that
     narrowed a key also key_narrowed and key_out_of_range, which the
     served path refuses as it does ``pk_violation``; or
     present/domain_miss/lowered on the planned lowering). A sort-path
@@ -670,7 +672,8 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
                          f"{node.label}.overflowed",
                          f"{node.label}.sum_overflow",
                          f"{node.label}.in_place",
-                         f"{node.label}.key_sorted"]
+                         f"{node.label}.key_sorted",
+                         f"{node.label}.key_one_word"]
                 if placement and placement[id(node.child)] == SHARDED:
                     keys += [f"{node.label}.shuffle_rows",
                              f"{node.label}.shuffle_bytes"]
@@ -853,6 +856,8 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                      jnp.asarray(g.sum_overflow)),
                     (f"{node.label}.in_place", jnp.asarray(g.in_place)),
                     (f"{node.label}.key_sorted", jnp.asarray(g.key_sorted)),
+                    (f"{node.label}.key_one_word",
+                     jnp.asarray(g.key_one_word)),
                 ]
                 if resolved[id(node)] is None:
                     rv_out = rv   # padded to the input rows: still positional
@@ -1164,6 +1169,9 @@ def _mesh_groupby(node: GroupBy, tbl: Table, rv, bound, axis: str):
                 # whether a chip's partial sorted its key words to count
                 # the groups past its bound: a fact of the data
                 (f"{label}.key_sorted", anywhere(part.key_sorted)),
+                # whether a chip's partial ordered a lone 64-bit key as one
+                # word (``ops/sort.py``): a fact of the data as well
+                (f"{label}.key_one_word", anywhere(part.key_one_word)),
                 (f"{label}.shuffle_rows", jax.lax.psum(sent, axis)),
                 # what the all_to_all carries between chips (a chip keeps
                 # its own share): a fact of the partial's schema and the
@@ -1726,7 +1734,9 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     ``ops/groupby.py``), how many of those sorted their key words to count
     the groups past a broken bound (``groupby.key_sorted``: a fact of the
     data; a bound of 64 or fewer that holds finds its groups with no
-    sort), and how many grouped a key at the width of its
+    sort), how many ordered a lone 64-bit key nobody declared a range for
+    as ONE word (``groupby.key_one_word``: a fact of the data,
+    ``ops/sort.py _lone_key_order``), and how many grouped a key at the width of its
     declared range (``groupby.key_narrowed``: a fact of the lowering too,
     ``ops/planner.narrow_group_keys``), and how many sorts ordered the rows
     before their input's padding alone (``sort.prefix_sorted``: a fact of
@@ -1741,7 +1751,7 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
              "join.overflow_rows": 0,
              "join.pk_violation": 0, "groupby.groups": 0,
              "groupby.overflowed": 0, "groupby.in_place": 0,
-             "groupby.key_sorted": 0,
+             "groupby.key_sorted": 0, "groupby.key_one_word": 0,
              "groupby.key_narrowed": 0, "groupby.key_out_of_range": 0,
              "groupby.rows_in": 0, "groupby.read_bytes": 0,
              "groupby.capacity_groups": 0,
@@ -1780,8 +1790,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["groupby.groups"] += int(groups)
             facts["groupby.overflowed"] += bool(
                 meta.get(f"{node.label}.overflowed", False))
-            for fact in ("in_place", "key_sorted", "key_narrowed",
-                         "key_out_of_range"):
+            for fact in ("in_place", "key_sorted", "key_one_word",
+                         "key_narrowed", "key_out_of_range"):
                 facts[f"groupby.{fact}"] += bool(
                     meta.get(f"{node.label}.{fact}", False))
             for fact, field in (("rows_in", "rows_in"),

@@ -1077,7 +1077,7 @@ class QueryServer:
         ``join.capacity_rows``, ``join.overflowed``,
         ``join.overflow_rows``,
         ``groupby.groups``, ``groupby.in_place``, ``groupby.key_sorted``,
-        ``groupby.key_narrowed``, ``groupby.rows_in``,
+        ``groupby.key_one_word``, ``groupby.key_narrowed``, ``groupby.rows_in``,
         ``groupby.read_bytes``, ``groupby.capacity_groups``,
         ``sort.prefix_sorted``,
         ``join.pk_violation``, ``groupby.overflowed``,

@@ -426,6 +426,42 @@ def test_auto_grows_past_the_gate_and_says_so():
     _same_columns(res.table, want.table, 3000)
 
 
+@pytest.mark.parametrize("keys", ["one_high_word", "two_high_words"])
+@pytest.mark.parametrize("m", [1024, 1025])
+def test_a_lone_int64_key_in_place_keeps_its_words(m, keys):
+    """q13's ``custdist`` shape (a count of a count under 1,024 slots): in
+    place the rows are matched against the words ``sort_key_words`` sorted
+    by, so the key is never ordered as one word (``key_one_word`` False,
+    no conditional in the region) whatever its values; one group over the
+    gate the word-moving path orders the same key as ONE word where its
+    values allow, and both find the same groups."""
+    rng = np.random.default_rng(m)
+    n = 2 * 1024 * 32 + 500
+    pool = np.arange(37, dtype=np.int64) * 3 + (
+        2**32 - 50 if keys == "two_high_words" else 5)
+    table = Table([_col(t.INT64, pool[rng.integers(0, 37, n)],
+                        rng.random(n) > 0.05)])
+    plan = fusion.Plan("custdist_like", fusion.GroupBy(
+        fusion.Scan("t"), (0,), ((0, "count"),), max_groups=m,
+        label="custdist"))
+    res = fusion.execute(plan, {"t": table})
+    in_place = m <= gb._SMALL_M
+    assert bool(res.meta["custdist.in_place"]) == in_place
+    assert bool(res.meta["custdist.key_one_word"]) == (
+        not in_place and keys == "one_high_word")
+    facts = fusion.meta_facts(plan, res.meta)
+    assert facts["groupby.key_one_word"] == int(
+        res.meta["custdist.key_one_word"])
+    assert (" conditional(" in _region_hlo(plan, {"t": table})) == (
+        not in_place)
+    valid = np.asarray(table.column(0).valid_mask())
+    values, counts = np.unique(np.asarray(table.column(0).data)[valid],
+                               return_counts=True)
+    assert int(res.meta["custdist.num_groups"]) == 38      # and the nulls'
+    got = [c.to_pylist()[:38] for c in res.table.columns]
+    assert got == [[None] + values.tolist(), [0] + counts.tolist()]
+
+
 # -- through fusion.execute: the table and every side output the parent's --
 
 @pytest.fixture

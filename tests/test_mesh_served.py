@@ -358,6 +358,44 @@ def test_bounded_domain_groupby_lowers_as_partial_and_psum(mesh):
         assert np.array_equal(np.asarray(g.data), np.asarray(w.data))
 
 
+def _price_as_key(tbl, far):
+    """(the price, every seventh value ``far`` higher; the quantity)."""
+    price = tbl.column(tpch.L_EXTENDEDPRICE)
+    key = price.data + jnp.where(price.data % 7 == 0, far, 0)
+    return Table([Column(price.dtype, key), tbl.column(tpch.L_QUANTITY)])
+
+
+@pytest.mark.parametrize("far", [0, 2 ** 32], ids=["one_word", "wide"])
+def test_a_lone_int64_key_over_the_mesh_says_how_it_was_ordered(mesh, far):
+    """A key nobody declared a range for, grouped over sharded rows: a
+    chip's partial orders its rows' keys as ONE word where ITS rows allow
+    (``ops/sort.py _lone_key_order`` under ``shard_map``: the reductions
+    and the conditional are a chip's own) and the node reports whether any
+    chip did; with keys that straddle a high word none does. The groups
+    are the one-chip result's either way."""
+    plan = fusion.Plan(f"by_price_{far}", fusion.GroupBy(
+        fusion.Project(fusion.Scan("lineitem"), _price_as_key,
+                       params=(far,)), (0,),
+        ((1, "sum"), (1, "count")), max_groups=8192, label="by_price"))
+    _, sharded = _lineitem(6000, 49, mesh)
+    _, one = _lineitem(6000, 49)
+    ticket, got, moved = _serve(plan, sharded)
+    assert (ticket.tier, ticket.rung, ticket.steps) == ("fused", 0, 0)
+    assert moved["shuffle.exchanges"] == 1
+    assert bool(got.meta["by_price.key_one_word"]) == (far == 0)
+    assert moved.get("groupby.key_one_word", 0) == (far == 0)
+    _, want, one_moved = _serve(plan, one)
+    assert one_moved.get("groupby.key_one_word", 0) == (far == 0)
+    groups = int(want.meta["by_price.num_groups"])
+    assert int(got.meta["by_price.num_groups"]) == groups > 1024
+
+    def rows(res):
+        cols = [np.asarray(c.data)[:groups] for c in res.table.columns]
+        return sorted(zip(*(c.tolist() for c in cols)))
+
+    assert rows(got) == rows(want)
+
+
 def test_step_inside_a_callers_shard_map(mesh):
     """``q1_distributed_step`` is the plan seen by one chip, for a caller
     that builds its own program over a mesh (one that spans processes)."""

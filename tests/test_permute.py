@@ -209,7 +209,7 @@ def _sorted_pick_oracle(table, keys, aggs, max_groups, row_valid):
         return res
     order = np.asarray(so._sort_order_impl(
         ((table, row_valid),), None, None, keys=tuple(keys),
-        ascending=(True,) * len(keys), nulls_first=(True,) * len(keys)))
+        ascending=(True,) * len(keys), nulls_first=(True,) * len(keys))[0])
     sorted_tbl = so.gather(table, jnp.asarray(order))
     n = table.num_rows
     m = n if max_groups is None else max_groups

@@ -86,6 +86,96 @@ def test_sort_order_single_int64_key_matches_numpy_lexsort():
     assert got.tolist() == np.lexsort((b, a)).tolist()
 
 
+def _lone(np_dt, values, valid=None, row_valid=None, dtype=None):
+    values = np.asarray(values, dtype=np_dt)
+    return (dtype or (t.INT64 if np_dt == np.int64 else t.UINT64), values,
+            None if valid is None else np.asarray(valid, bool),
+            None if row_valid is None else np.asarray(row_valid, bool))
+
+
+def _lone_key_cases() -> dict:
+    """case -> ((dtype, values, valid, row_valid), sorted as one word)."""
+    rng = np.random.default_rng(49)
+    n = 300
+    sparse = rng.integers(1, 6_000_000, 80)[rng.integers(0, 80, n)]
+    nulls = rng.random(n) > 0.15
+    real = rng.random(n) > 0.2
+    far = np.where(rng.random(n) > 0.5, 2**62, -2**61)
+    base = 5 * 2**32 + 2**31        # low words that end at a word's end
+    span = np.r_[base, base + 2**30 - 1, rng.integers(
+        base, base + 2**30, n - 2)]
+    return {
+        "dbgen_sparse_order_keys": (_lone(np.int64, sparse), True),
+        "a_validity_mask_with_nulls": (
+            # (a null's stored bytes lie far outside the range)
+            _lone(np.int64, np.where(nulls, sparse, far), nulls), True),
+        "phantom_rows_far_outside_the_range": (
+            _lone(np.int64, np.where(real, sparse, far), None, real), True),
+        "nulls_and_phantoms": (
+            _lone(np.int64, np.where(real & nulls, sparse, far),
+                  nulls | ~real, real), True),
+        "negative_keys": (_lone(np.int64, -sparse), True),
+        "a_decimal64_key_around_zero_straddles_a_high_word": (
+            _lone(np.int64, sparse - 3_000_000, nulls,
+                  dtype=t.decimal64(-2)), False),
+        "uint64_keys_past_the_sign_bit": (
+            _lone(np.uint64, sparse.astype(np.uint64) + np.uint64(2**63),
+                  nulls), True),
+        "a_span_of_2**30_less_one": (_lone(np.int64, span), True),
+        "a_span_of_2**30": (
+            _lone(np.int64, np.r_[span[:-1], base + 2**30]), False),
+        "keys_that_straddle_a_high_word": (
+            _lone(np.int64, 2**32 + rng.integers(-5, 5, n)), False),
+        "a_phantoms_key_does_not_widen_the_span": (
+            # one high word among the rows that hold a key; a phantom
+            # whose validity reads True holds another
+            _lone(np.int64, np.r_[2**40, sparse[1:]], None,
+                  np.r_[False, np.ones(n - 1, bool)]), True),
+        "no_keyed_row": (_lone(np.int64, sparse, np.zeros(n, bool)), False),
+        "every_row_a_phantom": (
+            _lone(np.int64, sparse, None, np.zeros(n, bool)), False),
+        "one_row": (_lone(np.int64, [-7]), True),
+    }
+
+
+LONE_KEY = _lone_key_cases()
+
+
+@pytest.mark.parametrize("nulls_first", [True, False])
+@pytest.mark.parametrize("ascending", [True, False])
+@pytest.mark.parametrize("case", list(LONE_KEY))
+def test_a_lone_64_bit_key_sorts_as_one_word_where_the_data_allows(
+        case, ascending, nulls_first):
+    """``sort_order`` on one int64 / uint64 / decimal64 key: on the real
+    rows numpy's stable lexsort, bit for bit, whichever form ran, and the
+    form it reports: ONE word where the rows that hold a key hold one high
+    word and low words under 2**30 apart, the word loop otherwise."""
+    (dtype, values, valid, row_valid), one_word = LONE_KEY[case]
+    table = Table([Column(dtype, jnp.asarray(values),
+                          None if valid is None else jnp.asarray(valid))])
+    rv = None if row_valid is None else jnp.asarray(row_valid)
+    got, form = so.sort_order_and_form(
+        table, [0], [ascending], [nulls_first], row_valid=rv)
+    assert form.dtype == jnp.bool_ and form.shape == ()
+    assert bool(form) == one_word
+    # the oracle: a value's dense rank (a null's counts as equal), the null
+    # rank over it, the phantom rank over both
+    n = len(values)
+    valid = np.ones(n, bool) if valid is None else valid
+    rank = np.unique(values, return_inverse=True)[1].astype(np.int64)
+    rank = np.where(valid, rank if ascending else -rank, 0)
+    null_rank = valid if nulls_first else ~valid
+    phantom = np.zeros(n, bool) if row_valid is None else ~row_valid
+    want = np.lexsort((rank, null_rank, phantom))
+    # (phantom rows rank after every real row, in no promised order)
+    real = n - int(phantom.sum())
+    assert np.asarray(got).dtype == np.int32
+    assert np.asarray(got).tolist()[:real] == want.tolist()[:real]
+    assert np.asarray(so.sort_order(
+        table, [0], [ascending], [nulls_first], row_valid=rv)).tolist() == (
+            np.asarray(got).tolist())
+
+
 def test_pack_words_is_the_keys_bit_string():
     """Keys of 8, 32, 32, 8 and 8 bits are 88 bits: three words, the
     second key straddling the first two."""
@@ -342,6 +432,76 @@ def test_a_sort_outside_the_gate_lowers_as_it_always_has(case):
             "sort.prefix_sorted"])
 
 
+def _lone_key_table(n: int = 2000) -> Table:
+    rng = np.random.default_rng(9)
+    return Table([
+        Column(t.INT64, jnp.asarray(rng.integers(1, 300, n)),
+               jnp.asarray(rng.random(n) > 0.1)),
+        Column(t.INT32, jnp.asarray(rng.integers(0, 9, n, dtype=np.int32)))])
+
+
+def _order_hlo(keys) -> str:
+    def order(tb, rv):
+        return so._sort_order_impl(
+            ((tb, rv),), None, None, keys=keys,
+            ascending=(True,) * len(keys), nulls_first=(True,) * len(keys))
+
+    tbl = _lone_key_table()
+    return jax.jit(order).lower(
+        tbl, jnp.ones(tbl.num_rows, bool)).compile().as_text()
+
+
+def _ranged_groupby_hlo() -> str:
+    plan = fusion.Plan("ranged", fusion.GroupBy(
+        fusion.Scan("t"), (0,), ((1, "sum"),), max_groups=2048,
+        key_ranges=((1, 300),), label="groupby"))
+    return _region_hlo(plan, {"t": _lone_key_table()})
+
+
+def _sort_key_words_hlo() -> str:
+    tbl = _lone_key_table()
+    return jax.jit(lambda tb, rv: so.sort_key_words(tb, [0], rv)).lower(
+        tbl, jnp.ones(tbl.num_rows, bool)).compile().as_text()
+
+
+# what keeps a sort out of the lone 64-bit key's gate -> its lowered text
+OUTSIDE_THE_ONE_WORD_GATE = {
+    "two_key_columns": lambda: _order_hlo((0, 1)),
+    "an_int32_key": lambda: _order_hlo((1,)),
+    "a_groupby_key_with_a_declared_range": _ranged_groupby_hlo,
+    "sort_key_words_on_an_int64_key": _sort_key_words_hlo,
+}
+
+
+@pytest.mark.parametrize("case", list(OUTSIDE_THE_ONE_WORD_GATE))
+def test_a_key_outside_the_one_word_gate_lowers_as_it_always_has(case):
+    """No ``conditional`` anywhere in the lowered text: the gate is what a
+    trace knows (one key column, 64-bit integers, no declared range), so
+    every other sort is the parent's."""
+    assert " conditional(" not in OUTSIDE_THE_ONE_WORD_GATE[case]()
+
+
+def test_a_lone_int64_key_lowers_with_one_conditional_of_both_forms():
+    """The control: ONE conditional; one branch holds one sort of two
+    operands (the word, a 32-bit iota) and no loop, the other the word
+    loop with its one two-operand sort; neither a 64-bit operand."""
+    hlo = _order_hlo((0,))
+    assert hlo.count(" conditional(") == 1
+    n = _lone_key_table().num_rows
+    sorts = re.findall(
+        r'= (\([^)]*\)|\S+) sort\([^\n]*?op_name="([^"]*)"', hlo)
+    assert sorted(kind for kind, _ in sorts) == [
+        f"(u32[{n}]{{0}}, s32[{n}]{{0}})"] * 2, sorts
+    in_loop = ["/while/" in name for _, name in sorts]
+    assert sorted(in_loop) == [False, True], sorts
+    branches = {re.search(r"/cond/(branch_\d)_fun/", name).group(1)
+                for _, name in sorts}
+    assert branches == {"branch_0", "branch_1"}, sorts
+    # the taken form's branch gathers nothing: the word is built in place
+    assert not [g for g in re.findall(
+        r'gather\([^\n]*?op_name="([^"]*)"', hlo) if "/while/" not in g]
+
+
 def test_planned_q3_groupby_finds_its_bounds_by_one_compaction(moved_by):
     """The bounds of the 70,001 groups come from one sort of the
     group-start mask and a slice: under the node's scope no ``while``
@@ -410,7 +570,7 @@ def test_general_q1_sort_keeps_its_two_packed_words():
     def order(tb, rv):
         return so._sort_order_impl(
             ((tb, rv),), None, None, keys=(0, 1), ascending=(True, True),
-            nulls_first=(True, True))
+            nulls_first=(True, True))[0]
 
     rv = jnp.asarray(rng.random(n) > 0.2)
     hlo = jax.jit(order).lower(flags, rv).compile().as_text()

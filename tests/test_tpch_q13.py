@@ -207,6 +207,16 @@ def test_served_q13_equals_reference(server, case, monkeypatch):
         assert n % change["block"] and n > change["block"]
     if case == "plain":
         assert bool(result.meta["c_orders.key_narrowed"])
+        # a key with a declared range is never ordered as one word
+        # (``ops/sort.py _lone_key_order``). ``custdist`` is at these 451
+        # rows: under 2 * 1,024 * 32 they are no in-place groupby's, and
+        # the word-moving path orders the counts as one word (at the
+        # cell's 1,500,001 rows it is in place and reports 0:
+        # ``test_groupby_in_place.py``)
+        assert not bool(result.meta["c_orders.key_one_word"])
+        assert not bool(result.meta["custdist.in_place"])
+        assert bool(result.meta["custdist.key_one_word"])
+        assert moved["groupby.key_one_word"] == 1
         assert not bool(result.meta["outer.pk_violation"])
         assert int(result.meta["outer.total"]) == len(
             np.unique(orders["o_custkey"][kept]))

@@ -168,6 +168,18 @@ def _a_tie_at_the_cut(host):
     return {"rows": reference_q18.LIMIT}
 
 
+def _a_key_past_a_high_word(host):
+    """One heavy order's key lies past 2**32, in the lineitem batch and in
+    its orders row: the keys straddle a high word, so ``order_qty`` sorts
+    them word by word (``ops/sort.py _lone_key_order``'s other branch) and
+    the semi join's merged sort is the wide one; the same answer."""
+    key, far = _heavy(host, 12)[6], 2 ** 32 + 17
+    for table, column in (("lineitem", "l_orderkey"),
+                          ("orders", "o_orderkey")):
+        host[table][column][host[table][column] == key] = far
+    return {"in": far, "wide": True}
+
+
 # case -> what it does to the seeded tables (and what it says to check)
 CASES = {
     "sparse_keys_rolled_tables_permuted_customer": lambda host: (
@@ -178,6 +190,7 @@ CASES = {
     "a_duplicated_custkey_counts_twice": _a_duplicated_custkey,
     "no_heavy_order_but_the_seeds": lambda host: {},
     "over_a_hundred_with_a_tie_at_the_cut": _a_tie_at_the_cut,
+    "an_order_key_past_a_high_word": _a_key_past_a_high_word,
 }
 
 
@@ -215,6 +228,14 @@ def test_served_q18_equals_both_references(server, case):
     assert meta["order_qty.rows_in"] == ITEMS
     assert meta["order_qty.read_bytes"] == 18 * ITEMS
     assert not meta["order_qty.overflowed"] and not meta["order_qty.in_place"]
+    # dbgen's keys, one high word and under 2**30 apart, are ordered as
+    # ONE word; the five-key outer groupby never is (a fact of the data,
+    # counted once a request)
+    one_word = not said.get("wide", False)
+    assert meta["order_qty.key_one_word"] == one_word
+    assert meta["groupby.key_one_word"] == 0
+    assert moved.get("groupby.key_one_word", 0) == one_word
+    assert moved.get("join.key_narrowed", 0) == one_word
     # a HAVING sees the groups, not the bound's rows
     assert meta["having.rows_in"] == groups
     # (the null group is a group: heavy, it passes the HAVING, and as a
