@@ -22,7 +22,13 @@ countable events (telemetry counters ``dispatch.compile`` /
 ``dispatch.hit``; ``dispatch.padded_waste_bytes`` accounts the padding
 tax and ``dispatch.padded_copy_bytes`` the bytes of the padded copy
 itself; ``dispatch.compile_ms`` is the wall time spent lowering and
-compiling). JAX's persistent compilation cache makes a second process
+compiling). It happens in one place, ``_lower_and_compile``, whose span
+``dispatch.compile`` says where JAX spent the seconds (tracing, lowering,
+XLA's compile or the persistent cache's load: ``jax.monitoring``), what
+the executable needs of the HBM (``memory_analysis()``) and why a compile
+failed; the facts stay beside the executable in the cache, and every
+``dispatch.execute`` span repeats ``need_bytes`` / ``temp_bytes``. JAX's
+persistent compilation cache makes a second process
 start warm: it lives where ``JAX_COMPILATION_CACHE_DIR`` says, else at
 the fixed path ``utils/config.cache_dir()`` names (set once at package
 import), with JAX's own persistence thresholds.
@@ -40,7 +46,6 @@ Config knobs (utils/config.py): ``dispatch.enabled``,
 
 from __future__ import annotations
 
-import contextlib
 import math
 import threading
 import time
@@ -55,7 +60,7 @@ import numpy as np
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.runtime import faults, resilience
 from spark_rapids_jni_tpu.telemetry import spans
-from spark_rapids_jni_tpu.telemetry.events import record_compile_cache
+from spark_rapids_jni_tpu.telemetry.events import enabled as _telemetry_on
 from spark_rapids_jni_tpu.telemetry.registry import REGISTRY
 from spark_rapids_jni_tpu.types import TypeId
 from spark_rapids_jni_tpu.utils.config import get_option
@@ -71,7 +76,6 @@ __all__ = [
     "sharded_call",
     "pad_sharded",
     "mesh_fingerprint",
-    "stats",
     "clear",
 ]
 
@@ -335,37 +339,184 @@ def _signature(tree: Any) -> tuple:
 # ---------------------------------------------------------------------------
 
 
-@contextlib.contextmanager
-def _compile_timer(op: str):
-    """Wall time of one lower+compile into ``dispatch.compile_ms`` and
-    ``dispatch.compile_ms.<op>`` (a persistent-cache hit shows here as a
-    short compile)."""
-    t0 = time.perf_counter()
+# What JAX says of a compile while it runs (``jax.monitoring``, delivered
+# on the compiling thread), and what of it goes where: a duration's field
+# of the open compile, or the persistent cache's answer.
+_XLA_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_compiling = threading.local()   # .open: this thread's _OpenCompile, or None
+_listening = False               # the listener pair is registered once a process
+
+
+class _OpenCompile:
+    """What JAX has reported of the compile this thread has open."""
+
+    __slots__ = ("staged", "backend", "cache_load", "persistent")
+
+    def __init__(self) -> None:
+        # tracing and lowering, outermost first: [start, seconds, field].
+        # jit traces nest (``jnp.sort`` inside a region reports its own
+        # trace inside the region's), and a nested one is its parent's time
+        self.staged: list = []
+        self.backend = self.cache_load = 0.0
+        self.persistent: Optional[str] = None
+
+    def add(self, field: str, secs: float) -> None:
+        if field == "backend":
+            self.backend += secs
+        elif field == "cache_load":
+            self.cache_load += secs
+        else:
+            # an event arrives as its interval closes: one that began
+            # before those already held contains them
+            start = time.perf_counter() - secs
+            while self.staged and self.staged[-1][0] >= start:
+                self.staged.pop()
+            self.staged.append((start, secs, field))
+
+    def seconds(self, field: str) -> float:
+        return sum((secs for _, secs, f in self.staged if f == field), 0.0)
+
+
+def _on_xla_duration(event: str, secs: float, **_: Any) -> None:
+    open_ = getattr(_compiling, "open", None)
+    if open_ is not None and event in _XLA_EVENTS:
+        open_.add(_XLA_EVENTS[event], secs)
+
+
+def _on_xla_event(event: str, **_: Any) -> None:
+    open_ = getattr(_compiling, "open", None)
+    if open_ is not None and event in _XLA_EVENTS:
+        open_.persistent = _XLA_EVENTS[event]   # "hit" or "miss"
+
+
+def _listen() -> None:
+    """Register the listener pair, at the first compile of the process
+    (never at import). A JAX without ``jax.monitoring`` is counted once and
+    every compile goes on unobserved."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+        try:
+            from jax import monitoring
+
+            monitoring.register_event_duration_secs_listener(_on_xla_duration)
+            monitoring.register_event_listener(_on_xla_event)
+        except Exception:
+            REGISTRY.counter("dispatch.xla.no_listener").inc()
+
+
+def _memory_facts(executable: Any) -> dict:
+    """What the executable needs of one chip's HBM, by XLA's own buffer
+    assignment (``memory_analysis()``; over a mesh: one chip's share):
+    ``need_bytes`` is arguments + outputs + temporaries less what the
+    outputs alias of the arguments. Empty, and counted, where the backend
+    gives none."""
     try:
-        yield
-    finally:
-        ms = (time.perf_counter() - t0) * 1e3
-        REGISTRY.histogram("dispatch.compile_ms").observe(ms)
-        REGISTRY.histogram(f"dispatch.compile_ms.{op}").observe(ms)
+        m = executable.memory_analysis()
+        facts = {
+            "argument_bytes": int(m.argument_size_in_bytes),
+            "output_bytes": int(m.output_size_in_bytes),
+            "alias_bytes": int(m.alias_size_in_bytes),
+            "temp_bytes": int(m.temp_size_in_bytes),
+            "code_bytes": int(m.generated_code_size_in_bytes),
+        }
+    except Exception:
+        REGISTRY.counter("dispatch.xla.no_memory_analysis").inc()
+        return {}
+    facts["need_bytes"] = (facts["argument_bytes"] + facts["output_bytes"]
+                           + facts["temp_bytes"] - facts["alias_bytes"])
+    peak = int(getattr(m, "peak_memory_in_bytes", 0) or 0)
+    if peak > 0:
+        facts["peak_bytes"] = peak
+    return facts
+
+
+def _lower_and_compile(op: str, jitted: Any, *args: Any) -> tuple:
+    """The one place an executable is made: ``(executable, its memory
+    facts)`` of ``jitted`` for exactly ``args``, under the span
+    ``dispatch.compile``. Wall time goes into ``dispatch.compile_ms`` and
+    ``dispatch.compile_ms.<op>`` (a persistent-cache hit shows there as a
+    short compile). With telemetry on, the span also says where JAX spent
+    the seconds (``trace_s``, ``lower_s``, ``backend_s``, of which
+    ``cache_load_s`` reading the persistent cache, and ``persistent``:
+    ``hit`` / ``miss`` / ``unasked``), what the executable needs
+    (:func:`_memory_facts`) and, where lowering or compiling raised, why
+    (``error``, ``error_message``); the seconds also go to the process's
+    ``dispatch.xla.*`` counters, which outlive the ring. Raises what
+    lowering or compiling raises."""
+    _listen()
+    open_ = _OpenCompile() if _telemetry_on() else None
+    t0 = time.perf_counter()
+    with spans.child("dispatch.compile", op=op) as sp:
+        _compiling.open = open_
+        try:
+            executable = jitted.lower(*args).compile()
+        except BaseException as e:
+            sp.annotate(error=type(e).__name__, error_message=str(e)[:200])
+            raise
+        finally:
+            _compiling.open = None
+            ms = (time.perf_counter() - t0) * 1e3
+            REGISTRY.histogram("dispatch.compile_ms").observe(ms)
+            REGISTRY.histogram(f"dispatch.compile_ms.{op}").observe(ms)
+            if open_ is not None:
+                _account_compile(open_, sp)
+        facts = _memory_facts(executable)
+        sp.annotate(**facts)
+    return executable, facts
+
+
+def _account_compile(open_: _OpenCompile, sp: Any) -> None:
+    """One closed compile's seconds onto its span and into the process's
+    counters."""
+    trace, lower = open_.seconds("trace"), open_.seconds("lower")
+    if not (open_.staged or open_.backend or open_.persistent):
+        REGISTRY.counter("dispatch.xla.unobserved").inc()
+        return   # this JAX delivered none of _XLA_EVENTS
+    sp.annotate(trace_s=trace, lower_s=lower, backend_s=open_.backend,
+                cache_load_s=open_.cache_load,
+                persistent=open_.persistent or "unasked")
+    REGISTRY.counter("dispatch.xla.trace_lower_ns").inc(
+        int((trace + lower) * 1e9))
+    REGISTRY.counter("dispatch.xla.backend_ns").inc(int(open_.backend * 1e9))
+    REGISTRY.counter("dispatch.xla.cache_load_ns").inc(
+        int(open_.cache_load * 1e9))
+    if open_.persistent:
+        REGISTRY.counter(f"dispatch.xla.persistent_{open_.persistent}").inc()
+
+
+def _region_need(facts: dict) -> dict:
+    """What of an executable's facts its ``dispatch.execute`` span says."""
+    return {k: facts[k] for k in ("need_bytes", "temp_bytes") if k in facts}
 
 
 def _cache_lookup(key) -> tuple:
-    """Single-flight cache lookup: ``(compiled, leader_event)``.
+    """Single-flight cache lookup: ``(entry, leader_event)``.
 
-    ``compiled`` non-None means a cached executable (a hit — possibly
+    ``entry`` non-None is what :func:`_lower_and_compile` made for the
+    key, ``(executable, its memory facts)`` (a hit — possibly
     after waiting out another thread's in-flight compile of the same
-    key). ``compiled`` None means THIS caller is the compile leader for
+    key). ``entry`` None means THIS caller is the compile leader for
     ``key`` and holds ``leader_event``; it MUST finish with
-    ``_cache_store(key, compiled_or_None, leader_event)`` on every exit
+    ``_cache_store(key, entry_or_None, leader_event)`` on every exit
     path, or waiters park forever. A leader that fails (stores None)
     wakes the waiters, and the first to re-loop becomes the new leader —
     a failed compile never wedges the key.
     """
     while True:
         with _lock:
-            compiled = _EXEC_CACHE.get(key)
-            if compiled is not None:
-                return compiled, None
+            entry = _EXEC_CACHE.get(key)
+            if entry is not None:
+                return entry, None
             ev = _INFLIGHT.get(key)
             if ev is None:
                 ev = threading.Event()
@@ -374,11 +525,11 @@ def _cache_lookup(key) -> tuple:
         ev.wait()
 
 
-def _cache_store(key, compiled, ev: threading.Event) -> None:
+def _cache_store(key, entry, ev: threading.Event) -> None:
     """Publish the leader's result (or its failure) and release waiters."""
     with _lock:
-        if compiled is not None:
-            _EXEC_CACHE[key] = compiled
+        if entry is not None:
+            _EXEC_CACHE[key] = entry
         if _INFLIGHT.get(key) is ev:
             del _INFLIGHT[key]
     ev.set()
@@ -506,20 +657,20 @@ def call(
     key = (op, statics, donate_rows,
            _signature((padded, aux_args, row_valids)),
            jax.default_backend())
-    compiled, lead_ev = _cache_lookup(key)
-    if compiled is None:
+    entry, lead_ev = _cache_lookup(key)
+    if entry is None:
         def _compile():
             faults.fire("dispatch.compile", 0, op=op)
             jitted = (jax.jit(fn, donate_argnums=(0,)) if donate_rows
                       else jax.jit(fn))
-            with spans.child("dispatch.compile", op=op), \
-                    _compile_timer(op), warnings.catch_warnings():
+            with warnings.catch_warnings():
                 # backends without donation support (CPU) warn per
                 # donated buffer at lowering; the declaration is still
                 # honored where the platform implements it
                 warnings.filterwarnings(
                     "ignore", message="Some donated buffers were not usable")
-                return jitted.lower(padded, aux_args, row_valids).compile()
+                return _lower_and_compile(
+                    op, jitted, padded, aux_args, row_valids)
 
         # transient device faults retry under the shared policy; genuine
         # compile errors (non-transient) give up on attempt 1 and take the
@@ -527,30 +678,30 @@ def call(
         # on its own behalf
         exc = None
         try:
-            compiled, exc = resilience.retry_or_none(
+            entry, exc = resilience.retry_or_none(
                 op, _compile, seam="dispatch.compile", rung="host_fallback")
         finally:
             # publish (or publish the failure) on EVERY leader exit path:
             # a waiter parked on this key must never hang
-            _cache_store(key, compiled, lead_ev)
-        if compiled is None:
+            _cache_store(key, entry, lead_ev)
+        if entry is None:
             if exc is not None and not isinstance(exc, Exception):
                 raise exc  # KeyboardInterrupt etc: not dispatch's to absorb
             REGISTRY.counter("dispatch.compile_error").inc()
             return _inline(op, "compile_error", fn, row_args, aux_args)
         REGISTRY.counter("dispatch.compile").inc()
         REGISTRY.counter(f"dispatch.compile.{op}").inc()
-        record_compile_cache(f"dispatch:{op}", hit=False)
     else:
         REGISTRY.counter("dispatch.hit").inc()
         REGISTRY.counter(f"dispatch.hit.{op}").inc()
-        record_compile_cache(f"dispatch:{op}", hit=True)
+    compiled, facts = entry
 
     def _execute():
         faults.fire("dispatch.execute", 0, op=op)
         # host-side only: the span closes when the dispatch RETURNS (jax
-        # is async); it never forces a device sync
-        with spans.child("dispatch.execute", op=op):
+        # is async); it never forces a device sync. It says what HBM the
+        # executable needs, hit or compile (``need_bytes``, ``temp_bytes``)
+        with spans.child("dispatch.execute", op=op, **_region_need(facts)):
             return compiled(padded, aux_args, row_valids)
 
     out, exc = resilience.retry_or_none(
@@ -592,20 +743,18 @@ def compiled(op: str, fn: Callable, *args: Any,
     :func:`call` it raises what lowering or compiling raises: the caller
     owns the fallback."""
     key = (op, statics, _signature(args))
-    executable, lead_ev = _cache_lookup(key)
-    if executable is not None:
+    entry, lead_ev = _cache_lookup(key)
+    if entry is not None:
         REGISTRY.counter("dispatch.hit").inc()
         REGISTRY.counter(f"dispatch.hit.{op}").inc()
-        return executable
+        return entry[0]
     try:
-        with spans.child("dispatch.compile", op=op), _compile_timer(op):
-            executable = jax.jit(fn).lower(*args).compile()
+        entry = _lower_and_compile(op, jax.jit(fn), *args)
     finally:
-        _cache_store(key, executable, lead_ev)
+        _cache_store(key, entry, lead_ev)
     REGISTRY.counter("dispatch.compile").inc()
     REGISTRY.counter(f"dispatch.compile.{op}").inc()
-    record_compile_cache(f"dispatch:{op}", hit=False)
-    return executable
+    return entry[0]
 
 
 def rowwise(
@@ -708,20 +857,19 @@ def sharded_call(
         return build()(*args)
     key = (op, ("sharded", cfg) + tuple(statics),
            _signature(args), jax.default_backend())
-    compiled, lead_ev = _cache_lookup(key)
-    if compiled is None:
+    entry, lead_ev = _cache_lookup(key)
+    if entry is None:
         def _compile():
             faults.fire("dispatch.compile", 0, op=op)
-            with spans.child("dispatch.compile", op=op), _compile_timer(op):
-                return jax.jit(build()).lower(*args).compile()
+            return _lower_and_compile(op, jax.jit(build()), *args)
 
         exc = None
         try:
-            compiled, exc = resilience.retry_or_none(
+            entry, exc = resilience.retry_or_none(
                 op, _compile, seam="dispatch.compile", rung="host_fallback")
         finally:
-            _cache_store(key, compiled, lead_ev)
-        if compiled is None:
+            _cache_store(key, entry, lead_ev)
+        if entry is None:
             if exc is not None and not isinstance(exc, Exception):
                 raise exc
             REGISTRY.counter("dispatch.compile_error").inc()
@@ -730,15 +878,14 @@ def sharded_call(
             return build()(*args)
         REGISTRY.counter("dispatch.compile").inc()
         REGISTRY.counter(f"dispatch.compile.{op}").inc()
-        record_compile_cache(f"dispatch:{op}", hit=False)
     else:
         REGISTRY.counter("dispatch.hit").inc()
         REGISTRY.counter(f"dispatch.hit.{op}").inc()
-        record_compile_cache(f"dispatch:{op}", hit=True)
+    compiled, facts = entry
 
     def _execute():
         faults.fire("dispatch.execute", 0, op=op)
-        with spans.child("dispatch.execute", op=op):
+        with spans.child("dispatch.execute", op=op, **_region_need(facts)):
             return compiled(*args)
 
     out, exc = resilience.retry_or_none(
@@ -756,26 +903,6 @@ def sharded_call(
 # ---------------------------------------------------------------------------
 # introspection
 # ---------------------------------------------------------------------------
-
-
-def stats() -> dict:
-    """Aggregate dispatch counters for the bench ``dispatch`` block."""
-    c = REGISTRY.counters("dispatch.")
-    compiles = c.get("dispatch.compile", 0)
-    hits = c.get("dispatch.hit", 0)
-    total_bytes = c.get("dispatch.row_bytes_total", 0)
-    waste = c.get("dispatch.padded_waste_bytes", 0)
-    return {
-        "calls": c.get("dispatch.calls", 0),
-        "compiles": compiles,
-        "hits": hits,
-        "hit_rate": hits / max(1, hits + compiles),
-        "inline": c.get("dispatch.inline", 0),
-        "padded_waste_bytes": waste,
-        "padded_waste_frac": (waste / total_bytes) if total_bytes else 0.0,
-        "donated_bytes": c.get("dispatch.donated_bytes", 0),
-        "executables": cache_size(),
-    }
 
 
 def cache_size() -> int:

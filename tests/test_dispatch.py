@@ -272,9 +272,10 @@ def test_disabled_dispatch_never_compiles(rng):
 def test_padded_waste_accounted(rng):
     col = Column.from_numpy(np.arange(17, dtype=np.int64))  # bucket 32
     red.sum_(col)
-    stats = dispatch.stats()
-    assert stats["padded_waste_bytes"] > 0
-    assert 0.0 < stats["padded_waste_frac"] < 1.0
+    c = REGISTRY.counters("dispatch.")
+    assert c["dispatch.padded_waste_bytes"] > 0
+    assert 0.0 < (c["dispatch.padded_waste_bytes"]
+                  / c["dispatch.row_bytes_total"]) < 1.0
 
 
 # ---------------------------------------------------------------------------
@@ -543,3 +544,228 @@ def test_a_pad_that_fails_runs_the_op_inline(rng, monkeypatch):
     c = REGISTRY.counters("dispatch.")
     assert (c["dispatch.pad_error"], c["dispatch.inline.pad_error"]) == (1, 1)
     assert c["dispatch.pad.jitted"] == 1   # the first call's
+
+
+# ---------------------------------------------------------------------------
+# 5. the compile seam: one span a compile, with where its seconds went,
+#    what the executable needs, and why it failed
+# ---------------------------------------------------------------------------
+
+_MEMORY = ("argument_bytes", "output_bytes", "alias_bytes", "temp_bytes",
+           "code_bytes", "need_bytes")
+_SECONDS = ("trace_s", "lower_s", "backend_s", "cache_load_s")
+
+
+@pytest.fixture
+def observed():
+    from spark_rapids_jni_tpu import telemetry
+
+    set_option("telemetry.enabled", True)
+    telemetry.drain()
+    yield telemetry
+    telemetry.drain()
+    reset_option("telemetry.enabled")
+
+
+def _records(telemetry, op):
+    return [r for r in telemetry.events()
+            if r.get("kind") == "span" and r["op"] == op]
+
+
+def _scaled(rows, aux, row_valid):
+    import jax.numpy as jnp
+
+    return jnp.cumsum(rows[0] * 3)
+
+
+def _via_call(x):
+    return dispatch.call("seam_call", _scaled, (x,), bucket_rows=False)
+
+
+def _via_compiled(x):
+    return dispatch.compiled("seam_compiled", lambda v: v * 3, x)(x)
+
+
+def _via_sharded_call(x):
+    return dispatch.sharded_call(
+        "seam_sharded", lambda: (lambda v: v * 3), (x,))
+
+
+@pytest.mark.parametrize("run, op, executes", [
+    (_via_call, "seam_call", True), (_via_compiled, "seam_compiled", False),
+    (_via_sharded_call, "seam_sharded", True)],
+    ids=["call", "compiled", "sharded_call"])
+def test_a_compile_leaves_one_span_with_its_facts(run, op, executes,
+                                                  observed):
+    """Each of the three ways in goes through ``_lower_and_compile``: ONE
+    ``dispatch.compile`` record an executable (``call`` makes its pad's
+    first) with XLA's buffer assignment and JAX's own seconds, the facts
+    kept beside the executable, and the need repeated on every
+    ``dispatch.execute``, compile or hit."""
+    import jax.numpy as jnp
+
+    from spark_rapids_jni_tpu.telemetry import spans
+
+    x = jnp.arange(48, dtype=jnp.int64)
+    with spans.span("query.seam"):
+        run(x)
+        run(x + 1)   # a hit
+    c = REGISTRY.counters("dispatch.")
+    *pads, rec = _records(observed, "dispatch.compile")
+    assert len(pads) == c.get("dispatch.compile.pad", 0) == (op == "seam_call")
+    assert (c[f"dispatch.compile.{op}"], c[f"dispatch.hit.{op}"]) == (1, 1)
+    assert rec["status"] == "ok" and "error" not in rec
+    assert all(isinstance(rec[k], int) for k in _MEMORY)
+    assert rec["need_bytes"] == (rec["argument_bytes"] + rec["output_bytes"]
+                                 + rec["temp_bytes"] - rec["alias_bytes"])
+    assert rec["argument_bytes"] >= x.nbytes
+    assert all(rec[k] >= 0 for k in _SECONDS)
+    assert rec["trace_s"] > 0 and rec["lower_s"] > 0
+    assert rec["trace_s"] + rec["lower_s"] + rec["backend_s"] <= (
+        rec["t1"] - rec["t0"])
+    assert rec["cache_load_s"] <= rec["backend_s"]
+    assert rec["persistent"] in ("hit", "miss", "unasked")
+    ((_, facts),) = [v for k, v in dispatch._EXEC_CACHE.items() if k[0] == op]
+    assert {k: rec[k] for k in _MEMORY} == {k: facts[k] for k in _MEMORY}
+    ran = _records(observed, "dispatch.execute")
+    assert len(ran) == (2 if executes else 0)
+    assert all((r["need_bytes"], r["temp_bytes"]) == (
+        facts["need_bytes"], facts["temp_bytes"]) for r in ran)
+    assert c["dispatch.xla.trace_lower_ns"] == sum(
+        int((r["trace_s"] + r["lower_s"]) * 1e9) for r in pads + [rec])
+    assert c["dispatch.xla.backend_ns"] == sum(
+        int(r["backend_s"] * 1e9) for r in pads + [rec])
+    assert not any(r["kind"] == "compile_cache" for r in observed.events())
+    assert "compile_cache.hit" not in REGISTRY.counters()
+
+
+def test_two_threads_compiling_at_once_keep_their_own_seconds(observed):
+    """The listener adds to the compile open on ITS thread: a slow trace on
+    one thread is not the other's, whose whole compile ran meanwhile."""
+    import threading
+    import time
+
+    import jax.numpy as jnp
+
+    from spark_rapids_jni_tpu.telemetry import spans
+
+    tracing, errors = threading.Event(), []
+
+    def slow(v):
+        tracing.set()
+        time.sleep(0.6)    # inside the trace of "seam_slow"
+        return v + 1
+
+    def compile_on_a_thread(op, fn, wait):
+        try:
+            assert wait is None or wait.wait(30)
+            with spans.span(f"query.{op}"):
+                dispatch.compiled(op, fn, jnp.arange(8))
+        except BaseException as exc:  # noqa: B036 - surfaced to the test
+            errors.append(exc)
+
+    threads = [
+        threading.Thread(target=compile_on_a_thread,
+                         args=("seam_slow", slow, None)),
+        threading.Thread(target=compile_on_a_thread,
+                         args=("seam_quick", lambda v: v * 2, tracing))]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(60)
+        assert not th.is_alive()
+    assert not errors
+    slow_rec, quick_rec = sorted(_records(observed, "dispatch.compile"),
+                                 key=lambda r: -r["trace_s"])
+    assert slow_rec["trace_s"] >= 0.6 > quick_rec["trace_s"]
+    # the quick one closed inside the slow one's trace: at once, not after
+    assert slow_rec["t0"] < quick_rec["t1"] < slow_rec["t1"]
+    assert getattr(dispatch._compiling, "open", None) is None
+
+
+def test_a_compile_that_raises_leaves_failed_with_its_reason(observed):
+    import jax.numpy as jnp
+
+    from spark_rapids_jni_tpu.telemetry import spans
+
+    def bad(v):
+        raise ValueError("no such lowering: " + "x" * 300)
+
+    with spans.span("query.seam"):
+        with pytest.raises(ValueError):
+            dispatch.compiled("seam_bad", bad, jnp.arange(4))
+        # ``call`` answers inline, and the ring still says why
+        out = dispatch.call(
+            "seam_bad_call", lambda rows, aux, valid: bad(rows)
+            if valid is not None else rows[0] + 1, (jnp.arange(4),))
+    assert out.tolist() == [1, 2, 3, 4]
+    first, pad, second = _records(observed, "dispatch.compile")
+    assert pad["status"] == "ok" and pad["need_bytes"] > 0
+    for rec in (first, second):
+        assert rec["status"] == "failed" and rec["error"] == "ValueError"
+        assert rec["error_message"] == ("no such lowering: " + "x" * 300)[:200]
+        assert "need_bytes" not in rec and rec["trace_s"] >= 0
+    assert REGISTRY.counters()["dispatch.compile_error"] == 1
+    assert dispatch.cache_size() == 1   # the pad's; nothing failed is kept
+    assert getattr(dispatch._compiling, "open", None) is None
+
+
+def test_with_telemetry_off_nothing_is_written_and_the_facts_are_kept():
+    import jax.numpy as jnp
+
+    from spark_rapids_jni_tpu import telemetry
+    from spark_rapids_jni_tpu.telemetry import spans
+
+    assert not telemetry.enabled()
+    telemetry.drain()
+    with spans.span("query.seam"):
+        _via_call(jnp.arange(48, dtype=jnp.int64))
+    assert telemetry.events() == []
+    assert not REGISTRY.counters("dispatch.xla.")
+    ((_, facts),) = [v for k, v in dispatch._EXEC_CACHE.items()
+                     if k[0] == "seam_call"]
+    assert facts["need_bytes"] == (
+        facts["argument_bytes"] + facts["output_bytes"]
+        + facts["temp_bytes"] - facts["alias_bytes"]) > 0
+    assert REGISTRY.counters()["dispatch.compile.seam_call"] == 1
+
+
+_PERSISTENT_PROBE = """
+import jax.numpy as jnp
+from spark_rapids_jni_tpu import telemetry
+from spark_rapids_jni_tpu.runtime import dispatch
+from spark_rapids_jni_tpu.utils.config import set_option
+set_option("telemetry.enabled", True)
+with telemetry.spans.span("query.probe"):
+    dispatch.compiled("probe", lambda v: jnp.cumsum(v) * 2, jnp.arange(64))
+(rec,) = [r for r in telemetry.events() if r["op"] == "dispatch.compile"]
+xla = telemetry.REGISTRY.counters("dispatch.xla.persistent_")
+print("PROBE", rec["persistent"], rec["cache_load_s"] > 0, sorted(xla.items()))
+"""
+
+
+def test_a_second_process_reads_the_persistent_cache_as_a_hit(tmp_path):
+    """The running test process initialised its cache long ago, so two
+    children against a cache directory of their own: the first writes
+    (``miss``), the second loads (``hit``, with the load's seconds)."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    said = []
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", _PERSISTENT_PROBE],
+                             cwd=root, env=env, capture_output=True,
+                             text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        said.append([ln for ln in out.stdout.splitlines()
+                     if ln.startswith("PROBE")][-1])
+    assert said == [
+        "PROBE miss False [('dispatch.xla.persistent_miss', 1)]",
+        "PROBE hit True [('dispatch.xla.persistent_hit', 1)]"]

@@ -1124,7 +1124,7 @@ def test_region_module_is_named_after_the_plan():
     tell one plan's region from another's."""
     plan, bindings = _q1_bindings(600)
     fusion.execute(plan, bindings)
-    (compiled,) = [v for k, v in dispatch._EXEC_CACHE.items()
+    (compiled,) = [exe for k, (exe, _) in dispatch._EXEC_CACHE.items()
                    if k[0] == "fusion.tpch_q1"]
     head = compiled.as_text().split("\n", 1)[0]
     assert "jit_region_tpch_q1" in head, head
