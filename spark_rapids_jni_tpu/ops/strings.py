@@ -29,6 +29,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from spark_rapids_jni_tpu import telemetry
 from spark_rapids_jni_tpu.columnar import Column
@@ -363,7 +364,8 @@ def like(col: Column, pattern: str, escape: str = "\\") -> Column:
     """SQL LIKE: '%' any run, '_' any single CHARACTER, escape char
     literal-izes the next char. Compiled to a literal-segment plan
     evaluated with vectorized window matches + a per-gap reachability
-    scan — no regex engine, no per-row host work.
+    step ('%': one reduction to the first position it can start from)
+    — no regex engine, no per-row host work.
 
     '_' advances one UTF-8 CHARACTER (Spark semantics) via character-
     boundary tracking; '%' and literals are byte-exact for any UTF-8
@@ -426,11 +428,13 @@ def like(col: Column, pattern: str, escape: str = "\\") -> Column:
     n, block = p.size, _LIKE_BLOCK_ROWS
     if n <= block:
         return _bool8_result(_like_rows(p.chars, p.data, *plan), col)
-    # Row blocks: every step below makes (rows, w + 1) booleans, a byte
-    # each, and every '%' is a scan of seven levels along them, some twenty
-    # such arrays alive at a time. Over 16,777,216 rows of 79 bytes one is
-    # 1.3 GB; a block's are 5 MB each. The rows stand alone, so the blocks'
-    # answers side by side are the column's.
+    # Row blocks: a literal segment is a window of (rows, w) booleans over a
+    # shifted slice of the bytes for each byte of the needle, and a '_' gap
+    # or an anchored segment some twenty (rows, w + 1) booleans more; a '%'
+    # between literals is one int32 a row (``_like_rows``' ``first``). Over
+    # 16,777,216 rows of 79 bytes one such array is 1.3 GB; a block's are
+    # 5 MB each. The rows stand alone, so the blocks' answers side by side
+    # are the column's.
     full = n // block
     chars, lengths = p.chars, p.data
     hit = jax.lax.map(
@@ -445,8 +449,11 @@ def like(col: Column, pattern: str, escape: str = "\\") -> Column:
 
 # Rows a step of ``like``'s loop over a long column: large enough that the
 # loop's 256 steps at 16,777,216 rows are no cost beside a step's work
-# (0.8 ms each on a v5e, PERF.md section 5, PR 38), small enough that a
-# step's twenty-odd temporaries (5 MB each at 79 bytes a row) leave the
+# (0.18 ms each on a v5e for '%special%requests%', PERF.md section 5, PR
+# 51; 0.83 while each '%' was a scan), small enough that a step's
+# temporaries (q13's: the block and fourteen shifted slices of it, 5 MB
+# each at 79 bytes a row, no (rows, w + 1) booleans at all; twenty-odd of
+# those where a pattern holds a '_' or an anchored segment) leave the
 # chip's 16 GB to the tables. The chip holds uint8[n, 79] column-major, 80
 # bytes a row, and XLA copies it into the loop's [80, blocks, rows] order
 # once before the loop: that copy is the column's size whatever the block.
@@ -459,6 +466,14 @@ def _like_rows(chars: jnp.ndarray, lengths: jnp.ndarray, segs: tuple,
     ``lengths`` int32[n]) match ``like``'s compiled pattern: literal
     ``segs``, each after its gap ``(single characters, saw %)``, and the
     gap after the last."""
+    if any(floating for _, floating in gaps):
+        # The bytes with the rows along the lanes and a position a slab, as
+        # the column rests in HBM: a needle's byte shifts then move whole
+        # slabs. Left to itself XLA:TPU lays a block's 79 positions along
+        # the 128 lanes once no scan along them stands in its way, every
+        # shift becomes a lane shift of the block, and q13's predicate
+        # takes 0.145 s a request for 0.050 (PERF.md section 6, PR 51).
+        chars = with_layout_constraint(chars, Layout(major_to_minor=(1, 0)))
     p = Column(STRING, lengths, None, chars=chars)
     n = p.size
     w = int(p.chars.shape[1])
@@ -494,22 +509,44 @@ def _like_rows(chars: jnp.ndarray, lengths: jnp.ndarray, segs: tuple,
         def advance_chars(r, k):  # pragma: no cover - zero-count gaps
             return r
 
-    # reach[j] True: pattern consumed so far can end exactly at byte j
+    # reach[j] True: pattern consumed so far can end exactly at byte j. After
+    # a floating gap reach is its own prefix-or, which one number a row says
+    # whole: ``first``, the least position set (w + 1: none), reach[j] being
+    # j >= first. It is carried as that int32[n] while the pattern allows
+    # (``first is not None``), and spread into booleans only for a step that
+    # needs them: a '_' gap, an anchored tail.
     reach = jnp.zeros((n, w + 1), jnp.bool_).at[:, 0].set(True)
-    for seg, (mincnt, floating) in zip(segs, gaps):
+    # nothing consumed yet ends at byte 0: a leading '%' floats from there
+    first = jnp.zeros((n,), jnp.int32) if gaps[0] == (0, True) else None
+    after = gaps[1:] + (tail_gap,)  # the gap that follows each segment
+    for seg, (mincnt, floating), nxt in zip(segs, gaps, after):
         # gap: advance exactly mincnt chars (then any amount if floating)
-        if mincnt:
-            reach = advance_chars(reach, mincnt)
-        reach = reach & (jdx[None, :] <= p.data[:, None])
-        if floating:
-            reach = jax.lax.associative_scan(jnp.logical_or, reach, axis=1)
-        if seg:
-            win = _needle_windows(p, seg)  # (n, w): match starting at j
-            ok_start = jnp.concatenate(
-                [win, jnp.zeros((n, 1), jnp.bool_)], axis=1)
-            moved = jnp.roll(reach & ok_start, len(seg), axis=1)
-            reach = moved & (jdx[None, :] >= len(seg))
+        if first is None:
+            if mincnt:
+                reach = advance_chars(reach, mincnt)
+            reach = reach & (jdx[None, :] <= p.data[:, None])
+            if floating:
+                first = _first_set(reach, w + 1)
+        if not seg:
+            continue
+        win = _needle_windows(p, seg)  # (n, w): match starting at j
+        if first is not None and nxt == (0, True):
+            # between two floating gaps only the leftmost match matters: it
+            # ends at first + len(seg) <= the row's length, or past w + 1
+            first = _first_set(win & (jdx[:w][None, :] >= first[:, None]),
+                               w + 1) + len(seg)
+            continue
+        if first is not None:
+            reach, first = jdx[None, :] >= first[:, None], None
+        ok_start = jnp.concatenate(
+            [win, jnp.zeros((n, 1), jnp.bool_)], axis=1)
+        moved = jnp.roll(reach & ok_start, len(seg), axis=1)
+        reach = moved & (jdx[None, :] >= len(seg))
     mincnt, floating = tail_gap
+    if first is not None:
+        if tail_gap == (0, True):
+            return first <= p.data
+        reach = jdx[None, :] >= first[:, None]
     if mincnt:
         reach = advance_chars(reach, mincnt)
     reach = reach & (jdx[None, :] <= p.data[:, None])
@@ -519,6 +556,14 @@ def _like_rows(chars: jnp.ndarray, lengths: jnp.ndarray, segs: tuple,
         hit = jnp.take_along_axis(
             reach, jnp.clip(p.data, 0, w)[:, None], axis=1)[:, 0]
     return hit
+
+
+def _first_set(mask: jnp.ndarray, none: int) -> jnp.ndarray:
+    """int32[n]: the least position set in a row of ``mask``, ``none``
+    where no position is."""
+    jdx = jnp.arange(mask.shape[1], dtype=jnp.int32)
+    return jnp.min(jnp.where(mask, jdx[None, :], jnp.int32(none)), axis=1,
+                   initial=none)
 
 
 # ---- transforms ------------------------------------------------------------

@@ -385,6 +385,97 @@ def test_like_multibyte_vs_regex_oracle(rng):
             assert g == want, (v, pat, g, want)
 
 
+def _like_rows_numpy(chars, lengths, segs, gaps, tail_gap):
+    """``_like_rows``' compiled plan evaluated plainly: the reachable end
+    positions as booleans, a floating gap as ``np.logical_or.accumulate``."""
+    n, w = chars.shape
+    jdx = np.arange(w + 1)
+    within = jdx[None, :] <= lengths[:, None]
+    edge = np.ones((n, 1), bool)
+    is_b = np.concatenate(
+        [edge, (chars[:, 1:] & 0xC0) != 0x80, edge], axis=1)
+    upto = np.maximum.accumulate(np.where(is_b, jdx[None, :], -1), axis=1)
+    prev_b = np.concatenate([np.full((n, 1), -1), upto[:, :-1]], axis=1)
+
+    def advance(reach, chars_on):
+        for _ in range(chars_on):
+            reach = is_b & (prev_b >= 0) & np.take_along_axis(
+                reach, np.clip(prev_b, 0, w), axis=1)
+        return reach
+
+    reach = np.zeros((n, w + 1), bool)
+    reach[:, 0] = True
+    for seg, (mincnt, floating) in zip(segs, gaps):
+        reach = advance(reach, mincnt) & within
+        if floating:
+            reach = np.logical_or.accumulate(reach, axis=1)
+        if seg:
+            f = len(seg)
+            moved = np.zeros_like(reach)
+            for j in range(w + 1 - f):
+                moved[:, j + f] = reach[:, j] & (j + f <= lengths) & np.all(
+                    chars[:, j:j + f] == np.frombuffer(seg, np.uint8), axis=1)
+            reach = moved
+    mincnt, floating = tail_gap
+    reach = advance(reach, mincnt) & within
+    if floating:
+        return reach.any(axis=1)
+    return reach[np.arange(n), lengths]
+
+
+@pytest.mark.parametrize(
+    "pattern", ["%a%b%", "%a_b%", "a%b", "%a%_", "%本%c", "%%a"])
+def test_like_rows_equal_the_plain_scan_of_the_same_plan(
+        pattern, rng, monkeypatch):
+    """A floating gap carried as its first position answers what the
+    prefix-or along the positions answers: before a literal, before a '_'
+    gap, before an anchored tail, around a multibyte literal; rows shorter
+    than the needle, empty rows and rows of the full width among them."""
+    alphabet = list("ab本c")
+    vals = ["".join(rng.choice(alphabet, size=int(rng.integers(0, 9))))
+            for _ in range(400)]
+    vals += ["", "", "a", "b", "本", "ab", "abababab", "本本本本本本本本"]
+    calls = []
+    rows = s._like_rows
+
+    def spy(chars, lengths, *plan):
+        calls.append((np.asarray(chars), np.asarray(lengths), plan))
+        return rows(chars, lengths, *plan)
+
+    monkeypatch.setattr(s, "_like_rows", spy)
+    got = np.asarray(s.like(Column.from_pylist(vals, t.STRING), pattern).data)
+    (chars, lengths, plan), = calls
+    assert chars.shape[1] == lengths.max() == 24 and lengths.min() == 0
+    want = _like_rows_numpy(chars, lengths, *plan)
+    assert np.array_equal(got.astype(bool), want)
+    assert 0 < want.sum() < len(vals)
+
+
+def test_like_floating_gap_lowers_to_no_scan():
+    """q13's pattern holds no prefix scan along the positions: an
+    ``associative_scan`` unrolls into levels of ``pad`` and ``or`` equations
+    (24 and 36 for this pattern's two), none of which XLA:TPU fuses."""
+    import jax
+
+    plan = ((b"special", b"requests"), ((0, True), (0, True)), (0, True))
+    closed = jax.make_jaxpr(lambda c, n: s._like_rows(c, n, *plan))(
+        jax.ShapeDtypeStruct((256, 79), jnp.uint8),
+        jax.ShapeDtypeStruct((256,), jnp.int32))
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            names.append(eqn.primitive.name)
+            for value in eqn.params.values():
+                inner = getattr(value, "jaxpr", value)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(closed.jaxpr)
+    assert names.count("reduce_min") == 2
+    assert not {"pad", "or"} & set(names)
+
+
 def test_like_invalid_escape_patterns_raise():
     """Spark's checkLikePattern posture: the escape char must precede
     '%', '_', or itself; a trailing escape or escape of an ordinary char
