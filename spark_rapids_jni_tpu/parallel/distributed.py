@@ -25,7 +25,7 @@ from spark_rapids_jni_tpu import telemetry
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate
 from spark_rapids_jni_tpu.parallel.mesh import EXEC_AXIS
-from spark_rapids_jni_tpu.parallel.shuffle import hash_shuffle
+from spark_rapids_jni_tpu.parallel.shuffle import ShuffleResult, hash_shuffle
 from spark_rapids_jni_tpu.runtime.dispatch import (
     mesh_fingerprint as _mesh_fingerprint,
 )
@@ -714,6 +714,63 @@ def distributed_window(
     return DistributedWindow(out_tbl, results, rv, ovf)
 
 
+class ShuffledJoin(NamedTuple):
+    """One chip's part of an exchange-then-join (:func:`shuffled_join`)."""
+
+    left: ShuffleResult      # the left rows this chip owns after the exchange
+    right: ShuffleResult     # the right rows it owns
+    # the join of the two: ``JoinMaps`` into ``out_size`` rows, or with
+    # ``out_size=None`` a ``SemiJoinMask`` over the left rows where they landed
+    joined: object
+
+
+def shuffled_join(left: Table, right: Table, left_keys: Sequence[int],
+                  right_keys: Sequence[int], axis: str, how: str,
+                  out_size: Optional[int], *,
+                  left_row_valid=None, right_row_valid=None,
+                  left_capacity: Optional[int] = None,
+                  right_capacity: Optional[int] = None) -> ShuffledJoin:
+    """The exchange-then-join step as ONE chip of ``axis`` runs it (inside
+    ``shard_map``): both sides exchanged by the hash of their join keys
+    (``hash_shuffle``: ``partition_hash`` depends on the key's value and
+    storage type alone, so equal keys land on one chip whichever side they
+    come from), then the one-chip join of what landed, the shuffles'
+    occupied slots as its row masks (an empty slot emits nothing, not even
+    under an outer join). The one such step in the package:
+    ``distributed_join`` and the served path's lowering of a ``Join`` over
+    sharded rows (``runtime/fusion.py``) both call it.
+
+    ``out_size=None`` takes ``left_semi`` / ``left_anti`` as a mask over the
+    landed left rows (``ops/join.semi_join_mask``); otherwise ``join`` lays
+    its maps out into ``out_size`` rows a chip. Rows that are False in a
+    ``*_row_valid`` are packed out before the exchange and never travel.
+    Whether a shuffle found more rows for a chip than ``*_capacity`` slots
+    (default ``hash_shuffle``'s) is ``.left.overflowed`` /
+    ``.right.overflowed``: rows were dropped, the caller must not answer.
+
+    A device trace splits the step by its sub-scopes: ``exchange/left``,
+    ``exchange/right``, and the join's own ``build`` / ``probe``."""
+    from spark_rapids_jni_tpu.ops.join import join, semi_join_mask
+
+    lkeys, rkeys = list(left_keys), list(right_keys)
+    with jax.named_scope("exchange"):
+        with jax.named_scope("left"):
+            ls = hash_shuffle(left, lkeys, axis, capacity=left_capacity,
+                              row_valid=left_row_valid)
+        with jax.named_scope("right"):
+            rs = hash_shuffle(right, rkeys, axis, capacity=right_capacity,
+                              row_valid=right_row_valid)
+    if out_size is None:
+        joined = semi_join_mask(
+            ls.table, rs.table, lkeys, rkeys, how,
+            left_row_valid=ls.row_valid, right_row_valid=rs.row_valid)
+    else:
+        joined = join(ls.table, rs.table, lkeys, rkeys, out_size, how=how,
+                      left_row_valid=ls.row_valid,
+                      right_row_valid=rs.row_valid)
+    return ShuffledJoin(ls, rs, joined)
+
+
 class DistributedJoin(NamedTuple):
     table: Table             # per-device joined rows (padded), sharded
     total: jnp.ndarray       # int64[D] true match count per device
@@ -736,37 +793,29 @@ def distributed_join(
 ) -> DistributedJoin:
     """Repartitioned equi-join — the RapidsShuffleManager join pattern: both
     sides exchange rows by key hash over ICI, after which equal keys live on
-    the same device and a device-local sort-merge join finishes the work.
+    the same device and a device-local sort-merge join finishes the work
+    (:func:`shuffled_join`, the step the served path lowers a ``Join`` of
+    sharded rows to as well).
 
-    Both inputs must already be sharded row-wise over ``mesh``. Identical
-    routing for both tables is guaranteed because partition_hash depends
-    only on the key value and its storage type (join() rejects mismatched
-    key storage types). Pass the ``row_valid`` masks from
-    ``shard_table(..., return_row_valid=True)`` so padding rows are dropped
-    before the exchange — under a left join a padding row would otherwise
-    be indistinguishable from a genuine NULL-key row and emit output.
+    Both inputs must already be sharded row-wise over ``mesh``. Pass the
+    ``row_valid`` masks from ``shard_table(..., return_row_valid=True)`` so
+    padding rows are dropped before the exchange — under a left join a
+    padding row would otherwise be indistinguishable from a genuine
+    NULL-key row and emit output.
     """
-    from spark_rapids_jni_tpu.ops.join import apply_join_maps, join
+    from spark_rapids_jni_tpu.ops.join import apply_join_maps
 
     left_keys = [left_on] if isinstance(left_on, int) else list(left_on)
     right_keys = [right_on] if isinstance(right_on, int) else list(right_on)
 
     def step(l: Table, r: Table, lrv, rrv):
-        # identical routing for both sides: partition_hash depends only on
-        # key content (string hashing is over actual bytes, padding-blind)
-        ls = hash_shuffle(l, left_keys, EXEC_AXIS, capacity=left_capacity,
-                          row_valid=lrv)
-        rs = hash_shuffle(r, right_keys, EXEC_AXIS, capacity=right_capacity,
-                          row_valid=rrv)
-        # phantom (unoccupied) shuffle slots must not emit outer-join rows
-        # on either side
-        maps = join(ls.table, rs.table, left_keys, right_keys,
-                    out_size_per_device, how=how,
-                    left_row_valid=ls.row_valid,
-                    right_row_valid=rs.row_valid)
-        joined = apply_join_maps(ls.table, rs.table, maps)
-        overflow = ls.overflowed | rs.overflowed
-        return joined, maps.total.reshape(1), overflow.reshape(1)
+        sj = shuffled_join(
+            l, r, left_keys, right_keys, EXEC_AXIS, how,
+            out_size_per_device, left_row_valid=lrv, right_row_valid=rrv,
+            left_capacity=left_capacity, right_capacity=right_capacity)
+        joined = apply_join_maps(sj.left.table, sj.right.table, sj.joined)
+        overflow = sj.left.overflowed | sj.right.overflowed
+        return joined, sj.joined.total.reshape(1), overflow.reshape(1)
 
     if left_row_valid is None:
         left_row_valid = jnp.ones((left.num_rows,), jnp.bool_)

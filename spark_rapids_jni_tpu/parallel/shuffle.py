@@ -10,14 +10,14 @@ ICI in a single fused step.
 
 TPU-first shape discipline: ``all_to_all`` needs a static per-destination
 capacity, so each device packs its rows into a ``(D, capacity)`` send
-buffer (rows sorted by destination partition — one gather, radix-friendly)
-with an occupancy mask; unoccupied receive slots surface as null rows,
-which every downstream operator already skips (the same masked-row trick
-the local operators use for static-shape filtering). The capacity default
-``ceil(n/D) * 2`` covers 2x skew; overflow is detected and reported
-per-call (`ShuffleResult.overflowed`) rather than silently dropped —
-the moral equivalent of the reference's hard 2^31-byte batch bound
-(reference row_conversion.cu:476-479).
+buffer (rows sorted by destination, then one contiguous slice a
+destination: ``_plan_send`` / ``_pack_send``) with an occupancy mask;
+unoccupied receive slots surface as null rows, which every downstream
+operator already skips (the masked-row trick the local operators use for
+static-shape filtering). The capacity default ``ceil(n/D) * 2`` covers 2x
+skew; overflow is detected and reported per-call
+(`ShuffleResult.overflowed`) rather than silently dropped — the moral equal
+of the reference's 2^31-byte batch bound (row_conversion.cu:476-479).
 
 String columns travel in the padded device layout (ops.strings): their
 int32 lengths ride the fixed-width path and the (n, W) char matrix is
@@ -52,48 +52,88 @@ class ShuffleResult(NamedTuple):
 
 
 class _SendPlan(NamedTuple):
-    """Inverted send-buffer mapping: for output slot s, take sorted row
-    ``src[s]`` when ``hit[s]`` (else the slot is empty). Computed ONCE per
-    shuffle and reused by every column."""
+    """Where every local row goes in the ``(D, capacity)`` send buffer.
+    Computed ONCE per shuffle and reused by every column."""
 
-    src: jnp.ndarray  # int32[size] into destination-sorted rows
-    hit: jnp.ndarray  # bool[size]
-
-
-def _plan_send(dst_mono: jnp.ndarray, in_cap: jnp.ndarray,
-               size: int) -> _SendPlan:
-    """Invert the (monotone) row->slot map into a slot->row gather.
-
-    ``dst_mono`` is non-decreasing over the partition-sorted rows (slots
-    increase within a partition, partitions increase across runs; dropped
-    rows are capped at the partition boundary so monotonicity survives
-    overflow). A scatter would serialize on the TPU; searchsorted + gather
-    streams. Ties (capped overflow rows, phantom rows sharing a slot) are
-    broken by taking the LAST row of a tie group — the real in-capacity row
-    always sorts after its capped/phantom shadows — and ``in_cap[src]``
-    rejects groups with no real member.
-    """
-    n = dst_mono.shape[0]
-    slots = jnp.arange(size, dtype=dst_mono.dtype)
-    pos = jnp.searchsorted(dst_mono, slots, side="right").astype(jnp.int32) - 1
-    src = jnp.clip(pos, 0, max(n - 1, 0))
-    hit = (pos >= 0) & (dst_mono[src] == slots) & in_cap[src] if n else (
-        jnp.zeros((size,), jnp.bool_)
-    )
-    return _SendPlan(src, hit)
+    order: jnp.ndarray     # int32[n]: the rows by destination, non-rows last
+    starts: jnp.ndarray    # int32[D]: where a destination's rows begin in it
+    counts: jnp.ndarray    # int32[D]: a destination's real rows, all of them
+    occupied: jnp.ndarray  # bool[D * capacity]: the slot holds a real row
+    capacity: int
 
 
-def _pack_send(
-    data: jnp.ndarray, order: jnp.ndarray, plan: _SendPlan
-) -> jnp.ndarray:
-    """Lay rows out in send-buffer order via the inverted plan (pure
-    gathers, zero scatters). Works for 1-D columns and 2-D row matrices
-    (padded string chars)."""
-    g = data[order][plan.src]
-    zeros = jnp.zeros((), dtype=data.dtype)
-    if g.ndim == 1:
-        return jnp.where(plan.hit, g, zeros)
-    return jnp.where(plan.hit[:, None], g, zeros)
+def _plan_send(part: jnp.ndarray, row_valid: Optional[jnp.ndarray],
+               parts: int, capacity: int) -> _SendPlan:
+    """The send plan of rows bound for ``part`` (int32[n] in [0, parts)).
+
+    A stable sort by destination, with the rows that are none sent past
+    every destination, leaves destination p's real rows in their input
+    order at ``[starts[p], starts[p] + counts[p])``: its send slots are the
+    first ``capacity`` of them, so a buffer is packed by ``parts``
+    contiguous slices of the sorted rows (``_rows_by_destination``, then
+    ``_pack_send``): no scatter, no search a slot, no gather by slot. Rows
+    past the capacity are dropped (``counts > capacity`` says so)."""
+    dest = part.astype(jnp.int32)
+    if row_valid is not None:
+        dest = jnp.where(row_valid, dest, jnp.int32(parts))
+    order = jnp.argsort(dest, stable=True).astype(jnp.int32)
+    counts = jnp.sum(
+        dest[None, :] == jnp.arange(parts, dtype=jnp.int32)[:, None],
+        axis=1, dtype=jnp.int32)
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    occupied = (slot[None, :] < jnp.minimum(counts, capacity)[:, None])
+    return _SendPlan(order, jnp.cumsum(counts) - counts, counts,
+                     occupied.reshape(-1), int(capacity))
+
+
+def _rows_by_destination(table: Table, plan: _SendPlan) -> Table:
+    """``table``'s rows in the plan's order, the rows a destination gets
+    side by side. ``ops/sort.permute`` moves them: the fixed-width data and
+    every validity bit as packed 32-bit words carried through two-operand
+    sorts, where a gather by a permutation costs this chip 0.58 s for
+    16,777,216 int64s (``PERF.md`` section 6, PR 52); a padded string's and
+    a padded LIST's buffers keep their gathers. A column the shuffle does
+    not take is left as it is, for the caller to refuse."""
+    from spark_rapids_jni_tpu.ops.sort import permute
+
+    def movable(c: Column) -> bool:
+        return c.children is None and (
+            c.is_padded_string if c.dtype.is_string
+            else c.dtype.is_fixed_width or c.dtype.is_decimal128)
+
+    moved = iter(permute([c for c in table.columns if movable(c)],
+                         plan.order)[0])
+    order = plan.order
+
+    def taken(buf):
+        return None if buf is None else buf[order]
+
+    out = []
+    for c in table.columns:
+        if movable(c):
+            out.append(next(moved))
+        elif c.dtype.type_id == TypeId.LIST and c.is_padded_list:
+            out.append(Column(
+                c.dtype, taken(c.data), taken(c.validity),
+                children=[Column(k.dtype, taken(k.data), taken(k.validity))
+                          for k in c.children]))
+        else:
+            out.append(c)
+    return Table(out)
+
+
+def _pack_send(rows: jnp.ndarray, plan: _SendPlan) -> jnp.ndarray:
+    """A buffer of ``_rows_by_destination`` laid out in send-buffer order:
+    one slice of ``capacity`` rows a destination from where its rows begin,
+    empty slots zeroed. Works for 1-D columns and 2-D row matrices (padded
+    string chars)."""
+    tail = (plan.capacity,) + rows.shape[1:]
+    rows = jnp.concatenate([rows, jnp.zeros(tail, rows.dtype)])
+    g = jnp.concatenate([
+        jax.lax.dynamic_slice_in_dim(rows, plan.starts[p], plan.capacity)
+        for p in range(plan.starts.shape[0])])
+    keep = plan.occupied.reshape((-1,) + (1,) * (g.ndim - 1))
+    return jnp.where(keep, g, jnp.zeros((), dtype=rows.dtype))
 
 
 @func_range("hash_shuffle")
@@ -115,6 +155,17 @@ def hash_shuffle(
     shard_table etc.); non-rows are dropped before the exchange rather than
     shipped, and never count as overflow. Distinct from column validity — a
     real row with NULL key still shuffles (to the null-hash partition).
+
+    On the served path (``runtime/fusion.py``, "lowering over a mesh") two
+    kinds of plan node run it inside a region's ``shard_map``: a ``GroupBy``
+    of sharded rows, for its partial groups (capacity the group budget, which
+    cannot overflow), and a ``Join`` (``left_semi`` / ``left_anti`` /
+    ``inner``) of two sharded children, once a side, for the rows themselves
+    (``parallel.distributed.shuffled_join``, the default capacity: a chip's
+    receive buffer has twice its rows in slots). A join's exchange that
+    overflows is a refused request: ``overflowed`` reaches the result's meta
+    as ``<label>.shuffle_overflowed`` and ``QueryServer`` raises
+    ``CapacityOverflow`` in place of an answer.
     """
     part = partition_hash(table, list(keys), jax.lax.axis_size(axis_name))
     return shuffle_by_partition(table, part, axis_name, capacity=capacity,
@@ -145,42 +196,10 @@ def shuffle_by_partition(
 
         capacity = dispatch.quantize_capacity(max(1, math.ceil(n / D) * 2))
 
-    # Sort rows by destination partition; compute each row's slot within
-    # its partition run. Stable sort keeps within-partition input order.
-    order = jnp.argsort(part, stable=True)
-    part_sorted = part[order]
-    if row_valid is None:
-        real_sorted = jnp.ones((n,), dtype=jnp.bool_)
-    else:
-        real_sorted = row_valid[order]
-    real_i32 = real_sorted.astype(jnp.int32)
-    # real rows in earlier partitions (per-partition slot base), scatter-free:
-    # partitions are contiguous after the sort, so the base of partition p is
-    # the exclusive real-row rank at p's first row
-    rank_excl = jnp.cumsum(real_i32) - real_i32  # reals strictly before row
-    if n:
-        part_start = jnp.searchsorted(
-            part_sorted, jnp.arange(D, dtype=part_sorted.dtype), side="left"
-        )
-        base = rank_excl[jnp.clip(part_start, 0, n - 1)]
-        base = jnp.where(part_start < n, base, jnp.cumsum(real_i32)[-1])
-        offsets = base.astype(jnp.int32)
-    else:
-        offsets = jnp.zeros((D,), jnp.int32)
-    # Slot = count of real rows of the same partition preceding this row.
-    # Exclusive rank makes a phantom row tie with the NEXT real row (and
-    # sort BEFORE it) — the send-plan inversion picks the last row of a tie
-    # group, which is then always the real one.
-    slot = rank_excl.astype(jnp.int32) - offsets[part_sorted]
-    in_cap = (slot < capacity) & real_sorted
-    overflowed = jnp.any((slot >= capacity) & real_sorted)
+    plan = _plan_send(part, row_valid, D, capacity)
+    overflowed = jnp.any(plan.counts > capacity)
     size = D * capacity
-    # Monotone destination key over the sorted rows (overflow rows capped at
-    # the partition boundary slot, which is never queried as in-capacity).
-    dst_mono = part_sorted * capacity + jnp.clip(slot, 0, capacity)
-    plan = _plan_send(dst_mono, in_cap, size)
-
-    occupied = plan.hit
+    occupied = plan.occupied
 
     def exchange(flat: jnp.ndarray) -> jnp.ndarray:
         """(D*C, ...) send layout -> (D*C, ...) receive layout over ICI."""
@@ -196,7 +215,7 @@ def shuffle_by_partition(
 
     out_cols = []
     narrowing_overflow = jnp.zeros((), jnp.bool_)
-    for i, col in enumerate(table.columns):
+    for i, col in enumerate(_rows_by_destination(table, plan).columns):
         if col.dtype.is_string:
             if not col.is_padded_string:
                 raise NotImplementedError(
@@ -208,9 +227,9 @@ def shuffle_by_partition(
                     "wire narrowing does not apply to string columns "
                     f"(column {i}); pass None for its wire dtype"
                 )
-            recv_len = exchange(_pack_send(col.data, order, plan))
-            recv_mat = exchange(_pack_send(col.chars, order, plan))
-            valid_flat = _pack_send(col.valid_mask(), order, plan)
+            recv_len = exchange(_pack_send(col.data, plan))
+            recv_mat = exchange(_pack_send(col.chars, plan))
+            valid_flat = _pack_send(col.valid_mask(), plan)
             recv_valid = exchange(valid_flat) & recv_occupied
             out_cols.append(
                 Column(col.dtype, recv_len, recv_valid, chars=recv_mat)
@@ -226,11 +245,11 @@ def shuffle_by_partition(
                     "wire narrowing does not apply to LIST columns "
                     f"(column {i}); pass None for its wire dtype")
             elem = col.children[0]
-            recv_len = exchange(_pack_send(col.data, order, plan))
-            recv_mat = exchange(_pack_send(elem.data, order, plan))
-            recv_ev = exchange(_pack_send(elem.valid_mask(), order, plan))
+            recv_len = exchange(_pack_send(col.data, plan))
+            recv_mat = exchange(_pack_send(elem.data, plan))
+            recv_ev = exchange(_pack_send(elem.valid_mask(), plan))
             recv_valid = exchange(
-                _pack_send(col.valid_mask(), order, plan)) & recv_occupied
+                _pack_send(col.valid_mask(), plan)) & recv_occupied
             # unoccupied slots must read as EMPTY lists, not stale rows
             recv_len = jnp.where(recv_occupied, recv_len, 0)
             recv_ev = recv_ev & recv_occupied[:, None]
@@ -261,7 +280,7 @@ def shuffle_by_partition(
                 )
             ref = jnp.asarray(wire.reference, col.data.dtype)
             clean = jnp.where(col.valid_mask(), col.data, ref)
-            sent = _pack_send(clean, order, plan)
+            sent = _pack_send(clean, plan)
             sent = jnp.where(occupied, sent, ref)
             packed, ovf = pack_bits(sent.reshape(D, capacity), wire)
             narrowing_overflow = narrowing_overflow | ovf
@@ -277,7 +296,7 @@ def shuffle_by_partition(
             clean = jnp.where(
                 col.valid_mask(), col.data, jnp.zeros_like(col.data)
             )
-            sent = _pack_send(clean, order, plan)
+            sent = _pack_send(clean, plan)
             # nvcomp-equivalent transport compression, stage 1: the planner
             # declares a narrower integral wire type (dates in int32,
             # quantities in int16, ...) and the exchange moves 2-4x fewer
@@ -289,8 +308,8 @@ def shuffle_by_partition(
             narrowing_overflow = narrowing_overflow | jnp.any(widened != sent)
             recv = exchange(narrow).astype(col.data.dtype)
         else:
-            recv = exchange(_pack_send(col.data, order, plan))
-        valid_flat = _pack_send(col.valid_mask(), order, plan)
+            recv = exchange(_pack_send(col.data, plan))
+        valid_flat = _pack_send(col.valid_mask(), plan)
         recv_valid = exchange(valid_flat) & recv_occupied
         out_cols.append(Column(col.dtype, recv, recv_valid))
 

@@ -12,9 +12,11 @@ with no static slot table anywhere.
 Three halves, each reusing an existing discipline:
 
 * **Device half** — ``partition_hash`` -> destination-sorted pack into a
-  contiguous ``(parts, capacity)`` send buffer, via the SAME
-  searchsorted-inversion gather the ICI shuffle uses (``_plan_send`` /
-  ``_pack_send`` are imported, not copied). Capacities are quantized
+  contiguous ``(parts, capacity)`` send buffer, via the SAME send plan the
+  ICI shuffle uses (``_plan_send`` / ``_rows_by_destination`` /
+  ``_pack_send``: a stable sort by destination and one slice a
+  destination; imported, not copied).
+  Capacities are quantized
   through the dispatch bucket schedule so ragged partition sizes share
   executables; destination p's rows are exactly the first ``counts[p]``
   slots of its capacity run, so the host trims real rows with plain
@@ -63,6 +65,7 @@ from spark_rapids_jni_tpu.ops.table_ops import _slice_rows, concatenate
 from spark_rapids_jni_tpu.parallel.shuffle import (
     _pack_send,
     _plan_send,
+    _rows_by_destination,
     classify_overflow,
 )
 from spark_rapids_jni_tpu.runtime import dispatch, resilience
@@ -95,56 +98,32 @@ class PackResult(NamedTuple):
 
 
 def _make_pack_fn(keys: tuple, parts: int, capacity: int) -> Callable:
-    """The dispatchable pack: mirror of ``shuffle_by_partition``'s slot
-    math with the mesh axis replaced by a host-level destination dim (no
-    ``all_to_all`` — the wire half moves the buffers). The closure's
-    variation is fully captured by the caller's ``statics``."""
+    """The dispatchable pack: ``shuffle_by_partition``'s send plan with the
+    mesh axis replaced by a host-level destination dim (no ``all_to_all``
+    — the wire half moves the buffers). The closure's variation is fully
+    captured by the caller's ``statics``."""
 
     def pack(row_args, aux_args, row_valids):
         (table,) = row_args
         rv = None if row_valids is None else row_valids[0]
-        n = table.num_rows
         part = partition_hash(table, list(keys), parts)
-        order = jnp.argsort(part, stable=True)
-        part_sorted = part[order]
-        if rv is None:
-            real_sorted = jnp.ones((n,), dtype=jnp.bool_)
-        else:
-            real_sorted = rv.astype(jnp.bool_)[order]
-        real_i32 = real_sorted.astype(jnp.int32)
-        rank_excl = jnp.cumsum(real_i32) - real_i32
-        total_real = jnp.sum(real_i32).astype(jnp.int32)
-        if n:
-            part_start = jnp.searchsorted(
-                part_sorted, jnp.arange(parts, dtype=part_sorted.dtype),
-                side="left")
-            base = rank_excl[jnp.clip(part_start, 0, n - 1)]
-            base = jnp.where(part_start < n, base, total_real)
-            offsets = base.astype(jnp.int32)
-        else:
-            offsets = jnp.zeros((parts,), jnp.int32)
-        slot = rank_excl.astype(jnp.int32) - offsets[part_sorted]
-        in_cap = (slot < capacity) & real_sorted
-        size = parts * capacity
-        dst_mono = part_sorted * capacity + jnp.clip(slot, 0, capacity)
-        plan = _plan_send(dst_mono, in_cap, size)
-        occupied = plan.hit
+        plan = _plan_send(part, rv, parts, capacity)
+        occupied = plan.occupied
         # full real count per destination (including overflow past the
         # capacity) — the escalation ladder's exact `required`
-        ext = jnp.concatenate([offsets, total_real[None]])
-        counts = ext[1:] - ext[:-1]
+        counts = plan.counts
         overflowed = jnp.any(counts > capacity)
 
         out_cols = []
-        for col in table.columns:
+        for col in _rows_by_destination(table, plan).columns:
             if col.dtype.is_string:
                 if not col.is_padded_string:
                     raise NotImplementedError(
                         "exchange pack needs string columns in the padded "
                         "device layout (ops.strings.pad_strings)")
-                lens = _pack_send(col.data, order, plan)
-                chars = _pack_send(col.chars, order, plan)
-                valid = _pack_send(col.valid_mask(), order, plan) & occupied
+                lens = _pack_send(col.data, plan)
+                chars = _pack_send(col.chars, plan)
+                valid = _pack_send(col.valid_mask(), plan) & occupied
                 out_cols.append(Column(col.dtype, lens, valid, chars=chars))
                 continue
             if col.dtype.type_id == TypeId.LIST:
@@ -153,10 +132,10 @@ def _make_pack_fn(keys: tuple, parts: int, capacity: int) -> Callable:
                         "exchange pack needs LIST columns in the padded "
                         "wire layout (ops.lists.pad_lists)")
                 elem = col.children[0]
-                lens = _pack_send(col.data, order, plan)
-                emat = _pack_send(elem.data, order, plan)
-                ev = _pack_send(elem.valid_mask(), order, plan)
-                valid = _pack_send(col.valid_mask(), order, plan) & occupied
+                lens = _pack_send(col.data, plan)
+                emat = _pack_send(elem.data, plan)
+                ev = _pack_send(elem.valid_mask(), plan)
+                valid = _pack_send(col.valid_mask(), plan) & occupied
                 # unoccupied slots must read as EMPTY lists
                 lens = jnp.where(occupied, lens, 0)
                 ev = ev & occupied[:, None]
@@ -168,8 +147,8 @@ def _make_pack_fn(keys: tuple, parts: int, capacity: int) -> Callable:
                 raise NotImplementedError(
                     "exchange pack supports fixed-width columns only "
                     "(the ICI shuffle shares this restriction)")
-            data = _pack_send(col.data, order, plan)
-            valid = _pack_send(col.valid_mask(), order, plan) & occupied
+            data = _pack_send(col.data, plan)
+            valid = _pack_send(col.valid_mask(), plan) & occupied
             out_cols.append(Column(col.dtype, data, valid))
         return Table(out_cols), counts, overflowed
 

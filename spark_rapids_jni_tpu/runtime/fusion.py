@@ -271,6 +271,11 @@ class Join(NamedTuple):
     the served path refuses such a result (``QueryServer._account_meta``:
     ``CapacityOverflow`` with the true total).
 
+    With both children row-sharded over a mesh, ``inner``, ``left_semi``
+    and ``left_anti`` lower as a shuffled join (``_mesh_join``: the extra
+    sub-scope ``exchange``, the ``<label>.shuffle_*`` facts, ``out_rows``
+    then a chip's room); the other kinds have no lowering there.
+
     Lowers under its label's scope with the sub-scopes ``build`` and
     ``probe`` (``ops/join.py`` says which stage lies under which) and,
     where it lays rows out, ``gather_rows`` (``apply_join_maps``: the
@@ -386,9 +391,12 @@ class Limit(NamedTuple):
 
 class Exchange(NamedTuple):
     """General-cardinality hash repartition of the child's output — the
-    distributed-exchange boundary (runtime/exchange.py). A shuffle is a
-    genuine host boundary, so an Exchange never evaluates INSIDE a
-    fused/staged region; the planner instead breaks the plan at it. As
+    distributed-exchange boundary BETWEEN PROCESSES (runtime/exchange.py).
+    Such a shuffle is a genuine host boundary, so an Exchange never evaluates
+    INSIDE a fused/staged region; the planner instead breaks the plan at it.
+    (The exchange between the chips of ONE process's mesh is no node: a
+    ``Join`` or ``GroupBy`` of rows sharded over a mesh lowers to it inside
+    the region, "lowering over a mesh" below.) As
     a plan ROOT, the child region fuses and executes normally and the
     exchange pack runs as its own dispatch op on the result (the wire
     form the cluster ships). Placed MID-PLAN, ``execute`` splits the
@@ -654,8 +662,8 @@ def _spaces(nodes) -> dict:
 
 def _side_keys(nodes, placement: Optional[dict] = None) -> list:
     """Deterministic (label, field) order of traced side outputs;
-    ``placement`` (``_mesh_placement``) adds what a groupby lowered over a
-    mesh reports of its shuffle."""
+    ``placement`` (``_mesh_placement``) adds what a groupby or a join
+    lowered over a mesh reports of its shuffles."""
     keys: list = []
     scopes = node_scopes(nodes)    # a Sort has no label: its scope's name
     for node in nodes:
@@ -689,6 +697,8 @@ def _side_keys(nodes, placement: Optional[dict] = None) -> list:
             else:
                 keys += [f"{node.label}.overflowed",
                          f"{node.label}.probe_compacted"]
+            if placement and placement[id(node)] == SHARDED:
+                keys += [f"{node.label}.{fact}" for fact in _SHUFFLE_FACTS]
         elif isinstance(node, DensePkJoin):
             keys += [f"{node.label}.total", f"{node.label}.pk_violation"]
         elif isinstance(node, BloomProbe):
@@ -760,8 +770,8 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
     tables inside the fused region fn and with concrete tables on the
     staged path — the SAME per-op calls either way. With ``mesh_axis``
     the caller is one chip of a ``shard_map`` over that axis holding its
-    rows of every scan, and a groupby whose input is ``SHARDED`` in
-    ``placement`` (``_mesh_placement``) lowers across the chips."""
+    rows of every scan, and a join or a groupby whose input is ``SHARDED``
+    in ``placement`` (``_mesh_placement``) lowers across the chips."""
     from spark_rapids_jni_tpu import types as _t
     from spark_rapids_jni_tpu.ops import bloom_filter as _bloom
     from spark_rapids_jni_tpu.ops.groupby import groupby_aggregate
@@ -778,6 +788,10 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
 
     env: dict = {}
     side: list = []
+    # over a mesh, a sharded node's id -> bool[rows]: the rows no Filter
+    # below it dropped. A dropped row stays where it lay, null in every
+    # column; an exchange must not carry it (``_mesh_join``)
+    live: dict = {}
     # a bounded groupby's node id -> the groups its output holds (int32):
     # the rows past them are padding, which no row mask says
     groups_of_node: dict = {}
@@ -806,6 +820,7 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
                 # a chip saw its share of the rows: the counts of them all
                 real, kept = (jax.lax.psum(v, mesh_axis)
                               for v in (real, kept))
+                live[id(node)] = keep & live.get(id(node.child), True)
             width = sum(int(tbl.column(i).chars.shape[1])
                         for i in node.like_columns)
             side.extend([
@@ -818,6 +833,8 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
             tbl, rv = ev(node.child)
             if node.rowwise:
                 out = (node.fn(tbl, *node.params), rv)
+                if id(node.child) in live:
+                    live[id(node)] = live[id(node.child)]
             else:
                 out = (node.fn(tbl, rv, *node.params), None)
         elif isinstance(node, GroupBy):
@@ -899,7 +916,13 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
         elif isinstance(node, Join):
             ltbl, lrv = ev(node.left)
             rtbl, rrv = ev(node.right)
-            if node.how in _MASK_JOINS:
+            if placement is not None and placement[id(node)] == SHARDED:
+                out, facts, kept = _mesh_join(
+                    node, ltbl, lrv, live.get(id(node.left)), rtbl, rrv,
+                    live.get(id(node.right)), resolved[id(node)], mesh_axis)
+                if kept is not None:    # the rows it dropped stay, nulled
+                    live[id(node)] = kept
+            elif node.how in _MASK_JOINS:
                 # one bit a left row: no maps, nothing moves
                 semi = semi_join_mask(
                     ltbl, rtbl, list(node.left_on), list(node.right_on),
@@ -1027,27 +1050,49 @@ def _eval_plan(root, tables: dict, rvs: dict, resolved: dict,
 # The sharding of the bound buffers is the only signal (``parallel/mesh.py``
 # ``table_row_mesh``): no option, no second entry point. Each chip of the
 # axis is one Spark executor holding its partition of every scan. Filters
-# and row-wise projections run on a chip's own rows; a groupby is where the
-# chips meet: a partial aggregate a chip bounded at the plan's group
-# budget, ``hash_shuffle`` of the real partial rows over the axis (an
-# ``all_to_all`` over ICI), the merge of what a chip then owns, and the
-# collect of every chip's groups into one table that every chip holds.
-# Whatever stands above the groupby (q1's finalize and ORDER BY) then runs
-# on that small table as it does on one chip.
+# and row-wise projections run on a chip's own rows. Two kinds of node are
+# where the chips meet:
+#
+# * a ``Join`` (``left_semi``, ``left_anti``, ``inner``) of two sharded
+#   children is a shuffled join (``_mesh_join``): both sides exchanged by
+#   the hash of the join key (``hash_shuffle``, an ``all_to_all`` of ROWS
+#   over ICI), the one-chip join of what landed on a chip; its output is
+#   still a chip's share of the rows. A shuffle that finds more rows for a
+#   chip than its receive buffer has slots drops them and says so
+#   (``<label>.shuffle_overflowed``): the served path refuses that request
+#   (``QueryServer._account_meta``: ``CapacityOverflow``), it never answers.
+# * a groupby: a partial aggregate a chip bounded at the plan's group
+#   budget, ``hash_shuffle`` of the real partial rows over the axis, the
+#   merge of what a chip then owns, and the collect of every chip's groups
+#   into one table that every chip holds (declared domains: one collective
+#   over the slots, no row crosses). Whatever stands above the groupby (q1's
+#   finalize and ORDER BY) then runs on that small table as on one chip.
+#
+# ``left`` / ``right`` / ``full`` joins (a NULL-keyed row has to come out,
+# and every one of them hashes to one chip), a join with one whole child (a
+# broadcast side), a ``Sort`` and a ``Limit`` of sharded rows have no
+# lowering: ``_mesh_placement`` gives None and the plan runs as it always has.
 
 SHARDED, WHOLE = "sharded", "whole"
 # what merges a partial aggregate of each kind across the shuffle
 _MERGE_OF = {"sum": "sum", "count": "sum", "min": "min", "max": "max"}
+# ``Join.how`` with a lowering over a mesh
+_MESH_JOINS = _MASK_JOINS + ("inner",)
+# what a join lowered over a mesh reports of its two shuffles
+# (``<label>.<fact>``, ``_mesh_join``)
+_SHUFFLE_FACTS = ("shuffle_rows", "shuffle_bytes", "shuffle_capacity",
+                  "shuffle_read_bytes", "shuffle_overflowed")
 
 
 def _mesh_placement(nodes, resolved: dict) -> Optional[dict]:
     """``{id(node): SHARDED | WHOLE}``: whether a node's output is a
     chip's share of the rows or the one table every chip holds, when every
     bucketed scan is row-sharded over one mesh axis; None where the plan
-    has no lowering over a mesh (an exact scan, a join, sort or limit of
-    sharded rows, an aggregate with no associative merge, a groupby with
-    neither a group bound nor declared domains, a root that is still
-    sharded): ``execute`` then runs it as it always has."""
+    has no lowering over a mesh (an exact scan, an outer join, a join with
+    one whole child, a sort or limit of sharded rows, an aggregate with no
+    associative merge, a groupby with neither a group bound nor declared
+    domains, a root that is still sharded): ``execute`` then runs it as it
+    always has."""
     place: dict = {}
     for node in nodes:
         kids = [place[id(c)] for c in _children(node)]
@@ -1067,6 +1112,9 @@ def _mesh_placement(nodes, resolved: dict) -> Optional[dict]:
             elif _planned_lowering(node) != "bounded":
                 return None
             here = WHOLE
+        elif (isinstance(node, Join) and node.how in _MESH_JOINS
+                and kids == [SHARDED, SHARDED]):
+            here = SHARDED
         elif SHARDED in kids:
             return None
         else:
@@ -1181,6 +1229,98 @@ def _mesh_groupby(node: GroupBy, tbl: Table, rv, bound, axis: str):
                         part.table, None, budget, chips)["wire_bytes"],
                     jnp.int64))]
     return Table(cols), side
+
+
+def _mesh_join(node: Join, ltbl: Table, lrv, llive, rtbl: Table, rrv,
+               rlive, capacity, axis: str):
+    """One chip's part of a join of rows sharded across ``axis`` (inside
+    ``shard_map``): ``((its share of the output, row mask), facts, the left
+    rows a mask join kept)``. The step is ``distributed.shuffled_join``:
+    under the sub-scope ``exchange`` (``exchange/left``, ``exchange/right``)
+    both sides go through ``hash_shuffle`` by the join key, then the join the
+    node runs on one chip joins what landed (``build`` / ``probe`` / ...).
+
+    What rides: every column of the left side (the node's output holds
+    them), and of the right side every column where the join lays rows out
+    but the KEY alone for a mask join, whose right side nobody above reads.
+    Which rows: a bucket's padding (``*rv``) and the rows a ``Filter`` below
+    dropped (``*live``) are no rows and are packed out before the exchange,
+    and so is a row with a NULL key wherever it can match nothing and
+    appear nowhere (either side of a semi or inner join, an anti join's
+    right side); an anti join's NULL-keyed left rows ride, to the chip NULL
+    hashes to, and come out. ``<label>.total`` therefore counts, for an
+    anti join over a mesh, rows that a ``Filter`` kept; on one chip it
+    cannot tell them from the dropped ones (``Join``).
+
+    A mask join's output is the landed left rows under the occupied slots
+    as their row mask; an inner join's is ``capacity`` (the resolved
+    ``out_rows``) rows A CHIP, ``<label>.overflowed`` where any chip found
+    more. ``total``, ``build_rows`` and the shuffles' rows are the whole
+    request's, summed over the chips."""
+    from spark_rapids_jni_tpu.ops.join import apply_join_maps, key_valid
+    from spark_rapids_jni_tpu.parallel.distributed import shuffled_join
+    from spark_rapids_jni_tpu.parallel.wire import shuffle_wire_bytes
+
+    def anywhere(flag):
+        return jax.lax.psum(jnp.asarray(flag).astype(jnp.int32), axis) > 0
+
+    def of_all(count):
+        return jax.lax.psum(jnp.asarray(count, jnp.int64), axis)
+
+    chips = jax.lax.axis_size(axis)
+    mask = node.how in _MASK_JOINS
+    lkeys, rkeys = list(node.left_on), list(node.right_on)
+    if mask:
+        rtbl = Table([rtbl.column(k) for k in rkeys])
+        rkeys = list(range(len(rkeys)))
+
+    def riders(tbl, keys, rv, alive, keyed):
+        send = key_valid(tbl, keys, rv) if keyed else (
+            jnp.ones((tbl.num_rows,), jnp.bool_) if rv is None else rv)
+        return send if alive is None else send & alive
+
+    lsend = riders(ltbl, lkeys, lrv, llive, node.how != "left_anti")
+    rsend = riders(rtbl, rkeys, rrv, rlive, True)
+    sj = shuffled_join(ltbl, rtbl, lkeys, rkeys, axis, node.how,
+                       None if mask else capacity,
+                       left_row_valid=lsend, right_row_valid=rsend)
+    ls, rs, joined = sj
+    if mask:
+        out = (_null_all(ls.table, joined.keep), ls.row_valid)
+        facts = [("total", of_all(joined.total)),
+                 ("build_rows", of_all(joined.build_rows)),
+                 ("key_narrowed", anywhere(joined.key_narrowed))]
+    else:
+        with jax.named_scope("gather_rows"):
+            out = (apply_join_maps(ls.table, rs.table, joined), None)
+        facts = [("total", of_all(joined.total)),
+                 ("build_rows", of_all(jnp.sum(rsend, dtype=jnp.int64))),
+                 ("overflowed", anywhere(joined.total > capacity)),
+                 ("probe_compacted", anywhere(joined.probe_compacted))]
+    with jax.named_scope("exchange"):
+        sent = [(ltbl, jnp.sum(lsend, dtype=jnp.int64), ls),
+                (rtbl, jnp.sum(rsend, dtype=jnp.int64), rs)]
+        facts += [
+            ("shuffle_rows", of_all(sent[0][1] + sent[1][1])),
+            # what the two all_to_alls carry between chips (a chip keeps
+            # its own share): a fact of the schemas and the capacities,
+            # known when the region is traced, as the next is
+            ("shuffle_bytes", jnp.asarray(sum(
+                (chips - 1) * shuffle_wire_bytes(
+                    tbl, None, sh.row_valid.shape[0] // chips,
+                    chips)["wire_bytes"] for tbl, _, sh in sent), jnp.int64)),
+            # the slots of the receive buffers, both sides, every chip
+            ("shuffle_capacity", jnp.asarray(
+                chips * sum(sh.row_valid.shape[0] for _, _, sh in sent),
+                jnp.int64)),
+            # the bytes of the columns that rode, and a validity byte each,
+            # of every row that entered a shuffle
+            ("shuffle_read_bytes", of_all(sum(
+                rows * sum(_column_row_bytes(c) + 1 for c in tbl.columns)
+                for tbl, rows, _ in sent))),
+            ("shuffle_overflowed", anywhere(ls.overflowed | rs.overflowed)),
+        ]
+    return out, facts, (joined.keep if mask else None)
 
 
 def _bindings_mesh(bindings: dict, names: list) -> Optional[tuple]:
@@ -1571,6 +1711,12 @@ def execute(plan: Plan, bindings: dict, *,
     over = _bindings_mesh(bindings, bucketed)
     placement = _mesh_placement(nodes, resolved) if over else None
     side_keys = _side_keys(nodes, placement)
+    # of the region run over the mesh: a join that lays rows out there has
+    # room for ``out_rows`` on every chip
+    mesh_meta = {
+        f"{n.label}.capacity": resolved[id(n)] * int(over[0].shape[over[1]])
+        for n in nodes if placement is not None and isinstance(n, Join)
+        and n.how not in _MASK_JOINS and placement[id(n)] == SHARDED}
 
     def _staged_eval() -> FusedResult:
         # the staged reference path (the bit-identity oracle): the same
@@ -1701,6 +1847,7 @@ def execute(plan: Plan, bindings: dict, *,
         value = _slice_to(value, int(true_rows[root_space]))
     meta = _side_meta(zip(side_keys, side_vals))
     meta.update(static_meta)
+    meta.update(mesh_meta)
     _harvest_rtfilter(plan, nodes, meta)
     return FusedResult(value, meta)
 
@@ -1718,9 +1865,14 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
     can emit alone (``join.probe_compacted``: a fact of the data as
     well), how many outgrew their room
     (``join.overflowed``) and the true totals of those
-    (``join.overflow_rows``), groups, what a groupby lowered over a mesh
-    shuffled (exchanges, the partial rows it sent and the bytes its
-    ``all_to_all`` put between chips), and how many nodes broke what the plan
+    (``join.overflow_rows``), groups, what the groupbys and joins lowered
+    over a mesh shuffled (``shuffle.exchanges``: one a groupby, two a join;
+    the rows sent and the bytes the ``all_to_all``s put between chips; of a
+    join's exchanges also the slots of the receive buffers summed over the
+    chips, ``shuffle.capacity_rows``, the bytes of the columns that rode and
+    a validity byte each of every row sent, ``shuffle.read_bytes``, and how
+    many joins had a shuffle drop rows, ``shuffle.overflowed``), and how
+    many nodes broke what the plan
     declares: a dense primary key that is not one (``pk_violation``), a
     group bound or a join's capacity that was too small (``overflowed``),
     a key outside its declared range (``key_out_of_range``); the real rows
@@ -1756,6 +1908,8 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
              "groupby.rows_in": 0, "groupby.read_bytes": 0,
              "groupby.capacity_groups": 0,
              "shuffle.exchanges": 0, "shuffle.rows": 0, "shuffle.bytes": 0,
+             "shuffle.capacity_rows": 0, "shuffle.read_bytes": 0,
+             "shuffle.overflowed": 0,
              "filter.rows_in": 0, "filter.rows_kept": 0,
              "strings.like_bytes": 0, "sort.prefix_sorted": 0}
     nodes = _topo(plan.root)
@@ -1784,6 +1938,17 @@ def meta_facts(plan: Plan, meta: dict) -> dict:
                 facts["join.overflow_rows"] += int(total)
             facts["join.pk_violation"] += bool(
                 meta.get(f"{node.label}.pk_violation", False))
+            if f"{node.label}.shuffle_rows" in meta:
+                # lowered over a mesh: an all_to_all a side
+                facts["shuffle.exchanges"] += 2
+                for fact, field in (("rows", "shuffle_rows"),
+                                    ("bytes", "shuffle_bytes"),
+                                    ("capacity_rows", "shuffle_capacity"),
+                                    ("read_bytes", "shuffle_read_bytes")):
+                    facts[f"shuffle.{fact}"] += int(
+                        meta[f"{node.label}.{field}"])
+                facts["shuffle.overflowed"] += bool(
+                    meta[f"{node.label}.shuffle_overflowed"])
         elif isinstance(node, GroupBy):
             groups = meta.get(f"{node.label}.num_groups")
             if groups is not None:

@@ -1081,9 +1081,12 @@ class QueryServer:
         ``groupby.read_bytes``, ``groupby.capacity_groups``,
         ``sort.prefix_sorted``,
         ``join.pk_violation``, ``groupby.overflowed``,
-        ``groupby.key_out_of_range``, and of a groupby lowered over a mesh
-        ``shuffle.exchanges``, ``shuffle.rows`` and ``shuffle.bytes``: the
-        served path's shuffle telemetry), and refuse a result that broke what its plan declares:
+        ``groupby.key_out_of_range``, and of a groupby or a join lowered
+        over a mesh ``shuffle.exchanges``, ``shuffle.rows``,
+        ``shuffle.bytes``, ``shuffle.capacity_rows``, ``shuffle.read_bytes``
+        and ``shuffle.overflowed``: the served path's shuffle telemetry), and
+        refuse a result that broke what its plan declares, or whose join's
+        exchange found more rows for a chip than its buffer has slots:
         rows were dropped or merged, so it must not resolve as a success."""
         if not meta:
             return
@@ -1095,6 +1098,13 @@ class QueryServer:
                 f"plan {plan.name!r}: a join found more rows than its "
                 f"out_rows has room for; the result is not the query's "
                 f"answer", rows=facts["join.overflow_rows"])
+        if facts["shuffle.overflowed"]:
+            raise resilience.CapacityOverflow(
+                f"plan {plan.name!r}: a join's exchange over the mesh found "
+                f"more rows for a chip than its receive buffer has slots a "
+                f"sender (skewed keys); rows were dropped, the result is not "
+                f"the query's answer", rows=facts["shuffle.rows"],
+                capacity=facts["shuffle.capacity_rows"])
         if facts["groupby.overflowed"]:
             raise resilience.CapacityOverflow(
                 f"plan {plan.name!r}: a groupby found more groups than its "

@@ -272,6 +272,41 @@ def test_the_cell_runs_across_the_mesh_on_the_cpu():
         2 + 8 * 8 + 1 + 10)   # 3 of 4 slices of 4 x 64 slots, a row + masks
 
 
+def test_the_q4_cell_exchanges_rows_on_the_cpu():
+    """The shuffled-join cell crossed chips: every request exchanged both
+    sides of its join, the bytes the schema and the capacity give."""
+    from benchmark import harness
+    from spark_rapids_jni_tpu.utils.config import reset_option
+
+    before = REGISTRY.counters()
+    try:
+        result = harness.run_cell(
+            "q4_shuffled_join_4chip", 2**31 + 29, 0.5, False,
+            platform="cpu", sizes={"orders": 4096, "lineitem": 16384},
+            say=lambda msg, flush=False: None)
+    finally:
+        for name in ("server.estimate_path", "rtfilter.path"):
+            reset_option(name)
+    after = REGISTRY.counters()
+    assert result["correct"] is True and result["failed"] == 0
+    requests = result["attempted"] + 1   # and the warm-up's
+
+    def moved(name):
+        return after.get(name, 0) - before.get(name, 0)
+
+    assert moved("shuffle.exchanges") == 2 * requests
+    assert moved("fusion.regions") == requests
+    assert moved("cache.hit") == 0 == moved("shuffle.overflowed")
+    # a chip holds 1,024 orders and 4,096 lineitems: 512 and 2,048 slots a
+    # destination, 4 destinations; 3 of 4 slices leave the chip, from 4
+    # chips; an orders slot is its 31 bytes, 3 validity bytes and the
+    # occupied byte, a lineitem slot the key, its validity, the occupied
+    assert moved("shuffle.bytes") == requests * 3 * 4 * (
+        512 * (31 + 3 + 1) + 2048 * (8 + 1 + 1))
+    assert moved("shuffle.capacity_rows") == requests * 4 * 4 * (512 + 2048)
+    assert 0 < moved("shuffle.rows") < moved("shuffle.capacity_rows")
+
+
 def test_admission_is_per_chip(mesh):
     """The budget is one chip's: a sharded table whose shard fits it is
     admitted though the whole would not be; one whose shard does not fit
@@ -328,6 +363,36 @@ def test_plans_without_a_lowering_run_as_before(mesh):
     sort = fusion.Plan("s", fusion.Sort(fusion.Scan("lineitem"), (6,)))
     nodes = fusion._topo(sort.root)
     assert fusion._mesh_placement(nodes, {}) is None
+    # q4: sharded up to and including the join, whole from the groupby
+    nodes = fusion._topo(tpch._q4_plan().root)
+    place = fusion._mesh_placement(nodes, fusion._resolve_statics(
+        nodes, {"orders": 4096, "lineitem": 16384}))
+    assert [(type(n).__name__, place[id(n)]) for n in nodes] == [
+        ("Scan", fusion.SHARDED), ("Filter", fusion.SHARDED),
+        ("Scan", fusion.SHARDED), ("Filter", fusion.SHARDED),
+        ("Join", fusion.SHARDED), ("GroupBy", fusion.WHOLE),
+        ("Sort", fusion.WHOLE)]
+    # an outer join (a NULL-keyed row has to come out), a join with one
+    # whole side and a limit of sharded rows are still without a lowering
+    count = ((0, "count"),)
+
+    def counted(child):
+        nodes = fusion._topo(fusion.GroupBy(child, (0,), count,
+                                            max_groups=64, label="g"))
+        return fusion._mesh_placement(nodes, fusion._resolve_statics(
+            nodes, {"lineitem": 4096, "orders": 1024}))
+
+    scans = fusion.Scan("orders"), fusion.Scan("lineitem")
+    for how in ("inner", "left_semi", "left_anti"):
+        assert counted(fusion.Join(*scans, (0,), (0,), 64, how=how,
+                                   label="j")) is not None
+    for how in ("left", "right", "full"):
+        assert counted(fusion.Join(*scans, (0,), (0,), 64, how=how,
+                                   label="j")) is None
+    whole = fusion.GroupBy(scans[1], (0,), count, max_groups=64, label="w")
+    assert counted(fusion.Join(scans[0], whole, (0,), (0,), 64,
+                               label="j")) is None
+    assert counted(fusion.Limit(scans[0], 10)) is None
 
 
 def test_bounded_domain_groupby_lowers_as_partial_and_psum(mesh):
