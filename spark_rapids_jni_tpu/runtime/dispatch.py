@@ -16,6 +16,23 @@ not on the op, one an exact row count (a copy: a fraction of a second to
 compile); ``dispatch.pad.jitted`` / ``.passthrough`` count the calls whose
 rows went through it and those whose rows all sat on their bucket.
 
+What crosses the boundary between the pad and the op's executable: every
+leaf at its bucket's rows in its own dtype, but a 64-bit INTEGER leaf of a
+group off its bucket (a ``decimal64`` / ``bigint`` / timestamp Column's
+data, a bare ``int64`` / ``uint64`` array, any trailing shape) as two
+``uint32`` planes, low word and high word (``_Words``). XLA's TPU compiler
+computes 64-bit integers as pairs of 32-bit words and converts an int64
+buffer at an executable's boundary (``X64SplitLow`` / ``X64SplitHigh`` on
+the way in, each a pass over the buffer, ``X64Combine`` on the way out);
+words that leave the pad as words cost it no combine, and the executable
+``call`` compiles assembles them as its first operation (``(hi << 32) |
+lo``, which the compiler folds into the consumers: nothing is written), so
+no int64 buffer stands between the two. ``float64`` is left alone (the
+chip holds it as a float32 pair and cannot bitcast it), a group ON its
+bucket is handed on as the caller's own buffers, and the inline fallbacks
+see the caller's arrays: the op's ``fn`` gets the same pytree either way.
+``dispatch.pad.word_leaves`` counts the leaves handed on as words.
+
 Compilation is explicit — ``jax.jit(fn).lower(args).compile()`` — rather
 than delegated to jit's internal cache, so compiles and hits are exact,
 countable events (telemetry counters ``dispatch.compile`` /
@@ -50,7 +67,7 @@ import math
 import threading
 import time
 import warnings
-from functools import partial
+from functools import wraps
 from typing import Any, Callable, Iterator, Optional, Sequence
 
 import jax
@@ -185,14 +202,66 @@ def _zero_tail(x: jax.Array, B: int) -> jax.Array:
         jnp.zeros((B,) + tuple(x.shape[1:]), x.dtype), x, (0,) * x.ndim)
 
 
-def _pad_array(x: Any, n: int, B: int, acc: _PadStats) -> Any:
+class _Words:
+    """A 64-bit integer array as the two ``uint32`` planes the chip computes
+    in: ``lo`` the low words, ``hi`` the high words, ``dtype`` the array's
+    own (``int64`` / ``uint64``). A pytree node, so a Column holds one in
+    place of its ``data`` from the pad to the op's executable; ``shape`` and
+    ``ndim`` are the array's, which is all a Column's constructor asks."""
+
+    __slots__ = ("lo", "hi", "dtype")
+
+    def __init__(self, lo: Any, hi: Any, dtype: Any) -> None:
+        self.lo, self.hi, self.dtype = lo, hi, np.dtype(dtype)
+
+    shape = property(lambda self: self.lo.shape)
+    ndim = property(lambda self: self.lo.ndim)
+
+    def join(self) -> jax.Array:
+        """The array itself. XLA's TPU compiler folds this into whatever
+        reads it: no pass, no buffer."""
+        return ((self.hi.astype(self.dtype) << 32)
+                | self.lo.astype(self.dtype))
+
+
+jax.tree_util.register_pytree_node(
+    _Words, lambda w: ((w.lo, w.hi), w.dtype),
+    lambda dtype, planes: _Words(*planes, dtype))
+
+
+def _is_words(x: Any) -> bool:
+    return isinstance(x, _Words)
+
+
+def _has_words(x: Any) -> bool:
+    """Whether the pad hands leaf ``x`` of a group off its bucket on as
+    words: the 64-bit integers (never ``float64``, which the chip holds as
+    a float32 pair it cannot bitcast)."""
+    dtype = np.dtype(x.dtype)
+    return dtype.kind in "iu" and dtype.itemsize == 8
+
+
+def _join_words(tree: Any) -> Any:
+    """``tree`` with every ``_Words`` of it assembled."""
+    return jax.tree_util.tree_map(
+        lambda x: x.join() if _is_words(x) else x, tree, is_leaf=_is_words)
+
+
+def _pad_array(x: Any, n: int, B: int, acc: _PadStats,
+               words: bool = False) -> Any:
     row_bytes = _row_bytes(x, n)
     acc.padded_bytes += (B - n) * row_bytes
     acc.total_bytes += B * row_bytes
     if B == n:
         return jnp.asarray(x)
     acc.copied_bytes += B * row_bytes
-    return _zero_tail(jnp.asarray(x), B)
+    x = jnp.asarray(x)
+    if words and _has_words(x):
+        # an arithmetic shift for int64, a logical one for uint64: the
+        # narrowing keeps the high word's bits either way
+        return _Words(_zero_tail(x.astype(jnp.uint32), B),
+                      _zero_tail((x >> 32).astype(jnp.uint32), B), x.dtype)
+    return _zero_tail(x, B)
 
 
 def _check_column(col: Column, n: int) -> None:
@@ -206,9 +275,10 @@ def _check_column(col: Column, n: int) -> None:
 
 
 def _pad_column(col: Column, n: int, B: int, acc: _PadStats,
-                fills: Optional[Iterator] = None) -> Column:
+                fills: Optional[Iterator] = None,
+                words: bool = False) -> Column:
     _check_column(col, n)
-    data = _pad_array(col.data, n, B, acc)
+    data = _pad_array(col.data, n, B, acc, words)
     if fills is not None:
         # on its bucket: the validity it has, or a ready all-true mask
         validity = col.validity if col.validity is not None else next(fills)
@@ -224,46 +294,51 @@ def _pad_column(col: Column, n: int, B: int, acc: _PadStats,
 
 
 def _pad_tree(x: Any, n: int, B: int, acc: _PadStats,
-              fills: Optional[Iterator] = None) -> Any:
+              fills: Optional[Iterator] = None, words: bool = False) -> Any:
     """``x`` with every leaf padded from ``n`` to ``B`` rows. ``fills`` (only
     with ``B == n``, on the host): every leaf is handed on as it is and a
-    Column without a validity takes the next mask of ``fills``."""
+    Column without a validity takes the next mask of ``fills``. ``words``
+    (only ``_pad_groups`` sets it, only with ``B != n``): a 64-bit integer
+    leaf comes out as its two padded ``uint32`` planes, a ``_Words``."""
     if x is None:
         return None
     if isinstance(x, Column):
-        return _pad_column(x, n, B, acc, fills)
+        return _pad_column(x, n, B, acc, fills, words)
     if isinstance(x, Table):
-        return Table([_pad_column(c, n, B, acc, fills) for c in x.columns])
+        return Table([_pad_column(c, n, B, acc, fills, words)
+                      for c in x.columns])
     if _is_array(x):
-        return _pad_array(x, n, B, acc)
+        return _pad_array(x, n, B, acc, words)
     if isinstance(x, tuple):
-        vals = [_pad_tree(v, n, B, acc, fills) for v in x]
+        vals = [_pad_tree(v, n, B, acc, fills, words) for v in x]
         return type(x)(*vals) if hasattr(x, "_fields") else tuple(vals)
     if isinstance(x, list):
-        return [_pad_tree(v, n, B, acc, fills) for v in x]
+        return [_pad_tree(v, n, B, acc, fills, words) for v in x]
     if isinstance(x, dict):
-        return {k: _pad_tree(v, n, B, acc, fills) for k, v in x.items()}
+        return {k: _pad_tree(v, n, B, acc, fills, words)
+                for k, v in x.items()}
     raise Unbucketable(f"non-array leaf {type(x).__name__}")
 
 
 def _survey(group: Any, n: int) -> tuple:
     """What the host reads off one ``n``-row group before the pad runs:
-    ``(bytes a row of its data leaves, Columns without a validity)``. The
-    data leaves are what ``_pad_array`` copies (a Column's data and chars, a
-    bare array; no mask). Raises ``Unbucketable`` for what ``_pad_tree``
-    refuses, so such a group costs neither a trace nor a compile."""
-    row_bytes = bare = 0
+    ``(bytes a row of its data leaves, Columns without a validity, data
+    leaves that are 64-bit integers)``. The data leaves are what
+    ``_pad_array`` copies (a Column's data and chars, a bare array; no
+    mask). Raises ``Unbucketable`` for what ``_pad_tree`` refuses, so such a
+    group costs neither a trace nor a compile."""
+    row_bytes = bare = wide = 0
     for x in jax.tree_util.tree_leaves(
             group, is_leaf=lambda v: isinstance(v, Column)):
         if isinstance(x, Column):
             _check_column(x, n)
             bare += x.validity is None
-            row_bytes += _row_bytes(x.data, n)
             if x.chars is not None:
                 row_bytes += _row_bytes(x.chars, n)
-        else:
-            row_bytes += _row_bytes(x, n)
-    return row_bytes, bare
+            x = x.data
+        row_bytes += _row_bytes(x, n)
+        wide += _has_words(x)
+    return row_bytes, bare, wide
 
 
 def _slice_column(col: Column, n: int, B: int) -> Column:
@@ -544,28 +619,35 @@ def _inline(op: str, reason: str, fn: Callable, row_args: tuple,
 
 def _pad_groups(row_args: tuple, ns: tuple, buckets: tuple) -> tuple:
     """The bucketed pad of :func:`call`: ``(padded groups, row_valids, bytes
-    a row of each group's data leaves)``, the first two from ONE cached
-    executable: one host call whatever the number of leaves. A group off
-    its bucket goes through it whole (``_pad_tree``, traced once). A group
+    a row of each group's data leaves, leaves handed on as words)``, the
+    first two from ONE cached executable: one host call whatever the number
+    of leaves. A group off its bucket goes through it whole (``_pad_tree``,
+    traced once) and leaves it with every 64-bit integer leaf as a
+    ``_Words``, two bucket-sized ``uint32`` planes and no int64 buffer (the
+    executable of :func:`call` assembles them; ``_join_words`` gives the
+    tree an eager pad would); every other leaf in its own dtype. A group
     on its bucket stays out of its data path: its leaves are handed on as
-    they are (a jit would copy them, and ``donate_rows`` relies on the
-    alias) and the executable builds only its masks, an all-true validity
-    for each of its Columns without one among them. Keyed on what a pad
-    depends on and nothing else: the groups' signature, the row counts, the
-    buckets and the backend, not the op, so two ops over one column share
-    it. One executable an exact row count: it is a copy and compiles in a
-    fraction of a second. Raises ``Unbucketable`` for what cannot be
-    padded, and what compiling or running it raises."""
+    they are, int64 and all (a jit would copy them, a split there would be a
+    new pass, and ``donate_rows`` relies on the alias) and the executable
+    builds only its masks, an all-true validity for each of its Columns
+    without one among them. Keyed on what a pad depends on and nothing
+    else: the groups' signature, the row counts, the buckets and the
+    backend, not the op, so two ops over one column share it. One
+    executable an exact row count: it is a copy and compiles in a fraction
+    of a second. Counts ``dispatch.pad.word_leaves``. Raises
+    ``Unbucketable`` for what cannot be padded, and what compiling or
+    running it raises."""
     surveyed = tuple(_survey(g, n) for g, n in zip(row_args, ns))
     off = tuple(g if B != n else None
                 for g, n, B in zip(row_args, ns, buckets))
     bare = tuple(k if B == n else 0
-                 for (_, k), n, B in zip(surveyed, ns, buckets))
+                 for (_, k, _), n, B in zip(surveyed, ns, buckets))
 
     def pad(groups):   # traced once, by the call that compiles it
         unread = _PadStats()   # call reckons the bytes from the shapes
-        padded = tuple(None if g is None else _pad_tree(g, n, B, unread)
-                       for g, n, B in zip(groups, ns, buckets))
+        padded = tuple(
+            None if g is None else _pad_tree(g, n, B, unread, words=True)
+            for g, n, B in zip(groups, ns, buckets))
         row_valids = tuple(jnp.arange(B, dtype=jnp.int32) < jnp.int32(n)
                            for n, B in zip(ns, buckets))
         fills = tuple(tuple(jnp.ones((n,), jnp.bool_) for _ in range(k))
@@ -583,7 +665,23 @@ def _pad_groups(row_args: tuple, ns: tuple, buckets: tuple) -> tuple:
             padded[i] = _pad_tree(group, n, n, _PadStats(), iter(fill))
     REGISTRY.counter("dispatch.pad.jitted" if any(
         g is not None for g in off) else "dispatch.pad.passthrough").inc()
-    return tuple(padded), row_valids, tuple(row for row, _ in surveyed)
+    word_leaves = sum(wide for (_, _, wide), n, B in zip(
+        surveyed, ns, buckets) if B != n)
+    REGISTRY.counter("dispatch.pad.word_leaves").inc(word_leaves)
+    return (tuple(padded), row_valids,
+            tuple(row for row, _, _ in surveyed), word_leaves)
+
+
+def _on_words(fn: Callable) -> Callable:
+    """``fn`` as :func:`call` compiles it: behind the assembly of the words
+    the pad handed on, under ``fn``'s own name (jit names the device module
+    after it: ``jit_region_<plan>``). ``fn`` gets the pytree it gets
+    inline."""
+    @wraps(fn)
+    def on_words(row_args, aux_args, row_valids):
+        return fn(_join_words(row_args), aux_args, row_valids)
+
+    return on_words
 
 
 def call(
@@ -609,7 +707,13 @@ def call(
 
     ``fn(row_args, aux_args, row_valids)`` — ``row_valids`` is one
     bool[bucket] mask per group (True = real row), or None on the inline
-    path. ``slice_rows`` trims bucket-sized leading dimensions of the
+    path. What the compiled executable takes is not quite what ``fn``
+    sees: a 64-bit integer leaf of a group off its bucket arrives as two
+    ``uint32`` planes (``_pad_groups``) and is assembled in front of ``fn``
+    (``_on_words``: ``(hi << 32) | lo``, folded into its consumers), so
+    ``fn`` gets the same Columns, Tables and arrays on every path, and the
+    cache keys itself on the planes through ``_signature``.
+    ``slice_rows`` trims bucket-sized leading dimensions of the
     output back to group 0's true row count. ``bucket_rows=False`` keeps
     exact shapes (pure executable memoization, no padding) for ops whose
     semantics cannot absorb padded rows.
@@ -644,9 +748,10 @@ def call(
 
     buckets = tuple(bucket_for(n) for n in ns) if bucket_rows else ns
     try:
-        with spans.child("dispatch.pad", op=op):
-            padded, row_valids, row_bytes = _pad_groups(
+        with spans.child("dispatch.pad", op=op) as pad_span:
+            padded, row_valids, row_bytes, word_leaves = _pad_groups(
                 row_args, ns, buckets)
+            pad_span.annotate(word_leaves=word_leaves)
     except Unbucketable:
         return _inline(op, "unbucketable", fn, row_args, aux_args)
     except Exception:
@@ -661,8 +766,8 @@ def call(
     if entry is None:
         def _compile():
             faults.fire("dispatch.compile", 0, op=op)
-            jitted = (jax.jit(fn, donate_argnums=(0,)) if donate_rows
-                      else jax.jit(fn))
+            jitted = jax.jit(_on_words(fn),
+                             donate_argnums=(0,) if donate_rows else ())
             with warnings.catch_warnings():
                 # backends without donation support (CPU) warn per
                 # donated buffer at lowering; the declaration is still
@@ -790,7 +895,10 @@ def pad_sharded(op: str, row_args: tuple, mesh, axis: str) -> tuple:
     shape (it is a copy and compiles in a fraction of a second); the region
     that takes its output is keyed on the bucket, as on one chip. Same
     span and counters as the one-chip pad; the byte counters are summed
-    over the chips. Raises ``Unbucketable`` for what cannot be padded."""
+    over the chips, and ``dispatch.pad.word_leaves`` moves by 0: a 64-bit
+    integer leaf leaves this pad as int64 (the assembly would sit in the
+    caller's ``shard_map`` step). Raises ``Unbucketable`` for what cannot be
+    padded."""
     from jax.sharding import PartitionSpec as P
 
     chips = int(mesh.shape[axis])
@@ -813,7 +921,7 @@ def pad_sharded(op: str, row_args: tuple, mesh, axis: str) -> tuple:
                              out_specs=P(axis))(groups)
 
     pad.__name__ = pad.__qualname__ = "pad_sharded"
-    with spans.child("dispatch.pad", op=op):
+    with spans.child("dispatch.pad", op=op, word_leaves=0):
         executable = compiled(
             "pad_sharded", pad, row_args,
             statics=(op, local, buckets, mesh_fingerprint(mesh)))
@@ -825,6 +933,7 @@ def pad_sharded(op: str, row_args: tuple, mesh, axis: str) -> tuple:
     REGISTRY.counter("dispatch.padded_waste_bytes").inc(chips * stats[0])
     REGISTRY.counter("dispatch.padded_copy_bytes").inc(chips * stats[1])
     REGISTRY.counter("dispatch.row_bytes_total").inc(chips * stats[2])
+    REGISTRY.counter("dispatch.pad.word_leaves").inc(0)
     return out
 
 

@@ -189,7 +189,10 @@ def test_one_bucket_compiles_exactly_once(rng):
     pad compiles eight times: it is one executable an exact row count (a
     copy, a fraction of a second each), as the eager ``zeros`` and
     ``concatenate`` it replaces compiled once a shape unseen by any
-    counter. 1024 sits on its bucket: its pad builds masks only."""
+    counter. 1024 sits on its bucket: its pad builds masks only, and its
+    int64 column is handed on as the caller's own buffer where the seven
+    off the bucket arrive as two uint32 planes, so the op compiles a second
+    time for it (one executable for each form of the boundary)."""
     counts = (513, 600, 649, 700, 801, 900, 1000, 1024)  # all -> bucket 1024
     results = []
     for n in counts:
@@ -199,11 +202,12 @@ def test_one_bucket_compiles_exactly_once(rng):
         assert bool(ok)
     assert results == [n * (n - 1) // 2 for n in counts]
     c = REGISTRY.counters("dispatch.")
-    assert c["dispatch.compile.reduce_sum"] == 1
-    assert c["dispatch.hit.reduce_sum"] == len(counts) - 1
+    assert c["dispatch.compile.reduce_sum"] == 2
+    assert c["dispatch.hit.reduce_sum"] == len(counts) - 2
     assert c["dispatch.compile.pad"] == len(counts)
     assert "dispatch.hit.pad" not in c
-    assert c["dispatch.compile"] == 1 + len(counts)
+    assert c["dispatch.compile"] == 2 + len(counts)
+    assert c["dispatch.pad.word_leaves"] == len(counts) - 1
     assert (c["dispatch.pad.jitted"], c["dispatch.pad.passthrough"]) == (
         len(counts) - 1, 1)
 
@@ -438,16 +442,22 @@ _PAD_CASES = {
 @pytest.mark.parametrize("case", sorted(_PAD_CASES))
 def test_jitted_pad_equals_the_eager_pad_leaf_for_leaf(case):
     """``_pad_groups`` (one executable) against ``_pad_tree`` run eagerly,
-    the pad as it was: the same tree, every leaf the same shape, dtype and
-    bits, the same masks; the bytes ``call`` reckons from the shapes are
-    those the eager pad counted; a group on its bucket keeps its buffers."""
+    the pad as it was: once the words of its 64-bit integer leaves are
+    assembled (``_join_words``, what ``call``'s executable does first) the
+    same tree, every leaf the same shape, dtype and bits, the same masks;
+    the bytes ``call`` reckons from the shapes are those the eager pad
+    counted; a group on its bucket keeps its buffers."""
     import jax
 
     row_args = _PAD_CASES[case]()
     ns = tuple(dispatch._group_rows(g) for g in row_args)
     buckets = tuple(dispatch.bucket_for(n) for n in ns)
-    padded, row_valids, row_bytes = dispatch._pad_groups(
+    handed, row_valids, row_bytes, word_leaves = dispatch._pad_groups(
         row_args, ns, buckets)
+    for out, n in zip(handed, ns):   # the planes' tails are zeros too
+        for got in jax.tree_util.tree_leaves(out):
+            assert not np.asarray(got)[n:].any()
+    padded = dispatch._join_words(handed)
 
     acc = dispatch._PadStats()
     want = tuple(dispatch._pad_tree(g, n, B, acc)
@@ -480,7 +490,11 @@ def test_jitted_pad_equals_the_eager_pad_leaf_for_leaf(case):
     on_bucket = all(n == B for n, B in zip(ns, buckets))
     c = REGISTRY.counters("dispatch.pad.")
     assert c == {"dispatch.pad.passthrough" if on_bucket
-                 else "dispatch.pad.jitted": 1}
+                 else "dispatch.pad.jitted": 1,
+                 "dispatch.pad.word_leaves": word_leaves}
+    assert word_leaves == sum(
+        dispatch._is_words(x) for x in jax.tree_util.tree_leaves(
+            handed, is_leaf=dispatch._is_words))
     assert REGISTRY.counter("dispatch.compile.pad").value == 1
     # again: the same executable, whatever the values
     dispatch._pad_groups(row_args, ns, buckets)
@@ -544,6 +558,230 @@ def test_a_pad_that_fails_runs_the_op_inline(rng, monkeypatch):
     c = REGISTRY.counters("dispatch.")
     assert (c["dispatch.pad_error"], c["dispatch.inline.pad_error"]) == (1, 1)
     assert c["dispatch.pad.jitted"] == 1   # the first call's
+
+
+# ---------------------------------------------------------------------------
+# 4b. the boundary: a 64-bit integer leaf off its bucket crosses as words
+# ---------------------------------------------------------------------------
+
+# the bits a split or an assembly could lose: both ends of the range, a low
+# word of 2**31 and over (a sign the narrowing must not extend), a high word
+# alone, both words full
+_EDGES = np.array(
+    [0, -1, 1, np.iinfo(np.int64).min, np.iinfo(np.int64).max,
+     2**31, 2**31 + 5, 2**32 - 1, 2**32, -2**32, 2**63 - 2**32,
+     -2**31, -2**31 - 1, 0x7FFFFFFF80000000, -0x7FFFFFFF80000000,
+     0x123456789ABCDEF], dtype=np.int64)
+
+
+def _edge_values(n, dtype=np.int64):
+    vals = np.resize(_EDGES, n)
+    vals[len(_EDGES):] ^= np.arange(n - len(_EDGES), dtype=np.int64) << 29
+    return vals.view(dtype) if dtype != np.int64 else vals
+
+
+def _edge_col(dtype, nulls, n=21, storage=np.int64):
+    validity = (np.arange(n) % 5 != 3) if nulls else None
+    return Column.from_numpy(_edge_values(n, storage), dtype,
+                             validity=validity)
+
+
+def _device(values):
+    import jax.numpy as jnp
+
+    return jnp.asarray(values)
+
+
+# name -> (the row group, how many of its leaves cross as words)
+_WORD_CASES = {
+    "int64": lambda: (_edge_col(t.INT64, False), 1),
+    "int64_with_validity": lambda: (_edge_col(t.INT64, True), 1),
+    "uint64": lambda: (_edge_col(t.UINT64, False, storage=np.uint64), 1),
+    "uint64_with_validity": lambda: (
+        _edge_col(t.UINT64, True, storage=np.uint64), 1),
+    "decimal64": lambda: (_edge_col(t.decimal64(-2), False), 1),
+    "decimal64_with_validity": lambda: (_edge_col(t.decimal64(-2), True), 1),
+    "timestamp": lambda: (_edge_col(t.TIMESTAMP_MICROSECONDS, False), 1),
+    "timestamp_with_validity": lambda: (
+        _edge_col(t.TIMESTAMP_MICROSECONDS, True), 1),
+    "bare_int64_array": lambda: (_edge_values(33), 1),
+    "trailing_dimension": lambda: (_edge_values(40).reshape(20, 2), 1),
+    "decimal128_limbs": lambda: (Column(
+        t.decimal128(-2), _device(_edge_values(40).reshape(20, 2))), 1),
+    "table": lambda: (Table([
+        _edge_col(t.INT64, True), _edge_col(t.decimal64(-3), False),
+        Column.from_numpy(_ints(21, np.int32)),
+        _edge_col(t.UINT64, False, storage=np.uint64)]), 3),
+}
+
+# leaves that must cross in their own dtype, bit for bit
+_NARROW_CASES = {
+    "float64": lambda: np.linspace(-1e300, 1e300, 21),
+    "int32": lambda: _ints(21, np.int32),
+    "int8": lambda: _ints(21, np.int8),
+    "bool": lambda: np.arange(21) % 3 == 0,
+    "uint32": lambda: _ints(21, np.int64).astype(np.uint32),
+    "padded_string": lambda: _strings(19),
+}
+
+
+def _probe(rows, aux, row_valids):
+    """What ``fn`` sees, and something computed from it."""
+    import jax
+
+    return rows, jax.tree_util.tree_map(
+        lambda x: x * 3 + 1 if x.dtype.kind in "iu" else x, rows)
+
+
+def _same_bits(got, want):
+    """Two row groups (a Column, a Table of them or one array) hold the same
+    dtypes, shapes and bits; a Column without a validity equals one whose
+    mask is all true (the pad always writes one)."""
+    def columns(x):
+        return x.columns if isinstance(x, Table) else [x]
+
+    for g, w in zip(columns(got), columns(want)):
+        pairs = [(g, w)]
+        if isinstance(w, Column):
+            assert g.dtype == w.dtype
+            pairs = [(g.data, w.data), (g.valid_mask(), w.valid_mask())]
+            if w.chars is not None:
+                pairs.append((g.chars, w.chars))
+        for a, b in pairs:
+            a, b = np.asarray(a), np.asarray(b)
+            assert (a.shape, a.dtype) == (b.shape, b.dtype)
+            assert np.array_equal(a, b)
+
+
+def _executable_arguments(op):
+    """The leaves of the row groups the executable cached for ``op`` was
+    compiled over: ``(dtype, id)`` of what crossed the boundary."""
+    (key,) = [k for k in dispatch._EXEC_CACHE if k[0] == op]
+    return key[3][1]
+
+
+@pytest.mark.parametrize("case", sorted(_WORD_CASES))
+def test_words_cross_the_boundary_and_fn_sees_the_same_tree(case):
+    """``call`` over a group off its bucket: ``fn`` gets the pytree it gets
+    inline (the same tree, dtypes and bits, no ``_Words``), what it computes
+    is bit-identical to the inline path, the executable's own arguments
+    hold no 64-bit integer, and the counter says how many crossed."""
+    import jax
+
+    group, crossing = _WORD_CASES[case]()
+    (seen, computed), (seen_inline, computed_inline) = _both_paths(
+        lambda: dispatch.call("probe", _probe, (group,)))
+    assert seen_inline[0] is group   # the inline path: the caller's arrays
+    _same_bits(seen[0], group)
+    _same_bits(computed[0], computed_inline[0])
+    assert not any(dispatch._is_words(x) for x in jax.tree_util.tree_leaves(
+        seen, is_leaf=dispatch._is_words))
+    dtypes = [d for _, d, _ in _executable_arguments("probe")]
+    assert "int64" not in dtypes and "uint64" not in dtypes
+    assert dtypes.count("uint32") == 2 * crossing
+    assert REGISTRY.counter("dispatch.pad.word_leaves").value == crossing
+
+
+@pytest.mark.parametrize("case", sorted(_NARROW_CASES))
+def test_narrower_leaves_and_float64_cross_as_they_are(case):
+    """Only the 64-bit INTEGERS are split: a ``float64`` (a float32 pair on
+    the chip, no bitcast), every narrower leaf, a mask and a padded string
+    reach the executable in their own dtype, and nothing is counted."""
+    import jax
+
+    group = _NARROW_CASES[case]()
+    (seen, computed), (_, computed_inline) = _both_paths(
+        lambda: dispatch.call("probe", _probe, (group,)))
+    _same_bits(seen[0], group)
+    _same_bits(computed[0], computed_inline[0])
+    handed, _, _, word_leaves = dispatch._pad_groups(
+        (group,), (dispatch._group_rows(group),),
+        (dispatch.bucket_for(dispatch._group_rows(group)),))
+    assert word_leaves == 0
+    assert not any(dispatch._is_words(x) for x in jax.tree_util.tree_leaves(
+        handed, is_leaf=dispatch._is_words))
+    assert REGISTRY.counter("dispatch.pad.word_leaves").value == 0
+
+
+@pytest.mark.parametrize("donate", [False, True], ids=["kept", "donated"])
+def test_a_group_on_its_bucket_crosses_as_the_callers_int64(donate):
+    """On its bucket nothing is split (it would be a new pass) and nothing
+    copied: the executable takes the caller's own int64 buffer, which is
+    what ``donate_rows`` gives away; off its bucket, in the same bucket,
+    the op compiles a second executable over the planes."""
+    import jax.numpy as jnp
+
+    on = jnp.asarray(_edge_values(64))
+    taken = []
+
+    def fn(rows, aux, rvs):
+        taken.append(rows[0])
+        return rows[0] * 3
+
+    want = _edge_values(64) * 3
+    got = dispatch.call("on_bucket", fn, (on,), donate_rows=donate)
+    assert np.array_equal(np.asarray(got), want)
+    assert [d for _, d, _ in _executable_arguments("on_bucket")][0] == "int64"
+    c = REGISTRY.counters("dispatch.")
+    assert c["dispatch.pad.word_leaves"] == 0
+    assert c["dispatch.pad.passthrough"] == 1
+    handed, _, _, _ = dispatch._pad_groups((on,), (64,), (64,))
+    assert handed[0] is on
+    got = dispatch.call("on_bucket", fn, (jnp.asarray(_edge_values(50)),),
+                        donate_rows=donate)
+    assert np.array_equal(np.asarray(got), _edge_values(50) * 3)
+    assert REGISTRY.counter("dispatch.compile.on_bucket").value == 2
+    assert REGISTRY.counter("dispatch.pad.word_leaves").value == 1
+    assert all(x.dtype == np.int64 for x in taken)   # fn never sees planes
+
+
+def test_the_pad_span_says_how_many_leaves_crossed_as_words(observed):
+    """``dispatch.pad.word_leaves`` is also an attribute of the span."""
+    from spark_rapids_jni_tpu.telemetry import spans
+
+    with spans.span("query.seam"):
+        dispatch.call("probe", _probe, (_WORD_CASES["table"]()[0],))
+        dispatch.call("probe", _probe, (_NARROW_CASES["int32"](),))
+    pads = _records(observed, "dispatch.pad")
+    assert [r["word_leaves"] for r in pads] == [3, 0]
+
+
+def test_the_compiled_module_keeps_the_ops_name():
+    """The assembly sits behind ``fn``'s own name: a trace tells
+    ``jit_region_<plan>`` from everything else by it."""
+    def region_q(rows, aux, rvs):
+        return rows[0] + 1
+
+    wrapped = dispatch._on_words(region_q)
+    assert (wrapped.__name__, wrapped.__qualname__) == (
+        region_q.__name__, region_q.__qualname__)
+    dispatch.call("named", region_q, (_edge_values(21),))
+    (entry,) = [v for k, v in dispatch._EXEC_CACHE.items() if k[0] == "named"]
+    assert "jit_region_q" in entry[0].as_text()[:200]
+
+
+def test_pad_sharded_hands_int64_on_as_int64():
+    """Over a mesh the pad is as it was: the region's ``shard_map`` step
+    takes int64, and the counter moves by 0 (but exists)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_rapids_jni_tpu.parallel.mesh import executor_mesh
+
+    mesh = executor_mesh(4)
+    n = 4 * 21
+    shard = NamedSharding(mesh, P("exec"))
+    col = Column(t.INT64, jax.device_put(_edge_values(n), shard),
+                 jax.device_put(np.arange(n) % 5 != 3, shard))
+    (padded,), (row_valid,) = dispatch.pad_sharded(
+        "probe", (Table([col]),), mesh, "exec")
+    data = padded.columns[0].data
+    assert data.dtype == np.int64 and data.shape == (4 * 32,)
+    got = np.asarray(data).reshape(4, 32)
+    assert np.array_equal(got[:, :21], _edge_values(n).reshape(4, 21))
+    assert not got[:, 21:].any()
+    assert REGISTRY.counters("dispatch.pad.") == {
+        "dispatch.pad.word_leaves": 0}
 
 
 # ---------------------------------------------------------------------------
